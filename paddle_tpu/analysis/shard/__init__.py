@@ -10,7 +10,7 @@ with its moved bytes computed from operand shapes/dtypes and checked
 against the `jit.introspect.AxisCollectiveBudget` table, and every
 declared PartitionSpec (`_tp_specs`, `pool_pspec()`, the adapter
 pool's `pool_pspecs()`) compared against the lowered module's actual
-`mhlo.sharding` attributes. It is the readiness gate for the pp/DCN
+`sdy.sharding` attributes. It is the readiness gate for the pp/DCN
 mesh axis of ROADMAP item 1: per-axis byte totals are drift-pinned in
 `SHARD_BASELINE.json` (TPU300), and the DCN-hostile rule (TPU305) is
 armed before the slow axis exists. `verify_shards` is the in-process

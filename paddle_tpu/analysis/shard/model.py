@@ -6,7 +6,7 @@ harvest record — tpu-shard deliberately harvests NOTHING itself):
 
 - `parse_main_shardings` reads the lowered StableHLO module's
   `@main` signature and returns, per argument and per result, the
-  tensor shape/dtype and the `mhlo.sharding` attribute decoded to
+  tensor shape/dtype and the `sdy.sharding` attribute decoded to
   per-dim partition COUNTS — the form actually compiled, which is why
   the rules run on lowered shardings and not on source PartitionSpecs
   (a pspec the lowering dropped is exactly the bug class TPU302/303
@@ -58,8 +58,16 @@ _MAIN_RE = re.compile(
     r"(?:\((?P<res>.*?)\)|(?P<res1>tensor<[^>]*>))\s*"
     r"(?:attributes\b[^{]*)?\{", re.S)
 _TENSOR_RE = re.compile(r"tensor<([0-9x]*)([A-Za-z][A-Za-z0-9]*)>")
-_SHARDING_RE = re.compile(r'mhlo\.sharding\s*=\s*"([^"]*)"')
-_DEVICES_RE = re.compile(r"devices=\[([0-9,]+)\]")
+# Shardy, the partitioner of the installed JAX: the module declares
+# `sdy.mesh @mesh = <["mp"=2]>` and each sharded value carries
+# `sdy.sharding = #sdy.sharding<@mesh, [{}, {"mp"}]>` — one `{...}`
+# per tensor dim listing the mesh axes that split it
+_MESH_RE = re.compile(r"sdy\.mesh\s+@(\w+)\s*=\s*<\[([^\]]*)\]")
+_AXIS_RE = re.compile(r'"([^"]+)"\s*=\s*(\d+)')
+_SHARDING_RE = re.compile(
+    r"sdy\.sharding\s*=\s*#sdy\.sharding<@(\w+),\s*\[([^\]]*)\]")
+_DIM_RE = re.compile(r"\{([^}]*)\}")
+_NAME_RE = re.compile(r'"([^"]+)"')
 
 
 class ShardParseError(ValueError):
@@ -84,22 +92,34 @@ def _parse_tensor(text):
     return shape, dtype, n
 
 
-def _parse_sharding(text):
-    """Decode one `mhlo.sharding` attribute value to per-dim partition
+def _parse_meshes(lowered_text):
+    """-> {mesh name: {axis name: size}} from the module's `sdy.mesh`
+    declarations."""
+    return {m.group(1): {a: int(n) for a, n in
+                         _AXIS_RE.findall(m.group(2))}
+            for m in _MESH_RE.finditer(lowered_text)}
+
+
+def _parse_sharding(text, meshes):
+    """Decode one `sdy.sharding` attribute to per-dim partition
     counts: () = replicated/maximal, (1, 1, 1, 2, 1) = dim 3 split in
     two. None when the entry carries no sharding attribute at all
     (unspecified — jit chose; host args look like this)."""
     m = _SHARDING_RE.search(text)
     if m is None:
         return None
-    val = m.group(1)
-    if "devices=" not in val:
-        return ()                      # {replicated} / {maximal ...}
-    counts = tuple(int(d) for d in
-                   _DEVICES_RE.search(val).group(1).split(","))
-    if "last_tile_dim_replicate" in val:
-        counts = counts[:-1]
-    return counts if any(c > 1 for c in counts) else ()
+    sizes = meshes.get(m.group(1), {})
+    counts = []
+    for dim in _DIM_RE.findall(m.group(2)):
+        n = 1
+        for axis in _NAME_RE.findall(dim):
+            if axis not in sizes:
+                raise ShardParseError(
+                    f"sharding names axis {axis!r} of an undeclared "
+                    f"mesh @{m.group(1)}")
+            n *= sizes[axis]
+        counts.append(n)
+    return tuple(counts) if any(c > 1 for c in counts) else ()
 
 
 def parse_main_shardings(lowered_text):
@@ -110,19 +130,21 @@ def parse_main_shardings(lowered_text):
     m = _MAIN_RE.search(lowered_text)
     if m is None:
         raise ShardParseError("no @main signature in lowered module")
+    meshes = _parse_meshes(lowered_text)
     args = []
     arg_text = m.group("args").strip()
     if arg_text:
         for part in re.split(r",\s*(?=%arg\d+\s*:)", arg_text):
             shape, dtype, nbytes = _parse_tensor(part)
-            args.append((shape, dtype, nbytes, _parse_sharding(part)))
+            args.append((shape, dtype, nbytes,
+                         _parse_sharding(part, meshes)))
     results = []
     res_text = (m.group("res") or m.group("res1") or "").strip()
     if res_text:
         for part in re.split(r",\s*(?=tensor<)", res_text):
             shape, dtype, nbytes = _parse_tensor(part)
             results.append((shape, dtype, nbytes,
-                            _parse_sharding(part)))
+                            _parse_sharding(part, meshes)))
     return args, results
 
 

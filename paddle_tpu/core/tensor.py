@@ -56,23 +56,7 @@ class Tensor:
                 dtype = dtypes.infer_dtype(data)
             jd = dtypes.to_jax(dtype)
             npd = np.asarray(data)
-            if jnp.issubdtype(jd, jnp.complexfloating):
-                from paddle_tpu.core.device import supports_complex
-
-                cpu = None
-                if not supports_complex():
-                    try:
-                        cpu = jax.devices("cpu")[0]
-                    except Exception:
-                        cpu = None
-                if cpu is not None:
-                    # complex buffers live CPU-side on backends that
-                    # cannot hold them (see device.supports_complex)
-                    arr = jax.device_put(npd.astype(jd), cpu)
-                else:
-                    arr = jnp.asarray(npd, dtype=jd)
-            else:
-                arr = jnp.asarray(npd, dtype=jd)
+            arr = jnp.asarray(npd, dtype=jd)
         self._array = arr
         self.stop_gradient = stop_gradient
         self._grad: Optional[Tensor] = None

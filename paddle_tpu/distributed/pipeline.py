@@ -367,12 +367,17 @@ class PipelineStack(nn.Layer):
         mb = B // M
         x_mb = x_arr.reshape(M, mb, *x_arr.shape[1:])
 
+        # the stage dim IS the 'pp' mesh axis: saying so lets a
+        # shard_map inside a block (the per-shard attention kernels)
+        # keep each stage on its own devices instead of gathering it
         if self.uniform:
-            vm_stage = jax.vmap(self._stage_fn, in_axes=(0, 0, 0))
+            vm_stage = jax.vmap(self._stage_fn, in_axes=(0, 0, 0),
+                                spmd_axis_name="pp")
         else:
             stage_masks = jnp.asarray(np.stack(
                 [np.arange(self.per_stage) < c for c in self.stage_counts]))
-            vm_stage_m = jax.vmap(self._stage_fn, in_axes=(0, 0, 0, 0))
+            vm_stage_m = jax.vmap(self._stage_fn, in_axes=(0, 0, 0, 0),
+                                  spmd_axis_name="pp")
             vm_stage = lambda st, x, ids: vm_stage_m(st, x, ids, stage_masks)
         stage_ids = jnp.arange(S)
 

@@ -885,7 +885,8 @@ class GenerationEngine:
         self.attention_backend_requested = requested
         self.attention_backend = resolve_backend(
             requested, head_dim=cfg.hidden_size // cfg.num_heads,
-            block_size=self.block_size)
+            block_size=self.block_size,
+            num_heads=cfg.num_heads // self.mp_degree)
         # speculative decoding: K drafted tokens verified per compiled
         # step. Env override wins (deploy-time knob, like the backend);
         # K=0 builds today's one-token decode step unchanged.
@@ -1421,7 +1422,7 @@ class GenerationEngine:
         mp=1."""
         if self._mp_axis is None:
             return fn
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         pool = self.cache.pool_pspec()
@@ -1442,7 +1443,7 @@ class GenerationEngine:
             out_specs=(P(),) * n_out + (pool, pool) + scales,
             # all-gathered logits/argmax are replicated by
             # construction; the static rep-checker can't prove it
-            check_rep=False)
+            check_vma=False)
         sharded.__name__ = fn.__name__
         return sharded
 

@@ -11,6 +11,7 @@ False and NativeArrayLoader raises with a clear message).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -29,10 +30,14 @@ def _build_and_load():
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     src = os.path.join(repo, "cpp", "fastloader.cc")
-    out = os.path.join(repo, "cpp", "libfastloader.so")
     try:
-        if not os.path.exists(out) or \
-                os.path.getmtime(out) < os.path.getmtime(src):
+        # the library's name carries its source's content hash, so a
+        # binary is only ever loaded for the source it was built from
+        # (mtimes say nothing in a copied or freshly checked-out tree)
+        with open(src, "rb") as f:
+            tag = hashlib.sha1(f.read()).hexdigest()[:12]
+        out = os.path.join(repo, "cpp", f"libfastloader-{tag}.so")
+        if not os.path.exists(out):
             # compile to a per-process temp and rename atomically:
             # concurrent processes (the 2-process launcher, parallel
             # pytest) must never dlopen a half-written .so

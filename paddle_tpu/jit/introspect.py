@@ -60,7 +60,7 @@ TRACING_CALLABLES = {
     "jax.custom_vjp": (0,),
     "jax.custom_jvp": (0,),
     "jax.experimental.pallas.pallas_call": (0,),
-    "jax.experimental.shard_map.shard_map": (0,),
+    "jax.shard_map": (0,),
     "paddle_tpu.jit.to_static": (0,),
     "paddle_tpu.jit.api.to_static": (0,),
 }

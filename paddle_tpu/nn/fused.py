@@ -166,8 +166,7 @@ class ConvBNReLU(Layer):
         import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.conv import _on_tpu, \
-            fused_conv_bn_relu
+        from paddle_tpu.ops.pallas.conv import fused_conv_bn_relu
 
         x = as_tensor(x)
         eps = self.bn._epsilon
@@ -175,7 +174,6 @@ class ConvBNReLU(Layer):
         padding = self.conv._padding
         nchw = self._data_format == "NCHW"
         relu = self._act == "relu"
-        interpret = not _on_tpu()
 
         def fn(a, w, gamma, beta, mean, var):
             scale = gamma.astype(jnp.float32) * jax.lax.rsqrt(
@@ -187,7 +185,7 @@ class ConvBNReLU(Layer):
             wt = jnp.transpose(w, (2, 3, 1, 0))      # OIHW -> HWIO
             out = fused_conv_bn_relu(a, wt, scale, shift,
                                      stride=stride, padding=padding,
-                                     relu=relu, interpret=interpret)
+                                     relu=relu)
             if nchw:
                 out = jnp.transpose(out, (0, 3, 1, 2))
             return out
@@ -211,8 +209,7 @@ class ConvBNReLU(Layer):
         import jax.numpy as jnp
 
         from paddle_tpu.ops.dispatch import apply
-        from paddle_tpu.ops.pallas.conv import _on_tpu, \
-            fused_conv_bn_relu_train
+        from paddle_tpu.ops.pallas.conv import fused_conv_bn_relu_train
 
         x = as_tensor(x)
         eps = self.bn._epsilon
@@ -220,7 +217,6 @@ class ConvBNReLU(Layer):
         padding = self.conv._padding
         nchw = self._data_format == "NCHW"
         relu = self._act == "relu"
-        interpret = not _on_tpu()
 
         def fn(a, w, gamma, beta):
             if nchw:
@@ -228,7 +224,7 @@ class ConvBNReLU(Layer):
             wt = jnp.transpose(w, (2, 3, 1, 0))      # OIHW -> HWIO
             y, mean, var = fused_conv_bn_relu_train(
                 a, wt, gamma, beta, stride=stride, padding=padding,
-                relu=relu, eps=eps, interpret=interpret)
+                relu=relu, eps=eps)
             if nchw:
                 y = jnp.transpose(y, (0, 3, 1, 2))
             return y, mean, var

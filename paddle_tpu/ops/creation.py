@@ -154,9 +154,7 @@ def diagflat(x, offset=0, name=None):
 
 
 def complex(real, imag, name=None):
-    """Build a complex tensor from real/imag parts (paddle.complex).
-    On backends without complex buffers (core.device.supports_complex)
-    the result lives CPU-side, like complex creation in Tensor()."""
+    """Build a complex tensor from real/imag parts (paddle.complex)."""
     from .dispatch import apply, as_tensor
 
     r = as_tensor(real)
@@ -173,21 +171,4 @@ def complex(real, imag, name=None):
         a, b = jnp.broadcast_arrays(a.astype(fdt), b.astype(fdt))
         return jax.lax.complex(a, b)
 
-    from paddle_tpu.core.device import supports_complex
-
-    if not supports_complex() and \
-            not isinstance(r._array, jax.core.Tracer):
-        from .dispatch import apply_with_cpu_fallback
-
-        # two-input op: pack both (broadcast) inputs ON the tape — the
-        # pack is itself an apply() so gradients flow to r AND i through
-        # the fallback path — then hop the packed array to CPU
-        packed = apply(
-            "complex_pack",
-            lambda a, b: jnp.stack(
-                jnp.broadcast_arrays(a.astype(fdt), b.astype(fdt))),
-            r, i)
-        return apply_with_cpu_fallback(
-            apply, "complex", lambda p: jax.lax.complex(p[0], p[1]),
-            packed, supports_complex, complex_stays_on_cpu=True)
     return apply("complex", fn, r, i)

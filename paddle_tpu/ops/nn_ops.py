@@ -896,29 +896,23 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
     if (mask_arr is not None and dropout_p == 0.0 and not is_causal
             and getattr(mask_arr, "ndim", 0) == 4
             and mask_arr.shape[1] == 1 and mask_arr.shape[2] == 1):
-        from .pallas.flash_attention import (_on_tpu,
-                                             _shapes_ok_for_shortseq,
+        from paddle_tpu.core.device import on_tpu
+
+        from .pallas.flash_attention import (_shapes_ok_for_shortseq,
                                              shortseq_attention)
 
         Sq, Skv, D = q.shape[1], k.shape[1], q.shape[3]
-        if _on_tpu() and _shapes_ok_for_shortseq(Sq, Skv, D) and \
+        if on_tpu() and _shapes_ok_for_shortseq(Sq, Skv, D) and \
                 mask_arr.shape[0] in (1, q.shape[0]) and \
                 mask_arr.shape[3] == Skv:
             km = jnp.broadcast_to(
                 jnp.asarray(mask_arr)[:, 0, 0, :],
                 (q.shape[0], Skv))
-            try:
-                return apply(
-                    "flash_attention_keymask",
-                    lambda qa, ka, va: shortseq_attention(
-                        qa, ka, va, scale=scale, key_mask=km),
-                    q, k, v)
-            except Exception as e:  # noqa: BLE001 — dense still works
-                import warnings
-
-                warnings.warn(
-                    f"shortseq key-mask kernel unavailable, dense "
-                    f"fallback: {type(e).__name__}: {e}")
+            return apply(
+                "flash_attention_keymask",
+                lambda qa, ka, va: shortseq_attention(
+                    qa, ka, va, scale=scale, key_mask=km),
+                q, k, v)
 
     def fn(qa, ka, va):
         d = qa.shape[-1]

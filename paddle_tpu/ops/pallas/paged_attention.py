@@ -38,7 +38,7 @@ K+1 scored positions, which is the whole speculative-decoding win.
 Interpret mode (`interpret=True`) runs the same kernels through the
 Pallas interpreter, which is how CPU CI tests them token-exactly
 against the dense path; the op-tier seam (`ops/paged_attention.py`)
-forces interpret whenever no TPU is attached.
+runs them interpreted when the platform is `cpu`, and only then.
 
 Tensor-parallel serving (PR 8): the kernels read `heads` from the
 operand shapes, never from model config, so the sharded engine invokes
@@ -59,6 +59,16 @@ import jax.numpy as jnp
 __all__ = ["paged_decode_attention", "paged_verify_attention"]
 
 _NEG_INF = -1e30
+
+
+def _heads_first(x):
+    """`[rows, heads, D]` <-> `[heads, rows, D]`. The pool streams
+    blocks rows-major, but Mosaic only lowers a batched matmul whose
+    batch (head) axis leads on BOTH operands — the `hd,khd->hk` form,
+    head in the middle of the right operand, is refused by the chip's
+    compiler though the interpreter accepts it. One in-VMEM swap per
+    streamed block buys the canonical `hqd,hkd->hqk` shape."""
+    return jnp.swapaxes(x, 0, 1)
 
 
 def _decode_kernel(bt_ref, pos_ref, q_ref, knew_ref, vnew_ref,
@@ -119,8 +129,8 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, knew_ref, vnew_ref,
     # inputs stay at the pool dtype through the matmuls (bf16 MXU
     # passes on TPU); accumulation is forced fp32 by
     # preferred_element_type — same numerics policy as the dense path
-    q = q_ref[0].astype(kbuf.dtype)             # [heads, D]
-    heads, head_dim = q.shape
+    q = q_ref[0].astype(kbuf.dtype)[:, None]    # [heads, 1, D]
+    heads, _, head_dim = q.shape
 
     def body(j, carry):
         m, l, acc = carry
@@ -137,27 +147,27 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, knew_ref, vnew_ref,
         ck, cv = kv_copies(j, j % 2)
         ck.wait()
         cv.wait()
-        k = kbuf[j % 2]                         # [bs, heads, D]
-        v = vbuf[j % 2]
-        sc = jnp.einsum("hd,khd->hk", q, k,
+        k = _heads_first(kbuf[j % 2])           # [heads, bs, D]
+        v = _heads_first(vbuf[j % 2])
+        sc = jnp.einsum("hqd,hkd->hqk", q, k,
                         preferred_element_type=jnp.float32) * scale
         gpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (heads, block_size), 1)
+            jnp.int32, (heads, 1, block_size), 2)
         sc = jnp.where(gpos <= pos, sc, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)                 # [heads, bs] fp32
-        alpha = jnp.exp(m - m_new)              # [heads, 1]
+        p = jnp.exp(sc - m_new)                 # [heads, 1, bs] fp32
+        alpha = jnp.exp(m - m_new)              # [heads, 1, 1]
         l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc * alpha + jnp.einsum(
-            "hk,khd->hd", p.astype(v.dtype), v,
+            "hqk,hkd->hqd", p.astype(v.dtype), v,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((heads, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((heads, 1), jnp.float32)
-    acc0 = jnp.zeros((heads, head_dim), jnp.float32)
+    m0 = jnp.full((heads, 1, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((heads, 1, 1), jnp.float32)
+    acc0 = jnp.zeros((heads, 1, head_dim), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, nblk, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30))[:, 0].astype(o_ref.dtype)
 
 
 def _decode_kernel_int8(bt_ref, pos_ref, sref, q_ref, knew_ref,
@@ -215,8 +225,8 @@ def _decode_kernel_int8(bt_ref, pos_ref, sref, q_ref, knew_ref,
     def _first():                   # block 0 is write-independent
         start_copies(0, 0)
 
-    q = q_ref[0].astype(jnp.float32)            # [heads, D]
-    heads, head_dim = q.shape
+    q = q_ref[0].astype(jnp.float32)[:, None]   # [heads, 1, D]
+    heads, _, head_dim = q.shape
 
     def body(j, carry):
         m, l, acc = carry
@@ -235,27 +245,27 @@ def _decode_kernel_int8(bt_ref, pos_ref, sref, q_ref, knew_ref,
         cv.wait()
         bid = bt_ref[s, j]
         ks, vs = sref[bid, 0], sref[bid, 1]     # this block's grid
-        k = kbuf[j % 2].astype(jnp.float32)     # [bs, heads, D]
-        v = vbuf[j % 2].astype(jnp.float32)
-        sc = jnp.einsum("hd,khd->hk", q, k,
+        k = _heads_first(kbuf[j % 2].astype(jnp.float32))
+        v = _heads_first(vbuf[j % 2].astype(jnp.float32))
+        sc = jnp.einsum("hqd,hkd->hqk", q, k,
                         preferred_element_type=jnp.float32) * scale
         sc = sc * ks                            # fused dequant (K)
         gpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (heads, block_size), 1)
+            jnp.int32, (heads, 1, block_size), 2)
         sc = jnp.where(gpos <= pos, sc, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)                 # [heads, bs] fp32
-        alpha = jnp.exp(m - m_new)              # [heads, 1]
+        p = jnp.exp(sc - m_new)                 # [heads, 1, bs] fp32
+        alpha = jnp.exp(m - m_new)              # [heads, 1, 1]
         l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jnp.einsum("hk,khd->hd", p, v,
+        pv = jnp.einsum("hqk,hkd->hqd", p, v,
                         preferred_element_type=jnp.float32)
         return m_new, l_new, acc * alpha + pv * vs
 
-    m0 = jnp.full((heads, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((heads, 1), jnp.float32)
-    acc0 = jnp.zeros((heads, head_dim), jnp.float32)
+    m0 = jnp.full((heads, 1, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((heads, 1, 1), jnp.float32)
+    acc0 = jnp.zeros((heads, 1, head_dim), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, nblk, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30))[:, 0].astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, knew, vnew, kpool, vpool, layer,
@@ -312,13 +322,13 @@ def paged_decode_attention(q, knew, vnew, kpool, vpool, layer,
             pl.BlockSpec((1, heads, head_dim), row),
             pl.BlockSpec((1, heads, head_dim), row),
             pl.BlockSpec((1, heads, head_dim), row),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, heads, head_dim), row),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, block_size, heads, head_dim), kpool.dtype),
@@ -340,7 +350,7 @@ def paged_decode_attention(q, knew, vnew, kpool, vpool, layer,
         # mutates in place
         input_output_aliases={len(prefetch) + 3: 1,
                               len(prefetch) + 4: 2},
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*prefetch, q3, k3, v3, kpool, vpool)
@@ -417,8 +427,8 @@ def _verify_kernel(bt_ref, pos_ref, dlen_ref, q_ref, knew_ref, vnew_ref,
 
     # inputs stay at the pool dtype through the matmuls; accumulation
     # is forced fp32 — the same policy as decode and the dense paths
-    q = q_ref[0].astype(kbuf.dtype)             # [W, heads, D]
-    _, heads, head_dim = q.shape
+    q = _heads_first(q_ref[0].astype(kbuf.dtype))   # [heads, W, D]
+    heads, _, head_dim = q.shape
 
     def body(j, carry):
         m, l, acc = carry
@@ -434,9 +444,9 @@ def _verify_kernel(bt_ref, pos_ref, dlen_ref, q_ref, knew_ref, vnew_ref,
         ck, cv = kv_copies(j, j % 2)
         ck.wait()
         cv.wait()
-        k = kbuf[j % 2]                         # [bs, heads, D]
-        v = vbuf[j % 2]
-        sc = jnp.einsum("whd,khd->hwk", q, k,
+        k = _heads_first(kbuf[j % 2])           # [heads, bs, D]
+        v = _heads_first(vbuf[j % 2])
+        sc = jnp.einsum("hwd,hkd->hwk", q, k,
                         preferred_element_type=jnp.float32) * scale
         # causal per window row over absolute positions
         kpos = j * block_size + jax.lax.broadcasted_iota(
@@ -449,7 +459,7 @@ def _verify_kernel(bt_ref, pos_ref, dlen_ref, q_ref, knew_ref, vnew_ref,
         alpha = jnp.exp(m - m_new)              # [heads, W, 1]
         l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
         acc_new = acc * alpha + jnp.einsum(
-            "hwk,khd->hwd", p.astype(v.dtype), v,
+            "hwk,hkd->hwd", p.astype(v.dtype), v,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
@@ -457,8 +467,8 @@ def _verify_kernel(bt_ref, pos_ref, dlen_ref, q_ref, knew_ref, vnew_ref,
     l0 = jnp.zeros((heads, W, 1), jnp.float32)
     acc0 = jnp.zeros((heads, W, head_dim), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, nblk, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)) \
-        .transpose(1, 0, 2).astype(o_ref.dtype)
+    o_ref[0] = _heads_first(acc / jnp.maximum(l, 1e-30)) \
+        .astype(o_ref.dtype)
 
 
 def _verify_kernel_int8(bt_ref, pos_ref, dlen_ref, sref, q_ref,
@@ -528,8 +538,8 @@ def _verify_kernel_int8(bt_ref, pos_ref, dlen_ref, sref, q_ref,
     def _first():                   # block 0 is write-independent
         start_copies(0, 0)
 
-    q = q_ref[0].astype(jnp.float32)            # [W, heads, D]
-    _, heads, head_dim = q.shape
+    q = _heads_first(q_ref[0].astype(jnp.float32))  # [heads, W, D]
+    heads, _, head_dim = q.shape
 
     def body(j, carry):
         m, l, acc = carry
@@ -547,9 +557,9 @@ def _verify_kernel_int8(bt_ref, pos_ref, dlen_ref, sref, q_ref,
         cv.wait()
         bid = bt_ref[s, j]
         ks, vs = sref[bid, 0], sref[bid, 1]     # this block's grid
-        k = kbuf[j % 2].astype(jnp.float32)     # [bs, heads, D]
-        v = vbuf[j % 2].astype(jnp.float32)
-        sc = jnp.einsum("whd,khd->hwk", q, k,
+        k = _heads_first(kbuf[j % 2].astype(jnp.float32))
+        v = _heads_first(vbuf[j % 2].astype(jnp.float32))
+        sc = jnp.einsum("hwd,hkd->hwk", q, k,
                         preferred_element_type=jnp.float32) * scale
         sc = sc * ks                            # fused dequant (K)
         kpos = j * block_size + jax.lax.broadcasted_iota(
@@ -561,7 +571,7 @@ def _verify_kernel_int8(bt_ref, pos_ref, dlen_ref, sref, q_ref,
         p = jnp.exp(sc - m_new)                 # [heads, W, bs] fp32
         alpha = jnp.exp(m - m_new)              # [heads, W, 1]
         l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jnp.einsum("hwk,khd->hwd", p, v,
+        pv = jnp.einsum("hwk,hkd->hwd", p, v,
                         preferred_element_type=jnp.float32)
         return m_new, l_new, acc * alpha + pv * vs
 
@@ -569,8 +579,8 @@ def _verify_kernel_int8(bt_ref, pos_ref, dlen_ref, sref, q_ref,
     l0 = jnp.zeros((heads, W, 1), jnp.float32)
     acc0 = jnp.zeros((heads, W, head_dim), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, nblk, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)) \
-        .transpose(1, 0, 2).astype(o_ref.dtype)
+    o_ref[0] = _heads_first(acc / jnp.maximum(l, 1e-30)) \
+        .astype(o_ref.dtype)
 
 
 def paged_verify_attention(q, knew, vnew, kpool, vpool, layer,
@@ -628,13 +638,13 @@ def paged_verify_attention(q, knew, vnew, kpool, vpool, layer,
             pl.BlockSpec((1, W, heads, head_dim), row),
             pl.BlockSpec((1, W, heads, head_dim), row),
             pl.BlockSpec((1, W, heads, head_dim), row),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, W, heads, head_dim), row),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, block_size, heads, head_dim), kpool.dtype),
@@ -656,7 +666,7 @@ def paged_verify_attention(q, knew, vnew, kpool, vpool, layer,
         # in place
         input_output_aliases={len(prefetch) + 3: 1,
                               len(prefetch) + 4: 2},
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*prefetch, q, k4, v4, kpool, vpool)

@@ -76,16 +76,6 @@ def load(name: str, sources: Sequence[str], extra_cflags=None,
     return CustomOpLibrary(name, so_path)
 
 
-def _callback_apply(apply_fn, opname, f, t):
-    """apply() with an eager CPU hop on backends that cannot lower host
-    callbacks (shared protocol: ops.dispatch.apply_with_cpu_fallback)."""
-    from paddle_tpu.core.device import supports_host_callback
-    from paddle_tpu.ops.dispatch import apply_with_cpu_fallback
-
-    return apply_with_cpu_fallback(apply_fn, opname, f, t,
-                                   supports_host_callback)
-
-
 class CustomOpLibrary:
     """A loaded custom-op shared library. Raw symbols via .symbol(name);
     differentiable paddle ops via .wrap_elementwise(...)."""
@@ -140,8 +130,8 @@ class CustomOpLibrary:
 
         if backward is None:
             def op(x):
-                return _callback_apply(apply_nograd, symbol, cb_fwd,
-                                       check_dtype(as_tensor(x)))
+                return apply_nograd(symbol, cb_fwd,
+                                    check_dtype(as_tensor(x)))
             op.__name__ = symbol
             return op
 
@@ -176,8 +166,7 @@ class CustomOpLibrary:
         f.defvjp(f_fwd, f_bwd)
 
         def op(x):
-            return _callback_apply(apply, symbol, f,
-                                   check_dtype(as_tensor(x)))
+            return apply(symbol, f, check_dtype(as_tensor(x)))
         op.__name__ = symbol
         return op
 

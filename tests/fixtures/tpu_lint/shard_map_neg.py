@@ -1,5 +1,5 @@
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def body(x):

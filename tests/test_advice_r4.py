@@ -217,26 +217,6 @@ def test_asp_masks_hold_under_trainstep():
         "n:m sparsity decayed under compiled training"
 
 
-def test_complex_fallback_grads_and_dtype(monkeypatch):
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.core import device as device_mod
-
-    # force the complex-less fallback path even on CPU
-    monkeypatch.setattr(device_mod, "_supports_complex", False)
-    r = paddle.to_tensor(np.array([1.0, 2.0], np.float32),
-                         stop_gradient=False)
-    i = paddle.to_tensor(np.array([3.0, 4.0], np.float32),
-                         stop_gradient=False)
-    c = paddle.complex(r, i)
-    assert np.asarray(c._array).dtype == np.complex64
-    loss = (c.real() * 2 + c.imag() * 3).sum()
-    loss.backward()
-    np.testing.assert_allclose(np.asarray(r.grad._array), [2.0, 2.0])
-    np.testing.assert_allclose(np.asarray(i.grad._array), [3.0, 3.0])
-
-
 def test_recompute_threads_bn_buffers():
     """recompute (jax.checkpoint) composed with BatchNorm inside a
     compiled TrainStep: no tracer leak, and running stats advance

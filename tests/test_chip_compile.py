@@ -1,0 +1,177 @@
+"""The main-path Pallas kernels, compiled for a DESCRIBED TPU v5e chip
+at real widths with `interpret=False` — what the interpreter-mode tests
+cannot see (Mosaic's layout, tiling and VMEM rules). Nothing runs: a
+compile that passes is not a chip run (`python chip_smoke.py` is).
+
+The rule these guard: nothing `auto` selects on a TPU may be a kernel
+the chip's compiler refuses.
+
+The topology is described inside the module-scoped fixture below, never
+at import time: only one process at a time may load the TPU library, and
+every xdist worker imports every test file. Compiles happen in the
+test's own process, with the persistent compilation cache off (a compile
+for a described chip cannot be read back without one).
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """`chip(fn, *shapes)` compiles `fn` for the first described chip;
+    each shape is `(dims, dtype)`. Returns the compiled text."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def compile_for_chip(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one)
+                for s, d in shapes]
+        # production precision: conftest pins `highest` for CPU parity
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text    # the kernel is in there
+        return text
+
+    return compile_for_chip
+
+
+# GPT-1.3B serving geometry: 16 heads x 128 (4 per shard at mp=4), the
+# default engine's 8 slots, block 16, 128-block tables, 24-layer pool
+SLOTS, HD, BS, MB, LAYERS, NB = 8, 128, 16, 128, 24, 1025
+BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+
+
+@pytest.mark.parametrize("heads", [16, 4])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("window", [1, 4])
+def test_paged_attention_kernels_compile(chip, heads, pool, window):
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention, paged_verify_attention)
+
+    pdt = I8 if pool == "int8" else BF16
+    row = ((SLOTS, window, heads, HD), BF16)
+    new = ((SLOTS, window, heads, HD), pdt)
+    pools = ((LAYERS, NB, BS, heads, HD), pdt)
+    tail = [((SLOTS, MB), I32), ((SLOTS,), I32)]
+    if window > 1:
+        tail.append(((SLOTS,), I32))            # draft lengths
+    if pool == "int8":
+        tail.append(((NB, 2), F32))             # this layer's K/V grid
+    op = paged_verify_attention if window > 1 else paged_decode_attention
+
+    def fn(q, k, v, kp, vp, *rest):
+        if pool == "int8":
+            *rest, scales = rest
+            return op(q, k, v, kp, vp, 3, *rest, kv_scales=scales)
+        return op(q, k, v, kp, vp, 3, *rest)
+
+    chip(fn, row, new, new, pools, pools, *tail)
+
+
+@pytest.mark.parametrize("name,shape,causal", [
+    ("gpt_1p3b", (2, 2048, 16, 128), True),     # chunked causal kernel
+    ("bert_base", (32, 512, 12, 64), False),    # short-sequence kernel
+])
+def test_training_attention_compiles_fwd_and_bwd(chip, monkeypatch, name,
+                                                 shape, causal):
+    """Through `flash_attention()` itself, told it is on a TPU, so the
+    shape tests pick the kernel exactly as they will on the chip."""
+    from paddle_tpu.distributed import topology
+
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    # one chip: no hybrid mesh left behind by an earlier test of this
+    # worker may wrap the kernel in a shard_map over CPU devices
+    monkeypatch.setattr(topology, "_default_hcg", None)
+    fa.reset_path_stats()
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal) \
+            .astype(F32).sum()
+
+    chip(jax.grad(loss, argnums=(0, 1, 2)), *[(shape, BF16)] * 3)
+    assert fa.PATH_STATS == {"pallas": 1, "xla": 0}
+
+
+@pytest.mark.parametrize("hw,cin,cout,stride", [
+    (56, 64, 256, 1),       # stage-2 expand: the worst matmul-gap row
+    (56, 256, 512, 2),      # strided downsample
+    (7, 512, 2048, 1),      # stage-5 expand
+])
+def test_conv1x1_compiles_infer_and_train(chip, hw, cin, cout, stride):
+    from paddle_tpu.ops.pallas.conv import (fused_conv_bn_relu,
+                                            fused_conv_bn_relu_train)
+
+    x, w = ((32, hw, hw, cin), BF16), ((1, 1, cin, cout), BF16)
+    vec = ((cout,), F32)
+    chip(lambda x, w, a, b: fused_conv_bn_relu(
+        x, w, a, b, stride=stride, interpret=False), x, w, vec, vec)
+
+    def loss(x, w, g, b):
+        y, _, _ = fused_conv_bn_relu_train(x, w, g, b, stride=stride,
+                                           interpret=False)
+        return y.astype(F32).sum()
+
+    chip(jax.grad(loss, argnums=(0, 1, 2, 3)), x, w, vec, vec)
+
+
+@pytest.mark.parametrize("heads,head_dim,block,want", [
+    (16, 128, 16, "pallas"),    # GPT-1.3B
+    (4, 128, 16, "pallas"),     # its mp=4 shard
+    (8, 256, 8, "pallas"),
+    (12, 64, 16, "dense"),      # GPT-small widths: 64-wide heads refused
+    (16, 64, 16, "dense"),
+    (12, 128, 16, "dense"),     # 12 rows: "must be aligned to tiling (8)"
+    (2, 128, 16, "dense"),      # refused for int8 pools
+    (16, 128, 4, "dense"),
+])
+def test_auto_selects_only_paged_geometries_the_chip_accepts(
+        monkeypatch, heads, head_dim, block, want):
+    """Probed against the chip's compiler in PR 23: the fused paged
+    kernels compile at head_dim % 128 == 0 with 4 or a multiple of 8
+    heads per program, and are refused elsewhere."""
+    pa = importlib.import_module("paddle_tpu.ops.paged_attention")
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    assert pa.resolve_backend("auto", head_dim=head_dim,
+                              block_size=block, num_heads=heads) == want
+
+
+def test_auto_never_selects_the_refused_conv3x3(monkeypatch):
+    """The chip's compiler refuses the 3x3 family (unaligned slab
+    slices, strided vector slices — ROADMAP A4), so on a TPU `auto`
+    resolves it dense and only the 1x1 family fused."""
+    conv = importlib.import_module("paddle_tpu.ops.pallas.conv")
+    monkeypatch.setattr(conv, "on_tpu", lambda: True)
+    monkeypatch.delenv("PADDLE_CONV_BACKEND", raising=False)
+    kw = dict(in_channels=64, out_channels=64)
+    assert conv.resolve_conv_backend(
+        "auto", kernel=(3, 3), padding=1, **kw) == "dense"
+    assert conv.resolve_conv_backend(
+        "auto", kernel=(3, 3), stride=(2, 2), padding=1, **kw) == "dense"
+    assert conv.resolve_conv_backend("auto", kernel=(1, 1), **kw) \
+        == "pallas"
+    # an explicit request is still honoured (interpreter tests, A4)
+    assert conv.resolve_conv_backend(
+        "pallas", kernel=(3, 3), padding=1, **kw) == "pallas"

@@ -55,7 +55,7 @@ def test_rule_fixture(rule, pos, neg, lines):
 
 def test_shard_map_bodies_are_traced_contexts():
     """ISSUE 8 satellite: a callable staged through
-    `jax.experimental.shard_map.shard_map` is a traced context for the
+    `jax.shard_map` is a traced context for the
     jit-reachability walker — host syncs (TPU001) and eager
     collectives (TPU007) inside the body are findings, while the
     mesh-level `jax.lax.psum`/`all_gather` the sharded serving engine

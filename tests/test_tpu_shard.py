@@ -20,7 +20,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.analysis.findings import (Finding, assign_ids,
@@ -97,7 +97,7 @@ def _gather_fn(n_gathers, axis="mp"):
         return x
 
     return shard_map(body, mesh=_mesh(axis), in_specs=(P(axis),),
-                     out_specs=P(axis), check_rep=False)
+                     out_specs=P(axis), check_vma=False)
 
 
 def _rec(fn, args, contract, **kw):
@@ -294,7 +294,7 @@ def test_tpu305_positive_on_device_loop_body():
         return out
 
     fn = shard_map(body, mesh=_mesh("pp"), in_specs=(P("pp"),),
-                   out_specs=P("pp"), check_rep=False)
+                   out_specs=P("pp"), check_vma=False)
     c = _contract(collective_budget=_budget(
         axes=(("pp", "dcn"),),
         entries=(("pp", "psum", 2, 0, "tokens * hidden * 4"),)))
@@ -468,14 +468,15 @@ def test_contract_waiver_suppresses_shard_rule():
 # -- signature parser ---------------------------------------------------
 
 def test_parse_main_shardings_decodes_counts():
-    text = ('module @x { func.func public @main('
-            '%arg0: tensor<2x9x8x4x8xi8> {mhlo.sharding = '
-            '"{devices=[1,1,1,2,1]<=[2]}"}, '
-            '%arg1: tensor<32x64xf32> {mhlo.sharding = '
-            '"{replicated}"}, '
+    text = ('module @x { sdy.mesh @mesh = <["dp"=4, "mp"=2]> '
+            'func.func public @main('
+            '%arg0: tensor<2x9x8x4x8xi8> {sdy.sharding = '
+            '#sdy.sharding<@mesh, [{}, {}, {}, {"mp"}, {}]>}, '
+            '%arg1: tensor<32x64xf32> {sdy.sharding = '
+            '#sdy.sharding<@mesh, [{}, {}]>}, '
             '%arg2: tensor<4xi32>) -> (tensor<2x32xf32>, '
-            'tensor<8xbf16> {mhlo.sharding = '
-            '"{devices=[2,4]<=[8] last_tile_dim_replicate}"}) { } }')
+            'tensor<8xbf16> {jax.result_info = "result[1]", '
+            'sdy.sharding = #sdy.sharding<@mesh, [{"mp"}]>}) { } }')
     args, results = parse_main_shardings(text)
     assert [(a[0], a[3]) for a in args] == [
         ((2, 9, 8, 4, 8), (1, 1, 1, 2, 1)),

@@ -115,14 +115,14 @@ def test_tpu301_fires_on_an_extra_all_gather(tiny_mp2_engine):
     to the mp=2 decode step busts the per-axis count (9 = 4/layer x 2
     layers + 1 fixed) and TPU301 names the axis; the real step — with
     its live geometry, so the BYTE caps are exercised too — passes."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     eng = tiny_mp2_engine
     extra = shard_map(
         lambda t: jax.lax.all_gather(t, "mp", axis=0, tiled=True),
         mesh=eng.mesh, in_specs=(P(),), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
     def broken_step(*a):
         nxt, kp, vp = eng._decode_pure(*a)

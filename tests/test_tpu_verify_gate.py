@@ -162,7 +162,7 @@ def test_tpu104_fires_on_an_extra_all_gather(tiny_mp2_engine):
     """Deliberate contract break #2: one accidental extra all-gather
     appended to the mp=2 decode step busts the declared per-layer
     budget (9 = 4/layer x 2 layers + 1 fixed) and TPU104 says so."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.analysis.trace.rules import check_tpu104
@@ -174,7 +174,7 @@ def test_tpu104_fires_on_an_extra_all_gather(tiny_mp2_engine):
     extra = shard_map(
         lambda t: jax.lax.all_gather(t, "mp", axis=0, tiled=True),
         mesh=eng.mesh, in_specs=(P(),), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
     def broken_step(*a):
         nxt, kp, vp = eng._decode_pure(*a)

@@ -190,7 +190,6 @@ import os
 import threading
 import time
 from collections import OrderedDict, deque
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -209,7 +208,8 @@ from paddle_tpu.jit.api import bound_state, count_traces, dedup_params, \
     model_buffers
 from paddle_tpu.observability.metrics import LATENCY_BUCKETS, \
     MetricsRegistry
-from paddle_tpu.observability.tracing import (FlightRecorder,
+from paddle_tpu.observability.tracing import (STEP_PHASES,
+                                              FlightRecorder,
                                               PhaseTimer, TraceRecorder,
                                               export_timeline,
                                               new_trace_id, now_us,
@@ -219,6 +219,36 @@ from paddle_tpu.profiler import RecordEvent
 __all__ = ["PagedKVCache", "GenerationEngine", "Request",
            "PRIORITY_CLASSES", "prefix_key", "iter_prefix_key",
            "SamplingParams"]
+
+#: one span name per step phase, the same in every sink (the profiler's
+#: trace, the host-event recorder, the `TraceRecorder`)
+_PHASE_SPANS = {p: "engine." + p for p in STEP_PHASES}
+
+
+class _PhaseSpan:
+    """What `GenerationEngine._phase` returns: a context manager and
+    not a generator, since a step enters a dozen of them."""
+
+    __slots__ = ("_engine", "_span", "_event", "_clock", "_t0")
+
+    def __init__(self, engine, name):
+        self._engine = engine
+        self._span = _PHASE_SPANS[name]
+        self._event = RecordEvent(self._span)
+        self._clock = engine._phases.phase(name)
+
+    def __enter__(self):
+        self._t0 = now_us() if self._engine.tracer is not None else None
+        self._event.begin()
+        self._clock.__enter__()
+
+    def __exit__(self, *exc):
+        self._clock.__exit__(*exc)
+        self._event.end()
+        if self._t0 is not None and exc[0] is None:
+            self._engine.tracer.add_span(self._span, self._t0, now_us(),
+                                         cat="phase")
+        return False
 
 
 def iter_prefix_key(tokens, block_size, adapter_id=0):
@@ -1748,20 +1778,13 @@ class GenerationEngine:
 
     # -- request-scoped tracing / step phases ------------------------------
     def _phase(self, name):
-        """Enter one named host phase of the current step (exclusive
+        """Enter one named host phase of the current step: the
+        `engine.<phase>` span (`RecordEvent`: the profiler's clock and
+        the host-event recorder) round the phase clock (exclusive
         accounting — nesting pauses the enclosing phase) and, with
-        tracing on, record it as a span."""
-        if self.tracer is None:
-            return self._phases.phase(name)
-        return self._traced_phase(name)
-
-    @contextmanager
-    def _traced_phase(self, name):
-        t0 = now_us()
-        with self._phases.phase(name):
-            yield
-        self.tracer.add_span("phase." + name, t0, now_us(),
-                             cat="phase")
+        tracing on, the same span under the same name in the
+        `TraceRecorder`."""
+        return _PhaseSpan(self, name)
 
     def _trace_span(self, name, start_us, req=None, tid=0,
                     cat="request", **attrs):
@@ -2546,7 +2569,7 @@ class GenerationEngine:
                                    path="decode")
             return False
         src, dst = slot.blocks[bi], got[0]
-        with self._phase("cow"), RecordEvent("engine.cow"):
+        with self._phase("cow"):
             if self.cache.scales is not None:
                 # quantized pools: the block's per-layer grid rows
                 # ride the copy — a COW'd block must dequantize on

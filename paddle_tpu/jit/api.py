@@ -31,6 +31,7 @@ from contextlib import contextmanager
 from paddle_tpu.core import autograd
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.jit import introspect
+from paddle_tpu.profiler import RecordEvent
 
 
 _bound_depth = 0
@@ -662,6 +663,16 @@ def scatter_accums(opt, acc_idx, new_accums):
             opt._accumulators[k][j] = new_accums[k][out_pos]
 
 
+#: TrainStep's host stages as spans (`RecordEvent`: the profiler's clock
+#: and the host-event recorder): the whole call, and inside it the
+#: gathering of the live state, the call of the compiled program, and
+#: the write-back into the parameters, accumulators and buffers
+SPAN_STEP = "trainstep.step"
+SPAN_GATHER = "trainstep.gather"
+SPAN_DISPATCH = "trainstep.dispatch"
+SPAN_SCATTER = "trainstep.scatter"
+
+
 class TrainStep:
     """One fully-compiled training step over (model, optimizer, loss_fn).
 
@@ -854,48 +865,57 @@ class TrainStep:
             self._grad_bufs = [jnp.zeros(p._array.shape, jnp.float32)
                                for p in self._params]
         with_scaler = self._with_scaler()
-        if with_scaler:
-            scale = jnp.float32(self.scaler.get_scale())
-            found = getattr(self, "_accum_found", None)
-            if found is None:
-                found = jnp.bool_(False)
-            loss, self._grad_bufs, found, new_model_bufs = \
-                self._acc_jitted(
-                    self._grad_bufs, found,
-                    [p._array for p in self._params],
-                    self._buf_arrays(), in_arrays, label_arr,
-                    self._next_step_key(), scale)
-            self._accum_found = found
-        else:
-            loss, self._grad_bufs, new_model_bufs = self._acc_jitted(
-                self._grad_bufs, [p._array for p in self._params],
-                self._buf_arrays(), in_arrays, label_arr,
-                self._next_step_key())
-        self._write_buffers(new_model_bufs)
+        with RecordEvent(SPAN_GATHER):
+            param_arrays = [p._array for p in self._params]
+            bufs = self._buf_arrays()
+            key = self._next_step_key()
+            if with_scaler:
+                scale = jnp.float32(self.scaler.get_scale())
+                found = getattr(self, "_accum_found", None)
+                if found is None:
+                    found = jnp.bool_(False)
+        with RecordEvent(SPAN_DISPATCH):
+            if with_scaler:
+                loss, self._grad_bufs, found, new_model_bufs = \
+                    self._acc_jitted(
+                        self._grad_bufs, found, param_arrays, bufs,
+                        in_arrays, label_arr, key, scale)
+                self._accum_found = found
+            else:
+                loss, self._grad_bufs, new_model_bufs = self._acc_jitted(
+                    self._grad_bufs, param_arrays, bufs, in_arrays,
+                    label_arr, key)
+        with RecordEvent(SPAN_SCATTER):
+            self._write_buffers(new_model_bufs)
         self._accum_count += 1
         if self._accum_count >= self.accumulate_steps:
-            lr = jnp.asarray(opt.get_lr(), jnp.float32)
-            stepc = jnp.asarray(opt._step_count, jnp.int32)
+            with RecordEvent(SPAN_GATHER):
+                lr = jnp.asarray(opt.get_lr(), jnp.float32)
+                stepc = jnp.asarray(opt._step_count, jnp.int32)
+                param_arrays = [p._array for p in self._params]
+                accums = self._gather_accums()
+            with RecordEvent(SPAN_DISPATCH):
+                if with_scaler:
+                    new_params, new_accums, self._grad_bufs = \
+                        self._upd_jitted(
+                            param_arrays, accums, self._grad_bufs, lr,
+                            stepc, scale, self._accum_found)
+                else:
+                    new_params, new_accums, self._grad_bufs = \
+                        self._upd_jitted(
+                            param_arrays, accums, self._grad_bufs, lr,
+                            stepc)
             if with_scaler:
-                new_params, new_accums, self._grad_bufs = \
-                    self._upd_jitted(
-                        [p._array for p in self._params],
-                        self._gather_accums(), self._grad_bufs, lr,
-                        stepc, scale, self._accum_found)
                 skipped = bool(self._accum_found)
                 self.scaler._found_inf = skipped
                 self.scaler.update()
                 self._accum_found = jnp.bool_(False)
             else:
-                new_params, new_accums, self._grad_bufs = \
-                    self._upd_jitted(
-                        [p._array for p in self._params],
-                        self._gather_accums(), self._grad_bufs, lr,
-                        stepc)
                 skipped = False
-            for p, a in zip(self._params, new_params):
-                p._in_place_update(a)
-            self._scatter_accums(new_accums)
+            with RecordEvent(SPAN_SCATTER):
+                for p, a in zip(self._params, new_params):
+                    p._in_place_update(a)
+                self._scatter_accums(new_accums)
             if not skipped:
                 opt._step_count += 1
             self._accum_count = 0
@@ -982,6 +1002,10 @@ class TrainStep:
         return out
 
     def _call_inner(self, *inputs, label=None):
+        with RecordEvent(SPAN_STEP):
+            return self._step(*inputs, label=label)
+
+    def _step(self, *inputs, label=None):
         if label is None and len(inputs) >= 2:
             *inputs, label = inputs
             inputs = tuple(inputs)
@@ -999,29 +1023,36 @@ class TrainStep:
         label_arr = _unwrap(label) if label is not None else None
         if self.accumulate_steps > 1:
             return self._call_accumulate(in_arrays, label_arr)
-        param_arrays = [p._array for p in self._params]
-        accums = self._gather_accums()
-        bufs = self._buf_arrays()
-        lr = jnp.asarray(opt.get_lr(), jnp.float32)
-        stepc = jnp.asarray(opt._step_count, jnp.int32)
-        if self._with_scaler():
-            loss, found_inf, new_params, new_accums, new_bufs = \
-                self._jitted(
+        with RecordEvent(SPAN_GATHER):
+            param_arrays = [p._array for p in self._params]
+            accums = self._gather_accums()
+            bufs = self._buf_arrays()
+            lr = jnp.asarray(opt.get_lr(), jnp.float32)
+            stepc = jnp.asarray(opt._step_count, jnp.int32)
+            key = self._next_step_key()
+            scale = jnp.float32(self.scaler.get_scale()) \
+                if self._with_scaler() else None
+        with RecordEvent(SPAN_DISPATCH):
+            if scale is not None:
+                loss, found_inf, new_params, new_accums, new_bufs = \
+                    self._jitted(
+                        param_arrays, accums, bufs, lr, stepc, in_arrays,
+                        label_arr, key, scale)
+            else:
+                loss, new_params, new_accums, new_bufs = self._jitted(
                     param_arrays, accums, bufs, lr, stepc, in_arrays,
-                    label_arr, self._next_step_key(),
-                    jnp.float32(self.scaler.get_scale()))
+                    label_arr, key)
+        if scale is not None:
             skipped = bool(found_inf)
             self.scaler._found_inf = skipped
             self.scaler.update()
         else:
-            loss, new_params, new_accums, new_bufs = self._jitted(
-                param_arrays, accums, bufs, lr, stepc, in_arrays,
-                label_arr, self._next_step_key())
             skipped = False
-        for p, a in zip(self._params, new_params):
-            p._in_place_update(a)
-        self._scatter_accums(new_accums)
-        self._write_buffers(new_bufs)
+        with RecordEvent(SPAN_SCATTER):
+            for p, a in zip(self._params, new_params):
+                p._in_place_update(a)
+            self._scatter_accums(new_accums)
+            self._write_buffers(new_bufs)
         if not skipped:
             # a scaler-skipped step doesn't count (GradScaler.step skips
             # optimizer.step entirely — bias-correction t must match the

@@ -16,6 +16,9 @@ Three host-side pieces, shared by the engine and the fleet:
   PAUSE their parent, so per-phase totals partition the step wall
   exactly — the serial-host tax of ROADMAP item 3 becomes a number
   (`engine_step_host_gap_seconds{phase=…}`) instead of an assertion.
+  The engine opens each phase inside a `profiler.RecordEvent` named
+  `engine.<phase>`, which is how the phases reach a `jax.profiler`
+  trace (the device's clock); this module stays off jax.
 - **FlightRecorder**: a bounded ring of recent request-lifecycle
   events (queued/admit/first_token/stall/finish/handoff/…) — the
   postmortem `drain()`'s leak audit attaches to its exception.
@@ -279,8 +282,9 @@ class FlightRecorder:
 
 def profiler_host_events():
     """Non-destructive peek at the profiler's `_HostEventRecorder`
-    stream (the `engine.step`/`engine.prefill`/`engine.decode`/
-    `engine.cow` spans `RecordEvent` emits while a Profiler records).
+    stream (the `engine.step`/`engine.prefill`/`engine.decode` and
+    `engine.<phase>` spans `RecordEvent` emits while a Profiler
+    records — the same names the `TraceRecorder` gives the phases).
     Lazy import: the profiler package is stdlib-only too, but tracing
     must stay importable standalone."""
     from paddle_tpu.profiler.profiler import _recorder

@@ -92,6 +92,7 @@ import jax.numpy as jnp
 from paddle_tpu.analysis.trace.contracts import TraceContract, \
     register_contract
 from paddle_tpu.core.device import on_tpu, pallas_interpret
+from paddle_tpu.ops.pallas.naming import kernel_name
 
 __all__ = ["fused_conv_bn_relu", "fused_conv_bn_relu_train",
            "conv_bn_relu_reference", "conv_bn_relu_train_reference",
@@ -348,6 +349,7 @@ def _conv1x1_call(x2, w2, scale, shift, relu, interpret):
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_conv1x1_kernel, relu=relu),
+        **kernel_name("conv1x1_fwd"),
         grid=((M + pad) // TM,),
         in_specs=[
             pl.BlockSpec((TM, Cin), lambda i: (i, 0)),
@@ -505,6 +507,7 @@ def _conv3x3_call(x, w, scale, shift, stride=1, pads=None, relu=True,
     out = pl.pallas_call(
         functools.partial(_conv3x3_kernel, stride=s, th=th,
                           num_tiles=num_tiles, tw=tw, relu=relu),
+        **kernel_name("conv3x3_fwd"),
         grid=(N, num_wtiles),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -569,6 +572,7 @@ def _conv1x1_train_call(x2, w2, interpret=False):
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     out, sums = pl.pallas_call(
         _conv1x1_train_kernel,
+        **kernel_name("conv1x1_train_fwd"),
         grid=((M + pad) // TM,),
         in_specs=[
             pl.BlockSpec((TM, Cin), lambda i: (i, 0)),
@@ -659,6 +663,7 @@ def _conv3x3_train_call(x, w, stride=1, pads=((1, 1), (1, 1)),
     out, sums = pl.pallas_call(
         functools.partial(_conv3x3_train_kernel, stride=s, th=th,
                           num_tiles=num_tiles, tw=tw),
+        **kernel_name("conv3x3_train_fwd"),
         grid=(N, num_wtiles),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -741,6 +746,7 @@ def _conv1x1_bwd_call(x2, dy2, y2, rows, wt, relu=True,
         y2 = jnp.pad(y2, ((0, pad), (0, 0)))
     dx, dw = pl.pallas_call(
         functools.partial(_conv1x1_bwd_kernel, relu=relu),
+        **kernel_name("conv1x1_bwd"),
         grid=((M + pad) // TM,),
         in_specs=[
             pl.BlockSpec((TM, Cin), lambda i: (i, 0)),
@@ -827,6 +833,7 @@ def _conv3x3_dw_call(x, g, stride=1, pads=((1, 1), (1, 1)),
     out = pl.pallas_call(
         functools.partial(_conv3x3_dw_kernel, stride=s, th=th,
                           num_tiles=num_tiles, tw=tw),
+        **kernel_name("conv3x3_dw"),
         grid=(N, num_wtiles),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),

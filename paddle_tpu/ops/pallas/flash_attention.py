@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.core.device import on_tpu
+from paddle_tpu.ops.pallas.naming import kernel_name
 
 _NEG_INF = -1e30
 
@@ -146,6 +147,7 @@ def pallas_sdpa_forward(q, k, v, causal: bool = True, scale=None,
 
     out = pl.pallas_call(
         kernel,
+        **kernel_name("flash_sdpa_fwd"),
         interpret=interpret,
         grid=grid,
         in_specs=[
@@ -302,6 +304,7 @@ def _shortseq_call_fwd(q, k, v, kmask, scale, hb, interpret=False):
     if kmask is None:  # mask-free hot path: no zero-mask traffic
         return pl.pallas_call(
             functools.partial(_shortseq_fwd_kernel, scale=scale, hb=hb),
+            **kernel_name("flash_shortseq_fwd"),
             grid=grid,
             interpret=interpret,
             in_specs=[blk(), blk(), blk()],
@@ -311,6 +314,7 @@ def _shortseq_call_fwd(q, k, v, kmask, scale, hb, interpret=False):
     return pl.pallas_call(
         functools.partial(_shortseq_fwd_kernel_masked, scale=scale,
                           hb=hb),
+        **kernel_name("flash_shortseq_fwd"),
         grid=grid,
         interpret=interpret,
         in_specs=[blk(), blk(), blk(), row],
@@ -336,6 +340,7 @@ def _shortseq_call_bwd(q, k, v, kmask, o, do, lse, scale, hb,
     if kmask is None:
         return pl.pallas_call(
             functools.partial(_shortseq_bwd_kernel, scale=scale, hb=hb),
+            **kernel_name("flash_shortseq_bwd"),
             grid=grid,
             interpret=interpret,
             in_specs=[blk(), blk(), blk(), blk(), blk(), row],
@@ -345,6 +350,7 @@ def _shortseq_call_bwd(q, k, v, kmask, o, do, lse, scale, hb,
     return pl.pallas_call(
         functools.partial(_shortseq_bwd_kernel_masked, scale=scale,
                           hb=hb),
+        **kernel_name("flash_shortseq_bwd"),
         grid=grid,
         interpret=interpret,
         in_specs=[blk(), blk(), blk(), row, blk(), blk(), row],
@@ -526,6 +532,7 @@ def _causal_call_fwd(q, k, v, scale, bq, interpret=False):
 
     return pl.pallas_call(
         functools.partial(_causal_fwd_kernel, scale=scale, bq=bq),
+        **kernel_name("flash_causal_fwd"),
         grid=(BH,),
         interpret=interpret,
         in_specs=[blk(), blk(), blk()],
@@ -549,6 +556,7 @@ def _causal_call_bwd(q, k, v, o, do, lse, scale, bq, interpret=False):
 
     return pl.pallas_call(
         functools.partial(_causal_bwd_kernel, scale=scale, bq=bq),
+        **kernel_name("flash_causal_bwd"),
         grid=(BH,),
         interpret=interpret,
         in_specs=[blk(), blk(), blk(), blk(), blk(),

@@ -56,6 +56,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops.pallas.naming import kernel_name
+
 __all__ = ["paged_decode_attention", "paged_verify_attention"]
 
 _NEG_INF = -1e30
@@ -339,6 +341,9 @@ def paged_decode_attention(q, knew, vnew, kpool, vpool, layer,
     )
     out, new_kpool, new_vpool = pl.pallas_call(
         kernel,
+        # the attribute alone: `name=` would rename the instruction
+        # away from `%engine_decode_step.N`, by which traces find it
+        **kernel_name("paged_decode_attention", rename=False),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((slots, heads, head_dim), q.dtype),
@@ -655,6 +660,7 @@ def paged_verify_attention(q, knew, vnew, kpool, vpool, layer,
     )
     out, new_kpool, new_vpool = pl.pallas_call(
         kernel,
+        **kernel_name("paged_verify_attention", rename=False),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((slots, W, heads, head_dim), q.dtype),

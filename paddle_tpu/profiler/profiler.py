@@ -5,6 +5,7 @@ import enum
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Callable, Iterable, List, Optional
@@ -56,21 +57,44 @@ _recorder = _HostEventRecorder()
 
 
 class RecordEvent:
-    """Analog of paddle.profiler.RecordEvent (event_tracing.h RecordEvent)."""
+    """Analog of paddle.profiler.RecordEvent (event_tracing.h RecordEvent).
+
+    The one bridge between the program's host spans and the profilers:
+    a span goes to the `_HostEventRecorder` (while a `Profiler` records)
+    AND, as a `jax.profiler.TraceAnnotation`, onto the clock of whatever
+    `jax.profiler` trace is running — the one `Profiler` starts itself
+    or an outside one (the benchmark's traced slice) — where the device
+    lines live. Outside a profiler session the annotation is a no-op of
+    well under a microsecond. A process that has not imported jax (a
+    dataloader worker) does not import it for a span.
+
+    Names are constants: what varies per step (request ids, lane
+    counts) belongs in the flight recorder or the `TraceRecorder`, never
+    in a name — it would split one row of a reduction into thousands.
+    """
 
     def __init__(self, name: str, event_type=None):
         self.name = name
         self._start = None
+        self._annotation = None
 
     def begin(self):
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self._start = time.perf_counter_ns() // 1000
 
     def end(self):
         if self._start is not None:
-            _recorder.record(self.name, self._start,
-                             time.perf_counter_ns() // 1000,
-                             threading.get_ident() % 100000)
+            if _recorder.enabled:
+                _recorder.record(self.name, self._start,
+                                 time.perf_counter_ns() // 1000,
+                                 threading.get_ident() % 100000)
             self._start = None
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
 
     def __enter__(self):
         self.begin()
@@ -171,11 +195,12 @@ class Profiler:
                          cat="device" if synced else "op")
 
     def start(self):
+        # first, so that a trace that cannot start leaves nothing on
+        self._maybe_start_device_trace()
         self._state = (self._scheduler(self.step_num)
                        if self._scheduler else ProfilerState.RECORD)
         self._set_recording(self._state in (
             ProfilerState.RECORD, ProfilerState.RECORD_AND_RETURN))
-        self._maybe_start_device_trace()
         self._step_t0 = time.perf_counter()
         return self
 
@@ -218,27 +243,21 @@ class Profiler:
 
     # -- device trace ------------------------------------------------------
     def _maybe_start_device_trace(self):
-        if self._timer_only:
+        """A trace that cannot start says so: the error goes through."""
+        d = os.environ.get("PADDLE_TPU_TRACE_DIR")
+        if self._timer_only or not d:
             return
-        try:
-            import jax
+        import jax
 
-            d = os.environ.get("PADDLE_TPU_TRACE_DIR")
-            if d:
-                jax.profiler.start_trace(d)
-                self._jax_tracing = True
-        except Exception:
-            pass
+        jax.profiler.start_trace(d)
+        self._jax_tracing = True
 
     def _maybe_stop_device_trace(self):
         if self._jax_tracing:
-            try:
-                import jax
+            import jax
 
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
             self._jax_tracing = False
+            jax.profiler.stop_trace()
 
     # -- export / summary --------------------------------------------------
     def export(self, path: str, format: str = "json"):

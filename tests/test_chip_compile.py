@@ -175,3 +175,68 @@ def test_auto_never_selects_the_refused_conv3x3(monkeypatch):
     # an explicit request is still honoured (interpreter tests, A4)
     assert conv.resolve_conv_backend(
         "pallas", kernel=(3, 3), padding=1, **kw) == "pallas"
+
+
+# -- the kernels' names, as the chip's compiler prints them ------------------
+def _instructions(text):
+    """The compiled text cut into instructions, each starting at its
+    `%name = ` as a trace's `XLA Ops` event does (the `kernel_metadata`
+    attribute spreads one instruction over several lines)."""
+    import re
+
+    flat = "\n".join(line.strip() for line in text.splitlines())
+    return [c for c in re.split(r"\n(?=%\S+ = )", flat)
+            if "tpu_custom_call" in c]
+
+
+def test_paged_decode_kernel_is_named_and_still_found_by_the_benchmark(
+        chip):
+    """`paged_attn_roofline` finds the decode kernel by the enclosing
+    program's name (`%engine_decode_step.N`): the kernel's own name must
+    reach the text without renaming the instruction."""
+    import json
+    import re
+    from pathlib import Path
+
+    from benchmarks.lib import trace_reduce
+    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    def engine_decode_step(q, k, v, kp, vp, tables, positions):
+        return paged_decode_attention(q, k, v, kp, vp, 3, tables, positions)
+
+    row = ((SLOTS, 1, 16, HD), BF16)
+    pools = ((LAYERS, NB, BS, 16, HD), BF16)
+    text = chip(engine_decode_step, row, row, row, pools, pools,
+                ((SLOTS, MB), I32), ((SLOTS,), I32))
+    (call,) = _instructions(text)
+    patterns = json.loads(
+        (Path(__file__).parent.parent / "benchmarks" / "layer_metrics"
+         / "paged_attn_roofline.json").read_text())["patterns"]
+    assert any(re.search(p, call) for p in patterns), call[:200]
+    assert "paged_decode_attention" in trace_reduce.op_group(call)
+
+
+@pytest.mark.parametrize("kernel,shape,causal", [
+    ("flash_causal", (2, 2048, 16, 128), True),
+    ("flash_shortseq", (32, 512, 12, 64), False),
+])
+def test_training_kernels_are_named_in_the_compiled_text(
+        chip, monkeypatch, kernel, shape, causal):
+    from benchmarks.lib import trace_reduce
+    from paddle_tpu.distributed import topology
+
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    monkeypatch.setattr(topology, "_default_hcg", None)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal) \
+            .astype(F32).sum()
+
+    text = chip(jax.grad(loss, argnums=(0, 1, 2)), *[(shape, BF16)] * 3)
+    calls = _instructions(text)
+    for name in (kernel + "_fwd", kernel + "_bwd"):
+        # jax's name stack wraps the name: `%jvp_flash_causal_fwd_.1`,
+        # `%transpose_jvp_flash_causal_bwd__.1`
+        mine = [c for c in calls if name in c.split(" = ")[0]]
+        assert mine, (name, [c[:40] for c in calls])
+        assert all(name in trace_reduce.op_group(c) for c in mine)

@@ -1629,6 +1629,22 @@ class GenerationEngine:
             "Paged-attention kernel backend the compiled decode step "
             "dispatches to (1 = selected).", labelnames=("backend",))
         self._m_backend.labels(backend=self.attention_backend).set(1)
+        # what engaged inside the kernel: the fp decode walk's pages a
+        # compute step, from the function the kernel itself asks; the
+        # int8 and verify kernels walk one page a step, dense none
+        from ..ops.pallas.paged_attention import pages_per_step
+        if self.attention_backend != "pallas":
+            pages = 0
+        elif self.kv_dtype == "int8" or self.spec_decode_k:
+            pages = 1
+        else:
+            (*_, heads, head_dim), pool_dtype = self.cache.pool_spec()
+            pages = pages_per_step(self.block_size,
+                                   heads // self.mp_degree, head_dim,
+                                   pool_dtype)
+        m.gauge("engine_paged_decode_pages_per_step",
+                "Pool pages the paged decode kernel fetches and scores "
+                "per compute step (0 = the dense path serves).").set(pages)
         self._m_mesh = m.gauge(
             "engine_mesh_info",
             "Serving mesh the compiled steps span (1 = this "

@@ -134,10 +134,15 @@ def resolve_backend(backend, head_dim, block_size, num_heads):
     tensor parallel — 4 or a multiple of 8 (the per-slot `[heads, D]`
     rows are sliced out of tiled VMEM blocks; 1, 2, 3 or 12 heads, or
     64-wide heads, are refused as "Slice shape ... must be aligned to
-    tiling"), and block_size >= 8 (smaller blocks make the per-block
-    DMA smaller than its descriptor overhead). Everything else stays
-    dense. Explicit `dense`/`pallas` always wins (off-TPU, `pallas`
-    runs the interpreter — the CPU CI path)."""
+    tiling"), and block_size >= 8. The decode walk gathers
+    `pallas.paged_attention.pages_per_step` pages a compute step (8 at
+    block 16, PR 27) and compiles at every geometry admitted here —
+    4, 8, 16 and 32 heads, bf16 and float32 pools — so none of them
+    had to be narrowed to dense; it still issues one copy descriptor a
+    page, and a page under 8 rows has never been compiled for the
+    chip. Everything else stays dense. Explicit `dense`/`pallas`
+    always wins (off-TPU, `pallas` runs the interpreter — the CPU CI
+    path)."""
     if backend not in PAGED_BACKENDS:
         raise ValueError(f"backend must be one of {PAGED_BACKENDS}, "
                          f"got {backend!r}")

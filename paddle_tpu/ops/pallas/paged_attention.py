@@ -9,20 +9,36 @@ the grid runs one program per decode slot, and each program
   `(block_table[pos // bs], pos % bs)` (fused KV write: the pool is an
   input/output-aliased operand, so the write is an in-place DMA, not a
   functional copy of the pool);
-- walks the slot's block table and STREAMS only the blocks at or below
-  its position from HBM into a double-buffered VMEM scratch
-  (`make_async_copy`, next block's DMA in flight behind the current
-  block's compute) — O(active context) HBM traffic per slot per step,
-  where the dense fallback pays O(high-water) and the PR-1 gather paid
-  O(max_model_len);
+- walks the slot's block table and STREAMS only the pages at or below
+  its position from HBM into two VMEM step buffers — O(active context)
+  HBM traffic per slot per step, where the dense fallback pays
+  O(high-water) and the PR-1 gather paid O(max_model_len). A compute
+  step gathers `pages_per_step(...)` pages (8 of 16 tokens = 128 keys
+  at the GPT-1.3B shape: 1 MB of K and V, one `make_async_copy` a live
+  page, none for a page past the position), and the next step's
+  copies — in a slot's last step the NEXT SLOT's first — are in flight
+  behind it, so 1-2 MB are always on their way and the copy queue
+  does not drain between programs;
+- scores a step's keys as they lie, `[keys, heads, D]` read as
+  `[keys * heads, D]` with no head swap: one matmul of the `[heads, D]`
+  query against all rows, a mask that keeps each head's own rows, and
+  one PV matmul in which the masked probabilities are zero (PR 27; on
+  a v5e the walk with its compute taken out runs 0.41 ms a call at 96
+  slots of 344 tokens and the compute with its copies taken out 0.23
+  ms, so the copies bound it, at the rate the chip's DMA reaches);
 - accumulates FlashAttention-style online softmax in fp32 across the
-  streamed blocks and normalizes once at the end.
+  steps and normalizes once at the end.
 
 Null-block semantics are preserved: an idle slot (position 0, all-null
-table) writes its garbage row into block 0 and attends only position 0
-— a one-element softmax, finite by construction — and live slots never
-read a trailing-zero table entry because the walk stops at
-`pos // block_size`.
+table) writes its garbage row into block 0, fetches that one page and
+attends only position 0 — a one-element softmax, finite by
+construction — and live slots never read a trailing-zero table entry
+because the walk stops at `pos // block_size`. The token a step writes
+is never read back from the pool: its row goes from `knew`/`vnew` into
+the step buffer, so no copy waits for the write.
+
+The int8 decode kernel and the two verify kernels keep the older walk:
+one page a compute step, double-buffered, the written rows read back.
 
 `paged_verify_attention` is the speculative-decoding sibling (PR 7):
 the same per-slot grid, block-table walk, and fused-write machinery,
@@ -58,7 +74,8 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas.naming import kernel_name
 
-__all__ = ["paged_decode_attention", "paged_verify_attention"]
+__all__ = ["paged_decode_attention", "paged_verify_attention",
+           "pages_per_step"]
 
 _NEG_INF = -1e30
 
@@ -69,114 +86,174 @@ def _heads_first(x):
     batch (head) axis leads on BOTH operands — the `hd,khd->hk` form,
     head in the middle of the right operand, is refused by the chip's
     compiler though the interpreter accepts it. One in-VMEM swap per
-    streamed block buys the canonical `hqd,hkd->hqk` shape."""
+    streamed block buys the canonical `hqd,hkd->hqk` shape (the int8
+    and verify kernels; `_decode_kernel` scores the rows as they lie)."""
     return jnp.swapaxes(x, 0, 1)
+
+
+#: VMEM the decode walk's two K and two V step buffers may take together
+_WALK_VMEM_BYTES = 4 * 1024 * 1024
+#: keys one compute step scores: a full 128-lane register row per head
+_WALK_KEYS = 128
+
+
+def pages_per_step(block_size, heads, head_dim, dtype):
+    """Pages of a slot's table that ONE compute step of `_decode_kernel`
+    fetches and scores. A function of what the kernel sees and of
+    nothing else: enough pages for `_WALK_KEYS` keys a step, as far as
+    two step buffers of K and two of V fit `_WALK_VMEM_BYTES` (8 pages
+    = 128 keys = 2 MB of scratch at block 16, 16 heads x 128, bf16).
+    The engine publishes the same number as
+    `engine_paged_decode_pages_per_step`."""
+    page = block_size * heads * head_dim * jnp.dtype(dtype).itemsize
+    return max(1, min(_WALK_KEYS // block_size,
+                      _WALK_VMEM_BYTES // (4 * page)))
 
 
 def _decode_kernel(bt_ref, pos_ref, q_ref, knew_ref, vnew_ref,
                    kpool_in, vpool_in, o_ref, kpool_ref, vpool_ref,
-                   kbuf, vbuf, copy_sems, write_sems, *,
+                   kbuf, vbuf, copy_sems, write_sems, buf_ref, *,
                    layer, block_size, scale):
     """One program per slot. bt_ref [slots, max_blocks] and pos_ref
     [slots] are scalar-prefetch (SMEM) so DMA indices are computable
     before the body runs. kpool_ref/vpool_ref are the ALIASED output
     refs of the full pools (ANY/HBM memory space); kpool_in/vpool_in
     are the same buffers' input refs and are intentionally unused.
-    kbuf/vbuf are [2, block_size, heads, D] VMEM double buffers."""
+    kbuf/vbuf are [2, pages * block_size, heads, D] VMEM step buffers
+    and buf_ref [1] (SMEM) says which of the two holds this program's
+    first step: the program before started those copies.
+
+    The walk moves `pages` pages a compute step: one copy descriptor a
+    live page (none for a page past `pos // block_size`), all of a
+    step's K copies on one semaphore and its V copies on another, the
+    next step's — or, in the last step, the NEXT SLOT's first step's —
+    in flight behind this step's compute, so the copy queue never
+    drains between programs.
+
+    A step scores its `pages * block_size` keys as they lie. The tile
+    `[keys, heads, D]` is read as `[keys * heads, D]` (no head swap),
+    one matmul gives every head's query against every (key, head) row,
+    and the mask keeps the entries whose two heads agree; the same
+    mask zeroes the probabilities, so the PV matmul over all rows sums
+    each head's own keys only. The MXU does `heads` times the needed
+    products and is still idle most of the step; the softmax state is
+    whole 128-lane rows."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    # positions and table entries are never negative: lax.div/rem, for
+    # the floor forms trace and lower several ops each, 24 layers over
+    div, rem = jax.lax.div, jax.lax.rem
     s = pl.program_id(0)
     pos = pos_ref[s]
-    last_blk = pos // block_size
-    nblk = last_blk + 1
+    last_blk = div(pos, block_size)
+    step_keys, heads, head_dim = kbuf.shape[1:]
+    pages = step_keys // block_size
+    nsteps = div(last_blk, pages) + 1
+    rows = step_keys * heads
 
-    # fused KV write: this token's row lands in the pool before the
-    # LAST block of this slot's walk is streamed (that block reads it
-    # back); earlier blocks don't depend on it, so their copies run
-    # concurrently with the write instead of behind a write round-trip
-    wk = pltpu.make_async_copy(
-        knew_ref.at[0],
-        kpool_ref.at[layer, bt_ref[s, last_blk], pos % block_size],
-        write_sems.at[0])
-    wv = pltpu.make_async_copy(
-        vnew_ref.at[0],
-        vpool_ref.at[layer, bt_ref[s, last_blk], pos % block_size],
-        write_sems.at[1])
+    # fused KV write: in flight for the whole walk, waited for at the
+    # end. Nothing reads it back: the last step takes this token's row
+    # from knew/vnew (below), so no copy has to queue behind the write
+    written = (layer, bt_ref[s, last_blk], rem(pos, block_size))
+    wk = pltpu.make_async_copy(knew_ref.at[0], kpool_ref.at[written],
+                               write_sems.at[0])
+    wv = pltpu.make_async_copy(vnew_ref.at[0], vpool_ref.at[written],
+                               write_sems.at[1])
     wk.start()
     wv.start()
 
-    def kv_copies(j, buf):
-        bid = bt_ref[s, j]
-        return (pltpu.make_async_copy(kpool_ref.at[layer, bid],
-                                      kbuf.at[buf], copy_sems.at[0, buf]),
-                pltpu.make_async_copy(vpool_ref.at[layer, bid],
-                                      vbuf.at[buf], copy_sems.at[1, buf]))
+    def step_copies(slot, c, buf, start):
+        """Start, or wait for, the copies of step `c` of `slot`: its
+        live pages only, so the tail costs what it holds."""
+        first = c * pages
+        live = jnp.minimum(div(pos_ref[slot], block_size) + 1 - first,
+                           pages)
 
-    def start_copies(j, buf):
-        ck, cv = kv_copies(j, buf)
-        ck.start()
-        cv.start()
+        def page(i, _):
+            bid = bt_ref[slot, first + i]
+            at = pl.ds(pl.multiple_of(i * block_size, block_size),
+                       block_size)
+            for pool, buffer, sem in ((kpool_ref, kbuf, copy_sems.at[0, buf]),
+                                      (vpool_ref, vbuf, copy_sems.at[1, buf])):
+                copy = pltpu.make_async_copy(pool.at[layer, bid],
+                                             buffer.at[buf, at], sem)
+                copy.start() if start else copy.wait()
 
-    @pl.when(last_blk == 0)
-    def _first_is_last():           # 1-block walk: copy needs the write
-        wk.wait()
-        wv.wait()
-        start_copies(0, 0)
+        jax.lax.fori_loop(0, live, page, None)
 
-    @pl.when(last_blk > 0)
-    def _first():                   # block 0 is write-independent
-        start_copies(0, 0)
+    @pl.when(s == 0)
+    def _cold():
+        # rows no copy ever fills meet probability 0 in the PV matmul:
+        # they must be finite, and stay so (only pool pages land here)
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        buf_ref[0] = 0
+        step_copies(s, 0, 0, start=True)
 
+    buf0 = buf_ref[0]
     # inputs stay at the pool dtype through the matmuls (bf16 MXU
     # passes on TPU); accumulation is forced fp32 by
     # preferred_element_type — same numerics policy as the dense path
-    q = q_ref[0].astype(kbuf.dtype)[:, None]    # [heads, 1, D]
-    heads, _, head_dim = q.shape
+    q = q_ref[0].astype(kbuf.dtype)             # [heads, D]
+    col = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 1)
+    own_head = rem(col, heads) == jax.lax.broadcasted_iota(
+        jnp.int32, (heads, rows), 0)
+    key = div(col, heads)                       # key of the step's tile
 
-    def body(j, carry):
+    def body(c, carry):
         m, l, acc = carry
+        buf = rem(buf0 + c, 2)
+        more = c + 1 < nsteps
 
-        @pl.when(j + 1 < nblk)
+        # behind this step's compute: this slot's next step or, in its
+        # last step, the next slot's first
+        @pl.when(jnp.logical_or(more, s + 1 < pl.num_programs(0)))
         def _prefetch():
-            @pl.when(j + 1 == last_blk)
-            def _writes_land_first():   # exactly once per program
-                wk.wait()
-                wv.wait()
+            step_copies(jnp.where(more, s, s + 1),
+                        jnp.where(more, c + 1, 0), 1 - buf, start=True)
 
-            start_copies(j + 1, (j + 1) % 2)
+        step_copies(s, c, buf, start=False)
 
-        ck, cv = kv_copies(j, j % 2)
-        ck.wait()
-        cv.wait()
-        k = _heads_first(kbuf[j % 2])           # [heads, bs, D]
-        v = _heads_first(vbuf[j % 2])
-        sc = jnp.einsum("hqd,hkd->hqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-        gpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (heads, 1, block_size), 2)
-        sc = jnp.where(gpos <= pos, sc, _NEG_INF)
+        @pl.when(jnp.logical_not(more))
+        def _this_token():      # the row in flight to the pool
+            at = pl.ds(pos - c * step_keys, 1)
+            kbuf[buf, at] = knew_ref[...]
+            vbuf[buf, at] = vnew_ref[...]
+
+        k = kbuf[buf].reshape(rows, head_dim)
+        v = vbuf[buf].reshape(rows, head_dim)
+        sc = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [heads, rows]
+        live = jnp.logical_and(own_head, key <= pos - c * step_keys)
+        sc = jnp.where(live, sc, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)                 # [heads, 1, bs] fp32
-        alpha = jnp.exp(m - m_new)              # [heads, 1, 1]
+        p = jnp.exp(sc - m_new)                 # [heads, rows] fp32
+        alpha = jnp.exp(m - m_new)              # [heads, 1]
         l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jnp.einsum(
-            "hqk,hkd->hqd", p.astype(v.dtype), v,
-            preferred_element_type=jnp.float32)
+        acc_new = acc * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((heads, 1, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((heads, 1, 1), jnp.float32)
-    acc0 = jnp.zeros((heads, 1, head_dim), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, nblk, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30))[:, 0].astype(o_ref.dtype)
+    m0 = jnp.full((heads, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((heads, 1), jnp.float32)
+    acc0 = jnp.zeros((heads, head_dim), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, nsteps, body, (m0, l0, acc0))
+    buf_ref[0] = rem(buf0 + nsteps, 2)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    wk.wait()
+    wv.wait()
 
 
 def _decode_kernel_int8(bt_ref, pos_ref, sref, q_ref, knew_ref,
                         vnew_ref, kpool_in, vpool_in, o_ref, kpool_ref,
                         vpool_ref, kbuf, vbuf, copy_sems, write_sems,
                         *, layer, block_size, scale):
-    """int8 edition of `_decode_kernel`: the pools hold int8 codes and
+    """int8 decode, on the one-page walk `_decode_kernel` had before
+    PR 27 (kbuf/vbuf `[2, block_size, heads, D]`, the next page's copy
+    behind this page's compute, the written row read back with the
+    last page): the pools hold int8 codes and
     `sref` is this LAYER's per-block `[num_blocks, 2]` K/V scale plane,
     scalar-prefetched with the block tables. knew/vnew arrive ALREADY
     quantized (the op seam runs quant-on-write: grid grow + requantize
@@ -311,11 +388,16 @@ def paged_decode_attention(q, knew, vnew, kpool, vpool, layer,
         prefetch = (block_tables.astype(jnp.int32),
                     positions.astype(jnp.int32),
                     kv_scales.astype(jnp.float32))
+        step_keys, walk_state = block_size, []  # one page a step
     else:
         kernel = functools.partial(_decode_kernel, layer=int(layer),
                                    block_size=block_size, scale=scale)
         prefetch = (block_tables.astype(jnp.int32),
                     positions.astype(jnp.int32))
+        step_keys = block_size * pages_per_step(
+            block_size, heads, head_dim, kpool.dtype)
+        # which step buffer the slot before has filled for this one
+        walk_state = [pltpu.SMEM((1,), jnp.int32)]
     row = lambda s, *_: (s, 0, 0)  # noqa: E731 — per-slot [1,heads,D]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),  # tables, positions[, scales]
@@ -333,10 +415,11 @@ def paged_decode_attention(q, knew, vnew, kpool, vpool, layer,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, block_size, heads, head_dim), kpool.dtype),
-            pltpu.VMEM((2, block_size, heads, head_dim), vpool.dtype),
+            pltpu.VMEM((2, step_keys, heads, head_dim), kpool.dtype),
+            pltpu.VMEM((2, step_keys, heads, head_dim), vpool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),   # [k|v, buffer]
             pltpu.SemaphoreType.DMA((2,)),     # [k|v] fused write
+            *walk_state,
         ],
     )
     out, new_kpool, new_vpool = pl.pallas_call(

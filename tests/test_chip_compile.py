@@ -91,6 +91,27 @@ def test_paged_attention_kernels_compile(chip, heads, pool, window):
     chip(fn, row, new, new, pools, pools, *tail)
 
 
+@pytest.mark.parametrize("slots,heads,blocks", [
+    # 8 heads under a 16-row bf16 tile
+    pytest.param(SLOTS, 8, NB, id="mp2_shard"),
+    # `gpt1p3b_serve_chat` as it runs
+    pytest.param(96, 16, 3072, id="chat_cell"),
+])
+def test_paged_decode_walk_compiles_at(chip, slots, heads, blocks):
+    """The several-pages-a-step walk (PR 27) at the two geometries the
+    table above lacks: the mp=2 shard, and the benchmark's own engine
+    (96 slots, the 3,072-block pool, 128-entry tables)."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        pages_per_step, paged_decode_attention)
+
+    assert pages_per_step(BS, heads, HD, BF16) == 8
+    row = ((slots, 1, heads, HD), BF16)
+    pools = ((LAYERS, blocks, BS, heads, HD), BF16)
+    chip(lambda q, k, v, kp, vp, bt, pos: paged_decode_attention(
+        q, k, v, kp, vp, 3, bt, pos),
+        row, row, row, pools, pools, ((slots, MB), I32), ((slots,), I32))
+
+
 @pytest.mark.parametrize("name,shape,causal", [
     ("gpt_1p3b", (2, 2048, 16, 128), True),     # chunked causal kernel
     ("bert_base", (32, 512, 12, 64), False),    # short-sequence kernel
@@ -151,7 +172,10 @@ def test_auto_selects_only_paged_geometries_the_chip_accepts(
         monkeypatch, heads, head_dim, block, want):
     """Probed against the chip's compiler in PR 23: the fused paged
     kernels compile at head_dim % 128 == 0 with 4 or a multiple of 8
-    heads per program, and are refused elsewhere."""
+    heads per program, and are refused elsewhere. PR 27's decode walk
+    (several pages a compute step, rows scored as they lie) compiles at
+    all of them, 4 heads under a 16-row bf16 tile included, so nothing
+    admitted here was narrowed to dense for it."""
     pa = importlib.import_module("paddle_tpu.ops.paged_attention")
     monkeypatch.setattr(pa, "on_tpu", lambda: True)
     assert pa.resolve_backend("auto", head_dim=head_dim,

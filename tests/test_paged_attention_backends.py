@@ -222,6 +222,136 @@ def test_backends_agree_bitwise_on_pool_writes():
                                rtol=2e-5, atol=2e-6)
 
 
+# -- the decode walk: several pages a compute step (PR 27) ----------------
+
+_WALK_BS, _WALK_H, _WALK_D, _WALK_MAXB = 16, 2, 8, 12
+
+
+def _walk_pages():
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas.paged_attention import pages_per_step
+    return pages_per_step(_WALK_BS, _WALK_H, _WALK_D, jnp.float32)
+
+
+# context lengths in tokens (the incoming one counted) as functions of
+# the pages a step N and the page size; None is an idle slot
+_WALK_CASES = {
+    "one_token": lambda n, bs: [1],
+    "one_page": lambda n, bs: [bs],
+    "one_page_plus_1": lambda n, bs: [bs + 1],
+    "n_pages": lambda n, bs: [n * bs],
+    "n_pages_plus_1": lambda n, bs: [n * bs + 1],
+    "n_plus_1_pages": lambda n, bs: [(n + 1) * bs],
+    "ragged_with_idle": lambda n, bs: [
+        (n + 1) * bs, 1, None, n * bs + 1, bs, n * bs, bs + 1, None, 7],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_decode_walk_over_context_lengths(case):
+    """The walk's boundaries: a step that is one token, one page, one
+    page and a row, exactly the pages of a compute step, one row more,
+    one page more — alone (first the cold program, then behind an idle
+    slot, whose last step starts the next slot's copies) and mixed in
+    one ragged batch. Against fp64 attention at the file's tolerance,
+    with the pool written bitwise as the dense path writes it."""
+    from paddle_tpu.ops.paged_attention import paged_attention_step
+
+    bs, H, D, maxb = _WALK_BS, _WALK_H, _WALK_D, _WALK_MAXB
+    n = _walk_pages()
+    assert 1 < n and (n + 1) * bs <= maxb * bs    # the cases are distinct
+    lens = _WALK_CASES[case](n, bs)
+    if len(lens) == 1:
+        lens = [lens[0], None, lens[0]]
+    B = len(lens)
+    nb = 1 + B * maxb
+    rng = np.random.RandomState(27)
+    kpool = rng.randn(1, nb, bs, H, D).astype(np.float32)
+    vpool = rng.randn(1, nb, bs, H, D).astype(np.float32)
+    tables = np.zeros((B, maxb), np.int32)
+    positions = np.zeros(B, np.int32)
+    for b, n_tok in enumerate(lens):
+        if n_tok is None:
+            continue
+        positions[b] = n_tok - 1
+        pages = (n_tok - 1) // bs + 1
+        tables[b, :pages] = 1 + b * maxb + np.arange(pages)
+    q = rng.randn(B, 1, H, D).astype(np.float32)
+    k_new = rng.randn(B, 1, H, D).astype(np.float32)
+    v_new = rng.randn(B, 1, H, D).astype(np.float32)
+    # every idle slot writes the null row: make the rows equal, so the
+    # scatter's winner and the kernel's last writer agree
+    idle = [b for b, n_tok in enumerate(lens) if n_tok is None]
+    k_new[idle], v_new[idle] = k_new[idle[0]], v_new[idle[0]]
+
+    res = {}
+    for backend in ("dense", "pallas"):
+        out, kp, vp = paged_attention_step(q, k_new, v_new, kpool, vpool,
+                                           0, tables, positions,
+                                           backend=backend)
+        res[backend] = (np.asarray(out._array), np.asarray(kp._array),
+                        np.asarray(vp._array))
+    out, kp, vp = res["pallas"]
+    np.testing.assert_array_equal(kp, res["dense"][1])
+    np.testing.assert_array_equal(vp, res["dense"][2])
+    assert np.isfinite(out).all()                 # idle slots too
+    for b, n_tok in enumerate(lens):
+        if n_tok is None:
+            continue
+        pos = n_tok - 1
+        ctx_k = kpool[0, tables[b]].reshape(-1, H, D)
+        ctx_v = vpool[0, tables[b]].reshape(-1, H, D)
+        ref = _np_step_reference(q[b], k_new[b], v_new[b], ctx_k, ctx_v,
+                                 pos)
+        np.testing.assert_allclose(out[b], ref, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(out, res["dense"][0], rtol=2e-5, atol=2e-6)
+
+
+def test_pages_per_step_follows_from_shapes_alone(model, monkeypatch):
+    """The chat cell's shape reads 8 pages a step on every call; every
+    geometry `auto` admits on a TPU reads at least one, inside the
+    walk's VMEM budget once it reads more; the engine publishes the
+    kernel's own number."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import paged_attention as kernels
+
+    chat = (16, 16, 128, jnp.bfloat16)
+    assert [kernels.pages_per_step(*chat) for _ in range(3)] == [8] * 3
+    pa = importlib.import_module("paddle_tpu.ops.paged_attention")
+    with monkeypatch.context() as on_chip:
+        on_chip.setattr(pa, "on_tpu", lambda: True)
+        admitted = [
+            (block, heads, head_dim)
+            for heads in (1, 2, 4, 8, 12, 16, 24, 32, 40, 64)
+            for head_dim in (64, 128, 256)
+            for block in (4, 8, 16, 32, 64, 128, 256)
+            if pa.resolve_backend("auto", head_dim=head_dim,
+                                  block_size=block,
+                                  num_heads=heads) == "pallas"]
+    assert len(admitted) >= 30
+    for block, heads, head_dim in admitted:
+        for dt in (jnp.bfloat16, jnp.float32):
+            n = kernels.pages_per_step(block, heads, head_dim, dt)
+            assert n >= 1 and n * block <= max(block, 128)
+            held = 4 * n * block * heads * head_dim * jnp.dtype(dt).itemsize
+            assert n == 1 or held <= kernels._WALK_VMEM_BYTES
+
+    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
+    for backend, want in (
+            ("pallas", kernels.pages_per_step(4, 2, 16, jnp.float32)),
+            ("dense", 0)):
+        eng = GenerationEngine(model, num_slots=2, block_size=4,
+                               num_blocks=20, prefill_buckets=(8, 64),
+                               attention_backend=backend)
+        (series,) = eng.metrics_snapshot()[
+            "engine_paged_decode_pages_per_step"]["series"]
+        assert series["value"] == want
+
+
 # -- satellite: dense-fallback bf16 numerics ------------------------------
 
 def test_dense_bf16_pv_accumulation_fp32(model=None):

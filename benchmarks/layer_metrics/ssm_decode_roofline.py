@@ -1,0 +1,10 @@
+"""`ssm_decode_roofline`: state bytes read and written of the live lanes
+of the traced decode steps over the device time of `ssm_decode_update`."""
+from benchmarks.lib import kernel_shares
+
+
+def read(params, facts):
+    lanes = kernel_shares.slice_counter(facts, "decode_live_lanes")
+    work = kernel_shares.architecture_counts(facts).ssm_decode_work(
+        facts["cfg"], lanes) if lanes else None
+    return kernel_shares.share(params, facts, work)

@@ -10,6 +10,14 @@ static shapes, MXU-friendly), and the cross-device exchange over the 'ep'
 axis is lax.all_to_all inside the SPMD program instead of NCCL alltoall
 kernels. With ep degree 1 everything stays local and the layer is a dense
 jax computation.
+
+At hundreds of experts a `[T, E, C]` one-hot is the wrong shape and a
+capacity drops tokens: the second half of this module (`sorted_dispatch`,
+`expert_share`) is the DROPLESS form — assignments sorted by expert, each
+expert's group padded to whole row tiles, one grouped matmul over the
+experts HELD HERE (`ops/pallas/moe.py` on the chip). The layer is told
+which experts it holds (`first_expert`, the leading axis of its weights)
+and computes their part of the result; the router stays whole.
 """
 from __future__ import annotations
 
@@ -239,3 +247,130 @@ class MoELayer(nn.Layer):
                          self.b2)
         self.aux_loss = aux
         return out
+
+
+# ---------------------------------------------------------------------------
+# dropless sorted / grouped routing over the experts held here
+# ---------------------------------------------------------------------------
+
+MOE_BACKENDS = ("auto", "xla", "pallas")
+#: which form of the expert product was traced (per trace, as
+#: `PAGED_PATH_STATS`): never a silent fallback
+MOE_PATH_STATS = {"xla": 0, "pallas": 0}
+#: rows of a tile of the grouped matmul (a bf16 vreg holds 16 sublanes)
+TILE_ROWS = 16
+
+
+def reset_moe_path_stats():
+    for k in MOE_PATH_STATS:
+        MOE_PATH_STATS[k] = 0
+
+
+def resolve_moe_backend(backend, width=128):
+    """`auto` takes the kernel on a TPU where the experts' widths fill
+    whole 128-lane tiles; an explicit choice always wins (off the chip
+    `pallas` runs the interpreter)."""
+    from paddle_tpu.core.device import on_tpu
+
+    if backend not in MOE_BACKENDS:
+        raise ValueError(f"backend must be one of {MOE_BACKENDS}, "
+                         f"got {backend!r}")
+    if backend != "auto":
+        return backend
+    return "pallas" if on_tpu() and width % 128 == 0 else "xla"
+
+
+def sorted_dispatch(ids, first_expert, num_held, tile_rows=TILE_ROWS):
+    """Where every assignment goes. ids `[T, k]` int32, experts among
+    ALL the router's; this layer holds `first_expert .. first_expert +
+    num_held`. No capacity, nothing dropped: the buffer has room for
+    every assignment landing here plus each expert's padding.
+
+    -> dict: `row_token` `[M]` (the token of each buffer row, T for a
+    padding row), `slot_row` `[T, k]` (the buffer row of each assignment,
+    M where its expert is not held), `tile_expert` `[M / tile_rows]`,
+    `live_tiles` `[1]`, `group_sizes` `[num_held]`."""
+    t, k = ids.shape
+    a = t * k
+    m = -(-(a + num_held * (tile_rows - 1)) // tile_rows) * tile_rows
+    local = ids.reshape(a).astype(jnp.int32) - first_expert
+    held = (local >= 0) & (local < num_held)
+    key = jnp.where(held, local, num_held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=num_held + 1)[:num_held] \
+        .astype(jnp.int32)
+    padded = -(-sizes // tile_rows) * tile_rows
+    ends = jnp.cumsum(padded)
+    group_row = ends - padded                  # first buffer row a group
+    group_rank = jnp.cumsum(sizes) - sizes     # first sorted place a group
+    sorted_key = key[order]
+    safe = jnp.minimum(sorted_key, num_held - 1)
+    dest = jnp.where(sorted_key < num_held,
+                     group_row[safe] + jnp.arange(a) - group_rank[safe], m)
+    row_token = jnp.full(m, t, jnp.int32).at[dest].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    slot_row = jnp.zeros(a, jnp.int32).at[order].set(dest.astype(jnp.int32))
+    live = ends[-1] // tile_rows
+    tiles = jnp.arange(m // tile_rows)
+    tile_expert = jnp.searchsorted(
+        ends, jnp.minimum(tiles, jnp.maximum(live - 1, 0)) * tile_rows,
+        side="right")
+    return {"row_token": row_token, "slot_row": slot_row.reshape(t, k),
+            "tile_expert": jnp.minimum(tile_expert, num_held - 1)
+            .astype(jnp.int32),
+            "live_tiles": live.reshape(1).astype(jnp.int32),
+            "group_sizes": sizes}
+
+
+def _grouped_xla(x, w, tile_expert, live_tiles, tile_rows, relu_squared):
+    """The kernel's mathematics in plain XLA: each row tile against its
+    expert's gathered weights (the gather copies a weight block a tile:
+    for tests and as the other path, not for speed)."""
+    m, k = x.shape
+    tiles = x.reshape(m // tile_rows, tile_rows, k)
+    out = jnp.einsum("mtk,mkn->mtn", tiles, w[tile_expert],
+                     preferred_element_type=jnp.float32)
+    if relu_squared:
+        out = jnp.square(jnp.maximum(out, 0.0))
+    live = jnp.arange(m // tile_rows) < live_tiles[0]
+    out = jnp.where(live[:, None, None], out, 0.0)
+    return out.reshape(m, -1).astype(x.dtype)
+
+
+def expert_share(x, ids, weights, w1, w2, first_expert, backend="auto",
+                 tile_rows=TILE_ROWS):
+    """What the experts held here add for every token:
+    `sum_{e chosen and held} weight_e * relu(x W1_e)^2 W2_e`.
+
+    x `[T, d]`; ids, weights `[T, k]` (the router's choice among ALL its
+    experts, and the weights as normalised over the whole chosen set);
+    w1 `[held, d, h]`, w2 `[held, h, d]`: experts `first_expert ..
+    first_expert + held`. -> (`[T, d]` float32, counters `[3]` int32:
+    assignments held, experts touched, the largest expert's load)."""
+    from paddle_tpu.core.device import pallas_interpret
+
+    num_held = w1.shape[0]
+    plan = sorted_dispatch(ids, first_expert, num_held, tile_rows)
+    rows = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+    rows = rows[plan["row_token"]]
+    resolved = resolve_moe_backend(backend, min(w1.shape[1], w1.shape[2]))
+    MOE_PATH_STATS[resolved] += 1
+    if resolved == "pallas":
+        from paddle_tpu.ops.pallas.moe import moe_grouped_matmul
+
+        gmm = lambda a, w, sq: moe_grouped_matmul(  # noqa: E731
+            a, w, plan["tile_expert"], plan["live_tiles"], tile_rows,
+            relu_squared=sq, interpret=pallas_interpret())
+    else:
+        gmm = lambda a, w, sq: _grouped_xla(  # noqa: E731
+            a, w, plan["tile_expert"], plan["live_tiles"], tile_rows, sq)
+    out = gmm(gmm(rows, w1, True), w2, False)
+    out = jnp.concatenate([out, jnp.zeros((1, out.shape[1]), out.dtype)])
+    m = out.shape[0] - 1
+    w_held = jnp.where(plan["slot_row"] < m, weights, 0.0)
+    picked = out[plan["slot_row"]].astype(jnp.float32)   # [T, k, d]
+    sizes = plan["group_sizes"]
+    counters = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                          jnp.max(sizes)]).astype(jnp.int32)
+    return jnp.einsum("tk,tkd->td", w_held.astype(jnp.float32), picked,
+                      precision=jax.lax.Precision.HIGHEST), counters

@@ -2,7 +2,7 @@
 
 The serving tier the north star's "heavy traffic" clause asks for:
 instead of one request at a time against a per-request fixed-size cache
-(`GPTForCausalLM.generate`), MANY requests decode in ONE compiled step
+(a model's own `generate`), MANY requests decode in ONE compiled step
 (Orca-style iteration-level scheduling) against a global block pool
 shared by all of them (vLLM-style PagedAttention layout).
 
@@ -53,7 +53,7 @@ recompiles:
   shapes regardless of which lanes are live, so arrivals/completions
   never retrace — `jit.count_traces` probes prove it in CI.
 
-Greedy decoding matches `GPTForCausalLM.generate(use_cache=True)`
+Greedy decoding matches the model's own `generate(use_cache=True)`
 token-for-token per request (the parity contract CI enforces) — under
 either paged-attention backend: `attention_backend` (or the
 `PADDLE_PAGED_ATTENTION_BACKEND` env override) picks `auto` / `dense` /
@@ -70,7 +70,7 @@ one target-model pass over several tokens: with `spec_decode_k=K > 0`
 (`inference/speculative.NgramDrafter` by default — model-free
 prompt-lookup; any `propose(prompt, generated, k)` object plugs in)
 proposes up to K tokens per lane, and ONE fixed-shape compiled verify
-step (`forward_verify_paged`: `[slots, K+1]` tokens, traced per-row
+step (the model's verify step: `[slots, K+1]` tokens, traced per-row
 positions and draft lengths) scores all K+1 positions against the
 paged pools, writing their KV through the block tables. Acceptance is
 EXACT under the greedy contract: the longest draft prefix matching the
@@ -354,10 +354,17 @@ class PagedKVCache:
     """Global paged KV pool + host-side block allocator, refcounts, and
     hash-based prefix cache.
 
-    kpool/vpool: `[layers, num_blocks, block_size, heads, head_dim]`
+    kpool/vpool: `[layers, num_blocks, block_size, kv_heads, head_dim]`
     device arrays, functionally updated by the compiled steps (donated,
     so updated in place on device). Block 0 is reserved as the null
-    block — `allocate` never returns it.
+    block — `allocate` never returns it. `layers` are the layers that
+    keep K and V, `kv_heads` what they keep (a model's spec says both).
+
+    Beside the paged blocks the same manager holds the slots' state of
+    FIXED size (`slot_state`: recurrent layers' windows and state
+    matrices), one row a slot in arrays `[layers, 1 + state_rows, ...]`.
+    Row 0 is the null row (idle lanes write there); `allocate_state`
+    hands a row out ZEROED, `free_state` takes it back.
 
     Every live block carries a reference count: `allocate` hands blocks
     out at refcount 1, `share` seats an existing block in another
@@ -381,9 +388,9 @@ class PagedKVCache:
     RACE_RELEASE_METHODS = \
         introspect.ALLOCATOR_RELEASE_EFFECTS["PagedKVCache"]
 
-    def __init__(self, num_layers, num_blocks, block_size, num_heads,
+    def __init__(self, num_layers, num_blocks, block_size, kv_heads,
                  head_dim, dtype=jnp.float32, mesh=None, mp_axis="mp",
-                 kv_dtype=None):
+                 kv_dtype=None, slot_state=(), state_rows=0):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the null "
                              "block)")
@@ -394,7 +401,7 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
+        self.kv_heads = int(kv_heads)
         self.head_dim = int(head_dim)
         # int8 per-block-scaled KV (PR 11): the pools store int8 codes
         # and `self.scales` `[layers, num_blocks, 2]` f32 carries each
@@ -415,9 +422,9 @@ class PagedKVCache:
             from jax.sharding import NamedSharding
 
             mp = mesh.shape[mp_axis]
-            if self.num_heads % mp:
+            if self.kv_heads % mp:
                 raise ValueError(
-                    f"num_heads={num_heads} not divisible by mp "
+                    f"num_heads={kv_heads} not divisible by mp "
                     f"degree {mp} — cannot head-shard the KV pools")
             sharding = NamedSharding(mesh, self.pool_pspec())
             self.kpool = jax.device_put(jnp.zeros(shape, dt), sharding)
@@ -454,17 +461,61 @@ class PagedKVCache:
         # optional observer called with each block id the allocator
         # reclaims from the prefix cache (engine flight recorder)
         self.on_evict = None
+        # state of fixed size a slot: rows 1..state_rows, LIFO free list
+        self.state = tuple(
+            jnp.zeros((s.layers, 1 + int(state_rows)) + tuple(s.shape),
+                      s.dtype) for s in slot_state)
+        self.state_rows = int(state_rows) if slot_state else 0
+        self._free_rows = list(range(self.state_rows, 0, -1))
+        self._zero_row = None
+
+    # -- state of fixed size a slot ----------------------------------------
+    @property
+    def state_rows_used(self):
+        return self.state_rows - len(self._free_rows)
+
+    def state_nbytes(self):
+        return sum(int(a.nbytes) for a in self.state)
+
+    def allocate_state(self):
+        """A row of the state arrays, ZEROED in every layer (a recurrent
+        layer starts a prompt from nought, whoever sat there before), or
+        None when every row is held; 0 where the model keeps no such
+        state."""
+        if not self.state:
+            return 0
+        if not self._free_rows:
+            return None
+        row = self._free_rows.pop()
+        if self._zero_row is None:
+            def engine_state_zero_row(arrays, r):
+                return tuple(a.at[:, r].set(jnp.zeros((), a.dtype))
+                             for a in arrays)
+
+            self._zero_row = jax.jit(
+                engine_state_zero_row,
+                donate_argnums=(0,) if jax.default_backend() != "cpu"
+                else ())
+        self.state = self._zero_row(self.state, jnp.int32(row))
+        return row
+
+    def free_state(self, row):
+        if not self.state or not row:
+            return
+        if row in self._free_rows:
+            raise RuntimeError(f"double free of state row {row}")
+        self._free_rows.append(int(row))
 
     def pool_spec(self):
         """The ONE source of truth for a pool plane's logical
-        `([layers, blocks, block_size, heads, head_dim], dtype)`: the
+        `([layers, blocks, block_size, kv_heads, head_dim], dtype)`: the
         sharded and unsharded constructors (and anything rebuilding a
         pool-shaped buffer) derive it from here, so the two layouts
         cannot drift. Under `kv_dtype='int8'` the dtype is int8 (the
         codes); the per-block grids live in `scale_spec()`."""
         dt = jnp.int8 if self.kv_dtype == "int8" else self.dtype
         return ((self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads, self.head_dim), dt)
+                 self.kv_heads, self.head_dim), dt)
 
     def scale_spec(self):
         """Layout of the int8 pools' per-block scale array:
@@ -647,6 +698,11 @@ class PagedKVCache:
             leaked.append(b)
         return leaked
 
+    def state_leak_check(self):
+        """State rows still held on a quiesced pool."""
+        return sorted(set(range(1, self.state_rows + 1))
+                      - set(self._free_rows))
+
 
 # admission QoS classes, best-served-first; add_request validates
 # against this tuple and the TTFT/TPOT histograms are labeled by it
@@ -698,6 +754,7 @@ class _Slot:
     hit_tokens: int = 0                # prefix-cache tokens never computed
     admit_seq: int = 0                 # admission order tiebreak
     adapter_page: int = 0              # adapter-pool page (0 = null)
+    state_row: int = 0                 # row of the fixed state (0 = none)
     # per-slot sampling state threaded into the compiled steps as
     # traced per-row data (greedy lanes: 0 / 0 / 1.0 / zero key row)
     temp: float = 0.0
@@ -739,6 +796,7 @@ class _InFlight:
     runnable: list                     # lane indices dispatched
     slots: list                        # the _Slot objects, snapshotted
     drafts: dict = None                # lane -> draft (verify steps)
+    counters: object = None            # the step's counters, on device
     t_dec: float = 0.0                 # perf_counter at dispatch
     t_span: int = 0                    # now_us at schedule end
     seq: int = 0                       # pipeline sequence number
@@ -752,11 +810,12 @@ class GenerationEngine:
         ...                                  # add more any time
         results = engine.run()               # {req_id: full token list}
 
-    `model` is a GPTForCausalLM (or anything exposing
-    `gpt.forward_prefill`, `gpt.forward_decode_paged` and `_logits_of`
-    with the same contracts). Generation is eval-mode; the engine
-    refuses a model left in training mode with active dropout, same as
-    `generate(use_cache=True)`.
+    `model` is anything with a `serving_spec()`
+    (`inference/serving_spec.py`): the sizes, what state a slot holds per
+    kind of layer (paged K/V, state of fixed size), the step functions
+    the compiled programs call, and what the engine must refuse for it.
+    Generation is eval-mode; the engine refuses a model left in training
+    mode with active dropout, same as `generate(use_cache=True)`.
     """
 
     #: Dispatch/complete surface of the (async) step pipeline, declared
@@ -777,11 +836,10 @@ class GenerationEngine:
                  adapter_pool_pages=None, sampling=None,
                  tracing=None, trace_capacity=4096,
                  flight_capacity=256, async_core=None):
-        from paddle_tpu.ops.paged_attention import (copy_pool_block,
-                                                    resolve_backend)
+        from paddle_tpu.ops.paged_attention import copy_pool_block
 
-        cfg = model.config
-        if model.training and cfg.dropout > 0:
+        spec = self.spec = model.serving_spec()
+        if model.training and spec.dropout > 0:
             raise ValueError("GenerationEngine decodes deterministically "
                              "(no dropout) — call model.eval() first")
         self.model = model
@@ -791,12 +849,12 @@ class GenerationEngine:
         # env PADDLE_SERVE_MP override wins (deploy-time knob, like
         # the attention backend). mp=1 (the default) is exactly the
         # single-chip engine — no mesh, no shard_map, no resharding.
-        self._resolve_mesh(mesh, mp_degree, cfg)
-        self.max_model_len = int(max_model_len or cfg.max_seq_len)
-        if self.max_model_len > cfg.max_seq_len:
+        self._resolve_mesh(mesh, mp_degree)
+        self.max_model_len = int(max_model_len or spec.max_seq_len)
+        if self.max_model_len > spec.max_seq_len:
             raise ValueError(
                 f"max_model_len={self.max_model_len} exceeds the "
-                f"model's position table ({cfg.max_seq_len})")
+                f"model's position table ({spec.max_seq_len})")
         self.max_blocks = math.ceil(self.max_model_len / self.block_size)
         self.eos_token_id = eos_token_id
         self.max_queue = None if max_queue is None else int(max_queue)
@@ -817,10 +875,15 @@ class GenerationEngine:
         self.prefill_chunk = None if prefill_chunk is None \
             else max(1, min(int(prefill_chunk), self.max_model_len))
         self.chunked_prefill = self.prefill_chunk is not None
+        if not self.chunked_prefill:
+            self._refuse("bucketed_prefill")
         # prefix cache: content-hash block reuse needs tail-only
         # prefill, which only the chunked path can run
         if enable_prefix_cache is None:
-            enable_prefix_cache = self.chunked_prefill
+            enable_prefix_cache = self.chunked_prefill \
+                and "prefix_cache" not in spec.refuses
+        if enable_prefix_cache:
+            self._refuse("prefix_cache")
         if enable_prefix_cache and not self.chunked_prefill:
             raise ValueError("the prefix cache needs chunked prefill "
                              "(bucketed prefill always recomputes from "
@@ -837,6 +900,10 @@ class GenerationEngine:
             "PADDLE_SERVE_KV_DTYPE", kv_dtype)
         self.weight_dtype = self._resolve_dtype_knob(
             "PADDLE_SERVE_WEIGHT_DTYPE", weight_dtype)
+        if self.kv_dtype:
+            self._refuse("kv_int8")
+        if self.weight_dtype:
+            self._refuse("weight_int8")
         # probabilistic serving (PR 15): sampling=True threads per-slot
         # SamplingParams (temperature/top-k/top-p + a [slots, 2] uint32
         # key row) through every compiled step as traced DATA. Off (the
@@ -867,6 +934,8 @@ class GenerationEngine:
         # keeps today's serial step loop op-for-op.
         self.async_core = self._resolve_bool_knob(
             "PADDLE_SERVE_ASYNC", async_core)
+        if self.async_core:
+            self._refuse("async_core")
         self._inflight = None          # the single in-flight step slot
         self._ahead = None             # (helper thread, results dict)
         self._next_drafts = {}         # slot -> precomputed draft
@@ -880,13 +949,13 @@ class GenerationEngine:
         # default pool covers every slot at full context (+ null block):
         # correctness-first; serving deployments size it to live-context
         # expectations and lean on the stall/retry path under pressure
+        kv = spec.paged_kv
         self.cache = PagedKVCache(
-            cfg.num_layers,
+            kv.layers,
             int(num_blocks or 1 + self.num_slots * self.max_blocks),
-            self.block_size, cfg.num_heads,
-            cfg.hidden_size // cfg.num_heads,
-            dtype=model.gpt.wte.weight._array.dtype, mesh=self.mesh,
-            kv_dtype=self.kv_dtype)
+            self.block_size, kv.kv_heads, kv.head_dim,
+            dtype=spec.dtype, mesh=self.mesh, kv_dtype=self.kv_dtype,
+            slot_state=spec.slot_state, state_rows=self.num_slots)
         self.cache.on_evict = lambda b: self.flight.record(
             "prefix_evict", block=b)
         # multi-tenant adapter serving (paged batched-LoRA): an
@@ -894,8 +963,7 @@ class GenerationEngine:
         # per-slot adapter ids through every compiled step. None (the
         # default) threads nothing — the engine's programs are
         # BIT-identical to the pre-adapter ones.
-        self._resolve_adapters(adapters, adapter_pool_pages, cfg,
-                               model, donate)
+        self._resolve_adapters(adapters, adapter_pool_pages, donate)
         if self.chunked_prefill:
             self.prefill_buckets = ()
         else:
@@ -913,10 +981,8 @@ class GenerationEngine:
         requested = os.environ.get("PADDLE_PAGED_ATTENTION_BACKEND") \
             or attention_backend or "auto"
         self.attention_backend_requested = requested
-        self.attention_backend = resolve_backend(
-            requested, head_dim=cfg.hidden_size // cfg.num_heads,
-            block_size=self.block_size,
-            num_heads=cfg.num_heads // self.mp_degree)
+        self.attention_backend = spec.attention_backend(
+            requested, self.block_size, self.mp_degree)
         # speculative decoding: K drafted tokens verified per compiled
         # step. Env override wins (deploy-time knob, like the backend);
         # K=0 builds today's one-token decode step unchanged.
@@ -933,6 +999,7 @@ class GenerationEngine:
             raise ValueError(f"spec_decode_k must be >= 0, got {k}")
         self.spec_decode_k = k
         if k > 0:
+            self._refuse("spec_decode")
             from paddle_tpu.inference.speculative import NgramDrafter
 
             self.drafter = drafter if drafter is not None \
@@ -950,7 +1017,7 @@ class GenerationEngine:
         # the int8 bytes. `_qmeta[i]` is the entry's dequant target
         # dtype (None = unquantized); quantize_weights() (re)builds
         # the snapshot.
-        self._wq_plan = self._weight_quant_plan() \
+        self._wq_plan = spec.weight_quant_plan() \
             if self.weight_dtype == "int8" else {}
         self._qmeta = [None] * len(self._state)
         self._q_arrays = None
@@ -966,8 +1033,12 @@ class GenerationEngine:
         # the one donation table both analyzers and the engine read:
         # introspect.ENGINE_STEP_DONATION (tpu-lint TPU004 resolves
         # the constants, tpu-verify TPU101 checks the lowered aliases)
-        self._donate_argnums = introspect.ENGINE_STEP_DONATE_ARGNUMS \
-            if donate else ()
+        # (a model with state of fixed size donates that too: it rides
+        # as one tuple right after the pools)
+        self._donate_argnums = (
+            introspect.ENGINE_STATEFUL_STEP_DONATE_ARGNUMS
+            if self.cache.state
+            else introspect.ENGINE_STEP_DONATE_ARGNUMS) if donate else ()
         # with speculation on, the verify step IS the engine's decode
         # step: same probe, same donation, same traces==1 contract —
         # one program per (backend, K). Under sampling the verify step
@@ -1003,6 +1074,10 @@ class GenerationEngine:
         self._admit_counter = 0
         self.tokens_generated = 0
         self.prefix_hit_tokens = 0
+        self.decode_steps = 0
+        # the model's own counters, summed (or the largest) over every
+        # decode step: `spec.step_counters` names them
+        self.step_counter_totals = {n: 0 for n, _ in spec.step_counters}
         # serving telemetry: per-engine registry by default so counter
         # exactness survives multiple engines in one process; pass
         # observability.get_registry() to publish on the process default
@@ -1011,11 +1086,20 @@ class GenerationEngine:
         self._init_metrics()
 
     # -- tensor-parallel serving (mesh) ------------------------------------
-    def _resolve_mesh(self, mesh, mp_degree, cfg):
+    def _refuse(self, feature):
+        """Raise, with the model's reason, where its spec lists `feature`
+        among what must not be served for it."""
+        reason = self.spec.refuses.get(feature)
+        if reason:
+            raise ValueError(
+                f"{feature} is not served for this model: {reason}")
+
+    def _resolve_mesh(self, mesh, mp_degree):
         """Resolve (mesh, mp_degree, env) to the serving mesh. Env
         PADDLE_SERVE_MP wins; an explicit mesh must agree with it and
         must carry an 'mp' axis. Degree 1 means single-chip (no mesh).
-        Validates the Megatron divisibility constraints up front."""
+        The model's spec validates its divisibility constraints up
+        front."""
         from paddle_tpu.distributed.topology import serving_mesh
 
         env = os.environ.get("PADDLE_SERVE_MP")
@@ -1052,16 +1136,9 @@ class GenerationEngine:
                 self.mp_degree)
         if self.mp_degree > 1:
             # fail HERE with the shape story, not deep in a per-shard
-            # reshape (the serving_mesh contract, re-checked for an
-            # explicitly passed mesh too)
-            serving_mesh(self.mp_degree, num_heads=cfg.num_heads,
-                         vocab_size=cfg.vocab_size,
-                         devices=list(self.mesh.devices.reshape(-1)))
-            if cfg.intermediate_size % self.mp_degree:
-                raise ValueError(
-                    f"intermediate_size={cfg.intermediate_size} is not "
-                    f"divisible by mp degree {self.mp_degree} — cannot "
-                    "column-shard the MLP")
+            # reshape (re-checked for an explicitly passed mesh too)
+            self.spec.check_mesh(self.mp_degree,
+                                 list(self.mesh.devices.reshape(-1)))
         self._mp_axis = "mp" if self.mp_degree > 1 else None
 
     @staticmethod
@@ -1192,7 +1269,7 @@ class GenerationEngine:
                             else slot.key_row[None])]
 
     # -- multi-tenant adapter serving (paged batched-LoRA) -----------------
-    def _resolve_adapters(self, adapters, pages, cfg, model, donate):
+    def _resolve_adapters(self, adapters, pages, donate):
         """Wire the paged adapter pool: an AdapterRegistry builds a
         pool on this engine's mesh (`adapter_pool_pages` pages,
         default 1 + num_slots so a full batch of distinct tenants
@@ -1207,6 +1284,11 @@ class GenerationEngine:
             return
         from paddle_tpu.adapters import AdapterRegistry, \
             PagedAdapterPool
+
+        geometry = self.spec.adapter_geometry()
+        if geometry is None:
+            raise ValueError("this model's serving steps take no "
+                             "adapters")
 
         if isinstance(adapters, PagedAdapterPool):
             if pages is not None:
@@ -1232,16 +1314,12 @@ class GenerationEngine:
             pool = PagedAdapterPool(
                 reg, num_pages=int(pages) if pages is not None
                 else 1 + self.num_slots,
-                dtype=model.gpt.wte.weight._array.dtype,
-                mesh=self.mesh, donate=donate)
+                dtype=self.spec.dtype, mesh=self.mesh, donate=donate)
         else:
             raise TypeError(
                 "adapters= takes an AdapterRegistry or a "
                 f"PagedAdapterPool, got {type(adapters).__name__}")
-        for name, want in (("num_layers", cfg.num_layers),
-                           ("hidden_size", cfg.hidden_size),
-                           ("intermediate_size", cfg.intermediate_size),
-                           ("num_heads", cfg.num_heads)):
+        for name, want in geometry.items():
             if getattr(reg, name) != want:
                 raise ValueError(
                     f"adapter registry {name}={getattr(reg, name)} "
@@ -1273,31 +1351,6 @@ class GenerationEngine:
             or self.adapter_pool.can_acquire(adapter_id)
 
     # -- int8 weight serving ----------------------------------------------
-    def _weight_quant_plan(self):
-        """id(state tensor) -> (scale_transform, scale PartitionSpec)
-        for every weight served int8: the attention qkv/out and MLP
-        fc1/fc2 matmuls (the per-step weight-read floor), per-OUTPUT-
-        channel absmax scales via quantization.quantize_absmax(axis=1).
-        Embeddings/norms/biases stay fp — the logit head's quality is
-        the tolerance budget's scarcest resource. The scale transform
-        mirrors `_tp_plan`'s qkv head-grouping so scales shard exactly
-        like their weights."""
-        from jax.sharding import PartitionSpec as P
-
-        D = self.model.config.hidden_size // self.model.config.num_heads
-
-        def qkv_s(s):                  # [1, 3H] -> [1, heads, 3, D]
-            return s.reshape(1, 3, -1, D).transpose(0, 2, 1, 3)
-
-        plan = {}
-        for blk in self.model.gpt.blocks:
-            attn, mlp = blk.attn, blk.mlp
-            plan[id(attn.qkv_proj.weight)] = (qkv_s,
-                                              P(None, "mp", None, None))
-            for lin in (attn.out_proj, mlp.fc1, mlp.fc2):
-                plan[id(lin.weight)] = (None, P(None, "mp"))
-        return plan
-
     def quantize_weights(self):
         """(Re)build the served weight snapshot: the tensor-parallel
         mesh placement (mp > 1) and/or the int8 quantized state
@@ -1311,7 +1364,7 @@ class GenerationEngine:
 
     def _build_quant_state(self):
         """mp=1 int8 snapshot: state entries become (int8, scale)
-        pairs per `_weight_quant_plan`, everything else rides live."""
+        pairs per the spec's `weight_quant_plan`, the rest rides live."""
         from paddle_tpu.quantization import quantize_absmax
 
         arrays = []
@@ -1337,43 +1390,9 @@ class GenerationEngine:
                 if meta is not None else e
                 for e, meta in zip(state_arrays, self._qmeta)]
 
-    def _tp_plan(self):
-        """id(state tensor) -> (transform, PartitionSpec): the Megatron
-        column-parallel serving layout. qkv weights are re-grouped
-        head-major (`[H, heads, 3, D]`) so a contiguous heads-axis
-        shard holds complete (q, k, v) triples for ITS heads;
-        out_proj/fc1/fc2 shard their OUTPUT columns (full-length dots,
-        all-gathered activations — bit-exact vs mp=1, see
-        DESIGN_DECISIONS r12); wte shards vocab rows. Everything else
-        (layer norms, wpe) replicates."""
-        from jax.sharding import PartitionSpec as P
-
-        D = self.model.config.hidden_size // self.model.config.num_heads
-
-        def qkv_w(w):
-            return w.reshape(w.shape[0], 3, -1, D).transpose(0, 2, 1, 3)
-
-        def qkv_b(b):
-            return b.reshape(3, -1, D).transpose(1, 0, 2)
-
-        plan = {}
-        gpt = self.model.gpt
-        plan[id(gpt.wte.weight)] = (None, P("mp", None))
-        for blk in gpt.blocks:
-            attn, mlp = blk.attn, blk.mlp
-            plan[id(attn.qkv_proj.weight)] = (qkv_w,
-                                              P(None, "mp", None, None))
-            if attn.qkv_proj.bias is not None:
-                plan[id(attn.qkv_proj.bias)] = (qkv_b,
-                                                P("mp", None, None))
-            for lin in (attn.out_proj, mlp.fc1, mlp.fc2):
-                plan[id(lin.weight)] = (None, P(None, "mp"))
-                if lin.bias is not None:
-                    plan[id(lin.bias)] = (None, P("mp"))
-        return plan
-
     def _build_tp_state(self):
-        """Shard the model state onto the serving mesh per `_tp_plan`.
+        """Shard the model state onto the serving mesh per the spec's
+        `tp_plan`.
         Returns (committed arrays, PartitionSpecs) aligned with
         `self._state` — the arrays ride the compiled steps as traced
         args (weight-stationary: placed once, never re-sharded per
@@ -1381,7 +1400,7 @@ class GenerationEngine:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        plan = self._tp_plan()
+        plan = self.spec.tp_plan()
         arrays, specs = [], []
         for i, t in enumerate(self._state):
             transform, spec = plan.get(id(t), (None, P()))
@@ -1542,8 +1561,7 @@ class GenerationEngine:
             "Paged KV cache storage dtype this engine serves with "
             "(1 = selected).", labelnames=("kv_dtype",))
         self._m_kv_dtype.labels(kv_dtype=kv_name).set(1)
-        w_name = self.weight_dtype or np.dtype(
-            self.model.gpt.wte.weight._array.dtype).name
+        w_name = self.weight_dtype or np.dtype(self.spec.dtype).name
         self._m_weight_dtype = m.gauge(
             "engine_weight_dtype_info",
             "Served matmul-weight storage dtype (int8 = qkv/out/fc1/"
@@ -1663,6 +1681,20 @@ class GenerationEngine:
             buckets=LATENCY_BUCKETS).labels(
                 backend=self.attention_backend)
         self._decode_traces_seen = 0
+        # registered only where the model has them, so a plain engine's
+        # exposition is unchanged (the adapter precedent)
+        self._m_state_used = None
+        if self.cache.state:
+            self._m_state_used = m.gauge(
+                "engine_state_slots_used",
+                "Rows of the slots' fixed-size state (recurrent layers) "
+                "held by live lanes.")
+        self._m_step_counters = {
+            name: (m.counter if how == "sum" else m.gauge)(
+                f"engine_{name}" + ("_total" if how == "sum" else ""),
+                f"The model's decode-step counter `{name}` "
+                f"({how} over decode steps).")
+            for name, how in self.spec.step_counters}
         # step-phase decomposition (ISSUE 17 / ROADMAP item 3): the
         # host work between compiled steps, per named phase — the
         # measured baseline the async engine core must beat. Always
@@ -1776,6 +1808,8 @@ class GenerationEngine:
         self._m_pool_bytes.set(self.cache.pool_nbytes())
         self._m_pool_hw.set_max(used)
         self._m_cached_blocks.set(self.cache.num_cached_blocks)
+        if self._m_state_used is not None:
+            self._m_state_used.set(self.cache.state_rows_used)
 
     def _sample_traces(self):
         """Mirror the count_traces probes into metrics; a decode trace
@@ -1882,16 +1916,42 @@ class GenerationEngine:
 
         return LoraState(rest[0], rest[-1]), rest[1:-1]
 
+    def _state_args(self, rest):
+        """Unpack a compiled step's OPTIONAL fixed-state head: where the
+        model keeps state of fixed size a slot, the arrays ride as one
+        tuple right after the pools (donated with them) and the slots'
+        rows are the LAST host arg but the adapter row. Returns (keyword
+        arguments for the spec's step function, remaining rest)."""
+        if not self.cache.state:
+            return {}, rest
+        return {"slot_state": rest[0]}, rest[1:]
+
+    def _step_outputs(self, lead, r):
+        """The outputs of a compiled step in the one order
+        `_dispatch_step` reads: the leading replicated outputs, the
+        pools, then what rides beside them."""
+        out = tuple(lead) + (r.kpool._array, r.vpool._array)
+        if r.kv_scales is not None:
+            out += (r.kv_scales._array,)
+        out += tuple(r.slot_state)
+        if self.spec.step_counters and r.counters is not None:
+            out += (r.counters,)
+        return out
+
     def _build_decode(self):
         model, state = self.model, self._state
+        spec = self.spec
         backend = self.attention_backend
         mp_axis = self._mp_axis
         use_q = self.kv_dtype == "int8"
         use_s = self.sampling
 
         def decode_fn(state_arrays, kpool, vpool, *rest):
+            kw, rest = self._state_args(rest)
             scales = rest[0] if use_q else None
             lora, rest = self._lora_args(rest[1:] if use_q else rest)
+            if kw:
+                kw["state_rows"], rest = rest[-1], rest[:-1]
             if use_s:
                 (tokens, positions, tables,
                  temps, tks, tps, krows) = rest
@@ -1899,14 +1959,14 @@ class GenerationEngine:
                 tokens, positions, tables = rest
             arrays = self._materialize_state(state_arrays)
             with bound_state(zip(state, arrays), state):
-                r = model.gpt.forward_decode_paged(
+                r = spec.decode(
                     Tensor._wrap(tokens), Tensor._wrap(positions),
                     Tensor._wrap(kpool), Tensor._wrap(vpool),
                     Tensor._wrap(tables), backend=backend,
                     mp_axis=mp_axis,
                     kv_scales=None if scales is None
-                    else Tensor._wrap(scales), lora=lora)
-                logits = model._logits_of(r[0], mp_axis=mp_axis)
+                    else Tensor._wrap(scales), lora=lora, **kw)
+                logits = spec.logits(r.hidden, mp_axis=mp_axis)
                 if use_s:
                     # per-slot categorical draws on device; greedy
                     # rows take the literal argmax (bit-identical to
@@ -1921,7 +1981,7 @@ class GenerationEngine:
                 else:
                     nxt = jnp.argmax(logits._array[:, 0], axis=-1) \
                         .astype(jnp.int32)            # logits [slots,1,V]
-                return (nxt,) + tuple(t._array for t in r[1:])
+                return self._step_outputs((nxt,), r)
 
         decode_fn.__name__ = "engine_decode_step"
         return self._shard_steps(decode_fn, n_repl=7 if use_s else 3)
@@ -1935,6 +1995,7 @@ class GenerationEngine:
         on device (all K+1 logit positions are in hand) and leads with
         the (choices, accepts) pair instead of the argmax row."""
         model, state = self.model, self._state
+        spec = self.spec
         backend = self.attention_backend
         mp_axis = self._mp_axis
         use_q = self.kv_dtype == "int8"
@@ -1950,14 +2011,14 @@ class GenerationEngine:
                 tokens, positions, dlens, tables = rest
             arrays = self._materialize_state(state_arrays)
             with bound_state(zip(state, arrays), state):
-                r = model.gpt.forward_verify_paged(
+                r = spec.verify(
                     Tensor._wrap(tokens), Tensor._wrap(positions),
                     Tensor._wrap(dlens), Tensor._wrap(kpool),
                     Tensor._wrap(vpool), Tensor._wrap(tables),
                     backend=backend, mp_axis=mp_axis,
                     kv_scales=None if scales is None
                     else Tensor._wrap(scales), lora=lora)
-                logits = model._logits_of(r[0], mp_axis=mp_axis)
+                logits = spec.logits(r.hidden, mp_axis=mp_axis)
                 if use_s:
                     # rejection-sampling acceptance in the same
                     # compiled program: per-row accept coins + the
@@ -1969,11 +2030,10 @@ class GenerationEngine:
                     choices, accepts = verify_window(
                         logits._array, tokens, dlens, temps, tks,
                         tps, krows, positions)
-                    return (choices, accepts) \
-                        + tuple(t._array for t in r[1:])
+                    return self._step_outputs((choices, accepts), r)
                 nxt = jnp.argmax(logits._array, axis=-1) \
                     .astype(jnp.int32)           # logits [slots,K+1,V]
-                return (nxt,) + tuple(t._array for t in r[1:])
+                return self._step_outputs((nxt,), r)
 
         verify_fn.__name__ = "engine_verify_step"
         return self._shard_steps(verify_fn, n_repl=8 if use_s else 4,
@@ -1983,6 +2043,7 @@ class GenerationEngine:
         from paddle_tpu.ops.paged_attention import paged_prefill_write
 
         model, state = self.model, self._state
+        spec = self.spec
         mp_axis = self._mp_axis
         use_q = self.kv_dtype == "int8"
         use_s = self.sampling
@@ -1997,7 +2058,7 @@ class GenerationEngine:
                 tokens, plen, table_row = rest
             arrays = self._materialize_state(state_arrays)
             with bound_state(zip(state, arrays), state):
-                hidden, ks, vs = model.gpt.forward_prefill(
+                hidden, ks, vs = spec.prefill(
                     Tensor._wrap(tokens), mp_axis=mp_axis, lora=lora)
                 w = paged_prefill_write(
                     Tensor._wrap(kpool), Tensor._wrap(vpool), ks, vs,
@@ -2010,8 +2071,8 @@ class GenerationEngine:
                     .astype(hidden._array.dtype)
                 h_last = (hidden._array * sel[None, :, None]) \
                     .sum(axis=1, keepdims=True)
-                logits = model._logits_of(Tensor._wrap(h_last),
-                                          mp_axis=mp_axis)
+                logits = spec.logits(Tensor._wrap(h_last),
+                                     mp_axis=mp_axis)
                 if use_s:
                     # the FIRST generated token samples too: it lands
                     # at position plen, so its draw folds plen-1 —
@@ -2032,6 +2093,7 @@ class GenerationEngine:
 
     def _build_prefill_chunk(self):
         model, state = self.model, self._state
+        spec = self.spec
         C = self.prefill_chunk
         mp_axis = self._mp_axis
         use_q = self.kv_dtype == "int8"
@@ -2040,8 +2102,11 @@ class GenerationEngine:
         def prefill_chunk_fn(state_arrays, kpool, vpool, *rest):
             # tokens [1, C] FIXED; start/plen traced -> ONE program
             # serves every chunk of every prompt length
+            kw, rest = self._state_args(rest)
             scales = rest[0] if use_q else None
             lora, rest = self._lora_args(rest[1:] if use_q else rest)
+            if kw:
+                kw["state_row"], rest = rest[-1], rest[:-1]
             if use_s:
                 (tokens, start, plen, table_row,
                  temps, tks, tps, krows) = rest
@@ -2049,23 +2114,23 @@ class GenerationEngine:
                 tokens, start, plen, table_row = rest
             arrays = self._materialize_state(state_arrays)
             with bound_state(zip(state, arrays), state):
-                r = model.gpt.forward_prefill_chunk(
+                r = spec.prefill_chunk(
                     Tensor._wrap(tokens), Tensor._wrap(start),
                     Tensor._wrap(kpool), Tensor._wrap(vpool),
                     Tensor._wrap(table_row), Tensor._wrap(plen),
                     mp_axis=mp_axis,
                     kv_scales=None if scales is None
-                    else Tensor._wrap(scales), lora=lora)
+                    else Tensor._wrap(scales), lora=lora, **kw)
                 # the LAST REAL prompt position's logits yield the
                 # first generated token; it lives in the final chunk —
                 # for earlier chunks the one-hot selects nothing and
                 # the host ignores the returned token
                 sel = (start + jnp.arange(C) == plen - 1) \
-                    .astype(r[0]._array.dtype)
-                h_last = (r[0]._array * sel[None, :, None]) \
+                    .astype(r.hidden._array.dtype)
+                h_last = (r.hidden._array * sel[None, :, None]) \
                     .sum(axis=1, keepdims=True)
-                logits = model._logits_of(Tensor._wrap(h_last),
-                                          mp_axis=mp_axis)
+                logits = spec.logits(Tensor._wrap(h_last),
+                                     mp_axis=mp_axis)
                 if use_s:
                     # the first generated token's draw folds plen-1
                     # (it lands at position plen) — identical to the
@@ -2079,7 +2144,7 @@ class GenerationEngine:
                 else:
                     nxt = jnp.argmax(logits._array[0, 0]) \
                         .astype(jnp.int32)
-                return (nxt,) + tuple(t._array for t in r[1:])
+                return self._step_outputs((nxt,), r)
 
         prefill_chunk_fn.__name__ = "engine_prefill_chunk"
         return self._shard_steps(prefill_chunk_fn,
@@ -2162,6 +2227,8 @@ class GenerationEngine:
         no-sampling engine. A None seed is resolved here from the
         engine's deterministic counter, so a fixed trace replays
         token-for-token."""
+        if prefill_only:
+            self._refuse("handoff")
         if prefill_only and max_new_tokens != 1:
             raise ValueError(
                 "prefill_only requests carry max_new_tokens=1 (the "
@@ -2241,15 +2308,23 @@ class GenerationEngine:
         the per-slot adapter page row as the LAST host arg."""
         c = self.cache
         args = [self._state_arrays(), c.kpool, c.vpool]
+        if c.state:
+            args.append(c.state)
         if c.scales is not None:
             args.append(c.scales)
         if self.adapter_pool is not None:
             args.append(self.adapter_pool.arrays())
         out = jitted(*args, *host_args)
+        c.kpool, c.vpool = out[n_out:n_out + 2]
+        tail = n_out + 2
         if c.scales is not None:
-            c.kpool, c.vpool, c.scales = out[n_out:]
-        else:
-            c.kpool, c.vpool = out[n_out:]
+            c.scales = out[tail]
+            tail += 1
+        if c.state:
+            c.state = tuple(out[tail:tail + len(c.state)])
+            tail += len(c.state)
+        # what is left is the model's counters (a decode step's)
+        self._step_counters = out[tail] if len(out) > tail else None
         return out[0] if n_out == 1 else out[:n_out]
 
     def _in_flight(self):
@@ -2294,11 +2369,28 @@ class GenerationEngine:
                 and req.sampling is not None and not req.sampling.greedy:
             self._m_sampled_tokens.inc(n)
 
+    def _note_step_counters(self, values):
+        """Fold one decode step's counters (the model's: how many
+        assignments its experts took, ...) into the totals and the
+        metrics, each summed or kept as the largest, as its spec says."""
+        for (name, how), v in zip(self.spec.step_counters, values):
+            v = int(v)
+            if how == "sum":
+                self.step_counter_totals[name] += v
+                self._m_step_counters[name].inc(v)
+            else:
+                self.step_counter_totals[name] = max(
+                    self.step_counter_totals[name], v)
+                self._m_step_counters[name].set_max(v)
+
     def _finish(self, slot, reason):
         req = slot.req
         self._results[req.req_id] = \
             list(map(int, req.prompt)) + slot.generated
         self.cache.free(slot.blocks)
+        if slot.state_row:
+            with self._phase("state_free"):
+                self.cache.free_state(slot.state_row)
         self._release_adapter(slot)
         self._m_finished.labels(reason=reason).inc()
         self.flight.record("finish", req.req_id, reason=reason,
@@ -2397,11 +2489,16 @@ class GenerationEngine:
                     if hit:
                         self.prefix_hit_tokens += hit
                         self._m_hit_tokens.inc(hit)
+                state_row = 0
+                if self.cache.state:
+                    with self._phase("state_alloc"):
+                        # a row a slot: a free lane always finds one
+                        state_row = self.cache.allocate_state()
                 slot = _Slot(req=req, blocks=list(blocks),
                              prefill_pos=hit,
                              hit_tokens=hit,
                              admit_seq=self._admit_counter,
-                             adapter_page=page,
+                             adapter_page=page, state_row=state_row,
                              **self._slot_sampling_fields(req))
                 self._admit_counter += 1
                 self._slots[self._slots.index(None)] = slot
@@ -2482,6 +2579,8 @@ class GenerationEngine:
                 if self.sampling:
                     # the chunk serves ONE slot: its sampling rows, [1]
                     args.extend(self._sampling_host_args_one(slot))
+                if self.cache.state:
+                    args.append(jnp.int32(slot.state_row))
                 if self.adapter_pool is not None:
                     # the chunk serves ONE slot: its adapter page,
                     # [1]-row
@@ -2674,17 +2773,23 @@ class GenerationEngine:
             tables = np.zeros((self.num_slots, self.max_blocks),
                               np.int32)
             arows = np.zeros(self.num_slots, np.int32)
+            srows = np.zeros(self.num_slots, np.int32)
             for i in runnable:
                 slot = self._slots[i]
                 tokens[i, 0] = slot.feed_token
                 positions[i] = slot.feed_pos
                 tables[i, :len(slot.blocks)] = slot.blocks
                 arows[i] = slot.adapter_page
+                srows[i] = slot.state_row
             rows = [tokens, positions, tables]
             if self.sampling:
                 # per-slot sampling rows (idle/greedy lanes ride temp
                 # 0 — the argmax select, like the null block)
                 rows.extend(self._sampling_host_rows())
+            if self.cache.state:
+                # lanes that do not decode this step (idle, stalled,
+                # prefilling) ride the null row 0: their state stays
+                rows.append(srows)
             if self.adapter_pool is not None:
                 # per-slot adapter page row (idle/stalled lanes ride
                 # the null page 0 — exact-zero delta, like the null
@@ -2695,8 +2800,10 @@ class GenerationEngine:
                 t_dec = time.perf_counter()
                 nxt = self._dispatch_step(self._decode, *args)
         self._step_seq += 1
+        self.decode_steps += 1
         return _InFlight(out=nxt, runnable=runnable,
                          slots=[self._slots[i] for i in runnable],
+                         counters=self._step_counters,
                          t_dec=t_dec, t_span=t_span,
                          seq=self._step_seq)
 
@@ -2719,6 +2826,8 @@ class GenerationEngine:
         t_dec = inflight.t_dec
         now = time.perf_counter()
         with self._phase("finish"):
+            if inflight.counters is not None:
+                self._note_step_counters(np.asarray(inflight.counters))
             for i, slot in zip(inflight.runnable, inflight.slots):
                 tok = int(nxt[i])
                 is_first = not slot.generated   # full-prefix-hit lane
@@ -2773,7 +2882,7 @@ class GenerationEngine:
         stalls. Returns (runnable lane indices, lane -> draft)."""
         K = self.spec_decode_k
         bs = self.block_size
-        vocab = self.model.config.vocab_size
+        vocab = self.spec.vocab_size
         runnable, drafts = [], {}
         with self._phase("schedule"):
             for i, slot in enumerate(self._slots):
@@ -3153,7 +3262,7 @@ class GenerationEngine:
         if not self.spec_decode_k or self.drafter is None:
             return
         K = self.spec_decode_k
-        vocab = self.model.config.vocab_size
+        vocab = self.spec.vocab_size
         jobs = []
         for slot in self._slots:
             if slot is None or slot.prefilling:
@@ -3273,6 +3382,7 @@ class GenerationEngine:
         its finishes stay collectable via `pop_results`/`run`. Returns
         the n candidate token lists (prompt + generated), seed
         order."""
+        self._refuse("fork")
         params, base, self._seed_counter = _best_of_n_intake(
             self, sampling_params, n, self._seed_counter)
         out, stash = _best_of_n_fanout(
@@ -3332,6 +3442,7 @@ class GenerationEngine:
         lane re-derives the exact per-slot key row the colocated lane
         would carry, so sampled disaggregated output stays
         token-identical to colocated."""
+        self._refuse("handoff")
         adapter_id = self._check_adapter(adapter_id)
         sampling_params = self._check_sampling(sampling_params)
         if sampling_params is not None and not sampling_params.greedy \
@@ -3412,6 +3523,11 @@ class GenerationEngine:
                 f"drain leak check failed: block(s) {leaked} neither "
                 "free nor prefix-cached after all lanes finished — a "
                 "scheduler path dropped a reference without freeing")
+        leaked = self.cache.state_leak_check()
+        if leaked:
+            raise self._audit_error(
+                f"drain leak check failed: state row(s) {leaked} still "
+                "held after all lanes finished")
         if self.adapter_pool is not None:
             leaked = self.adapter_pool.leak_check()
             if leaked:
@@ -3457,9 +3573,11 @@ class GenerationEngine:
 # {dense,pallas} x K x mp matrix and lowers THESE OBJECTS' jitted
 # steps; rules TPU101-TPU106 then enforce what is declared below.
 # Donation comes from the same introspect table the constructor
-# consumes; the collective budget is a lazy reference into models/gpt
-# (the module whose _mp_all_gather/_vocab_parallel_embed emit them).
-_GPT_SERVING_BUDGET = "paddle_tpu.models.gpt:GPT_SERVING_COLLECTIVES"
+# consumes; the collective budget is a lazy reference to the table of
+# the one model whose steps are sharded today (the module whose
+# all-gather and vocabulary-parallel embedding emit them keeps the
+# canonical alias).
+_SERVING_BUDGET = "paddle_tpu.jit.introspect:SERVING_STEP_AXIS_BUDGET"
 
 for _step in ("engine_prefill", "engine_prefill_chunk",
               "engine_decode_step", "engine_verify_step"):
@@ -3467,7 +3585,7 @@ for _step in ("engine_prefill", "engine_prefill_chunk",
         name=_step,
         declared_at="paddle_tpu/inference/engine.py",
         donate_argnums=introspect.ENGINE_STEP_DONATION[_step],
-        collective_budget=_GPT_SERVING_BUDGET,
+        collective_budget=_SERVING_BUDGET,
         # decode/verify are the host loop body — one dispatch per
         # generated token, so their collectives sit on the per-token
         # latency path (tpu-shard TPU305 gates these against any

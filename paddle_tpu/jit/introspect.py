@@ -109,6 +109,9 @@ ACCUM_DONATE_ARGNUMS = (0,)
 #: paged KV cache in place in HBM. The copy-on-write block-copy step
 #: is `(kpool, vpool, src, dst)` and donates positions 0, 1.
 ENGINE_STEP_DONATE_ARGNUMS = (1, 2)
+#: a model with state of fixed size a slot (recurrent layers) threads it
+#: as one tuple right after the pools, donated with them
+ENGINE_STATEFUL_STEP_DONATE_ARGNUMS = (1, 2, 3)
 ENGINE_COW_DONATE_ARGNUMS = (0, 1)
 
 #: Donation layout of EVERY compiled engine program, by program name
@@ -133,6 +136,8 @@ DONATION_CONSTANTS = {
     "TRAINSTEP_DONATE_ARGNUMS": TRAINSTEP_DONATE_ARGNUMS,
     "ACCUM_DONATE_ARGNUMS": ACCUM_DONATE_ARGNUMS,
     "ENGINE_STEP_DONATE_ARGNUMS": ENGINE_STEP_DONATE_ARGNUMS,
+    "ENGINE_STATEFUL_STEP_DONATE_ARGNUMS":
+        ENGINE_STATEFUL_STEP_DONATE_ARGNUMS,
     "ENGINE_COW_DONATE_ARGNUMS": ENGINE_COW_DONATE_ARGNUMS,
 }
 
@@ -469,3 +474,7 @@ GPT_SERVING_AXIS_BUDGET = AxisCollectiveBudget(
         ("mp", "pmax", 1, 1, "layers * blocks * 2 * 4"),
     ),
 )
+
+#: what the engine's step contracts name: the budget of the one model
+#: whose serving steps are sharded today
+SERVING_STEP_AXIS_BUDGET = GPT_SERVING_AXIS_BUDGET

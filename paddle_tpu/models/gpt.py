@@ -21,6 +21,8 @@ import numpy as np
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
+from paddle_tpu.inference.serving_spec import PagedKV, ServingSpec, \
+    StepOut
 from paddle_tpu.jit import introspect
 from paddle_tpu.ops import manipulation as mp
 
@@ -799,6 +801,11 @@ class GPTForCausalLM(nn.Layer):
             pos = pos + 1
         return tokens
 
+    def serving_spec(self):
+        """What the generation engine asks of a model
+        (`inference/serving_spec.py`)."""
+        return GPTServing(self)
+
     def _logits_of(self, hidden, mp_axis=None):
         """Tied-embedding logits. Under tensor parallel the wte table
         is bound vocab-sharded, so each shard computes its `[.., V/mp]`
@@ -870,6 +877,139 @@ class GPTForCausalLM(nn.Layer):
         n = self.num_params()
         s = seq_len or c.max_seq_len
         return 6 * n + 12 * c.num_layers * c.hidden_size * s
+
+
+class GPTServing(ServingSpec):
+    """The GPT-2-style decoder as the engine sees it: every layer keeps
+    paged K and V of `num_heads` heads, no state of fixed size, and every
+    option is served (tensor parallel, int8 weights and KV, adapters,
+    prefix reuse, forks, speculative windows)."""
+
+    def __init__(self, model):
+        cfg = model.config
+        super().__init__(
+            model, cfg.vocab_size, cfg.max_seq_len,
+            model.gpt.wte.weight._array.dtype,
+            PagedKV(cfg.num_layers, cfg.num_heads,
+                    cfg.hidden_size // cfg.num_heads, cfg.num_heads),
+            dropout=cfg.dropout)
+        self.config = cfg
+
+    def check_mesh(self, mp_degree, devices):
+        """The Megatron divisibility constraints, up front: fail HERE
+        with the shape story, not deep in a per-shard reshape."""
+        from paddle_tpu.distributed.topology import serving_mesh
+
+        cfg = self.config
+        serving_mesh(mp_degree, num_heads=cfg.num_heads,
+                     vocab_size=cfg.vocab_size, devices=devices)
+        if cfg.intermediate_size % mp_degree:
+            raise ValueError(
+                f"intermediate_size={cfg.intermediate_size} is not "
+                f"divisible by mp degree {mp_degree} — cannot "
+                "column-shard the MLP")
+
+    def adapter_geometry(self):
+        cfg = self.config
+        return {"num_layers": cfg.num_layers,
+                "hidden_size": cfg.hidden_size,
+                "intermediate_size": cfg.intermediate_size,
+                "num_heads": cfg.num_heads}
+
+    def weight_quant_plan(self):
+        """id(state tensor) -> (scale_transform, scale PartitionSpec)
+        for every weight served int8: the attention qkv/out and MLP
+        fc1/fc2 matmuls (the per-step weight-read floor), per-OUTPUT-
+        channel absmax scales via quantization.quantize_absmax(axis=1).
+        Embeddings/norms/biases stay fp — the logit head's quality is
+        the tolerance budget's scarcest resource. The scale transform
+        mirrors `tp_plan`'s qkv head-grouping so scales shard exactly
+        like their weights."""
+        from jax.sharding import PartitionSpec as P
+
+        D = self.paged_kv.head_dim
+
+        def qkv_s(s):                  # [1, 3H] -> [1, heads, 3, D]
+            return s.reshape(1, 3, -1, D).transpose(0, 2, 1, 3)
+
+        plan = {}
+        for blk in self.model.gpt.blocks:
+            attn, mlp = blk.attn, blk.mlp
+            plan[id(attn.qkv_proj.weight)] = (qkv_s,
+                                              P(None, "mp", None, None))
+            for lin in (attn.out_proj, mlp.fc1, mlp.fc2):
+                plan[id(lin.weight)] = (None, P(None, "mp"))
+        return plan
+
+    def tp_plan(self):
+        """id(state tensor) -> (transform, PartitionSpec): the Megatron
+        column-parallel serving layout. qkv weights are re-grouped
+        head-major (`[H, heads, 3, D]`) so a contiguous heads-axis
+        shard holds complete (q, k, v) triples for ITS heads;
+        out_proj/fc1/fc2 shard their OUTPUT columns (full-length dots,
+        all-gathered activations — bit-exact vs mp=1, see
+        DESIGN_DECISIONS r12); wte shards vocab rows. Everything else
+        (layer norms, wpe) replicates."""
+        from jax.sharding import PartitionSpec as P
+
+        D = self.paged_kv.head_dim
+
+        def qkv_w(w):
+            return w.reshape(w.shape[0], 3, -1, D).transpose(0, 2, 1, 3)
+
+        def qkv_b(b):
+            return b.reshape(3, -1, D).transpose(1, 0, 2)
+
+        plan = {}
+        gpt = self.model.gpt
+        plan[id(gpt.wte.weight)] = (None, P("mp", None))
+        for blk in gpt.blocks:
+            attn, mlp = blk.attn, blk.mlp
+            plan[id(attn.qkv_proj.weight)] = (qkv_w,
+                                              P(None, "mp", None, None))
+            if attn.qkv_proj.bias is not None:
+                plan[id(attn.qkv_proj.bias)] = (qkv_b,
+                                                P("mp", None, None))
+            for lin in (attn.out_proj, mlp.fc1, mlp.fc2):
+                plan[id(lin.weight)] = (None, P(None, "mp"))
+                if lin.bias is not None:
+                    plan[id(lin.bias)] = (None, P("mp"))
+        return plan
+
+    # -- the step functions: GPTModel's, by name -------------------------
+    def logits(self, hidden, mp_axis=None):
+        return self.model._logits_of(hidden, mp_axis=mp_axis)
+
+    def prefill(self, tokens, mp_axis=None, lora=None):
+        return self.model.gpt.forward_prefill(tokens, mp_axis=mp_axis,
+                                              lora=lora)
+
+    @staticmethod
+    def _out(r, kv_scales):
+        if kv_scales is None:
+            return StepOut(*r)
+        return StepOut(r[0], r[1], r[2], kv_scales=r[3])
+
+    def prefill_chunk(self, tokens, start, kpool, vpool, block_row, plen,
+                      mp_axis=None, kv_scales=None, lora=None):
+        return self._out(self.model.gpt.forward_prefill_chunk(
+            tokens, start, kpool, vpool, block_row, plen,
+            mp_axis=mp_axis, kv_scales=kv_scales, lora=lora), kv_scales)
+
+    def decode(self, tokens, positions, kpool, vpool, block_tables,
+               backend="auto", mp_axis=None, kv_scales=None, lora=None):
+        return self._out(self.model.gpt.forward_decode_paged(
+            tokens, positions, kpool, vpool, block_tables,
+            backend=backend, mp_axis=mp_axis, kv_scales=kv_scales,
+            lora=lora), kv_scales)
+
+    def verify(self, tokens, positions, draft_lens, kpool, vpool,
+               block_tables, backend="auto", mp_axis=None,
+               kv_scales=None, lora=None):
+        return self._out(self.model.gpt.forward_verify_paged(
+            tokens, positions, draft_lens, kpool, vpool, block_tables,
+            backend=backend, mp_axis=mp_axis, kv_scales=kv_scales,
+            lora=lora), kv_scales)
 
 
 class GPTEmbeddingPipe(nn.Layer):

@@ -66,9 +66,13 @@ __all__ = [
 #: - sample_walk:   rejection-sampling acceptance walk (sampled lanes)
 #: - cow:           copy-on-write block promotion
 #: - finish:        token emission, TTFT/TPOT accounting, retirement
+#: - state_alloc:   a row of the slots' fixed-size state taken and
+#:                  zeroed at admission (models with recurrent layers)
+#: - state_free:    that row given back at retirement
 STEP_PHASES = ("schedule", "prefix_lookup", "adapter_swap",
                "draft_propose", "dispatch", "device_wait",
-               "accept_walk", "sample_walk", "cow", "finish")
+               "accept_walk", "sample_walk", "cow", "finish",
+               "state_alloc", "state_free")
 
 _trace_seq = itertools.count(1)
 

@@ -240,7 +240,8 @@ def _dense_step(qa, ka, va, kp, vp, layer, bt, pos, scale):
     keeps the program shape-stable (no recompiles as context grows)."""
     B = qa.shape[0]
     heads, d = qa.shape[2], qa.shape[3]
-    bs = kp.shape[2]
+    bs, kvh = kp.shape[2], kp.shape[3]
+    g = heads // kvh           # query heads a KV head (1: plain heads)
     bid_w = jnp.take_along_axis(bt, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs
     kp = kp.at[layer, bid_w, off].set(ka[:, 0])
@@ -257,10 +258,12 @@ def _dense_step(qa, ka, va, kp, vp, layer, bt, pos, scale):
         m, l, acc = carry
         bid = jax.lax.dynamic_index_in_dim(bt, j, axis=1,
                                            keepdims=False)   # [B]
-        keys = kp[layer, bid]                      # [B, bs, heads, d]
+        keys = kp[layer, bid]                      # [B, bs, kvh, d]
         vals = vp[layer, bid]
-        logits = jnp.einsum("bhd,bkhd->bhk", qf, keys,
-                            preferred_element_type=jnp.float32) * s
+        # g query heads read one KV head
+        logits = jnp.einsum(
+            "bngd,bknd->bngk", qf.reshape(B, kvh, g, d), keys,
+            preferred_element_type=jnp.float32).reshape(B, heads, bs) * s
         allowed = (j * bs + jnp.arange(bs))[None, :] <= pos[:, None]
         logits = jnp.where(allowed[:, None, :], logits, -1e30)
         m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
@@ -270,8 +273,10 @@ def _dense_step(qa, ka, va, kp, vp, layer, bt, pos, scale):
         # PV accumulates in fp32 (preferred_element_type): probs enter
         # the matmul at the pool dtype (bf16 MXU pass on TPU) but the
         # product never rounds to bf16 mid-accumulation
-        pv = jnp.einsum("bhk,bkhd->bhd", p.astype(vals.dtype), vals,
-                        preferred_element_type=jnp.float32)
+        pv = jnp.einsum(
+            "bngk,bknd->bngd",
+            p.astype(vals.dtype).reshape(B, kvh, g, bs), vals,
+            preferred_element_type=jnp.float32).reshape(B, heads, d)
         return m_new, l_new, acc * alpha + pv
 
     m0 = jnp.full((B, heads, 1), -1e30, jnp.float32)
@@ -829,14 +834,15 @@ def paged_prefill_chunk(q, k, v, kpool, vpool, layer, block_row, start,
     def fn(qa, ka, va, kp, vp, row, s0, n):
         C = qa.shape[1]
         heads, d = qa.shape[2], qa.shape[3]
-        bs = kp.shape[2]
+        bs, kvh = kp.shape[2], kp.shape[3]
+        g = heads // kvh       # query heads a KV head (1: plain heads)
         maxb = row.shape[0]
         pos = s0 + jnp.arange(C)                       # absolute [C]
         valid = pos < n
         bid = jnp.where(valid,
                         row[jnp.minimum(pos // bs, maxb - 1)], 0)
         off = pos % bs
-        kp = kp.at[layer, bid, off].set(ka[0])         # [C, heads, d]
+        kp = kp.at[layer, bid, off].set(ka[0])         # [C, kvh, d]
         vp = vp.at[layer, bid, off].set(va[0])
         s = scale if scale is not None else 1.0 / np.sqrt(d)
         # QK at pool dtype, fp32 accumulation — the _dense_step policy,
@@ -848,11 +854,13 @@ def paged_prefill_chunk(q, k, v, kpool, vpool, layer, block_row, start,
         def body(j, carry):
             m, l, acc = carry
             b = row[j]
-            keys = kp[layer, b]                        # [bs, heads, d]
+            keys = kp[layer, b]                        # [bs, kvh, d]
             vals = vp[layer, b]
+            # g query heads read one KV head
             logits = jnp.einsum(
-                "chd,khd->hck", qf, keys,
-                preferred_element_type=jnp.float32) * s
+                "cngd,knd->ngck", qf.reshape(C, kvh, g, d), keys,
+                preferred_element_type=jnp.float32
+            ).reshape(heads, C, bs) * s
             # causal over absolute positions: key j*bs+k visible to
             # query c iff it is at or before the query's position
             allowed = (j * bs + jnp.arange(bs))[None, :] <= pos[:, None]
@@ -862,8 +870,11 @@ def paged_prefill_chunk(q, k, v, kpool, vpool, layer, block_row, start,
             p = jnp.exp(logits - m_new)                # [heads, C, bs]
             alpha = jnp.exp(m - m_new)
             l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jnp.einsum("hck,khd->hcd", p.astype(vals.dtype), vals,
-                            preferred_element_type=jnp.float32)
+            pv = jnp.einsum(
+                "ngck,knd->ngcd",
+                p.astype(vals.dtype).reshape(kvh, g, C, bs), vals,
+                preferred_element_type=jnp.float32
+            ).reshape(heads, C, d)
             return m_new, l_new, acc * alpha + pv
 
         m0 = jnp.full((heads, C, 1), -1e30, jnp.float32)

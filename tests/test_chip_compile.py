@@ -264,3 +264,108 @@ def test_training_kernels_are_named_in_the_compiled_text(
         mine = [c for c in calls if name in c.split(" = ")[0]]
         assert mine, (name, [c[:40] for c in calls])
         assert all(name in trace_reduce.op_group(c) for c in mine)
+
+
+# -- the hybrid decoder's kernels at `nemotron3s_serve_chat`'s shapes ---------
+# 128 slots; Mamba-2: 128 heads x 64, 8 groups, state 128, 5 layers in the
+# pool; LatentMoE: 128 experts held, 1024 -> 2688 -> 1024, 22 a token
+
+N_SLOTS, M_LAYERS, M_HEADS, M_P, M_GROUPS, M_STATE = 128, 5, 128, 64, 8, 128
+E_HELD, E_LATENT, E_INTER, E_TOPK, E_TILE = 128, 1024, 2688, 22, 16
+
+
+def _metric_patterns(metric):
+    import json
+    from pathlib import Path
+
+    return json.loads(
+        (Path(__file__).parent.parent / "benchmarks" / "layer_metrics"
+         / f"{metric}.json").read_text())["patterns"]
+
+
+def test_ssm_decode_kernel_compiles_and_the_benchmark_finds_it(chip):
+    """`ssm_decode_roofline` finds the scan's decode kernel inside the
+    engine's jitted decode step: by the enclosing program's name and the
+    kernel's own, which must reach the compiled text without renaming
+    the instruction."""
+    import re
+
+    from benchmarks.lib import trace_reduce
+    from paddle_tpu.ops.pallas.ssm import ssm_decode_update
+
+    def engine_decode_step(pool, rows, x, dt, a, d, b, c):
+        return ssm_decode_update(pool, 2, rows, x, dt, a, d, b, c)
+
+    text = chip(
+        engine_decode_step,
+        ((M_LAYERS, N_SLOTS + 1, M_HEADS, M_P, M_STATE), F32),
+        ((N_SLOTS,), I32), ((N_SLOTS, M_HEADS, M_P), F32),
+        ((N_SLOTS, M_HEADS), F32), ((M_HEADS,), F32), ((M_HEADS,), F32),
+        ((N_SLOTS, M_GROUPS, M_STATE), F32),
+        ((N_SLOTS, M_GROUPS, M_STATE), F32))
+    (call,) = _instructions(text)
+    assert any(re.search(p, call)
+               for p in _metric_patterns("ssm_decode_roofline")), call[:200]
+    assert not any(re.search(p, call)
+                   for p in _metric_patterns("moe_experts_roofline"))
+    assert "ssm_decode_update" in trace_reduce.op_group(call)
+
+
+@pytest.mark.parametrize("tokens", [N_SLOTS, 256], ids=["decode", "chunk"])
+def test_moe_grouped_matmul_compiles_and_the_benchmark_finds_it(chip,
+                                                                tokens):
+    """Both products of an expert layer (up with relu^2, down) over the
+    dropless buffer of a decode step (128 rows) and of a prefill chunk
+    (256): every assignment could land here, each expert padded to a
+    tile."""
+    import re
+
+    from benchmarks.lib import trace_reduce
+    from paddle_tpu.ops.pallas.moe import column_tile, moe_grouped_matmul
+
+    rows = -(-(tokens * E_TOPK + E_HELD * (E_TILE - 1)) // E_TILE) * E_TILE
+    assert column_tile(E_LATENT, E_INTER, 2) == 896
+    assert column_tile(E_INTER, E_LATENT, 2) == 512
+
+    def product(x, w1, w2, tile_expert, live):
+        hid = moe_grouped_matmul(x, w1, tile_expert, live, E_TILE,
+                                 relu_squared=True)
+        return moe_grouped_matmul(hid, w2, tile_expert, live, E_TILE)
+
+    # the enclosing program's name tells the two metrics apart
+    product.__name__, metric, other = {
+        N_SLOTS: ("engine_decode_step", "moe_experts_roofline",
+                  "moe_prefill_experts_busy_pct"),
+        256: ("engine_prefill_chunk", "moe_prefill_experts_busy_pct",
+              "moe_experts_roofline")}[tokens]
+    text = chip(product, ((rows, E_LATENT), BF16),
+                ((E_HELD, E_LATENT, E_INTER), BF16),
+                ((E_HELD, E_INTER, E_LATENT), BF16),
+                ((rows // E_TILE,), I32), ((1,), I32))
+    # the second product is this test function's ROOT; in the engine's
+    # step neither is, and a trace names an event by the instruction
+    flat = "\n".join(line.strip() for line in text.splitlines())
+    calls = [c.removeprefix("ROOT ") for c in
+             re.split(r"\n(?=(?:ROOT )?%\S+ = )", flat)
+             if "tpu_custom_call" in c]
+    assert len(calls) == 2
+    for call in calls:
+        assert any(re.search(p, call)
+                   for p in _metric_patterns(metric)), call[:200]
+        assert not any(re.search(p, call)
+                       for p in _metric_patterns(other))
+        assert "moe_grouped_matmul" in trace_reduce.op_group(call)
+
+
+def test_auto_takes_the_new_kernels_on_a_tpu_only(monkeypatch):
+    from paddle_tpu.distributed import moe
+    from paddle_tpu.ops import ssm
+
+    assert ssm.resolve_ssm_backend("auto", M_STATE) == "xla"     # a CPU
+    assert moe.resolve_moe_backend("auto", E_LATENT) == "xla"
+    monkeypatch.setattr(ssm, "on_tpu", lambda: True)
+    monkeypatch.setattr("paddle_tpu.core.device.on_tpu", lambda: True)
+    assert ssm.resolve_ssm_backend("auto", M_STATE) == "pallas"
+    assert ssm.resolve_ssm_backend("auto", 16) == "xla"
+    assert moe.resolve_moe_backend("auto", E_LATENT) == "pallas"
+    assert moe.resolve_moe_backend("auto", 48) == "xla"

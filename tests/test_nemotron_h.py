@@ -1,0 +1,464 @@
+"""The `nemotron_h` hybrid decoder (Mamba-2 + LatentMoE + grouped-KV
+attention by a layer-pattern string) at a tiny size on the CPU, float32:
+each mixer and the whole model against the benchmark's plain reference on
+seeded weights; chunked prefill then decode through `GenerationEngine`
+against the reference's full forward; the slots' recurrent state zeroed
+with the slot; the features that need a snapshot of that state refused;
+the share test (the routed parts of all the shares, with the shared
+expert once, add up to the uncut layer; the sliced vocabulary's logits
+are the whole table's first rows); the kernels (interpreter) against the
+plain XLA forms; the dropless dispatch.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib import weights
+from benchmarks.reference import common, nemotron_h as ref, stepwise
+from paddle_tpu.distributed import moe
+from paddle_tpu.inference.engine import GenerationEngine
+from paddle_tpu.models.nemotron_h import (NemotronHConfig,
+                                          NemotronHForCausalLM)
+from paddle_tpu.ops import ssm
+
+SEED = 11
+REF_KEYS = (
+    "hidden_size", "hybrid_override_pattern", "mamba_num_heads",
+    "mamba_head_dim", "n_groups", "ssm_state_size", "conv_kernel",
+    "num_attention_heads", "num_key_value_heads", "head_dim",
+    "n_routed_experts", "router_experts", "expert_offset",
+    "num_experts_per_tok", "moe_latent_size", "moe_intermediate_size",
+    "moe_shared_expert_intermediate_size", "routed_scaling_factor",
+    "norm_eps", "vocab_size", "initializer_range", "conv_init_std")
+
+
+def ref_cfg(cfg):
+    """What the reference reads of the program's configuration, and the
+    spread of `A_log` in the seeded weights: at the matrices' spread
+    every head has A = -1 and forgets a state within a few tokens, so a
+    stale row or a lost carry would pass; at 2 some heads remember the
+    whole of these short sequences."""
+    return dict({k: getattr(cfg, k) for k in REF_KEYS},
+                a_log_init_std=2.0)
+
+
+def seeded(pattern="ME*E", seed=SEED, **kw):
+    """The program's model with the reference's seeded weights bound."""
+    cfg = NemotronHConfig.tiny(pattern=pattern, router_experts=16,
+                               n_routed_experts=8, expert_offset=4, **kw)
+    model = NemotronHForCausalLM(cfg)
+    model.eval()
+    arrays = weights.make_all(seed, ref.param_spec(ref_cfg(cfg)),
+                              jnp.float32)
+    named = dict(model.named_parameters())
+    assert set(named) == set(arrays)
+    for name, p in named.items():
+        assert tuple(p.shape) == tuple(arrays[name].shape), name
+        p._in_place_update(arrays[name])
+    return model, cfg
+
+
+def reference_logits(cfg, ids, seed=SEED):
+    return np.asarray(stepwise.logits_of(
+        ref.build(ref_cfg(cfg), common.MM["f32"]), seed,
+        np.asarray(ids, np.int32), jnp.float32))
+
+
+def prompts(cfg, lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
+            for n in lengths]
+
+
+# -- the mixers and the whole model against the reference ----------------------
+
+@pytest.mark.parametrize("pattern", ["M", "*", "E", "MM", "ME*E"])
+def test_forward_matches_the_reference(pattern):
+    """Each mixer alone (`M`, `*`, `E`), two scans in a row, and a
+    pattern holding all three: the program's whole-sequence forward
+    against the reference's, float32."""
+    model, cfg = seeded(pattern)
+    ids = np.stack(prompts(cfg, [21, 21]))
+    got = np.asarray(model(ids)._array)
+    want = reference_logits(cfg, ids)
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_state_matters_to_the_logits():
+    """The seeded weights must let the recurrence show: with the conv's
+    taps at the matrices' 0.02 the state would be nought beside `D x`;
+    at `conv_init_std` a carried state left at nought moves the logits
+    by far more than any tolerance here."""
+    model, cfg = seeded("M")
+    ids = np.stack(prompts(cfg, [16]))
+    whole = np.asarray(model(ids)._array)[0]
+    tail = np.asarray(model(ids[:, 8:])._array)[0]      # state forgotten
+    assert np.abs(whole[8:] - tail).max() > 1e-2
+
+
+# -- through the engine -----------------------------------------------------
+
+def engine_for(model, **kw):
+    base = dict(num_slots=2, block_size=4, prefill_chunk=16,
+                max_model_len=64)
+    base.update(kw)
+    return GenerationEngine(model, **base)
+
+
+@pytest.mark.parametrize("pattern", ["ME*E", "M*", "E*M"])
+def test_engine_chunked_prefill_then_decode_agrees_with_the_reference(
+        pattern):
+    """Prompts shorter and longer than a chunk, more requests than
+    slots: every served token is the reference's own first choice given
+    the tokens before it, by the reference's full forward over the whole
+    sequence (no cache, no chunks) — unless the reference's best two
+    logits tie closer than 1e-4."""
+    model, cfg = seeded(pattern)
+    asked = prompts(cfg, [5, 19, 33, 12, 16])
+    eng = engine_for(model)
+    for p in asked:
+        eng.add_request(p, max_new_tokens=9)
+    out = eng.run()
+    assert eng.decode_traces == 1 and eng.prefill_traces == 1
+    assert eng.cache.state_rows_used == 0 and eng.cache.num_free == \
+        eng.cache.num_blocks - 1
+    compared = 0
+    for rid, tokens in out.items():
+        tokens = np.asarray(tokens, np.int32)
+        plen = len(asked[rid])
+        assert tokens[:plen].tolist() == asked[rid].tolist()
+        rows = reference_logits(cfg, tokens[None, :-1])[0][plen - 1:]
+        served = rows[np.arange(len(rows)), tokens[plen:]]
+        assert (rows.max(-1) - served).max() < 1e-4
+        compared += len(rows)
+    assert compared == 9 * len(asked)
+
+
+def test_engine_step_functions_give_the_references_logits():
+    """The spec's own step functions, driven by hand over the engine's
+    pools: two prefill chunks (the second padded past the prompt) carry
+    the conv window and the state, then decode steps read them; the
+    logits of every fed position against the reference's."""
+    from paddle_tpu.core.tensor import Tensor
+
+    model, cfg = seeded("ME*EM")
+    eng = engine_for(model, prefill_chunk=8)
+    spec, cache = eng.spec, eng.cache
+    (seq,) = prompts(cfg, [18])
+    plen, chunk = 11, 8
+    want = reference_logits(cfg, seq[None])[0]
+    blocks = cache.allocate(-(-len(seq) // 4))
+    row = np.zeros(eng.max_blocks, np.int32)
+    row[:len(blocks)] = blocks
+    state_row = cache.allocate_state()
+    wrap = Tensor._wrap
+    kp, vp, state = cache.kpool, cache.vpool, cache.state
+    got = {}
+    for start in range(0, plen, chunk):
+        ids = np.zeros((1, chunk), np.int32)
+        n = min(chunk, plen - start)
+        ids[0, :n] = seq[start:start + n]
+        r = spec.prefill_chunk(
+            wrap(jnp.asarray(ids)), wrap(jnp.int32(start)), wrap(kp),
+            wrap(vp), wrap(jnp.asarray(row)), wrap(jnp.int32(plen)),
+            slot_state=state, state_row=jnp.int32(state_row))
+        kp, vp, state = r.kpool._array, r.vpool._array, r.slot_state
+        logits = np.asarray(spec.logits(r.hidden)._array)[0]
+        for j in range(n):
+            got[start + j] = logits[j]
+    for pos in range(plen, len(seq)):
+        tokens = np.zeros((eng.num_slots, 1), np.int32)
+        tokens[1, 0] = seq[pos]               # lane 1; lane 0 is idle
+        tables = np.zeros((eng.num_slots, eng.max_blocks), np.int32)
+        tables[1] = row
+        r = spec.decode(
+            wrap(jnp.asarray(tokens)),
+            wrap(jnp.asarray(np.array([0, pos], np.int32))), wrap(kp),
+            wrap(vp), wrap(jnp.asarray(tables)),
+            backend=eng.attention_backend, slot_state=state,
+            state_rows=jnp.asarray(np.array([0, state_row], np.int32)))
+        kp, vp, state = r.kpool._array, r.vpool._array, r.slot_state
+        got[pos] = np.asarray(spec.logits(r.hidden)._array)[1, 0]
+        assert np.asarray(r.counters)[0] == 1           # one live lane
+    for pos, logits in got.items():
+        np.testing.assert_allclose(logits, want[pos], atol=2e-5,
+                                   err_msg=f"position {pos}")
+
+
+def test_a_slot_another_request_just_left_starts_from_nought():
+    """One lane: the second request sits where the first sat. Its tokens
+    are what it gets alone on a fresh engine — the row of recurrent state
+    was zeroed at admission."""
+    model, cfg = seeded("ME*E")
+    first, second = prompts(cfg, [23, 14])
+    eng = engine_for(model, num_slots=1)
+    a = eng.add_request(first, max_new_tokens=8)
+    b = eng.add_request(second, max_new_tokens=8)
+    both = eng.run()
+    alone = engine_for(model, num_slots=1)
+    c = alone.add_request(second, max_new_tokens=8)
+    assert both[b] == alone.run()[c]
+    assert len(both[a]) == 23 + 8
+    assert eng.cache.state_rows_used == 0
+    assert "engine_state_slots_used 0" in eng.metrics.render_prometheus()
+
+
+def test_engine_counts_the_experts_load_and_the_state_rows():
+    model, cfg = seeded("ME*E")
+    eng = engine_for(model)
+    for p in prompts(cfg, [9, 9]):
+        eng.add_request(p, max_new_tokens=6)
+    eng.run()
+    totals = eng.step_counter_totals
+    # one prefill chunk an iteration: the second request starts a step
+    # later, 5 decode steps each
+    assert eng.decode_steps == 6 and totals["decode_live_lanes"] == 10
+    # 2 E layers, 3 of 16 experts a token, 8 held: at most 3 a token
+    assert 0 < totals["moe_assignments_held"] <= 10 * 2 * 3
+    assert 0 < totals["moe_experts_touched"] <= \
+        totals["moe_assignments_held"]
+    assert 1 <= totals["moe_max_expert_load"] <= 2
+    text = eng.metrics.render_prometheus()
+    for name in ("engine_moe_assignments_held_total",
+                 "engine_moe_experts_touched_total",
+                 "engine_moe_max_expert_load",
+                 "engine_state_slots_used"):
+        assert name in text
+    for phase in ("state_alloc", "state_free"):
+        assert f'phase="{phase}"' in text
+
+
+@pytest.mark.parametrize("kwargs,feature", [
+    (dict(enable_prefix_cache=True), "prefix_cache"),
+    (dict(spec_decode_k=2), "spec_decode"),
+    (dict(prefill_buckets=(16, 64), prefill_chunk="auto"),
+     "bucketed_prefill"),
+    (dict(kv_dtype="int8"), "kv_int8"),
+    (dict(weight_dtype="int8"), "weight_int8"),
+    (dict(async_core=True), "async_core"),
+])
+def test_what_needs_a_state_snapshot_is_refused_at_construction(kwargs,
+                                                                feature):
+    model, _ = seeded("M*")
+    with pytest.raises(ValueError, match=feature + " is not served"):
+        engine_for(model, **kwargs)
+
+
+def test_forks_handoffs_shards_and_adapters_are_refused():
+    from paddle_tpu.inference.sampling import SamplingParams
+
+    model, cfg = seeded("M*")
+    eng = engine_for(model, sampling=True)
+    assert eng.enable_prefix_cache is False
+    (p,) = prompts(cfg, [6])
+    with pytest.raises(ValueError, match="fork is not served.*snapshot"):
+        eng.best_of_n(p, 2, 4, sampling_params=SamplingParams(
+            temperature=1.0, seed=1))
+    with pytest.raises(ValueError, match="handoff is not served"):
+        eng.add_request(p, 1, prefill_only=True)
+    with pytest.raises(ValueError, match="handoff is not served"):
+        eng.adopt_request(p, 3, [1, 2], 4)
+    with pytest.raises(ValueError, match="not sharded"):
+        engine_for(model, mp_degree=2)
+    with pytest.raises(ValueError, match="take no adapters"):
+        engine_for(model, adapters=object())
+    with pytest.raises(ValueError, match="grouped KV"):
+        engine_for(model, attention_backend="pallas")
+
+
+def test_a_model_without_recurrent_layers_keeps_every_option():
+    """The refusals follow the state, not the model's name: the same
+    engine serves the GPT-2 block with its prefix cache on and no state
+    rows, and its compiled steps take no state argument."""
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    gpt = GPTForCausalLM(GPTConfig.tiny())
+    gpt.eval()
+    eng = GenerationEngine(gpt, num_slots=2, block_size=4)
+    assert eng.enable_prefix_cache and eng.cache.state == ()
+    assert eng.spec.refuses == {} and eng.spec.step_counters == ()
+    eng.add_request([1, 2, 3, 4, 5], max_new_tokens=4)
+    assert len(eng.run()[0]) == 9
+    assert "engine_state_slots_used" not in \
+        eng.metrics.render_prometheus()
+
+
+# -- the share test -----------------------------------------------------------
+
+def _moe_leaves(cfg, first, held, seed=3):
+    """Reference leaves of one E layer that holds `held` experts from
+    `first`, cut out of ONE uncut layer's seeded arrays."""
+    whole = dict(ref_cfg(cfg), hybrid_override_pattern="E",
+                 n_routed_experts=cfg.router_experts, expert_offset=0)
+    arrays = weights.make_all(seed, ref.param_spec(whole), jnp.float32)
+    p = [arrays[f"layers.0.mixer.{leaf}"] for leaf in ref.MIXER_LEAVES["E"]]
+    p[6], p[7] = p[6][first:first + held], p[7][first:first + held]
+    return p
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The routed parts that all 4 shares give, plus the shared expert
+    counted once, are the uncut layer — for the reference, and for the
+    program's layer told which experts it holds."""
+    cfg = NemotronHConfig.tiny(pattern="E", router_experts=16,
+                               n_routed_experts=4, num_experts_per_tok=5)
+    u = jax.random.normal(jax.random.PRNGKey(0), (2, 7, 64))
+    mm = common.mm_f32
+    uncut = dict(ref_cfg(cfg), n_routed_experts=16, expert_offset=0)
+    whole = ref.moe_mixer(_moe_leaves(cfg, 0, 16), u, ref.sizes(uncut), mm)
+    parts = []
+    for first in (0, 4, 8, 12):
+        z = ref.sizes(dict(ref_cfg(cfg), expert_offset=first))
+        p = _moe_leaves(cfg, first, 4)
+        parts.append(ref.moe_routed_part(p, u, z, mm))
+    shared = ref.moe_shared_part(_moe_leaves(cfg, 0, 4), u, mm)
+    np.testing.assert_allclose(sum(parts) + shared, whole, atol=1e-5)
+    assert all(np.abs(p).max() > 1e-3 for p in parts)
+
+    flat = u.reshape(-1, 64)
+    got = 0
+    for first in (0, 4, 8, 12):
+        layer = NemotronHForCausalLM(NemotronHConfig.tiny(
+            pattern="E", router_experts=16, n_routed_experts=4,
+            num_experts_per_tok=5, expert_offset=first)).layers[0].mixer
+        for leaf, a in zip(ref.MIXER_LEAVES["E"],
+                           _moe_leaves(cfg, first, 4)):
+            owner, name = leaf.split(".")
+            getattr(getattr(layer, owner), name)._in_place_update(a)
+        out, counters = layer.forward_rows(flat, jnp.ones(14, bool))
+        routed_and_shared = np.asarray(out).reshape(2, 7, 64)
+        got = got + routed_and_shared - (np.asarray(shared)
+                                         if first else 0)
+        assert counters[0] > 0
+    np.testing.assert_allclose(got, whole, atol=1e-5)
+
+
+def test_the_sliced_vocabularys_logits_are_the_whole_tables_first_rows():
+    """Ids drawn from the slice; embedding and head cut to the slice's
+    rows: the logits are the first rows of the whole table's."""
+    model, cfg = seeded("M*", vocab=128)
+    ids = np.stack(prompts(NemotronHConfig.tiny(vocab=32), [12]))
+    whole = np.asarray(model(ids)._array)
+    cut = NemotronHForCausalLM(NemotronHConfig.tiny(
+        pattern="M*", vocab=32, router_experts=16, n_routed_experts=8,
+        expert_offset=4))
+    cut.eval()
+    full = dict(model.named_parameters())
+    for name, p in cut.named_parameters():
+        a = full[name]._array
+        p._in_place_update(a[:32] if name in ("embed.weight",
+                                              "lm_head.weight") else a)
+    np.testing.assert_allclose(np.asarray(cut(ids)._array),
+                               whole[..., :32], atol=1e-6)
+
+
+# -- the kernels against the plain forms -------------------------------------
+
+def test_chunked_scan_carries_the_state_and_skips_the_padding():
+    """`ssd_chunk_scan` from a carried state, in chunks that do not
+    divide the length, with a padded tail: against the recurrence taken a
+    token at a time."""
+    key = jax.random.split(jax.random.PRNGKey(1), 6)
+    t, heads, p, g, n, valid = 13, 4, 8, 2, 16, 10
+    x = jax.random.normal(key[0], (t, heads, p))
+    dt = jax.nn.softplus(jax.random.normal(key[1], (t, heads)))
+    a = -jnp.exp(0.3 * jax.random.normal(key[2], (heads,)))
+    b = jax.random.normal(key[3], (t, g, n))
+    c = jax.random.normal(key[4], (t, g, n))
+    d = jnp.ones(heads)
+    state = jax.random.normal(key[5], (heads, p, n))
+    y, after = ssm.ssd_chunk_scan(x, dt, a, b, c, d, state, valid,
+                                  chunk_size=4)
+    s, want = np.asarray(state, np.float64), []
+    for i in range(valid):
+        bi = np.repeat(np.asarray(b[i]), heads // g, 0)
+        ci = np.repeat(np.asarray(c[i]), heads // g, 0)
+        decay = np.exp(np.asarray(dt[i] * a))[:, None, None]
+        s = decay * s + (np.asarray(dt[i])[:, None] * np.asarray(x[i])
+                         )[:, :, None] * bi[:, None, :]
+        want.append((s * ci[:, None, :]).sum(-1) + np.asarray(x[i]))
+    np.testing.assert_allclose(y[:valid], np.stack(want), atol=1e-4)
+    np.testing.assert_allclose(after, s, atol=1e-4)
+
+
+def test_decode_kernel_matches_the_plain_update_and_leaves_other_rows():
+    key = jax.random.split(jax.random.PRNGKey(2), 6)
+    slots, heads, p, g, n = 3, 4, 8, 2, 128
+    pool = jax.random.normal(key[0], (2, slots + 1, heads, p, n))
+    rows = jnp.asarray([2, 0, 3], jnp.int32)          # lane 1 is idle
+    x = jax.random.normal(key[1], (slots, heads, p))
+    dt = jax.nn.softplus(jax.random.normal(key[2], (slots, heads)))
+    a = -jnp.exp(0.3 * jax.random.normal(key[3], (heads,)))
+    b = jax.random.normal(key[4], (slots, g, n))
+    c = jax.random.normal(key[5], (slots, g, n))
+    d = jnp.ones(heads)
+    ssm.reset_ssm_path_stats()
+    got = ssm.ssm_decode_step(pool, 1, rows, x, dt, a, d, b, c, "pallas")
+    want = ssm.ssm_decode_step(pool, 1, rows, x, dt, a, d, b, c, "xla")
+    assert ssm.SSM_PATH_STATS == {"xla": 1, "pallas": 1}
+    live = np.array([0, 2])
+    np.testing.assert_allclose(np.asarray(got[0])[live],
+                               np.asarray(want[0])[live], atol=1e-5)
+    for row in (2, 3):
+        np.testing.assert_allclose(got[1][1, row], want[1][1, row],
+                                   atol=1e-5)
+    np.testing.assert_array_equal(got[1][0], pool[0])   # the other layer
+    np.testing.assert_array_equal(got[1][1, 1], pool[1, 1])   # a free row
+
+
+@pytest.mark.parametrize("crowded", [False, True])
+def test_dropless_dispatch_and_grouped_matmul(crowded):
+    """Every assignment to an expert held here gets a row of the buffer,
+    whatever the crowding (no capacity): with every token on ONE expert,
+    and spread; the kernel (interpreter) and the plain form agree with
+    the sum over experts written out."""
+    key = jax.random.split(jax.random.PRNGKey(4), 5)
+    t, k, d, h, held, first = 12, 3, 32, 48, 4, 6
+    x = jax.random.normal(key[0], (t, d))
+    w1 = 0.2 * jax.random.normal(key[1], (held, d, h))
+    w2 = 0.2 * jax.random.normal(key[2], (held, h, d))
+    weights_ = jax.random.uniform(key[3], (t, k))
+    if crowded:
+        ids = jnp.tile(jnp.asarray([[7, 0, 15]], jnp.int32), (t, 1))
+    else:
+        ids = jax.random.randint(key[4], (t, k), 0, 16).astype(jnp.int32)
+    plan = moe.sorted_dispatch(ids, first, held, tile_rows=8)
+    local = np.asarray(ids) - first
+    here = (local >= 0) & (local < held)
+    rows = np.asarray(plan["slot_row"])
+    m = len(plan["row_token"])
+    assert (rows[here] < m).all() and (rows[~here] == m).all()
+    assert len(set(rows[here].tolist())) == here.sum()    # none shares
+    assert np.asarray(plan["group_sizes"]).sum() == here.sum()
+    tokens = np.asarray(plan["row_token"])[rows[here]]
+    assert (tokens == np.nonzero(here)[0]).all()
+
+    want = np.zeros((t, d))
+    for e in range(held):
+        w_e = np.where(local == e, np.asarray(weights_), 0).sum(-1)
+        hid = np.square(np.maximum(np.asarray(x) @ np.asarray(w1[e]), 0))
+        want += w_e[:, None] * (hid @ np.asarray(w2[e]))
+    moe.reset_moe_path_stats()
+    for backend in ("xla", "pallas"):
+        out, counters = moe.expert_share(x, ids, weights_, w1, w2, first,
+                                         backend=backend, tile_rows=8)
+        np.testing.assert_allclose(out, want, atol=1e-4)
+        sizes = np.bincount(local[here], minlength=held)
+        assert counters.tolist() == [here.sum(), (sizes > 0).sum(),
+                                     sizes.max()]
+    assert moe.MOE_PATH_STATS == {"xla": 1, "pallas": 1}
+
+
+def test_engine_source_names_no_architecture():
+    """C1's "done when": the engine schedules, allocates and dispatches,
+    and what it knows of a model is its serving spec."""
+    import inspect
+
+    from paddle_tpu.inference import engine
+
+    source = inspect.getsource(engine).lower()
+    for word in ("gpt", "nemotron", "mamba", "cfg.num_heads",
+                 "model.config"):
+        assert word not in source, word

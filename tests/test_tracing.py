@@ -526,7 +526,7 @@ def test_tracing_import_has_no_backend_init():
         "import paddle_tpu.observability.tracing as t\n"
         "from jax._src import xla_bridge\n"
         "assert not xla_bridge._backends, 'backend initialized'\n"
-        "assert len(t.STEP_PHASES) == 10\n"
+        "assert len(t.STEP_PHASES) == 12\n"
         "r = t.TraceRecorder(capacity=2)\n"
         "r.add_span('x', 0, 1)\n"
         "assert r.snapshot()[0]['name'] == 'x'\n"

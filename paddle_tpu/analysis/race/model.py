@@ -23,6 +23,13 @@ consume:
    trace, loop bodies replay twice (loop-carried dispatches — the
    depth-2 pipe shape), so TPU203 can walk "is an allocator release
    reachable between a dispatch and its completion" per function.
+   A dispatch makes a RECORD, and the trace says which record a wait
+   completes and from which record a release draws its lanes (`bind`
+   effects follow the names a record is held under through
+   assignments, loop targets and the parameters of spliced calls):
+   the engine's ahead order waits for step N and releases step N's
+   lanes while step N+1 is outstanding, and that is sound; a release
+   drawn from a record that is still outstanding is not.
 
 Everything is name-based and module-local, like tpu-lint: locks are
 keyed by their attribute/global NAME (one lock reached through two
@@ -77,6 +84,54 @@ _SYNCHRONIZED_TYPES = frozenset(
 CTOR_NAMES = frozenset({"__init__", "__new__", "__post_init__"})
 
 
+#: Builtins whose result is made of their arguments' items: a loop over
+#: `zip(inflight.runnable, inflight.slots)` draws from `inflight`.
+_PASS_THROUGH_CALLS = frozenset({
+    "zip", "enumerate", "list", "tuple", "reversed", "sorted", "iter"})
+
+
+def _root_key(e):
+    """The name an expression is drawn from: `inflight` for
+    `inflight.slots[0].blocks`, `self._inflight` for
+    `self._inflight.out`, the first argument with one for `zip(...)` /
+    `[src]`; None where there is none."""
+    while True:
+        if isinstance(e, ast.Name):
+            return e.id
+        if isinstance(e, ast.Attribute):
+            if isinstance(e.value, ast.Name) and \
+                    e.value.id in ("self", "cls"):
+                return "self." + e.attr
+            e = e.value
+        elif isinstance(e, (ast.Subscript, ast.Starred)):
+            e = e.value
+        elif isinstance(e, (ast.List, ast.Tuple)) or (
+                isinstance(e, ast.Call) and isinstance(e.func, ast.Name)
+                and e.func.id in _PASS_THROUGH_CALLS):
+            items = e.elts if not isinstance(e, ast.Call) else e.args
+            for item in items:
+                key = _root_key(item)
+                if key is not None:
+                    return key
+            return None
+        else:
+            return None
+
+
+def _target_keys(t):
+    """Names an assignment or loop target binds (`x`, `self.x`)."""
+    if isinstance(t, ast.Name):
+        return [t.id]
+    if isinstance(t, ast.Attribute):
+        return ["self." + t.attr] if isinstance(t.value, ast.Name) \
+            and t.value.id in ("self", "cls") else []
+    if isinstance(t, (ast.Tuple, ast.List)):
+        return [k for e in t.elts for k in _target_keys(e)]
+    if isinstance(t, ast.Starred):
+        return _target_keys(t.value)
+    return []
+
+
 def _diverges(stmts):
     """True when a statement list ends by leaving the enclosing path
     (return/raise/break/continue) — such a branch contributes nothing
@@ -110,6 +165,8 @@ class RaceModuleAnalysis(ModuleAnalysis):
             for a in attrs)
         self._dispatch_attrs = frozenset(I.ENGINE_DISPATCH_EFFECTS)
         self._complete_calls = frozenset(I.STEP_COMPLETE_CALLS)
+        # names are followed only where a record can be made at all
+        self._tracks_records = any(a in src for a in self._dispatch_attrs)
         self._collect_name_types()
         self._collect_thread_reachable()
         self.accesses = []
@@ -254,11 +311,14 @@ class RaceModuleAnalysis(ModuleAnalysis):
 
     def effect_seq(self, fi, _stack=None):
         """Flattened ordered effect trace of `fi`: module-local calls
-        inlined (effects re-anchored at the call site in `fi`), cycles
-        cut. Entries are (kind, node, detail) with kind in
-        dispatch/complete/release plus the structural fork/alt/join
-        markers (always balanced; `detail` on alt/join is the
-        diverged flag of the arm just closed)."""
+        inlined (effects re-anchored at the call site in `fi`, between
+        an `enter` that maps the callee's parameters to the names the
+        caller passed and an `exit`), cycles cut. Entries are (kind,
+        node, detail) with kind in dispatch/complete/release (`detail`
+        of the last two: the call and the name its argument is drawn
+        from), `bind` (`detail`: (target, source) pairs), plus the
+        structural fork/alt/join markers (always balanced; `detail` on
+        alt/join is the diverged flag of the arm just closed)."""
         if id(fi) in self._effect_memo:
             return self._effect_memo[id(fi)]
         stack = _stack if _stack is not None else set()
@@ -268,8 +328,11 @@ class RaceModuleAnalysis(ModuleAnalysis):
         out = []
         for kind, node, detail in self.effects.get(id(fi), []):
             if kind == "call":
-                for k2, _n2, d2 in self.effect_seq(detail, stack):
+                callee, argmap = detail
+                out.append(("enter", node, argmap))
+                for k2, _n2, d2 in self.effect_seq(callee, stack):
                     out.append((k2, node, d2))
+                out.append(("exit", node, None))
             else:
                 out.append((kind, node, detail))
         stack.discard(id(fi))
@@ -315,6 +378,7 @@ class _FnWalker:
             self.scan(s.value)
             for t in s.targets:
                 self.write_target(t)
+                self.bind(s, t, s.value)
         elif isinstance(s, ast.AnnAssign):
             if s.value is not None:
                 self.scan(s.value)
@@ -346,6 +410,7 @@ class _FnWalker:
             self.effects.append(("join", s, _diverges(s.orelse)))
         elif isinstance(s, (ast.For, ast.AsyncFor)):
             self.scan(s.iter)
+            self.bind(s, s.target, s.iter, each=True)
             # replay the body: loop-carried dispatch/release ordering
             # (iteration N dispatches, N+1 releases) needs two passes
             self.block(s.body)
@@ -382,6 +447,28 @@ class _FnWalker:
             for child in ast.iter_child_nodes(s):
                 if isinstance(child, ast.expr):
                     self.scan(child)
+
+    def bind(self, stmt, target, value, each=False):
+        """Record which names now hold what `value` is drawn from
+        (`each`: every item of it, a loop's target). A dispatch call's
+        own result is the record that call makes."""
+        if not self.r._tracks_records:
+            return
+        if not each and isinstance(target, (ast.Tuple, ast.List)) \
+                and isinstance(value, (ast.Tuple, ast.List)) \
+                and len(target.elts) == len(value.elts):
+            pairs = list(zip(target.elts, value.elts))
+        else:
+            pairs = [(target, value)]
+        out = []
+        for t, v in pairs:
+            made = isinstance(v, ast.Call) \
+                and isinstance(v.func, ast.Attribute) \
+                and v.func.attr in self.r._dispatch_attrs
+            source = ("dispatch", v) if made else _root_key(v)
+            out.extend((key, source) for key in _target_keys(t))
+        if out:
+            self.effects.append(("bind", stmt, out))
 
     def lock_leaf(self, expr):
         """Lock name a `with <expr>:` guards, or None."""
@@ -547,17 +634,35 @@ class _FnWalker:
                 (c, self.fi, sorted(locks)[0], what))
 
         # pipeline effects (TPU203)
+        drawn = _root_key(c.args[0]) if c.args else None
         if fname in r._complete_calls:
-            self.effects.append(("complete", c, fname))
+            self.effects.append(("complete", c, (fname, drawn)))
         elif attr in r._dispatch_attrs:
             self.effects.append(("dispatch", c, attr))
         elif attr in r._release_attrs and \
                 self.lock_leaf(c.func.value) is None:
-            self.effects.append(("release", c, attr))
+            self.effects.append(("release", c, (attr, drawn)))
         else:
             callee = self._local_callee(c)
             if callee is not None:
-                self.effects.append(("call", c, callee))
+                self.effects.append(
+                    ("call", c, (callee, self._argmap(c, callee))))
+
+    @staticmethod
+    def _argmap(c, callee):
+        """Callee parameter -> the caller's name its argument is drawn
+        from (None where there is none), positionals and keywords."""
+        a = getattr(callee.node, "args", None)
+        if a is None:
+            return {}
+        params = [p.arg for p in a.posonlyargs + a.args]
+        if params and params[0] in ("self", "cls") \
+                and isinstance(c.func, ast.Attribute):
+            params = params[1:]
+        out = {p: _root_key(v) for p, v in zip(params, c.args)}
+        out.update({kw.arg: _root_key(kw.value)
+                    for kw in c.keywords if kw.arg})
+        return out
 
     def _blocking_receiver(self, base):
         """Was the receiver built by a known blocking type (Thread,

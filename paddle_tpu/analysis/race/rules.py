@@ -90,66 +90,155 @@ def check_tpu202(mod):
     return findings
 
 
+class _PipeState:
+    """What the TPU203 walk knows at one point of a function: which
+    dispatched records are outstanding (in launch order), which
+    records a wait has completed, and which record each name holds.
+    A record is an int (made by a dispatch seen in this walk) or, for
+    one made elsewhere, the qualified name it was first met under."""
+
+    def __init__(self, outstanding=(), completed=(), env=None):
+        self.outstanding = list(outstanding)
+        self.completed = set(completed)
+        self.env = dict(env or {})
+
+    def copy(self):
+        return _PipeState(self.outstanding, self.completed, self.env)
+
+    @staticmethod
+    def merged(arms):
+        """Pessimistic join of exclusive arms: outstanding on ANY arm
+        stays outstanding; completed, and a name's record, only where
+        every arm agrees."""
+        out = arms[0].copy()
+        for arm in arms[1:]:
+            out.outstanding = sorted(
+                set(out.outstanding) | set(arm.outstanding))
+            out.completed &= arm.completed
+            out.env = {k: v for k, v in out.env.items()
+                       if arm.env.get(k) == v}
+        return out
+
+
 def check_tpu203(mod):
     """free-before-complete: an allocator release (introspect
     ALLOCATOR_RELEASE_EFFECTS) reachable on a path between a recorded
-    dispatch (ENGINE_DISPATCH_EFFECTS) and its completion
-    (STEP_COMPLETE_CALLS) — the zombie-write hazard that holds the
-    async pipe at depth 1 (DESIGN_DECISIONS r21/r22). Loop bodies
+    dispatch (ENGINE_DISPATCH_EFFECTS) and THAT dispatch's completion
+    (STEP_COMPLETE_CALLS) — the zombie-write hazard (DESIGN_DECISIONS
+    r21/r22). The invariant it holds: a lane's pages are released only
+    after the last step dispatched over the lane has completed. So a
+    release drawn from a record whose wait has been seen is sound even
+    with a LATER step outstanding (the engine's ahead order: launch
+    N+1, wait for N, release N's lanes), and any other release with a
+    step outstanding fires: one drawn from the outstanding record
+    itself, from no record, or made before the wait. Loop bodies
     replay twice in the effect walk, so the depth-2 shape (iteration
     N+1 frees before waiting on iteration N's dispatch) fires too.
 
-    `if` arms fork the outstanding-dispatch state (exclusive arms
-    can't see each other's dispatches); the merge is pessimistic —
-    a dispatch surviving on ANY non-diverging arm stays outstanding,
-    and an arm ending in return/raise/break/continue drops out of
-    the merge entirely (early-return guards read as guards)."""
+    A wait completes the record its argument is drawn from and every
+    record launched before it (one stream). A wait on anything else
+    completes only dispatches whose result was never bound to a name
+    (nothing could wait on them by name). `if` arms fork the state
+    (exclusive arms can't see each other's dispatches); the merge is
+    pessimistic — a dispatch surviving on ANY non-diverging arm stays
+    outstanding, and an arm ending in return/raise/break/continue
+    drops out of the merge entirely (early-return guards read as
+    guards)."""
     findings = []
     seen = set()
     for fi in mod.functions:
-        outstanding = None
+        st = _PipeState()
         forks = []      # [saved_state, [non-diverged arm exit states]]
+        nodes = {}      # record -> the dispatch call that made it
+        named = set()   # records some name has held
+        frames = [0]
+        n_frames = 0
+
+        def held(key):
+            """The record `key` holds in the frame being walked (and
+            the key qualified by that frame); a name never bound
+            stands for the record made elsewhere that it holds."""
+            q = key if key.startswith("self.") else (frames[-1], key)
+            return st.env.get(q, q), q
+
         for kind, node, detail in mod.effect_seq(fi):
             if kind == "dispatch":
-                outstanding = node
+                rec = len(nodes)
+                nodes[rec] = node
+                st.outstanding.append(rec)
+            elif kind == "bind":
+                values = []
+                for key, source in detail:
+                    if isinstance(source, tuple):
+                        # the result of the dispatch call just walked
+                        values.append(len(nodes) - 1 if nodes else None)
+                    else:
+                        values.append(None if source is None
+                                      else held(source)[0])
+                for (key, _), rec in zip(detail, values):
+                    _, q = held(key)
+                    st.completed.discard(q)
+                    if rec is None:
+                        st.env.pop(q, None)
+                    else:
+                        st.env[q] = rec
+                        named.add(rec)
+            elif kind == "enter":
+                n_frames += 1
+                for param, key in detail.items():
+                    if key is not None:
+                        st.env[(n_frames, param)] = held(key)[0]
+                frames.append(n_frames)
+            elif kind == "exit":
+                frames.pop()
             elif kind == "complete":
-                outstanding = None
+                rec = None if detail[1] is None else held(detail[1])[0]
+                if rec in st.outstanding:
+                    cut = st.outstanding.index(rec) + 1
+                    st.completed.update(st.outstanding[:cut])
+                    st.outstanding = st.outstanding[cut:]
+                else:
+                    if rec is not None:
+                        st.completed.add(rec)
+                    st.outstanding = [r for r in st.outstanding
+                                      if r in named]
             elif kind == "fork":
-                forks.append([outstanding, []])
+                forks.append([st.copy(), []])
             elif kind == "alt":
                 if forks:
-                    saved, rec = forks[-1]
+                    saved, arms = forks[-1]
                     if not detail:
-                        rec.append(outstanding)
-                    outstanding = saved
+                        arms.append(st)
+                    st = saved.copy()
             elif kind == "join":
                 if forks:
-                    saved, rec = forks.pop()
+                    saved, arms = forks.pop()
                     if not detail:
-                        rec.append(outstanding)
-                    merged = None
-                    for st in rec:
-                        if st is not None:
-                            merged = st
-                    outstanding = merged if rec else saved
-            elif kind == "release" and outstanding is not None:
-                if outstanding is node:
-                    # dispatch and release both spliced from ONE
-                    # callee: reported inside that callee, not here
+                        arms.append(st)
+                    st = _PipeState.merged(arms) if arms else saved
+            elif kind == "release":
+                # a dispatch spliced from the SAME callee as this
+                # release is reported inside that callee, not here
+                live = [r for r in st.outstanding if nodes[r] is not node]
+                if not live:
+                    continue
+                attr, key = detail
+                if key is not None and held(key)[0] in st.completed:
                     continue
                 sig = (id(fi), getattr(node, "lineno", 0),
-                       getattr(node, "col_offset", 0), detail)
+                       getattr(node, "col_offset", 0), attr)
                 if sig in seen:
                     continue
                 seen.add(sig)
                 findings.append(mod.finding(
                     "TPU203", node,
-                    f"allocator release '{detail}' is reachable "
+                    f"allocator release '{attr}' is reachable "
                     f"between the dispatch at line "
-                    f"{getattr(outstanding, 'lineno', 0)} and its "
+                    f"{getattr(nodes[live[0]], 'lineno', 0)} and its "
                     "completion — a dispatched step may still write "
                     "the released blocks (zombie write); complete "
-                    "the in-flight step before releasing", fi))
+                    "the step the lanes were last dispatched in "
+                    "before releasing them", fi))
     return findings
 
 

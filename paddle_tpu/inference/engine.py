@@ -761,6 +761,14 @@ class _Slot:
     top_k: int = 0
     top_p: float = 1.0
     key_row: object = None             # [2] uint32 base PRNG key
+    # the ahead order: programs launched over this lane (decode steps,
+    # its prompt's last chunk) whose token the host has not read yet.
+    # The lane's blocks, state row and adapter page go back only at 0.
+    ahead: int = 0
+    # finish reason, set when the host read the lane's last token (an
+    # EOS) while a later step over the lane was still unread: the
+    # result is out, the lane stays seated until that step completes
+    done: str = None
 
     @property
     def prefilling(self):
@@ -770,13 +778,22 @@ class _Slot:
 
     @property
     def feed_pos(self):
-        """Absolute position of the token about to be fed. With
-        `generated` non-empty that is the newest generated token;
+        """Absolute position of the token the next decode step feeds:
+        the newest token DISPATCHED. With nothing unread (`ahead` 0)
+        and `generated` non-empty that is the newest generated token;
         empty `generated` is the full-prefix-hit state, where the
         first decode feeds the LAST PROMPT token (its logits produce
         the first generated token — the one step a full hit cannot
-        skip)."""
-        return len(self.req.prompt) + len(self.generated) - 1
+        skip). A position is a count: the host knows it without the
+        unread tokens themselves."""
+        return len(self.req.prompt) + len(self.generated) \
+            + self.ahead - 1
+
+    @property
+    def dispatched(self):
+        """Generated tokens launched so far, read or not: what the
+        length test and the scheduler count."""
+        return len(self.generated) + self.ahead
 
     @property
     def feed_token(self):
@@ -786,9 +803,9 @@ class _Slot:
 
 @dataclass(eq=False)
 class _InFlight:
-    """The single in-flight result slot of the dispatch-ahead
-    pipeline: one dispatched decode/verify step whose device output
-    has NOT been waited on yet. The async core leaves exactly one of
+    """One launched program whose device output has NOT been read
+    yet: a decode/verify step, or (the ahead order) the last chunk of
+    a prompt. The pipelined core leaves exactly one decode step of
     these across `step()` calls (depth 1 — see DESIGN_DECISIONS r21);
     the serial core completes it inline within the same step."""
 
@@ -818,7 +835,7 @@ class GenerationEngine:
     mode with active dropout, same as `generate(use_cache=True)`.
     """
 
-    #: Dispatch/complete surface of the (async) step pipeline, declared
+    #: Dispatch/complete surface of the pipelined step orders, declared
     #: in introspect so tpu-race TPU203 can order allocator releases
     #: against in-flight device steps (see RACE_RELEASE_METHODS on
     #: PagedKVCache / PagedAdapterPool).
@@ -922,21 +939,29 @@ class GenerationEngine:
             "PADDLE_SERVE_TRACING", tracing)
         self.tracer = TraceRecorder(capacity=trace_capacity) \
             if self.tracing else None
-        # async engine core (ROADMAP item 3): a one-step dispatch-ahead
-        # pipeline — `step()` leaves the decode/verify dispatch IN
-        # FLIGHT and the next call's host work (admissions, prefill
-        # chunk, drafter proposals on a helper thread, adapter-page
-        # prefetch) overlaps its device time. Pure host restructuring:
-        # the compiled programs are byte-identical and the emitted
-        # token streams token-identical to the serial core (CI's
-        # serial-vs-async parity matrix). Env override wins
-        # (deploy-time knob, like the backend); off (the default)
-        # keeps today's serial step loop op-for-op.
+        # pipelined engine core: `step()` returns with ONE decode step
+        # launched and unread, so the host's work (the finish walk,
+        # the caller's work between calls, admissions, the prefill
+        # chunk, the next schedule) runs behind a device step. Which
+        # order that takes follows from the step, not from a knob:
+        # plain decode (K = 0) goes AHEAD — step N+1 is launched from
+        # step N's tokens where they lie, on the device, before the
+        # host reads them (`_step_ahead`); the speculative verify step
+        # completes step N first, because its next window's content is
+        # the accepted prefix, which only the host's walk knows
+        # (`_step_async`). Pure host restructuring: the compiled steps
+        # are byte-identical and the token streams identical to the
+        # serial order (`async_core=False`, the parity tests' foil).
+        # Env override wins (deploy-time knob, like the backend). A
+        # model whose spec refuses the pipelined core is served in the
+        # serial order unless the caller asked for the core outright.
         self.async_core = self._resolve_bool_knob(
-            "PADDLE_SERVE_ASYNC", async_core)
+            "PADDLE_SERVE_ASYNC", async_core,
+            default="async_core" not in spec.refuses)
         if self.async_core:
             self._refuse("async_core")
-        self._inflight = None          # the single in-flight step slot
+        self._inflight = None          # the one unread decode step
+        self._first = None             # a prompt's last chunk, unread
         self._ahead = None             # (helper thread, results dict)
         self._next_drafts = {}         # slot -> precomputed draft
         self._step_seq = 0
@@ -1065,6 +1090,21 @@ class GenerationEngine:
             donate_argnums=introspect.ENGINE_COW_DONATE_ARGNUMS
             if donate else (),
             out_shardings=self._step_out_shardings(0))
+        # the ahead order's feed: one tiny program picks each lane's
+        # next input token from where it lies — the previous decode
+        # step's output, the output of the chunk that ended a prompt
+        # this iteration, or the host's row for a lane whose newest
+        # token the host has read. The decode step itself is untouched.
+        def engine_feed_select(host, from_prev, first_lane, prev, first):
+            col = jnp.where(from_prev, prev, host[:, 0])
+            lanes = jnp.arange(col.shape[0], dtype=jnp.int32)
+            return jnp.where(lanes == first_lane, first, col)[:, None]
+
+        self._feed_select = jax.jit(
+            engine_feed_select,
+            out_shardings=None if self.mesh is None
+            else self._step_out_shardings(1)[0])
+        self._no_unread = None         # `_unread_outputs`' zeros
         self._queues = {p: deque() for p in PRIORITY_CLASSES}
         self._slots = [None] * self.num_slots
         self._results = {}
@@ -1075,6 +1115,11 @@ class GenerationEngine:
         self.tokens_generated = 0
         self.prefix_hit_tokens = 0
         self.decode_steps = 0
+        # of those, the steps launched while the previous was unread,
+        # and the tokens computed past an EOS and discarded
+        self.decode_steps_ahead = 0
+        self.overshoot_tokens = 0
+        self._t_read = 0.0             # perf_counter of the newest read
         # the model's own counters, summed (or the largest) over every
         # decode step: `spec.step_counters` names them
         self.step_counter_totals = {n: 0 for n, _ in spec.step_counters}
@@ -1158,9 +1203,9 @@ class GenerationEngine:
         return "int8"
 
     @staticmethod
-    def _resolve_bool_knob(env_name, requested):
+    def _resolve_bool_knob(env_name, requested, default=False):
         """Resolve a boolean serving knob: env override wins, ''
-        means unset, None defaults to off."""
+        means unset, None takes `default`."""
         env = os.environ.get(env_name)
         if env not in (None, ""):
             low = env.lower()
@@ -1170,7 +1215,7 @@ class GenerationEngine:
                 return False
             raise ValueError(
                 f"{env_name}={env!r} is not a boolean (use 0/1)")
-        return bool(requested) if requested is not None else False
+        return bool(requested) if requested is not None else default
 
     # -- probabilistic serving (per-slot sampling) -------------------------
     def _check_sampling(self, params):
@@ -1239,12 +1284,12 @@ class GenerationEngine:
 
     def _put_host_args(self, rows):
         """Move one step's dynamic host rows to the device. Serial
-        core: one `jnp.asarray` per row, in row order — op-for-op
-        today's path. Async core: ONE fused `jax.device_put` over the
-        whole tree (positions, draft windows, sampling rows, page rows
-        ride a single transfer instead of 3-8 round trips). The leaf
-        avals are identical either way, so the compiled step programs
-        — and TRACE_BASELINE.json — cannot move."""
+        order: one `jnp.asarray` per row, in row order. Pipelined
+        orders: ONE fused `jax.device_put` over the whole tree
+        (positions, draft windows, sampling rows, page rows ride a
+        single transfer instead of 3-8 round trips). The leaf avals
+        are identical either way, so the compiled step programs — and
+        TRACE_BASELINE.json — cannot move."""
         if not self.async_core:
             return [jnp.asarray(a) for a in rows]
         if self.mesh is not None:
@@ -1681,6 +1726,17 @@ class GenerationEngine:
             buckets=LATENCY_BUCKETS).labels(
                 backend=self.attention_backend)
         self._decode_traces_seen = 0
+        self._m_steps_ahead = m.counter(
+            "engine_decode_steps_ahead_total",
+            "Decode steps launched while the previous decode step was "
+            "still unread (fed from its tokens on the device): over "
+            "`engine.decode_steps` the share of steps the ahead order "
+            "engaged; 0 for a speculative or a serial engine.")
+        self._m_overshoot = m.counter(
+            "engine_overshoot_tokens_total",
+            "Tokens computed for a lane past its EOS and discarded: "
+            "the ahead order sees an EOS one step late. A finish by "
+            "length is a count and never overshoots.")
         # registered only where the model has them, so a plain engine's
         # exposition is unchanged (the adapter precedent)
         self._m_state_used = None
@@ -1696,16 +1752,19 @@ class GenerationEngine:
                 f"({how} over decode steps).")
             for name, how in self.spec.step_counters}
         # step-phase decomposition (ISSUE 17 / ROADMAP item 3): the
-        # host work between compiled steps, per named phase — the
-        # measured baseline the async engine core must beat. Always
+        # host work between compiled steps, per named phase — what
+        # the pipelined orders run behind a device step. Always
         # registered: the phase clock is host bookkeeping, on for
         # every engine (tracing only adds the span stream).
         self._m_host_gap = m.histogram(
             "engine_step_host_gap_seconds",
             "Exclusive wall time one engine.step() spent in each named "
-            "host phase (device_wait is the block_until_ready wait — "
-            "the only phase that is device time; everything else is "
-            "the serial host gap ROADMAP item 3 wants overlapped).",
+            "host phase. device_wait is the only phase that is device "
+            "time: in the serial order the whole decode step, in the "
+            "pipelined orders the throttle (the host is ahead and "
+            "waits for a step that is still running); everything else "
+            "is host work, which the pipelined orders run behind a "
+            "device step.",
             labelnames=("phase",), buckets=LATENCY_BUCKETS)
         self._m_device_fraction = m.gauge(
             "engine_step_device_fraction",
@@ -2383,20 +2442,56 @@ class GenerationEngine:
                     self.step_counter_totals[name], v)
                 self._m_step_counters[name].set_max(v)
 
-    def _finish(self, slot, reason):
+    def _publish(self, slot, reason):
+        """The host holds the lane's last token: the result is out."""
         req = slot.req
         self._results[req.req_id] = \
             list(map(int, req.prompt)) + slot.generated
-        self.cache.free(slot.blocks)
-        if slot.state_row:
-            with self._phase("state_free"):
-                self.cache.free_state(slot.state_row)
-        self._release_adapter(slot)
         self._m_finished.labels(reason=reason).inc()
         self.flight.record("finish", req.req_id, reason=reason,
                            tokens=len(slot.generated))
         self._trace_instant("request.finish", req, reason=reason,
                             tokens=len(slot.generated))
+
+    def _release(self, slot):
+        """Give back what a seated lane holds: its blocks, its state
+        row, its adapter page. THE allocator-safety invariant of the
+        pipelined orders (DESIGN_DECISIONS r21): only after the last
+        step launched over the lane has completed, so no program that
+        may still write the lane's pages is unfinished when they get
+        a new owner. `ahead` counts exactly those steps."""
+        if slot.ahead:
+            raise self._audit_error(
+                f"lane of request {slot.req.req_id!r} released with "
+                f"{slot.ahead} step(s) launched over it still unread")
+        self.cache.free(slot.blocks)
+        if slot.state_row:
+            with self._phase("state_free"):
+                self.cache.free_state(slot.state_row)
+        self._release_adapter(slot)
+
+    def _finish(self, slot, reason):
+        self._publish(slot, reason)
+        self._release(slot)
+
+    def _retire(self, i, slot, reason):
+        """The host has read lane `i`'s last token. A finish by length
+        is a count: the scheduler left the lane out of every later
+        step, so it is vacated here. An EOS read while a later step
+        over the lane is unread (the ahead order sees it one step
+        late) puts the result out now and holds the lane and all it
+        owns until that step's walk."""
+        if slot.req.prefill_only:
+            # a count too (one token): park the blocks for the
+            # disaggregated handoff, don't free them
+            self._handoff_finish(slot)
+        else:
+            self._publish(slot, reason)
+            if slot.ahead:
+                slot.done = reason
+                return
+            self._release(slot)
+        self._slots[i] = None
 
     def _first_token(self, slot, first, t_step):
         """Seat a request's FIRST generated token (from the final
@@ -2428,11 +2523,8 @@ class GenerationEngine:
             # engine_tokens_generated_total — record the producing
             # step's latency explicitly
             self._obs_tpot(req, now - t_step)
-            if req.prefill_only:
-                self._handoff_finish(slot)
-            else:
-                self._finish(slot, "eos" if done_eos else "length")
-            self._slots[self._slots.index(slot)] = None
+            self._retire(self._slots.index(slot), slot,
+                         "eos" if done_eos else "length")
             return False
         return True
 
@@ -2591,18 +2683,34 @@ class GenerationEngine:
                     nxt = self._dispatch_step(self._prefill, *args)
                     self._m_prefill_chunks.inc()
                     slot.prefill_pos = end
-                    if end < plen:     # mid-prompt: no sync needed
-                        self._trace_span("prefill.chunk", t_span,
-                                         req=req, start=start, end=end)
-                        return 1
-                    with self._phase("device_wait"):
-                        first = int(nxt)   # sync: first token is out
             self._trace_span("prefill.chunk", t_span, req=req,
-                             start=start, end=end, final=True)
-            with self._phase("finish"):
-                self._first_token(slot, first, t0)
+                             start=start, end=end,
+                             **({"final": True} if end == plen else {}))
+            if end < plen:             # mid-prompt: no token to read
+                return 1
+            # the prompt's last chunk: its output is the request's
+            # first token. The serial order reads it here; the ahead
+            # order feeds this iteration's decode step from it where
+            # it lies and reads it after that launch (`_step_ahead`).
+            slot.ahead = 1
+            first = _InFlight(out=nxt, runnable=[self._slots.index(slot)],
+                              slots=[slot], t_dec=t0, t_span=t_span)
+            if self._goes_ahead:
+                self._first = first
+            else:
+                self._first_complete(first)
             return 1
         return 0
+
+    def _first_complete(self, first):
+        """Read the first token a prompt's last chunk produced (the
+        sync on that chunk alone) and seat it."""
+        slot, = first.slots
+        with self._phase("device_wait"):
+            tok = int(np.asarray(first.out))   # sync: first token is out
+        slot.ahead -= 1
+        with self._phase("finish"):
+            self._first_token(slot, tok, first.t_dec)
 
     # -- admission: legacy whole-prompt bucketed prefill -------------------
     def _admit(self):
@@ -2706,17 +2814,15 @@ class GenerationEngine:
 
     def _decode_step(self):
         """One batched decode step over every decode-phase lane that
-        holds an exclusively-writable block for its write position.
-        Copy-on-write happens here: a lane whose feed position sits in
-        a shared or prefix-cached block first gets a private copy via
-        the compiled block-copy step.
-
-        SERIAL core: schedule, dispatch, and complete run inline in
-        this one call — the same operations in the same order as the
-        pre-pipeline engine. The ASYNC core drives the same three
-        stages through `_dispatch_ahead`/`_complete_inflight`, with
-        the complete of step N and the dispatch of step N+1 split
-        across `step()` calls."""
+        holds an exclusively-writable block for its write position, in
+        the SERIAL order: schedule, launch and complete run inline in
+        this one call. The pipelined orders drive the same three
+        stages with the completion of step N and the launch of step
+        N+1 in the other order (`_step_ahead`) or split across
+        `step()` calls (`_step_async`). Copy-on-write happens in the
+        schedule stage: a lane whose feed position sits in a shared or
+        prefix-cached block first gets a private copy via the compiled
+        block-copy step."""
         if self.spec_decode_k:
             runnable, drafts = self._spec_schedule()
             if not runnable:
@@ -2727,16 +2833,21 @@ class GenerationEngine:
         if not runnable:
             return 0
         inflight = self._plain_dispatch(runnable)
-        return self._plain_complete(inflight, synced=False)
+        return self._plain_complete(inflight)
 
     def _plain_schedule(self):
         """Schedule stage of a plain decode step: on-demand block
         growth + COW promotion per decode-phase lane; returns the
-        runnable lane indices."""
+        runnable lane indices. It counts tokens LAUNCHED, not tokens
+        read (`_Slot.dispatched`, `feed_pos`): a lane whose last token
+        is already on its way is left out (a finish by length never
+        overshoots), and so is one whose EOS the host has read."""
         runnable = []
         with self._phase("schedule"):
             for i, slot in enumerate(self._slots):
-                if slot is None or slot.prefilling:
+                if slot is None or slot.prefilling \
+                        or slot.done is not None \
+                        or slot.dispatched >= slot.req.max_new_tokens:
                     continue
                 bi = slot.feed_pos // self.block_size
                 if bi >= len(slot.blocks):
@@ -2761,11 +2872,54 @@ class GenerationEngine:
                 runnable.append(i)
         return runnable
 
-    def _plain_dispatch(self, runnable):
+    def _feed_rows(self, runnable):
+        """The ahead order's feed column: the host's column holds the
+        lanes whose newest token the host has read; every other
+        runnable lane's newest token is still on the device — in the
+        unread decode step's output or in the output of the chunk
+        that ended its prompt this iteration. Returns the two host
+        rows of the select that merges the three on the device (which
+        lanes take the unread step's token, which lane the chunk's),
+        or [] where the host has read every token."""
+        from_prev = np.zeros(self.num_slots, bool)
+        first_lane = -1
+        first = None if self._first is None else self._first.slots[0]
+        for i in runnable:
+            slot = self._slots[i]
+            if slot is first:
+                first_lane = i
+            elif slot.ahead:
+                from_prev[i] = True
+        if first_lane < 0 and not from_prev.any():
+            return []
+        return [from_prev, np.int32(first_lane)]
+
+    def _unread_outputs(self, prev):
+        """The two device values `_feed_select` merges: the unread
+        decode step's tokens and the first token of the chunk that
+        ended a prompt this iteration. Where one is absent, zeros
+        placed as the other, a compiled step's output, is (committed
+        to its sharding or not), so the select compiles once."""
+        outs = [None if r is None else r.out
+                for r in (prev, self._first)]
+        if self._no_unread is None:
+            like = outs[0] if outs[1] is None else outs[1]
+            where = (like.sharding,) if like.committed else ()
+            self._no_unread = (
+                jax.device_put(np.zeros(self.num_slots, np.int32),
+                               *where),
+                jax.device_put(np.int32(0), *where))
+        return [h if o is None else o
+                for o, h in zip(outs, self._no_unread)]
+
+    def _plain_dispatch(self, runnable, prev=None):
         """Dispatch stage of a plain decode step: build the dynamic
         host rows, move them in one `_put_host_args` batch, and issue
-        the compiled step WITHOUT waiting on its output. Returns the
-        `_InFlight` record the complete stage consumes."""
+        the compiled step WITHOUT waiting on its output. With `prev`
+        (the ahead order: the decode step still unread) the feed
+        column comes from the device wherever the host has not read
+        the token (`_feed_rows`). Returns the `_InFlight`
+        record the complete stage consumes."""
         t_span = now_us()
         with self._phase("dispatch"):
             tokens = np.zeros((self.num_slots, 1), np.int32)
@@ -2776,7 +2930,8 @@ class GenerationEngine:
             srows = np.zeros(self.num_slots, np.int32)
             for i in runnable:
                 slot = self._slots[i]
-                tokens[i, 0] = slot.feed_token
+                if not slot.ahead:
+                    tokens[i, 0] = slot.feed_token
                 positions[i] = slot.feed_pos
                 tables[i, :len(slot.blocks)] = slot.blocks
                 arows[i] = slot.adapter_page
@@ -2795,40 +2950,62 @@ class GenerationEngine:
                 # the null page 0 — exact-zero delta, like the null
                 # block)
                 rows.append(arows)
-            args = self._put_host_args(rows)
+            select = self._feed_rows(runnable) if self._goes_ahead \
+                else []
+            args = self._put_host_args(rows + select)
+            if select:
+                *args, from_prev, first_lane = args
+                args[0] = self._feed_select(
+                    args[0], from_prev, first_lane,
+                    *self._unread_outputs(prev))
             with RecordEvent("engine.decode"):
                 t_dec = time.perf_counter()
                 nxt = self._dispatch_step(self._decode, *args)
+        for i in runnable:
+            self._slots[i].ahead += 1
         self._step_seq += 1
         self.decode_steps += 1
+        if prev is not None:
+            self.decode_steps_ahead += 1
+            self._m_steps_ahead.inc()
         return _InFlight(out=nxt, runnable=runnable,
                          slots=[self._slots[i] for i in runnable],
                          counters=self._step_counters,
                          t_dec=t_dec, t_span=t_span,
                          seq=self._step_seq)
 
-    def _plain_complete(self, inflight, synced):
-        """Complete stage of a plain decode step: sync on the device
-        output, then the per-lane finish walk. `synced=False` is the
-        serial core — the np.asarray IS the device sync, measured as
-        `device_wait`; `synced=True` is the async core, where
-        `_complete_inflight` already blocked (the true residual) and
-        this conversion is only a host copy."""
-        if synced:
-            nxt = np.asarray(inflight.out)
-        else:
-            with self._phase("device_wait"):
-                nxt = np.asarray(inflight.out)  # sync: tokens are out
+    def _plain_complete(self, inflight):
+        """Complete stage of a plain decode step: read the device
+        output (the sync, measured as `device_wait`: the whole step
+        in the serial order, the throttle in the ahead order, where
+        the next step is already queued behind this one), then the
+        per-lane finish walk."""
+        with self._phase("device_wait"):
+            nxt = np.asarray(inflight.out)      # sync: tokens are out
+        now = time.perf_counter()
+        # the step had the device from its launch or, launched ahead,
+        # from the read of the step before it
         self._m_decode_seconds.observe(
-            time.perf_counter() - inflight.t_dec)
+            now - max(inflight.t_dec, self._t_read))
+        self._t_read = now
         self._trace_span("decode.step", inflight.t_span, cat="engine",
                          lanes=len(inflight.runnable))
         t_dec = inflight.t_dec
-        now = time.perf_counter()
         with self._phase("finish"):
             if inflight.counters is not None:
                 self._note_step_counters(np.asarray(inflight.counters))
             for i, slot in zip(inflight.runnable, inflight.slots):
+                slot.ahead -= 1
+                if slot.done is not None:
+                    # this step was launched before the host read the
+                    # lane's EOS: its token is discarded, and with the
+                    # step complete the lane gives back what it holds
+                    self.overshoot_tokens += 1
+                    self._m_overshoot.inc()
+                    self.flight.record("overshoot", slot.req.req_id)
+                    self._release(slot)
+                    self._slots[i] = None
+                    continue
                 tok = int(nxt[i])
                 is_first = not slot.generated   # full-prefix-hit lane
                 slot.generated.append(tok)
@@ -2856,16 +3033,8 @@ class GenerationEngine:
                         # lands in the TPOT histogram (producing-step
                         # latency)
                         self._obs_tpot(req, now - t_dec)
-                    if req.prefill_only:
-                        # full-prefix-hit prefill-only lane: its one
-                        # decode step produced the first token — park
-                        # the blocks for the disaggregated handoff,
-                        # don't free them
-                        self._handoff_finish(slot)
-                    else:
-                        self._finish(slot,
-                                     "eos" if done_eos else "length")
-                    self._slots[i] = None
+                    self._retire(i, slot,
+                                 "eos" if done_eos else "length")
         return len(inflight.runnable)
 
     def _spec_schedule(self):
@@ -3129,26 +3298,32 @@ class GenerationEngine:
                         # single-token instant finisher: keep it
                         # visible (the PR-6 TPOT contract)
                         self._obs_tpot(req, now - t_dec)
-                    if req.prefill_only:
-                        self._handoff_finish(slot)
-                    else:
-                        self._finish(slot,
-                                     "eos" if done_eos else "length")
-                    self._slots[i] = None
+                    self._retire(i, slot,
+                                 "eos" if done_eos else "length")
         return len(inflight.runnable)
 
     def step(self):
         """One scheduler iteration: admit queued requests into free
         lanes, run AT MOST one prefill chunk (chunked mode — long
-        prompts never monopolize an iteration), then one batched decode
+        prompts never monopolize an iteration), and one batched decode
         step over every decode-phase lane. Returns the number of
         admissions/chunks/lanes that made progress.
 
-        With the async core on (`async_core=True` / PADDLE_SERVE_ASYNC)
-        the same stages run pipelined one step ahead — `_step_async`;
-        off (the default) this is the serial loop, op-for-op."""
+        By default the iteration is PIPELINED: it returns with one
+        decode step launched and unread, so the device always has its
+        next program queued while the host walks, admits and
+        schedules. Plain decode goes one step AHEAD (`_step_ahead`:
+        step N+1 is launched from step N's tokens on the device before
+        the host reads them); the speculative verify step completes
+        step N before it launches N+1 (`_step_async`), because the
+        next window's content is the accepted prefix, which only the
+        host's walk knows. `async_core=False` / PADDLE_SERVE_ASYNC=0
+        is the serial order below: launch, read, walk, one after the
+        other — what the parity tests compare against."""
         if self.async_core:
-            return self._step_async()
+            if self.spec_decode_k:
+                return self._step_async()
+            return self._step_ahead()
         with RecordEvent("engine.step"):
             t_wall = time.perf_counter()
             if self.chunked_prefill:
@@ -3161,10 +3336,77 @@ class GenerationEngine:
             self._end_of_step_gauges()
             return progressed
 
-    # -- async engine core (dispatch-ahead pipeline) -----------------------
+    @property
+    def _goes_ahead(self):
+        """Whether decode steps are launched ahead of the host's read
+        of the step before: a property of the step (a plain decode
+        step's inputs need no host walk), not a knob."""
+        return self.async_core and not self.spec_decode_k
+
+    # -- pipelined engine core: plain decode, one step ahead ---------------
+    def _step_ahead(self):
+        """One iteration of the ahead order. When the call begins,
+        the decode step D(c-1) of the previous call is in flight and
+        unread. The call
+
+        (a) admits, and launches at most one prefill chunk P(c);
+        (b) schedules and launches D(c), fed from D(c-1)'s tokens
+            where they lie, on the device — and from P(c)'s, for the
+            lane whose prompt just ended; only lanes whose newest
+            token the host has read take it from the host's row;
+        (c) only THEN waits for D(c-1) (`engine.device_wait`: the one
+            wait, a throttle — D(c) is queued behind it), reads its
+            tokens, runs the finish walk and retires lanes; then reads
+            P(c)'s first token by a wait on that chunk alone. Both
+            tokens are read as their programs end, so `first_token`
+            and `finish` stamps stay on one footing.
+
+        It returns with D(c) in flight: between two decode programs
+        the device always has the next one queued, and exactly one
+        decode step is unread. What the host learns late is safe by
+        one rule (`_release`): a lane gives back its blocks, state row
+        and adapter page only when the last step launched over it has
+        completed. A finish by length is a count, so the scheduler
+        leaves the lane out of D(c) and nothing overshoots; an EOS is
+        seen one step late: the lane rides D(c), that token is
+        discarded (`engine_overshoot_tokens_total`) and the lane is
+        vacated at D(c)'s walk. A finished request's result is out at
+        the end of the call after the one that launched its last
+        step.
+
+        `progressed` counts admissions, prefill chunks and COMPLETED
+        decode lanes, as the serial order does; a call that only
+        launched counts that launch, so `run()`'s no-progress check
+        stays sound."""
+        with RecordEvent("engine.step"):
+            t_wall = time.perf_counter()
+            if self.chunked_prefill:
+                progressed = self._admit_chunked()
+                progressed += self._prefill_step()
+            else:
+                progressed = self._admit()
+            prev = self._inflight
+            runnable = self._plain_schedule()
+            self._inflight = self._plain_dispatch(runnable, prev) \
+                if runnable else None
+            if prev is not None:
+                progressed += self._plain_complete(prev)
+            first, self._first = self._first, None
+            if first is not None:
+                self._first_complete(first)
+            self._prefetch_ahead()
+            if not progressed and self._inflight is not None:
+                progressed = 1
+            self._flush_step_phases(time.perf_counter() - t_wall)
+            self._end_of_step_gauges()
+            return progressed
+
+    # -- pipelined engine core: speculative verify, complete-then-launch ---
     def _step_async(self):
-        """One pipelined scheduler iteration — the dispatch-ahead core
-        (ROADMAP item 3). Stage order per call:
+        """One pipelined iteration of a SPECULATIVE engine (r21's
+        order: the next window's content is the accepted prefix, so
+        step N completes before step N+1 is launched). Stage order
+        per call:
 
         1. COMPLETE step N: `jax.block_until_ready` on the in-flight
            output the PREVIOUS call dispatched. `device_wait` here is
@@ -3173,9 +3415,7 @@ class GenerationEngine:
            inter-step work, e.g. the fleet's other replicas) already
            overlapped the device time. The acceptance/sample walks and
            lane retirement stay on the step thread: their results
-           decide the NEXT window's context, and a retired lane's
-           blocks must not re-enter the allocator while a dispatched
-           step could still write to them.
+           decide the NEXT window's context.
         2. SPAWN the drafter helper: every decode lane's next-window
            proposal runs on a short-lived thread over SNAPSHOTS of the
            post-walk context — identical inputs to the serial
@@ -3217,9 +3457,9 @@ class GenerationEngine:
             return progressed
 
     def _complete_inflight(self):
-        """Retire the dispatched-ahead step, if one is outstanding:
-        block for the device residual, then run the normal complete
-        stage (walks + finish) on the step thread."""
+        """Retire the verify step dispatched ahead, if one is
+        outstanding: block for the device residual, then run the
+        normal complete stage (walks + finish) on the step thread."""
         inflight = self._inflight
         if inflight is None:
             return 0
@@ -3230,24 +3470,16 @@ class GenerationEngine:
             jax.block_until_ready(inflight.out)
         self.flight.record("async_complete", seq=inflight.seq,
                            lanes=len(inflight.runnable))
-        if self.spec_decode_k:
-            return self._spec_complete(inflight, synced=True)
-        return self._plain_complete(inflight, synced=True)
+        return self._spec_complete(inflight, synced=True)
 
     def _dispatch_ahead(self):
-        """Schedule + dispatch the next decode/verify step into the
-        single in-flight slot — no wait; the next `step()` call (or
-        `drain`) completes it."""
-        if self.spec_decode_k:
-            runnable, drafts = self._spec_schedule()
-            if not runnable:
-                return
-            self._inflight = self._spec_dispatch(runnable, drafts)
-        else:
-            runnable = self._plain_schedule()
-            if not runnable:
-                return
-            self._inflight = self._plain_dispatch(runnable)
+        """Schedule + dispatch the next verify step into the single
+        in-flight slot — no wait; the next `step()` call (or `drain`)
+        completes it."""
+        runnable, drafts = self._spec_schedule()
+        if not runnable:
+            return
+        self._inflight = self._spec_dispatch(runnable, drafts)
         self.flight.record("async_dispatch", seq=self._inflight.seq,
                            lanes=len(runnable))
 

@@ -295,22 +295,27 @@ BLOCKING_RECEIVER_TYPES = (
 # ---------------------------------------------------------------------------
 # Async-pipeline effect table (tpu-race TPU203)
 # ---------------------------------------------------------------------------
-# The ENGINE_STEP_DONATION precedent, applied to the dispatch-ahead
-# pipeline: the engine and the allocators DECLARE their effect surfaces
+# The ENGINE_STEP_DONATION precedent, applied to the pipelined engine
+# cores: the engine and the allocators DECLARE their effect surfaces
 # here, the race analyzer READS them — no magic method-name strings on
 # either side. Three effect classes:
 #
 # - DISPATCH: engine methods that issue a compiled step and return
-#   WITHOUT waiting on its output (they seat an `_InFlight` record).
+#   WITHOUT waiting on its output (they make an `_InFlight` record).
 #   Between such a call and its completion the device may still be
-#   writing into allocator-managed KV blocks / adapter pages.
+#   writing into the KV blocks / state rows / adapter pages of the
+#   lanes the step was dispatched over.
 # - COMPLETE: calls that synchronize outstanding device work — the
-#   explicit wait plus every host materialization the serial complete
-#   stages use (np.asarray IS the sync on the serial path).
+#   explicit wait plus every host materialization the complete stages
+#   use (np.asarray IS the sync of the serial and the ahead order).
+#   A wait completes the record its argument is drawn from.
 # - RELEASE: allocator methods that free or recycle device-visible
-#   pages. Calling one while a dispatch is outstanding is the
-#   zombie-write hazard of DESIGN_DECISIONS r21 — the reason the
-#   async pipe holds at depth 1.
+#   pages. The invariant (DESIGN_DECISIONS r21, as restated by PR 29):
+#   a lane's pages are released only after the LAST step dispatched
+#   over the lane has completed. Releasing them while that step is
+#   outstanding is the zombie-write hazard; releasing the lanes of a
+#   completed step while a LATER step (which does not hold them) is
+#   outstanding is the ahead order, and sound.
 
 #: Engine methods that dispatch a compiled step without waiting.
 ENGINE_DISPATCH_EFFECTS = (
@@ -323,11 +328,12 @@ ENGINE_DISPATCH_EFFECTS = (
 STEP_COMPLETE_CALLS = ("jax.block_until_ready",) \
     + tuple(sorted(HOST_SYNC_CALLS))
 
-#: Allocator release/recycle surface, by owning class. `free`/`release`
-#: drop references (blocks can re-enter the pool under an in-flight
-#: writer); `allocate`/`acquire` recycle evictable pages in place.
+#: Allocator release/recycle surface, by owning class. `free`/
+#: `free_state`/`release` drop a lane's references (its pages can get
+#: a new owner under an in-flight writer); `allocate`/`acquire`
+#: recycle evictable pages in place.
 ALLOCATOR_RELEASE_EFFECTS = {
-    "PagedKVCache": ("free", "allocate"),
+    "PagedKVCache": ("free", "allocate", "free_state"),
     "PagedAdapterPool": ("release", "acquire"),
 }
 

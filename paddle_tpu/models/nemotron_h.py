@@ -397,8 +397,6 @@ class NemotronHServing(ServingSpec):
                             "chunked prefill alone",
         "kv_int8": "the grouped-KV paged path is not quantized",
         "weight_int8": "no int8 plan for the experts and the scan",
-        "async_core": "the dispatch-ahead core has not been proven with "
-                      "state rows zeroed at admission",
     }
 
     def __init__(self, model):

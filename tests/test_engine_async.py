@@ -1,6 +1,11 @@
-"""Async engine core (ISSUE 18): the dispatch-ahead decode pipeline.
+"""The pipelined engine core (ISSUE 18; the default since ISSUE 29):
+`step()` returns with one decode step launched and unread. Plain
+decode goes one step AHEAD (step N+1 is launched from step N's tokens
+on the device before the host reads them); the speculative verify step
+completes step N before it launches N+1. `async_core=False` is the
+serial order everything here is compared against.
 
-The contract under test is brutal on purpose: the async core is a
+The contract under test is brutal on purpose: the pipelined core is a
 SCHEDULING refactor, not a numerics change —
 
 - token IDENTITY serial vs async across the whole serving matrix
@@ -17,9 +22,14 @@ SCHEDULING refactor, not a numerics change —
   block/adapter-page leak audits stay green.
 - `decode_traces == 1` per config and steady-state `expect_traces(0)`
   — dispatch-ahead reuses the exact compiled programs.
-- `PADDLE_SERVE_ASYNC` wins over the ctor arg; async off (the
-  default) leaves the engine on the serial path with no in-flight
-  machinery engaged.
+- `PADDLE_SERVE_ASYNC` wins over the ctor arg; the default is ON;
+  `async_core=False` leaves the engine on the serial path with no
+  in-flight machinery engaged.
+- the ahead order's late knowledge is safe: a finish by length is a
+  count and never overshoots; an EOS is seen one step late, the
+  overshoot token is discarded and counted, and the lane's blocks go
+  back only when the step that rode it has completed; the last chunk
+  of a prompt no longer syncs inside `_prefill_step`.
 - the flight recorder shows the pipeline actually pipelining:
   `async_dispatch(seq)` strictly precedes `async_complete(seq)` and
   completes interleave one-ahead, never deeper.
@@ -137,6 +147,17 @@ def _assert_async_matrix_cell(model, backend, K, mp=None, kv=None,
             "helper-thread drafts diverged from serial proposals"
         assert sum(_spec_counters(eng_s)) > 0, \
             "trace never exercised the drafter — weak test"
+        # the verify step completes before it dispatches
+        assert eng_a.decode_steps_ahead == 0
+    else:
+        # plain decode went ahead; no EOS in the trace, so nothing
+        # was computed and discarded, and every token asked for came
+        assert eng_a.decode_steps_ahead >= 0.8 * eng_a.decode_steps > 0
+        assert eng_a.overshoot_tokens == 0
+        assert eng_a.tokens_generated == eng_s.tokens_generated
+        assert eng_a._feed_select._cache_size() == 1, \
+            "the feed select compiled more than once"
+    assert eng_s.decode_steps_ahead == 0
     # the async engine retired every dispatched step before returning
     assert eng_a._inflight is None and eng_a._ahead is None
 
@@ -149,8 +170,7 @@ def _assert_async_matrix_cell(model, backend, K, mp=None, kv=None,
 # so tier-1 carries ONE identity cell — dense K=4, the cell that
 # exercises the helper-thread drafter AND the pipeline at once — and
 # the slow tier carries the rest (the test_engine_sharded precedent).
-@pytest.mark.parametrize(
-    "K", [pytest.param(0, marks=pytest.mark.slow), 4])
+@pytest.mark.parametrize("K", [0, 4])
 def test_async_token_identity_dense(model, K):
     """Tier-1 cut of THE acceptance gate: (dense, K, mp=1, fp) over
     chunked cold + warm + bucketed with mid-run admissions."""
@@ -177,19 +197,22 @@ def test_async_token_identity_full_matrix(model, backend, K, mp, kv):
     _assert_async_matrix_cell(model, backend, K, mp=mp, kv=kv)
 
 
-@pytest.mark.slow
-def test_async_sampled_lanes_identical(model):
+@pytest.mark.parametrize(
+    "K", [0, pytest.param(4, marks=pytest.mark.slow)])
+def test_async_sampled_lanes_identical(model, K):
     """Sampled lanes are where draft identity has teeth: the
     acceptance coin compares against p(draft token), so ANY
     helper-thread draft divergence shows up as a different token
-    stream. Mixed greedy + sampled lanes, serial vs async."""
+    stream. Under the ahead order (K = 0) a draw folds the token's
+    position, a count the host knows without the unread token. Mixed
+    greedy + sampled lanes, serial vs pipelined."""
     rng = np.random.RandomState(7)
     reqs = _trace(rng)
 
     def serve(async_core):
         eng = GenerationEngine(model, num_slots=3, block_size=4,
                                num_blocks=64, prefill_chunk=8,
-                               spec_decode_k=4, sampling=True,
+                               spec_decode_k=K, sampling=True,
                                async_core=async_core)
         ids = []
         for i, (p, n) in enumerate(reqs):
@@ -266,12 +289,35 @@ def test_draft_window_snapshot_equals_live_context():
 # satellite: knob resolution + serial path untouched
 # ---------------------------------------------------------------------------
 
-def test_async_knob_default_off_and_ctor(model):
-    assert GenerationEngine(model, num_slots=2, block_size=4,
-                            num_blocks=32).async_core is False
-    assert GenerationEngine(model, num_slots=2, block_size=4,
-                            num_blocks=32,
-                            async_core=True).async_core is True
+def test_async_knob_default_on_and_ctor(model):
+    """`None` resolves to the pipelined core; which order it takes
+    follows from the step (`spec_decode_k`), not from a knob."""
+    mk = lambda **kw: GenerationEngine(model, num_slots=2,
+                                       block_size=4, num_blocks=32,
+                                       **kw)
+    assert mk().async_core is True and mk()._goes_ahead
+    assert mk(async_core=True).async_core is True
+    assert mk(async_core=False).async_core is False
+    assert not mk(async_core=False)._goes_ahead
+    spec = mk(spec_decode_k=2)
+    assert spec.async_core is True and not spec._goes_ahead
+
+
+def test_async_default_falls_to_serial_where_the_spec_refuses(model,
+                                                              monkeypatch):
+    """A model whose spec refuses the pipelined core is served in the
+    serial order by default (an observable of the spec, not a name),
+    and refused only where the caller asks for the core outright."""
+    spec = model.serving_spec()
+    monkeypatch.setattr(type(spec), "refuses",
+                        {"async_core": "not proven for this model"},
+                        raising=False)
+    mk = lambda **kw: GenerationEngine(model, num_slots=2,
+                                       block_size=4, num_blocks=32,
+                                       **kw)
+    assert mk().async_core is False
+    with pytest.raises(ValueError, match="async_core is not served"):
+        mk(async_core=True)
 
 
 def test_async_env_knob_wins_over_ctor(model, monkeypatch):
@@ -289,20 +335,177 @@ def test_async_env_knob_wins_over_ctor(model, monkeypatch):
         mk()
 
 
-def test_async_off_engages_no_pipeline_state(model):
-    """The serial default never touches the in-flight machinery: no
-    dispatched-ahead slot, no helper thread, no async flight events —
-    the op-for-op guarantee has an observable witness."""
+@pytest.mark.parametrize("K", [0, 4])
+def test_async_off_engages_no_pipeline_state(model, K):
+    """The serial order never touches the in-flight machinery: no step
+    left unread across calls, no helper thread, no async flight
+    events, no step counted as launched ahead — the op-for-op
+    guarantee has an observable witness."""
     rng = np.random.RandomState(3)
     eng = GenerationEngine(model, num_slots=2, block_size=4,
                            num_blocks=32, prefill_chunk=8,
-                           spec_decode_k=4)
+                           spec_decode_k=K, async_core=False)
     eng.add_request(rng.randint(0, VOCAB, 6).astype(np.int32), 5)
-    eng.run()
-    assert eng._inflight is None and eng._ahead is None
+    while eng.num_pending or eng.num_active:
+        eng.step()
+        assert eng._inflight is None and eng._first is None
+        assert all(s is None or s.ahead == 0 for s in eng._slots)
+    assert eng._ahead is None and eng.decode_steps_ahead == 0
+    assert eng._feed_select._cache_size() == 0
     events = {e["event"] for e in eng.flight.dump()}
     assert not (events & {"async_dispatch", "async_complete",
-                          "adapter_prefetch"})
+                          "adapter_prefetch", "overshoot"})
+
+
+# ---------------------------------------------------------------------------
+# the ahead order: what the host knows late, and the rule that keeps it safe
+# ---------------------------------------------------------------------------
+
+def _ahead_engine(model, async_core=None, **kw):
+    return GenerationEngine(model, num_slots=2, block_size=4,
+                            num_blocks=64, prefill_chunk=8,
+                            async_core=async_core, **kw)
+
+
+def test_ahead_one_decode_step_is_unread_when_step_returns(model):
+    """The invariant: between two decode programs the device always
+    has the next one queued — `step()` returns with exactly one decode
+    step launched and unread while any lane decodes, no first token
+    left unread, and every lane in that step is one token ahead of
+    what the host has read."""
+    rng = np.random.RandomState(3)
+    eng = _ahead_engine(model)
+    for n in (6, 11):
+        eng.add_request(rng.randint(0, VOCAB, n).astype(np.int32), 7)
+    seen = 0
+    while eng.num_pending or eng.num_active:
+        eng.step()
+        assert eng._first is None
+        decoding = [s for s in eng._slots if s is not None
+                    and not s.prefilling and s.done is None
+                    and len(s.generated) < s.req.max_new_tokens]
+        if decoding:
+            seen += 1
+            assert eng._inflight is not None
+            assert sorted(map(id, eng._inflight.slots)) == \
+                sorted(map(id, decoding))
+            assert all(s.ahead == 1 for s in decoding)
+    assert seen > 5 and eng._inflight is None
+    assert eng.decode_steps_ahead == eng.decode_steps - 1
+    text = eng.metrics.render_prometheus()
+    assert f"engine_decode_steps_ahead_total {eng.decode_steps_ahead}" \
+        in text
+    assert "engine_overshoot_tokens_total" in text
+    assert eng._m_overshoot.value == 0
+
+
+def test_ahead_finish_by_length_never_overshoots(model):
+    """A finish by length is a count: the scheduler leaves the lane
+    out of the step after its last, nothing is computed and thrown
+    away, and exactly the tokens asked for are generated."""
+    rng = np.random.RandomState(8)
+    eng = _ahead_engine(model)
+    asked = [1, 2, 3, 9, 5, 1]
+    ids = [eng.add_request(rng.randint(0, VOCAB, 5 + n).astype(np.int32),
+                           n) for n in asked]
+    out = eng.drain()
+    assert [len(out[i]) - (5 + n) for i, n in zip(ids, asked)] == asked
+    assert eng.overshoot_tokens == 0
+    assert eng.tokens_generated == sum(asked)
+    # a lane rides exactly as many decode steps as tokens it was owed
+    # past its first: no step was launched over a finished lane
+    assert eng.decode_steps <= sum(n - 1 for n in asked)
+
+
+def test_ahead_eos_overshoot_is_discarded_and_blocks_wait(model):
+    """An EOS is seen one step late: the lane rides the step already
+    launched, that token is discarded and counted, the result is out
+    at once — and the lane's blocks are NOT on the free list until
+    the step that rode it has completed. drain()'s leak audit sees
+    the deferred release done."""
+    rng = np.random.RandomState(5)
+    prompt = rng.randint(0, VOCAB, 7).astype(np.int32)
+
+    def serve(async_core, eos):
+        eng = _ahead_engine(model, async_core=async_core)
+        rid = eng.add_request(prompt, 12, eos_token_id=eos)
+        held = []
+        while eng.num_pending or eng.num_active:
+            eng.step()
+            slot = eng._slots[0]
+            if slot is not None and slot.done is not None:
+                # the result is out, the lane still seated: its
+                # blocks are held while the unread step rides them
+                assert rid in eng._results
+                assert slot.ahead == 1 and eng._inflight is None \
+                    or slot in eng._inflight.slots
+                assert all(eng.cache.refcount(b) == 1
+                           for b in slot.blocks)
+                assert not set(slot.blocks) & set(eng.cache._free)
+                held.append(len(slot.blocks))
+        out = eng.drain()               # audits blocks; raises on leak
+        return list(map(int, out[rid])), eng, held
+
+    base, _, _ = serve(False, None)
+    eos = base[len(prompt) + 4]         # emitted mid-stream
+    serial, eng_s, _ = serve(False, eos)
+    ahead, eng, held = serve(None, eos)
+    assert ahead == serial and len(serial) < len(base)
+    assert eng.overshoot_tokens == 1 and held, \
+        "the EOS never caught a step in flight — weak test"
+    assert eng.tokens_generated == eng_s.tokens_generated
+    assert eng.decode_steps == eng_s.decode_steps + 1
+    assert eng_s.overshoot_tokens == 0
+    assert "engine_overshoot_tokens_total 1" in \
+        eng.metrics.render_prometheus()
+    assert [e["event"] for e in eng.flight.dump()].count("overshoot") == 1
+    assert eng.cache.num_free == eng.cache.num_blocks - 1
+
+
+def test_ahead_release_with_a_step_unread_is_refused(model):
+    """The fence behind the rule: `_release` refuses a lane over which
+    a launched step is still unread (what the static gate cannot see
+    across `step()` calls, the engine checks as it runs)."""
+    rng = np.random.RandomState(2)
+    eng = _ahead_engine(model)
+    eng.add_request(rng.randint(0, VOCAB, 6).astype(np.int32), 8)
+    for _ in range(3):
+        eng.step()
+    slot = eng._slots[0]
+    assert slot.ahead == 1 and eng._inflight is not None
+    with pytest.raises(RuntimeError, match="still unread"):
+        eng._release(slot)
+    eng.drain()
+
+
+@pytest.mark.parametrize("async_core", [None, False])
+def test_last_chunk_syncs_only_in_the_serial_order(model, async_core):
+    """The ahead order's `_prefill_step` launches a prompt's last
+    chunk and returns: no `device_wait` phase inside it, the first
+    token still on the device (fed to this iteration's decode step
+    from there) and read after that launch. The serial order reads it
+    inside `_prefill_step`, as before."""
+    rng = np.random.RandomState(6)
+    eng = _ahead_engine(model, async_core=async_core)
+    eng.add_request(rng.randint(0, VOCAB, 13).astype(np.int32), 4)
+    seen = []
+    inner = eng._prefill_step
+
+    def spy():
+        n = inner()
+        seen.append((eng._first is not None,
+                     "device_wait" in eng._phases.totals()))
+        return n
+
+    eng._prefill_step = spy
+    out = eng.run()
+    assert len(out[0]) == 13 + 4
+    final = seen[1]                     # 13 tokens: two chunks of 8
+    assert seen[0] == (False, False)
+    assert final == ((True, False) if async_core is None
+                     else (False, True))
+    firsts = [e for e in eng.flight.dump() if e["event"] == "first_token"]
+    assert len(firsts) == 1
 
 
 # ---------------------------------------------------------------------------

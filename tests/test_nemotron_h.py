@@ -205,6 +205,86 @@ def test_a_slot_another_request_just_left_starts_from_nought():
     assert "engine_state_slots_used 0" in eng.metrics.render_prometheus()
 
 
+def _serve_with_admissions_midrun(model, cfg, eos=None, **kw):
+    """More requests than slots, half of them added after two
+    iterations, so rows of state are taken, given back and taken again
+    while decode steps are in flight."""
+    asked = prompts(cfg, [5, 19, 33, 12, 16, 7])
+    eng = engine_for(model, **kw)
+    ids = [eng.add_request(p, max_new_tokens=9, eos_token_id=eos)
+           for p in asked[:3]]
+    for _ in range(2):
+        eng.step()
+    ids += [eng.add_request(p, max_new_tokens=9, eos_token_id=eos)
+            for p in asked[3:]]
+    out = eng.drain()                   # audits blocks and state rows
+    return [out[i] for i in ids], eng
+
+
+@pytest.mark.parametrize("pattern", ["ME*E", "M*"])
+def test_ahead_and_serial_orders_serve_the_same_tokens(pattern):
+    """The hybrid decoder rides the default loop: decode step N+1 is
+    launched from step N's tokens on the device, the rows of state are
+    host rows, the program that zeroes a row, the chunk that first reads
+    it and the decode steps follow one another through the state arrays
+    they hand on. Token for token what the serial order serves."""
+    model, cfg = seeded(pattern)
+    serial, eng_s = _serve_with_admissions_midrun(model, cfg,
+                                                  async_core=False)
+    ahead, eng = _serve_with_admissions_midrun(model, cfg)
+    assert eng.async_core and not eng_s.async_core
+    assert ahead == serial
+    assert eng.decode_steps_ahead >= eng.decode_steps - 2 > 0
+    assert eng_s.decode_steps_ahead == 0
+    assert eng.overshoot_tokens == 0
+    assert eng.tokens_generated == 9 * 6 == eng_s.tokens_generated
+    # per-token counters agree; the per-step ones (experts touched,
+    # the largest load) depend on which lanes share a step
+    for name in ("decode_live_lanes", "moe_assignments_held"):
+        assert eng.step_counter_totals.get(name) == \
+            eng_s.step_counter_totals.get(name)
+    assert eng.cache.state_rows_used == 0
+
+
+def test_an_eos_seen_a_step_late_keeps_the_row_until_its_step_ends():
+    """The ahead order reads an EOS one step late: the lane rides one
+    more decode step, which updates its row of state once more. The
+    row goes back only after that step, the next owner starts from
+    nought, and the streams are the serial order's."""
+    model, cfg = seeded("ME*E")
+    base, _ = _serve_with_admissions_midrun(model, cfg, async_core=False)
+    eos = base[1][19 + 3]               # a token the streams do emit
+    serial, _ = _serve_with_admissions_midrun(model, cfg, eos=eos,
+                                              async_core=False)
+    ahead, eng = _serve_with_admissions_midrun(model, cfg, eos=eos)
+    assert ahead == serial
+    assert any(len(a) < len(b) for a, b in zip(serial, base))
+    assert eng.overshoot_tokens > 0
+    assert eng.cache.state_rows_used == 0
+
+
+@pytest.mark.parametrize("async_core", [None, False])
+def test_a_state_row_left_unzeroed_changes_the_tokens(async_core,
+                                                      monkeypatch):
+    """The planted fault of PR 28, under both orders: a row handed out
+    as the request before left it. The next request in that lane reads
+    another request's state, and its tokens change."""
+    from paddle_tpu.inference.engine import PagedKVCache
+
+    model, cfg = seeded("ME*E")
+    sound, _ = _serve_with_admissions_midrun(model, cfg,
+                                             async_core=async_core)
+
+    def unzeroed(self):
+        return self._free_rows.pop() if self._free_rows else None
+
+    monkeypatch.setattr(PagedKVCache, "allocate_state", unzeroed)
+    faulty, _ = _serve_with_admissions_midrun(model, cfg,
+                                              async_core=async_core)
+    assert faulty[:2] == sound[:2]      # first owners of their rows
+    assert faulty != sound
+
+
 def test_engine_counts_the_experts_load_and_the_state_rows():
     model, cfg = seeded("ME*E")
     eng = engine_for(model)
@@ -237,7 +317,6 @@ def test_engine_counts_the_experts_load_and_the_state_rows():
      "bucketed_prefill"),
     (dict(kv_dtype="int8"), "kv_int8"),
     (dict(weight_dtype="int8"), "weight_int8"),
-    (dict(async_core=True), "async_core"),
 ])
 def test_what_needs_a_state_snapshot_is_refused_at_construction(kwargs,
                                                                 feature):

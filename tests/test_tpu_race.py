@@ -34,6 +34,7 @@ def hits(findings, rule):
     ("TPU201", "tpu201_pos.py", "tpu201_neg.py", [11]),
     ("TPU202", "tpu202_pos.py", "tpu202_neg.py", [16, 31]),
     ("TPU203", "tpu203_pos.py", "tpu203_neg.py", [17]),
+    ("TPU203", "tpu203_ahead_pos.py", "tpu203_ahead_neg.py", [20, 23]),
     ("TPU204", "tpu204_pos.py", "tpu204_neg.py", [20, 24, 28]),
     ("TPU205", "tpu205_pos.py", "tpu205_neg.py", [15]),
 ])
@@ -127,6 +128,75 @@ def test_conditional_complete_is_pessimistic():
     findings, _ = R.analyze_file("e.py", src)
     assert [(f.rule, f.line) for f in findings] == [("TPU203", 7)], \
         [f.render() for f in findings]
+
+
+# -- which dispatch a wait completes (the ahead order's invariant) --------
+
+_AHEAD = (
+    "import jax\n"
+    "class E:\n"
+    "    def step(self, work):\n"
+    "        prev = self._inflight\n"
+    "        self._inflight = self._plain_dispatch(work)\n"
+    "        if prev is None:\n"
+    "            return\n"
+    "        {wait}\n"
+    "        for i, slot in zip(prev.runnable, prev.slots):\n"
+    "            self._finish(slot)\n"
+    "{tail}"
+    "    def _finish(self, slot):\n"
+    "        self.cache.free(slot.blocks)\n"
+    "    def _plain_dispatch(self, work):\n"
+    "        return work\n")
+
+
+@pytest.mark.parametrize("wait,tail,lines", [
+    # the ahead order: wait for step N, release ITS lanes with N+1 out
+    ("jax.block_until_ready(prev.out)", "", []),
+    # a wait on something else completes no record held under a name
+    ("jax.block_until_ready(work)", "", [10]),
+    # no wait at all
+    ("pass", "", [10]),
+    # the wait on N does not cover the lanes of N+1, still running
+    ("jax.block_until_ready(prev.out)",
+     "        self.cache.free(self._inflight.slots[0].blocks)\n", [11]),
+    # nor a release that draws its lanes from no record
+    ("jax.block_until_ready(prev.out)",
+     "        self.cache.free(work)\n", [11]),
+    # a wait on the NEWER step completes the older one too (one stream)
+    ("jax.block_until_ready(self._inflight.out)", "", []),
+])
+def test_a_release_needs_the_wait_on_the_step_its_lanes_last_rode(
+        wait, tail, lines):
+    """The invariant TPU203 holds since the ahead order (r21 as
+    restated): release after the LAST step dispatched over the lane has
+    completed. The record a wait completes and the record a release
+    draws its lanes from are followed by name through assignments, loop
+    targets and spliced calls' parameters."""
+    findings, _ = R.analyze_file(
+        "e.py", _AHEAD.format(wait=wait, tail=tail))
+    assert [(f.rule, f.line) for f in findings] == \
+        [("TPU203", ln) for ln in lines], [f.render() for f in findings]
+
+
+def test_engine_ahead_order_is_clean_and_a_planted_early_release_fires():
+    """The engine's own `_step_ahead` passes; the same source with a
+    release planted between the launch of step N+1 and the wait on
+    step N (step N's lanes), or drawn from step N+1's record, fires."""
+    rel = "paddle_tpu/inference/engine.py"
+    src, findings = _analyze_repo_file(rel)
+    assert [f for f in findings if f.rule == "TPU203"] == [], \
+        [f.render() for f in findings]
+    anchor = ("            if prev is not None:\n"
+              "                progressed += self._plain_complete(prev)\n")
+    assert src.count(anchor) == 1
+    for record in ("prev", "self._inflight"):
+        planted = src.replace(
+            anchor,
+            f"            for slot in {record}.slots:\n"
+            "                self._release(slot)\n" + anchor)
+        fired, _ = R.analyze_file(os.path.join(REPO, rel), planted)
+        assert {f.rule for f in fired} == {"TPU203"}, record
 
 
 def test_getattr_default_lock_idiom_is_a_lock():
@@ -271,11 +341,11 @@ def test_cli_stats_reports_counts_and_unparseable():
     res = _run_race([str(FIXTURES), "--baseline", "none", "--stats"])
     assert res.returncode == 1
     out = res.stdout
-    assert "files analyzed: 12" in out
+    assert "files analyzed: 14" in out
     assert "UNPARSEABLE files: 1" in out
     assert "unparseable.py" in out
     for rule, n in [("TPU200", 1), ("TPU201", 1), ("TPU202", 4),
-                    ("TPU203", 1), ("TPU204", 3), ("TPU205", 1)]:
+                    ("TPU203", 3), ("TPU204", 3), ("TPU205", 1)]:
         assert any(line.startswith(rule)
                    and line.rstrip().endswith(str(n))
                    for line in out.splitlines()), (rule, n, out)

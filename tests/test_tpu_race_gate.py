@@ -69,6 +69,21 @@ def test_tpu203_fires_on_broken_depth2_pipe_and_passes_fixed():
     assert fixed == [], [f.render() for f in fixed]
 
 
+def test_tpu203_holds_the_ahead_orders_invariant():
+    """Since PR 29 the engine launches step N+1 before it reads step
+    N. The invariant (DESIGN_DECISIONS r21, restated): a lane is
+    released only after the LAST step dispatched over it has
+    completed. The gate passes the order itself (wait for N, release
+    N's lanes, N+1 outstanding) and still refuses a release between a
+    dispatch and that dispatch's completion: step N's lanes before the
+    wait on N, and step N+1's lanes while it runs."""
+    broken, _ = R.analyze_file(str(FIXTURES / "tpu203_ahead_pos.py"))
+    assert [(f.rule, f.line) for f in broken] == \
+        [("TPU203", 20), ("TPU203", 23)], [f.render() for f in broken]
+    sound, _ = R.analyze_file(str(FIXTURES / "tpu203_ahead_neg.py"))
+    assert sound == [], [f.render() for f in sound]
+
+
 def test_rule_id_namespaces_are_disjoint():
     """One registry test over all four analysis tiers: tpu-lint
     TPU0xx, tpu-verify TPU1xx, tpu-race TPU2xx, tpu-shard TPU3xx — no

@@ -98,7 +98,7 @@ SUITE_ROWS = (
     "embedding_50k", "reduce_sum_64M", "gpt_decode_kv_32tok",
     "gpt_decode_kv_350m", "gpt_engine_offered_load",
     "paged_attention_decode_sweep", "gpt_engine_offered_load_pallas",
-    "gpt_engine_prefix_cache", "gpt_engine_chunked_prefill",
+    "gpt_engine_prefix_cache",
     "gpt_engine_speculative", "gpt_engine_offered_load_mp2",
     "gpt_engine_offered_load_int8", "gpt_fleet_offered_load",
     "gpt_engine_multitenant_lora", "gpt_engine_sampling",
@@ -202,7 +202,6 @@ def suite():
     cases["gpt_engine_offered_load_pallas"] = _engine_offered_load_case(
         attention_backend="pallas")
     cases["gpt_engine_prefix_cache"] = _engine_prefix_cache_case()
-    cases["gpt_engine_chunked_prefill"] = _engine_chunked_prefill_case()
     cases["gpt_engine_speculative"] = _engine_speculative_case()
     cases["gpt_engine_offered_load_mp2"] = _engine_offered_load_case(
         mp_degree=2)
@@ -746,7 +745,7 @@ def _token_match_fraction(ref_outs, got_outs):
 
 
 def _engine_offered_load_case(model_cfg=None, requests=None, num_slots=8,
-                              block_size=16, prefill_buckets=None,
+                              block_size=16,
                               seed=0, attention_backend=None,
                               mp_degree=None, kv_dtype=None):
     """Engine-level offered-load row: the continuous-batching engine
@@ -756,7 +755,7 @@ def _engine_offered_load_case(model_cfg=None, requests=None, num_slots=8,
     tracks from this PR on. Self-timed (the scheduler loop is
     host-driven admission between compiled iterations, so _timeit's
     in-graph fori_loop doesn't apply): compile is excluded by warming
-    every prefill bucket + the decode step on a throwaway trace first.
+    the prefill chunk + the decode step on a throwaway request first.
     The row also carries the engine's metrics snapshot distilled to
     serving-SLO numbers (TTFT/TPOT percentiles, block stalls, pool
     high-water, recompiles) so BENCH rounds record latency health, not
@@ -812,57 +811,22 @@ def _engine_offered_load_case(model_cfg=None, requests=None, num_slots=8,
                    for plen, _ in reqs]
         model = GPTForCausalLM(cfg)
         model.eval()
-        buckets = prefill_buckets or tuple(
-            b for b in (32, 64, 128, 256, cfg.max_seq_len)
-            if b <= cfg.max_seq_len)
 
         def build(mp, quant=False):
             qkw = dict(kv_dtype="int8", weight_dtype="int8") \
                 if quant else {}
-            engine = GenerationEngine(model, num_slots=num_slots,
-                                      block_size=block_size,
-                                      prefill_buckets=buckets,
-                                      attention_backend=attention_backend,
-                                      mp_degree=mp, **qkw)
-            if not quant and (engine.kv_dtype is not None
-                              or engine.weight_dtype is not None):
-                # either env knob would silently quantize the fp
-                # reference too, making the parity numbers a lie
-                raise RuntimeError(
-                    "the fp reference engine resolved kv_dtype="
-                    f"{engine.kv_dtype!r} / weight_dtype="
-                    f"{engine.weight_dtype!r} (is PADDLE_SERVE_KV_DTYPE"
-                    " or PADDLE_SERVE_WEIGHT_DTYPE set?) — unset them "
-                    "to run this row")
-            if mp and engine.mp_degree != mp:
-                # a row NAMED for an mp degree must never record an
-                # env-overridden mesh's numbers under that name
-                raise RuntimeError(
-                    f"bench row requested mp_degree={mp} but the "
-                    f"engine resolved {engine.mp_degree} (is "
-                    "PADDLE_SERVE_MP set?) — unset it to run this row")
-            if attention_backend and \
-                    engine.attention_backend != attention_backend:
-                # the env knob overrides the constructor (deploy
-                # semantics) — but a bench row NAMED for a backend must
-                # never record another backend's numbers under that name
-                raise RuntimeError(
-                    f"bench row requested attention_backend="
-                    f"{attention_backend!r} but the engine resolved "
-                    f"{engine.attention_backend!r} (is "
-                    "PADDLE_PAGED_ATTENTION_BACKEND set?) — unset it "
-                    "to run this row")
-            return engine
+            return GenerationEngine(model, num_slots=num_slots,
+                                    block_size=block_size,
+                                    attention_backend=attention_backend,
+                                    mp_degree=mp, **qkw)
 
         def serve(engine, warm_rng_seed=1):
-            """Warm every compiled program the trace will hit (bucketed
-            prefill per bucket + the one decode step), then measure."""
+            """Warm both compiled programs the trace will hit (the
+            prefill chunk + the one decode step), then measure."""
             wrng = np.random.RandomState(warm_rng_seed)
-            for b in sorted({engine._bucket_for(p) for p, _ in reqs}):
-                warm_len = min(b, engine.max_model_len - 2)
-                engine.add_request(
-                    wrng.randint(0, cfg.vocab_size, warm_len),
-                    max_new_tokens=2)
+            engine.add_request(
+                wrng.randint(0, cfg.vocab_size, reqs[0][0]),
+                max_new_tokens=2)
             engine.run()
             base = engine.tokens_generated
             engine.metrics.reset()         # drop warmup observations
@@ -905,14 +869,6 @@ def _engine_offered_load_case(model_cfg=None, requests=None, num_slots=8,
             # reference serve at mp=1: the parity oracle AND the
             # single-chip tokens/s this row's speedup is judged against
             ref_engine = build(None)
-            if ref_engine.mp_degree != 1:
-                # PADDLE_SERVE_MP would silently shard the "mp=1"
-                # baseline too, making the parity assert vacuous and
-                # tokens_per_s_mp1 a lie
-                raise RuntimeError(
-                    "the mp=1 reference engine resolved mp="
-                    f"{ref_engine.mp_degree} (is PADDLE_SERVE_MP "
-                    "set?) — unset it to run this row")
             dt1, toks1, outs1 = serve(ref_engine)
             engine = build(mp_degree)
             dt, new_toks, outs = serve(engine)
@@ -1060,15 +1016,6 @@ def _fleet_offered_load_case(model_cfg=None, num_tenants=3,
                                  num_slots=num_slots,
                                  block_size=block_size,
                                  prefill_chunk=prefill_chunk)
-            eng0 = fleet._any_engine()
-            if eng0.kv_dtype is not None or eng0.mp_degree != 1:
-                # an env knob would silently change every replica,
-                # making the replica-count comparison a lie
-                raise RuntimeError(
-                    "fleet bench replicas resolved kv_dtype="
-                    f"{eng0.kv_dtype!r}/mp={eng0.mp_degree} (is a "
-                    "PADDLE_SERVE_* env set?) — unset it to run this "
-                    "row")
             # compile warmup per replica, off the record
             for rep in fleet._replicas.values():
                 rep.engine.add_request(
@@ -1197,12 +1144,6 @@ def _engine_multitenant_lora_case(model_cfg=None, num_tenants=4,
                 prefill_chunk=prefill_chunk, adapters=adapters,
                 adapter_pool_pages=adapter_pool_pages
                 if adapters is not None else None)
-            if eng.kv_dtype is not None or eng.mp_degree != 1:
-                raise RuntimeError(
-                    "lora bench engine resolved kv_dtype="
-                    f"{eng.kv_dtype!r}/mp={eng.mp_degree} (is a "
-                    "PADDLE_SERVE_* env set?) — unset it to run this "
-                    "row")
             # compile warmup off the record (chunk + decode programs)
             eng.add_request(
                 rng.randint(0, cfg.vocab_size, prefill_chunk + 1),
@@ -1375,76 +1316,6 @@ def _engine_prefix_cache_case(model_cfg=None, num_tenants=4,
     return run_bench
 
 
-def _engine_chunked_prefill_case(model_cfg=None, long_prompt=384,
-                                 decode_lanes=4, max_new=48,
-                                 num_slots=6, block_size=16,
-                                 prefill_chunk=64, seed=0):
-    """Chunked-prefill tail-latency row: `decode_lanes` short-prompt
-    requests decode steadily while a LONG prompt is admitted mid-
-    stream — once through the chunked scheduler (one chunk per
-    iteration interleaves with decode) and once through the legacy
-    whole-prompt bucketed prefill (the admission monopolizes an
-    iteration). The tracked numbers are the decode lanes' tail TPOT
-    under each mode; on TPU the whole-prompt p99 spikes by the full
-    long-prefill latency while the chunked p99 is bounded by one
-    chunk. (CPU CI only asserts both modes run and report.)"""
-
-    def run_bench():
-        import time
-
-        import numpy as np
-
-        import paddle_tpu  # noqa: F401
-        from paddle_tpu.inference import GenerationEngine
-        from paddle_tpu.models import GPTConfig, GPTForCausalLM
-
-        cfg = model_cfg or GPTConfig(
-            vocab_size=50304, hidden_size=1024, num_layers=24,
-            num_heads=16, max_seq_len=512)
-        rng = np.random.RandomState(seed)
-        model = GPTForCausalLM(cfg)
-        model.eval()
-        short = [rng.randint(0, cfg.vocab_size,
-                             rng.randint(4, 2 * block_size))
-                 for _ in range(decode_lanes)]
-        long_p = rng.randint(0, cfg.vocab_size, long_prompt)
-
-        def serve(**engine_kw):
-            engine = GenerationEngine(model, num_slots=num_slots,
-                                      block_size=block_size,
-                                      **engine_kw)
-            # warm every compiled program off the record (the chunked
-            # engine runs cache-off so this warm-up cannot seed prefix
-            # hits that would skip the prefill being measured)
-            engine.add_request(long_p, 2)
-            engine.add_request(short[0], 2)
-            engine.run()
-            engine.metrics.reset()
-            t0 = time.perf_counter()
-            for p in short:
-                engine.add_request(p, max_new_tokens=max_new)
-            for _ in range(3):
-                engine.step()          # lanes are decoding...
-            engine.add_request(long_p, max_new_tokens=8)  # ...bomb
-            out = engine.run()
-            dt = time.perf_counter() - t0
-            assert len(out) == decode_lanes + 1
-            return dt, _tpot_pct(engine.metrics_snapshot(), 0.99)
-
-        dt_chunked, p99_chunked = serve(prefill_chunk=prefill_chunk,
-                                        enable_prefix_cache=False)
-        buckets = tuple(b for b in (32, 64, 128, 256, cfg.max_seq_len)
-                        if b <= cfg.max_seq_len)
-        _, p99_whole = serve(prefill_buckets=buckets)
-        return {"ms": round(dt_chunked * 1e3, 1),
-                "prefill_chunk": prefill_chunk,
-                "long_prompt": long_prompt,
-                "tpot_ms_p99_chunked": p99_chunked,
-                "tpot_ms_p99_whole": p99_whole}
-
-    return run_bench
-
-
 def _engine_speculative_case(model_cfg=None, num_requests=12,
                              num_slots=4, block_size=16,
                              prefill_chunk=64, spec_k=4, max_new=48,
@@ -1488,14 +1359,6 @@ def _engine_speculative_case(model_cfg=None, num_requests=12,
                                       block_size=block_size,
                                       prefill_chunk=prefill_chunk,
                                       spec_decode_k=k)
-            if engine.spec_decode_k != k:
-                # a row comparing K=spec_k against K=0 must never
-                # record an env-overridden K under either name
-                raise RuntimeError(
-                    f"bench row requested spec_decode_k={k} but the "
-                    f"engine resolved {engine.spec_decode_k} (is "
-                    "PADDLE_SPEC_DECODE_K set?) — unset it to run "
-                    "this row")
             engine.add_request(reqs[0], 2)     # compile warmup
             engine.run()
             engine.metrics.reset()
@@ -1576,19 +1439,9 @@ def _engine_sampling_case(model_cfg=None, num_requests=12,
         model.eval()
 
         def build(on):
-            engine = GenerationEngine(model, num_slots=num_slots,
-                                      block_size=block_size,
-                                      sampling=on)
-            if engine.sampling != on:
-                # a row comparing sampling-on against sampling-off
-                # must never record an env-overridden engine's
-                # numbers under either name
-                raise RuntimeError(
-                    f"bench row requested sampling={on} but the "
-                    f"engine resolved {engine.sampling} (is "
-                    "PADDLE_SERVE_SAMPLING set?) — unset it to run "
-                    "this row")
-            return engine
+            return GenerationEngine(model, num_slots=num_slots,
+                                    block_size=block_size,
+                                    sampling=on)
 
         def serve(engine, params_of):
             engine.add_request(reqs[0], 2)     # compile warmup
@@ -1690,17 +1543,9 @@ def _engine_host_gap_case(model_cfg=None, num_requests=12,
         model.eval()
 
         def build(k):
-            engine = GenerationEngine(model, num_slots=num_slots,
-                                      block_size=block_size,
-                                      spec_decode_k=k, tracing=True)
-            if not engine.tracing:
-                # a host-gap row without its spans/phases is a
-                # different measurement — never record it as this one
-                raise RuntimeError(
-                    "bench row requested tracing=True but the engine "
-                    "resolved tracing off (is PADDLE_SERVE_TRACING "
-                    "set?) — unset it to run this row")
-            return engine
+            return GenerationEngine(model, num_slots=num_slots,
+                                    block_size=block_size,
+                                    spec_decode_k=k, tracing=True)
 
         def serve(engine):
             base = engine.tokens_generated
@@ -1780,7 +1625,6 @@ def _engine_async_overlap_case(model_cfg=None, num_requests=24,
     lower — the ROADMAP item 3 claim."""
 
     def run_bench():
-        import os
         import time
 
         import numpy as np
@@ -1790,12 +1634,6 @@ def _engine_async_overlap_case(model_cfg=None, num_requests=24,
         from paddle_tpu.inference import GenerationEngine
         from paddle_tpu.models import GPTConfig, GPTForCausalLM
 
-        if os.environ.get("PADDLE_SERVE_ASYNC") not in (None, ""):
-            # the row IS the serial-vs-async comparison; a global env
-            # override would silently collapse both arms to one mode
-            raise RuntimeError(
-                "unset PADDLE_SERVE_ASYNC to run the async-overlap "
-                "row (it builds both modes explicitly)")
         cfg = model_cfg or GPTConfig(
             vocab_size=50304, hidden_size=1024, num_layers=24,
             num_heads=16, max_seq_len=512)
@@ -1831,18 +1669,11 @@ def _engine_async_overlap_case(model_cfg=None, num_requests=24,
             return reg
 
         def build(async_core):
-            engine = GenerationEngine(
+            return GenerationEngine(
                 model, num_slots=num_slots, block_size=block_size,
                 spec_decode_k=spec_k, tracing=True,
                 adapters=registry(), adapter_pool_pages=4,
                 async_core=async_core)
-            if not engine.tracing:
-                raise RuntimeError(
-                    "bench row requested tracing=True but the engine "
-                    "resolved tracing off (is PADDLE_SERVE_TRACING "
-                    "set?) — unset it to run this row")
-            assert engine.async_core == async_core
-            return engine
 
         def serve(engine):
             t0 = time.perf_counter()

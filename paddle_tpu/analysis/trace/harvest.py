@@ -206,21 +206,20 @@ def _build_registry(config):
 
 def harvest(matrix=None):
     """-> list[TracedProgram] over the full contract matrix: one
-    chunked engine per (backend, K, mp, kv_dtype) contributes its
+    engine per (backend, K, mp, kv_dtype) contributes its
     decode-or-verify step (16 programs — where the backends/K/kv
-    diverge); the backend/K-invariant programs (chunked prefill,
-    legacy bucketed prefill from a bucketed engine, COW block-copy)
-    harvest once per (mp, kv_dtype) (12 more). The kv="int8" configs
-    serve int8 per-block-scaled KV AND int8 weights — the full
-    quantized serving shape. The LORA_CONFIGS entries add the
-    adapter-threaded programs (4 more: a dense mp=1 decode + both
-    prefills, and the composed pallas/K=4/mp=2/int8 verify); the
+    diverge); the backend/K-invariant programs (the prefill chunk,
+    COW block-copy) harvest once per (mp, kv_dtype) (8 more). The
+    kv="int8" configs serve int8 per-block-scaled KV AND int8 weights
+    — the full quantized serving shape. The LORA_CONFIGS entries add
+    the adapter-threaded programs (3 more: a dense mp=1 decode + its
+    prefill chunk, and the composed pallas/K=4/mp=2/int8 verify); the
     SAMPLING_CONFIGS entries add the sampling-threaded programs
-    (4 more: a dense mp=1 sampled decode + both sampled prefills, and
-    the composed pallas/K=4/mp=2/int8 REJECTION-SAMPLING verify). The
-    default (full) harvest also carries the fused Pallas conv suite's
-    4 programs (`_conv_programs`) so their lowering is drift-gated
-    like every engine step."""
+    (3 more: a dense mp=1 sampled decode + its sampled prefill chunk,
+    and the composed pallas/K=4/mp=2/int8 REJECTION-SAMPLING verify).
+    The default (full) harvest also carries the fused Pallas conv
+    suite's 4 programs (`_conv_programs`) so their lowering is
+    drift-gated like every engine step."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -228,8 +227,7 @@ def harvest(matrix=None):
 
     include_conv = matrix is None
     # pad short (pre-sampling / pre-lora) matrix entries with the
-    # DEFAULTS for the missing trailing fields — positional slicing
-    # would hand a 5-tuple samp=None and trip check_knobs
+    # DEFAULTS for the missing trailing fields
     matrix = default_matrix() if matrix is None else tuple(
         (*m, *(None, False, False)[len(m) - 3:]) if len(m) < 6 else m
         for m in matrix)
@@ -238,27 +236,6 @@ def harvest(matrix=None):
     model = _build_model()
     L = model.config.num_layers
     programs = []
-
-    def check_knobs(engine, kv, samp=False):
-        # serve-time env overrides win over ctor args by design — but
-        # a leaked PADDLE_SERVE_KV_DTYPE/PADDLE_SERVE_WEIGHT_DTYPE
-        # (or PADDLE_SERVE_SAMPLING) would silently harvest (and
-        # baseline) a quantized/sampling program under the wrong
-        # config label, or feed wrong-shaped step args to the
-        # signature. Fail loudly instead.
-        if (engine.kv_dtype, engine.weight_dtype) != (kv, kv):
-            raise RuntimeError(
-                f"harvest config kv={kv!r} resolved kv_dtype="
-                f"{engine.kv_dtype!r}/weight_dtype="
-                f"{engine.weight_dtype!r} (is PADDLE_SERVE_KV_DTYPE "
-                "or PADDLE_SERVE_WEIGHT_DTYPE set?) — unset them to "
-                "harvest")
-        if engine.sampling != samp:
-            raise RuntimeError(
-                f"harvest config sampling={samp!r} resolved "
-                f"{engine.sampling!r} (is PADDLE_SERVE_SAMPLING "
-                "set?) — unset it to harvest")
-        return engine
 
     def samp_rows(n):
         """The four traced sampling rows of an n-slot dispatch —
@@ -278,11 +255,11 @@ def harvest(matrix=None):
             registry = _build_registry(model.config)
         adapt = dict(adapters=registry) if lora else {}
         skw = dict(sampling=True) if samp else {}
-        eng = check_knobs(GenerationEngine(
+        eng = GenerationEngine(
             model, num_slots=TINY["slots"],
             block_size=TINY["block_size"], attention_backend=backend,
             spec_decode_k=K, mp_degree=mp, donate=True, **quant,
-            **adapt, **skw), kv, samp)
+            **adapt, **skw)
         S, MB, C = eng.num_slots, eng.max_blocks, eng.prefill_chunk
         state = eng._state_arrays()
         kp, vp = eng.cache.kpool, eng.cache.vpool
@@ -313,7 +290,7 @@ def harvest(matrix=None):
             declared=_declared_specs(eng, step_args, kv, lora,
                                      eng._decode_n_out),
             geometry=_geometry(eng, L, S * (K + 1))))
-        # the prefill programs and the COW copy are backend- and
+        # the prefill chunk and the COW copy are backend- and
         # K-invariant today (paged_prefill_chunk has no backend seam;
         # the decode/verify steps are where the backends diverge), so
         # they harvest ONCE per (mp, kv_dtype, lora) — if a prefill
@@ -334,29 +311,6 @@ def harvest(matrix=None):
                 eng._prefill_pure, eng._prefill, pc_args, mp, L,
                 declared=_declared_specs(eng, pc_args, kv, lora, 1),
                 geometry=_geometry(eng, L, C)))
-            bucket = TINY["seq"] // 2
-            beng = check_knobs(GenerationEngine(
-                model, num_slots=TINY["slots"],
-                block_size=TINY["block_size"],
-                attention_backend=backend,
-                prefill_buckets=(bucket, TINY["seq"]), mp_degree=mp,
-                donate=True, **quant, **adapt, **skw), kv, samp)
-            btok = jnp.asarray(np.zeros((1, bucket), np.int32))
-            # every arg from the BUCKETED engine itself — if its
-            # geometry/state layout ever diverges from the chunked
-            # engine's, the harvested signature must follow the real
-            # program, not a lookalike
-            bsc = (beng.cache.scales,) if kv else ()
-            blp = (beng.adapter_pool.arrays(),) if lora else ()
-            brow = jnp.asarray(np.zeros(beng.max_blocks, np.int32))
-            bp_args = (beng._state_arrays(), beng.cache.kpool,
-                       beng.cache.vpool, *bsc, *blp, btok,
-                       jnp.int32(bucket - 2), brow, *srows1, *arow1)
-            programs.append(_trace_one(
-                "engine_prefill", f"mp={mp}{tag}", beng._prefill_pure,
-                beng._prefill, bp_args, mp, L,
-                declared=_declared_specs(beng, bp_args, kv, lora, 1),
-                geometry=_geometry(beng, L, bucket)))
             if not lora and not samp:
                 # the COW copy is adapter- AND sampling-oblivious:
                 # both config families skip it (no duplicate entry)

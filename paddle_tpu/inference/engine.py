@@ -19,17 +19,15 @@ recompiles:
 - a slot scheduler: `num_slots` decode lanes. Between decode
   iterations, finished requests vacate their lane and queued requests
   are admitted into free lanes (priority classes first, FIFO within a
-  class). Prefill is CHUNKED by default: each scheduler iteration runs
-  at most ONE fixed-shape compiled prefill chunk, so a long admission
-  interleaves with the in-flight decode batch instead of monopolizing
-  an iteration — and the chunk program compiles ONCE for every prompt
-  length (`start`/`plen` are traced). Passing `prefill_buckets`
-  selects the legacy whole-prompt bucketed prefill, kept as the parity
-  foil CI proves the chunked path token-identical against. A lane that
+  class). Prefill is CHUNKED: each scheduler iteration runs at most
+  ONE fixed-shape compiled prefill chunk (`prefill_chunk` tokens), so
+  a long admission interleaves with the in-flight decode batch instead
+  of monopolizing an iteration — and the chunk program compiles ONCE
+  for every prompt length (`start`/`plen` are traced). A lane that
   cannot get a block this iteration simply skips it (masked to the
   null block) and retries — graceful degradation under pool pressure
   instead of an abort.
-- a prefix cache (chunked mode, on by default): `PagedKVCache` keeps a
+- a prefix cache (on by default): `PagedKVCache` keeps a
   chain-hash → block map over FULL prompt blocks with per-block
   refcounts. Admission seats the longest cached block-aligned prefix
   read-only in the slot's table — hit tokens are never recomputed,
@@ -55,18 +53,17 @@ recompiles:
 
 Greedy decoding matches the model's own `generate(use_cache=True)`
 token-for-token per request (the parity contract CI enforces) — under
-either paged-attention backend: `attention_backend` (or the
-`PADDLE_PAGED_ATTENTION_BACKEND` env override) picks `auto` / `dense` /
-`pallas` per `ops.paged_attention.resolve_backend`, resolved once at
-construction so the compiled decode step is fixed; the selection is
-published as the `engine_attention_backend_info` gauge and every decode
-dispatch lands in the backend-labeled `engine_decode_step_seconds`
-histogram.
+either paged-attention backend: `attention_backend` picks `auto` /
+`dense` / `pallas` per `ops.paged_attention.resolve_backend`, resolved
+once at construction so the compiled decode step is fixed; the
+selection is published as the `engine_attention_backend_info` gauge
+and every decode dispatch lands in the backend-labeled
+`engine_decode_step_seconds` histogram.
 
 Speculative decoding (PR 7): decode is HBM-bandwidth-bound (every
 step re-reads the weights and the live KV), so the engine can amortize
-one target-model pass over several tokens: with `spec_decode_k=K > 0`
-(env override `PADDLE_SPEC_DECODE_K`), a host-side DRAFTER
+one target-model pass over several tokens: with `spec_decode_k=K > 0`,
+a host-side DRAFTER
 (`inference/speculative.NgramDrafter` by default — model-free
 prompt-lookup; any `propose(prompt, generated, k)` object plugs in)
 proposes up to K tokens per lane, and ONE fixed-shape compiled verify
@@ -91,10 +88,10 @@ against its producing step (the step gap amortized per token), and
 how much the drafter is actually buying.
 
 Tensor-parallel sharded serving (PR 8): `GenerationEngine(model,
-mp_degree=N)` (or `mesh=serving_mesh(N)`, env `PADDLE_SERVE_MP`) runs
+mp_degree=N)` (or `mesh=serving_mesh(N)`) runs
 the SAME host-side scheduler — allocator, prefix cache, COW, QoS,
 speculative acceptance all unchanged — while every compiled step
-(prefill, chunked prefill, decode, K-token verify) becomes ONE
+(prefill chunk, decode, K-token verify) becomes ONE
 shard_map program over an `mp`-axis device mesh. Attention is sharded
 by heads: per-shard paged KV pools `[L, blocks, bs, heads/mp, D]`
 with the block tables REPLICATED across shards, so a block id means
@@ -113,16 +110,16 @@ sharded pools stay donated. CPU CI runs the real mp=2/mp=4 program on
 a virtual device mesh (`--xla_force_host_platform_device_count`).
 
 Quantized serving (PR 11): decode's other wall is the BYTES — every
-step re-streams the live KV and the weights. `kv_dtype='int8'` (env
-`PADDLE_SERVE_KV_DTYPE`) stores the paged pools as int8 codes plus a
+step re-streams the live KV and the weights. `kv_dtype='int8'`
+stores the paged pools as int8 codes plus a
 `[layers, blocks, 2]` per-block K/V scale array threaded through
 every compiled step beside the pools: quant-on-write grows and
 requantizes only the written (engine-private) block's grid, dequant
 is fused into both backends' streamed-block matmuls (fp32 online
 softmax unchanged), COW copies scale rows with blocks, and the
 prefix cache shares them by block id — so pool bytes halve vs bf16
-and warm/speculative runs replay exactly. `weight_dtype='int8'` (env
-`PADDLE_SERVE_WEIGHT_DTYPE`, re-snapshot via `quantize_weights()`)
+and warm/speculative runs replay exactly. `weight_dtype='int8'`
+(re-snapshot via `quantize_weights()`)
 serves qkv/out/fc1/fc2 as (int8, per-channel scale) pairs
 dequantized inside the step to the compute dtype — int8 in HBM, fp32
 accumulation (tpu-verify TPU103). Both knobs off is BIT-identical to
@@ -149,8 +146,8 @@ model, mp>1 shards the B pages column-parallel (no new collectives,
 bit-identical across mesh shapes), and int8 KV/weights quantize the
 BASE path while adapters ride fp.
 
-Probabilistic serving (PR 15): `GenerationEngine(sampling=True)` (env
-`PADDLE_SERVE_SAMPLING`) turns on per-request on-device sampling —
+Probabilistic serving (PR 15): `GenerationEngine(sampling=True)`
+turns on per-request on-device sampling —
 `add_request(..., sampling_params=SamplingParams(temperature, top_k,
 top_p, seed))` carries each request's knobs PER SLOT through the
 fixed-shape decode and verify steps as traced per-row arrays (params
@@ -159,7 +156,7 @@ are data, never trace keys: `decode_traces == 1` holds per
 lanes). Each sampled slot owns a `[2]` uint32 base key row derived
 from its seed; every draw folds the slot's absolute position (plus a
 draw-purpose salt) into it on device (`ops/sampling.py`), so same
-(seed, trace, config) means same tokens across prefill modes, cache
+(seed, trace, config) means same tokens across chunk sizes, cache
 states and backends — while greedy lanes (`temperature=0`, and every
 lane of a `sampling=False` engine, whose programs are byte-identical
 to the pre-sampling ones) keep taking the literal argmax. With
@@ -186,7 +183,6 @@ from __future__ import annotations
 
 import hashlib
 import math
-import os
 import threading
 import time
 from collections import OrderedDict, deque
@@ -310,7 +306,7 @@ def _best_of_n_intake(eng, sampling_params, n, counter):
             "candidates")
     if not eng.enable_prefix_cache:
         raise ValueError(
-            "best_of_n needs the prefix cache (chunked prefill) — "
+            "best_of_n needs the prefix cache — "
             "without it every candidate re-prefills the prompt")
     params = eng._check_sampling(
         sampling_params if sampling_params is not None
@@ -843,10 +839,10 @@ class GenerationEngine:
     RACE_COMPLETE_CALLS = introspect.STEP_COMPLETE_CALLS
 
     def __init__(self, model, num_slots=8, block_size=16,
-                 num_blocks=None, prefill_buckets=None,
+                 num_blocks=None,
                  max_model_len=None, eos_token_id=None, donate=None,
                  registry=None, attention_backend=None,
-                 prefill_chunk="auto", enable_prefix_cache=None,
+                 prefill_chunk=128, enable_prefix_cache=None,
                  max_queue=None, spec_decode_k=0, drafter=None,
                  mesh=None, mp_degree=None, kv_dtype=None,
                  weight_dtype=None, adapters=None,
@@ -862,10 +858,8 @@ class GenerationEngine:
         self.model = model
         self.num_slots = int(num_slots)
         self.block_size = int(block_size)
-        # tensor-parallel serving mesh: constructor mesh/mp_degree,
-        # env PADDLE_SERVE_MP override wins (deploy-time knob, like
-        # the attention backend). mp=1 (the default) is exactly the
-        # single-chip engine — no mesh, no shard_map, no resharding.
+        # tensor-parallel serving mesh: mp=1 (the default) is exactly
+        # the single-chip engine — no mesh, no shard_map, no resharding.
         self._resolve_mesh(mesh, mp_degree)
         self.max_model_len = int(max_model_len or spec.max_seq_len)
         if self.max_model_len > spec.max_seq_len:
@@ -875,48 +869,31 @@ class GenerationEngine:
         self.max_blocks = math.ceil(self.max_model_len / self.block_size)
         self.eos_token_id = eos_token_id
         self.max_queue = None if max_queue is None else int(max_queue)
-        # prefill strategy: chunked (default) runs the prompt through a
-        # FIXED-shape compiled chunk step, one chunk per scheduler
-        # iteration — long admissions interleave with decode instead of
-        # monopolizing an iteration, and prefill traces are bounded by
-        # the chunk shape (1), not a bucket ladder. Passing
-        # prefill_buckets (or prefill_chunk=None) selects the legacy
-        # whole-prompt bucketed prefill — kept as the parity foil CI
-        # proves the chunked path token-identical against.
-        if prefill_chunk == "auto":
-            prefill_chunk = None if prefill_buckets is not None \
-                else min(128, self.max_model_len)
-        elif prefill_chunk is not None and prefill_buckets is not None:
-            raise ValueError("prefill_chunk and prefill_buckets are "
-                             "mutually exclusive prefill strategies")
-        self.prefill_chunk = None if prefill_chunk is None \
-            else max(1, min(int(prefill_chunk), self.max_model_len))
-        self.chunked_prefill = self.prefill_chunk is not None
-        if not self.chunked_prefill:
-            self._refuse("bucketed_prefill")
-        # prefix cache: content-hash block reuse needs tail-only
-        # prefill, which only the chunked path can run
+        # prefill runs the prompt through a FIXED-shape compiled chunk
+        # step, one chunk per scheduler iteration — long admissions
+        # interleave with decode instead of monopolizing an iteration,
+        # and the chunk shape is the only prefill program there is
+        self.prefill_chunk = max(
+            1, min(int(prefill_chunk), self.max_model_len))
         if enable_prefix_cache is None:
-            enable_prefix_cache = self.chunked_prefill \
-                and "prefix_cache" not in spec.refuses
+            enable_prefix_cache = "prefix_cache" not in spec.refuses
         if enable_prefix_cache:
             self._refuse("prefix_cache")
-        if enable_prefix_cache and not self.chunked_prefill:
-            raise ValueError("the prefix cache needs chunked prefill "
-                             "(bucketed prefill always recomputes from "
-                             "position 0)")
         self.enable_prefix_cache = bool(enable_prefix_cache)
         # quantized serving (PR 11): kv_dtype='int8' stores the paged
         # pools as int8 codes + per-block scales (halves the HBM bytes
         # every decode step streams and doubles effective prefix-cache
         # capacity); weight_dtype='int8' serves qkv/out/fc1/fc2 as
         # int8 + per-channel scales, dequantized inside the compiled
-        # steps. Env overrides win (deploy-time knobs, like the
-        # backend); None keeps today's fp path BIT-identical.
-        self.kv_dtype = self._resolve_dtype_knob(
-            "PADDLE_SERVE_KV_DTYPE", kv_dtype)
-        self.weight_dtype = self._resolve_dtype_knob(
-            "PADDLE_SERVE_WEIGHT_DTYPE", weight_dtype)
+        # steps. None keeps today's fp path BIT-identical (the fp path
+        # is the absence of the knob, not a named dtype).
+        for name, requested in (("kv_dtype", kv_dtype),
+                                ("weight_dtype", weight_dtype)):
+            if requested not in (None, "int8"):
+                raise ValueError(
+                    f"{name} must be None or 'int8', got {requested!r}")
+        self.kv_dtype = kv_dtype
+        self.weight_dtype = weight_dtype
         if self.kv_dtype:
             self._refuse("kv_int8")
         if self.weight_dtype:
@@ -925,18 +902,15 @@ class GenerationEngine:
         # SamplingParams (temperature/top-k/top-p + a [slots, 2] uint32
         # key row) through every compiled step as traced DATA. Off (the
         # default) threads nothing — the engine's programs stay
-        # byte-identical to the pre-sampling ones. Env override wins
-        # (deploy-time knob, like the backend).
-        self.sampling = self._resolve_bool_knob(
-            "PADDLE_SERVE_SAMPLING", sampling)
+        # byte-identical to the pre-sampling ones.
+        self.sampling = bool(sampling)
         self._seed_counter = 0
         # request-scoped tracing (PR 17): host-side spans ONLY — no
         # tracing state ever becomes a compiled-program argument, so a
         # tracing-enabled engine runs byte-identical programs to a
         # disabled one (the sampling=False precedent, held trivially
-        # by construction). Env override wins (deploy-time knob).
-        self.tracing = self._resolve_bool_knob(
-            "PADDLE_SERVE_TRACING", tracing)
+        # by construction).
+        self.tracing = bool(tracing)
         self.tracer = TraceRecorder(capacity=trace_capacity) \
             if self.tracing else None
         # pipelined engine core: `step()` returns with ONE decode step
@@ -952,12 +926,10 @@ class GenerationEngine:
         # (`_step_async`). Pure host restructuring: the compiled steps
         # are byte-identical and the token streams identical to the
         # serial order (`async_core=False`, the parity tests' foil).
-        # Env override wins (deploy-time knob, like the backend). A
-        # model whose spec refuses the pipelined core is served in the
-        # serial order unless the caller asked for the core outright.
-        self.async_core = self._resolve_bool_knob(
-            "PADDLE_SERVE_ASYNC", async_core,
-            default="async_core" not in spec.refuses)
+        # A model whose spec refuses the pipelined core is served in
+        # the serial order unless the caller asked for the core outright.
+        self.async_core = bool(async_core) if async_core is not None \
+            else "async_core" not in spec.refuses
         if self.async_core:
             self._refuse("async_core")
         self._inflight = None          # the one unread decode step
@@ -989,37 +961,16 @@ class GenerationEngine:
         # default) threads nothing — the engine's programs are
         # BIT-identical to the pre-adapter ones.
         self._resolve_adapters(adapters, adapter_pool_pages, donate)
-        if self.chunked_prefill:
-            self.prefill_buckets = ()
-        else:
-            self.prefill_buckets = tuple(sorted(
-                prefill_buckets or self._default_buckets()))
-            if self.prefill_buckets[-1] < self.max_model_len:
-                raise ValueError("largest prefill bucket "
-                                 f"({self.prefill_buckets[-1]}) must "
-                                 "cover max_model_len="
-                                 f"{self.max_model_len}")
-        # paged-attention kernel backend: constructor arg, overridden by
-        # the env (deploy-time switch without a code change), resolved
-        # ONCE to a concrete backend so the compiled decode step is
-        # fixed — `auto` never changes mid-engine (decode traces == 1)
-        requested = os.environ.get("PADDLE_PAGED_ATTENTION_BACKEND") \
-            or attention_backend or "auto"
+        # paged-attention kernel backend: resolved ONCE to a concrete
+        # backend so the compiled decode step is fixed — `auto` never
+        # changes mid-engine (decode traces == 1)
+        requested = attention_backend or "auto"
         self.attention_backend_requested = requested
         self.attention_backend = spec.attention_backend(
             requested, self.block_size, self.mp_degree)
         # speculative decoding: K drafted tokens verified per compiled
-        # step. Env override wins (deploy-time knob, like the backend);
-        # K=0 builds today's one-token decode step unchanged.
-        env_k = os.environ.get("PADDLE_SPEC_DECODE_K")
-        if env_k not in (None, ""):
-            try:
-                k = int(env_k)
-            except ValueError:
-                raise ValueError(
-                    f"PADDLE_SPEC_DECODE_K={env_k!r} is not an integer")
-        else:
-            k = int(spec_decode_k)
+        # step. K=0 builds today's one-token decode step unchanged.
+        k = int(spec_decode_k)
         if k < 0:
             raise ValueError(f"spec_decode_k must be >= 0, got {k}")
         self.spec_decode_k = k
@@ -1074,9 +1025,7 @@ class GenerationEngine:
         self._decode = jax.jit(
             self._decode_pure, donate_argnums=self._donate_argnums,
             out_shardings=self._step_out_shardings(self._decode_n_out))
-        self._prefill_pure = count_traces(
-            self._build_prefill_chunk() if self.chunked_prefill
-            else self._build_prefill())
+        self._prefill_pure = count_traces(self._build_prefill_chunk())
         self._prefill = jax.jit(self._prefill_pure,
                                 donate_argnums=self._donate_argnums,
                                 out_shardings=self._step_out_shardings(1))
@@ -1140,23 +1089,13 @@ class GenerationEngine:
                 f"{feature} is not served for this model: {reason}")
 
     def _resolve_mesh(self, mesh, mp_degree):
-        """Resolve (mesh, mp_degree, env) to the serving mesh. Env
-        PADDLE_SERVE_MP wins; an explicit mesh must agree with it and
-        must carry an 'mp' axis. Degree 1 means single-chip (no mesh).
-        The model's spec validates its divisibility constraints up
-        front."""
+        """Resolve (mesh, mp_degree) to the serving mesh. An explicit
+        mesh must agree with `mp_degree` and must carry an 'mp' axis.
+        Degree 1 means single-chip (no mesh). The model's spec
+        validates its divisibility constraints up front."""
         from paddle_tpu.distributed.topology import serving_mesh
 
-        env = os.environ.get("PADDLE_SERVE_MP")
-        env_mp = None
-        if env not in (None, ""):
-            try:
-                env_mp = int(env)
-            except ValueError:
-                raise ValueError(
-                    f"PADDLE_SERVE_MP={env!r} is not an integer")
-        requested = env_mp if env_mp is not None else \
-            (int(mp_degree) if mp_degree is not None else None)
+        requested = int(mp_degree) if mp_degree is not None else None
         if mesh is not None:
             if "mp" not in mesh.axis_names:
                 raise ValueError(
@@ -1167,9 +1106,7 @@ class GenerationEngine:
             if requested is not None and requested != mesh_mp:
                 raise ValueError(
                     f"mesh mp axis has {mesh_mp} devices but "
-                    + ("PADDLE_SERVE_MP" if env_mp is not None
-                       else "mp_degree")
-                    + f"={requested} — drop one of the two")
+                    f"mp_degree={requested} — drop one of the two")
             self.mp_degree = int(mesh_mp)
             self.mesh = mesh if self.mp_degree > 1 else None
         else:
@@ -1185,37 +1122,6 @@ class GenerationEngine:
             self.spec.check_mesh(self.mp_degree,
                                  list(self.mesh.devices.reshape(-1)))
         self._mp_axis = "mp" if self.mp_degree > 1 else None
-
-    @staticmethod
-    def _resolve_dtype_knob(env_name, requested):
-        """Resolve a quantization knob: env override wins, '' means
-        unset, only None/'int8' are valid (the fp path is the absence
-        of the knob, not a named dtype)."""
-        env = os.environ.get(env_name)
-        if env not in (None, ""):
-            requested = env
-        if requested in (None, ""):
-            return None
-        if requested != "int8":
-            raise ValueError(
-                f"{env_name}/ctor value must be unset or 'int8', got "
-                f"{requested!r}")
-        return "int8"
-
-    @staticmethod
-    def _resolve_bool_knob(env_name, requested, default=False):
-        """Resolve a boolean serving knob: env override wins, ''
-        means unset, None takes `default`."""
-        env = os.environ.get(env_name)
-        if env not in (None, ""):
-            low = env.lower()
-            if low in ("1", "true", "on", "yes"):
-                return True
-            if low in ("0", "false", "off", "no"):
-                return False
-            raise ValueError(
-                f"{env_name}={env!r} is not a boolean (use 0/1)")
-        return bool(requested) if requested is not None else default
 
     # -- probabilistic serving (per-slot sampling) -------------------------
     def _check_sampling(self, params):
@@ -1623,8 +1529,8 @@ class GenerationEngine:
             "Times the decode step traced (steady-state contract: 1).")
         self._m_prefill_traces = m.gauge(
             "engine_prefill_traces",
-            "Times prefill traced (chunked: bounded by the one chunk "
-            "shape; bucketed: by len(prefill_buckets)).")
+            "Times the prefill chunk traced (contract: 1, whatever the "
+            "prompt lengths).")
         self._m_prefill_chunks = m.counter(
             "engine_prefill_chunks_total",
             "Compiled prefill-chunk dispatches (prefix-cache hits "
@@ -1947,7 +1853,7 @@ class GenerationEngine:
         if self.tracer is None:
             raise RuntimeError(
                 "tracing is off — build the engine with tracing=True "
-                "(or PADDLE_SERVE_TRACING=1) to record spans")
+                "to record spans")
         groups = [("engine", self.tracer.snapshot())]
         if include_profiler:
             ev = profiler_host_events()
@@ -1956,14 +1862,6 @@ class GenerationEngine:
         return export_timeline(path, groups)
 
     # -- compiled steps ----------------------------------------------------
-    def _default_buckets(self):
-        b, out = 16, []
-        while b < self.max_model_len:
-            out.append(b)
-            b *= 2
-        out.append(self.max_model_len)
-        return out
-
     def _lora_args(self, rest):
         """Unpack a compiled step's OPTIONAL adapter tail: with the
         adapter subsystem on, the pool arrays ride as one tuple arg
@@ -2098,58 +1996,6 @@ class GenerationEngine:
         return self._shard_steps(verify_fn, n_repl=8 if use_s else 4,
                                  n_out=2 if use_s else 1)
 
-    def _build_prefill(self):
-        from paddle_tpu.ops.paged_attention import paged_prefill_write
-
-        model, state = self.model, self._state
-        spec = self.spec
-        mp_axis = self._mp_axis
-        use_q = self.kv_dtype == "int8"
-        use_s = self.sampling
-
-        def prefill_fn(state_arrays, kpool, vpool, *rest):
-            # tokens [1, bucket]; plen traced -> one program per bucket
-            scales = rest[0] if use_q else None
-            lora, rest = self._lora_args(rest[1:] if use_q else rest)
-            if use_s:
-                tokens, plen, table_row, temps, tks, tps, krows = rest
-            else:
-                tokens, plen, table_row = rest
-            arrays = self._materialize_state(state_arrays)
-            with bound_state(zip(state, arrays), state):
-                hidden, ks, vs = spec.prefill(
-                    Tensor._wrap(tokens), mp_axis=mp_axis, lora=lora)
-                w = paged_prefill_write(
-                    Tensor._wrap(kpool), Tensor._wrap(vpool), ks, vs,
-                    Tensor._wrap(table_row), Tensor._wrap(plen),
-                    scales=None if scales is None
-                    else Tensor._wrap(scales), mp_axis=mp_axis)
-                # only the last REAL position's logits matter: one-hot
-                # reduce to [1,1,H] before the vocab matmul
-                sel = (jnp.arange(tokens.shape[1]) == plen - 1) \
-                    .astype(hidden._array.dtype)
-                h_last = (hidden._array * sel[None, :, None]) \
-                    .sum(axis=1, keepdims=True)
-                logits = spec.logits(Tensor._wrap(h_last),
-                                     mp_axis=mp_axis)
-                if use_s:
-                    # the FIRST generated token samples too: it lands
-                    # at position plen, so its draw folds plen-1 —
-                    # exactly the key a full-prefix-hit decode (or the
-                    # final prefill chunk) would fold for it
-                    from paddle_tpu.ops.sampling import sample_token
-
-                    nxt = sample_token(
-                        logits._array[:, 0], temps, tks, tps, krows,
-                        jnp.maximum(plen - 1, 0).reshape(1))[0]
-                else:
-                    nxt = jnp.argmax(logits._array[0, 0]) \
-                        .astype(jnp.int32)
-                return (nxt,) + tuple(t._array for t in w)
-
-        prefill_fn.__name__ = "engine_prefill"
-        return self._shard_steps(prefill_fn, n_repl=7 if use_s else 3)
-
     def _build_prefill_chunk(self):
         model, state = self.model, self._state
         spec = self.spec
@@ -2193,8 +2039,7 @@ class GenerationEngine:
                 if use_s:
                     # the first generated token's draw folds plen-1
                     # (it lands at position plen) — identical to the
-                    # bucketed prefill's and the full-prefix-hit
-                    # decode's key for that token
+                    # full-prefix-hit decode's key for that token
                     from paddle_tpu.ops.sampling import sample_token
 
                     nxt = sample_token(
@@ -2218,7 +2063,9 @@ class GenerationEngine:
 
     @property
     def prefill_traces(self):
-        """Times prefill traced — bounded by len(prefill_buckets)."""
+        """Times the prefill chunk traced. Contract: 1 — `start` and
+        `plen` are traced, so one program serves every chunk of every
+        prompt length."""
         return self._prefill_pure.traces
 
     # -- request intake ----------------------------------------------------
@@ -2340,13 +2187,6 @@ class GenerationEngine:
         self._trace_instant("request.shed", req, priority=req.priority)
 
     # -- scheduler ---------------------------------------------------------
-    def _bucket_for(self, plen):
-        for b in self.prefill_buckets:
-            if b >= plen:
-                return b
-        raise AssertionError("unreachable: last bucket covers "
-                             "max_model_len")
-
     def _state_arrays(self):
         if self._tp_arrays is not None:
             # tensor parallel: the mesh-placed (weight-stationary)
@@ -2495,9 +2335,8 @@ class GenerationEngine:
 
     def _first_token(self, slot, first, t_step):
         """Seat a request's FIRST generated token (from the final
-        prefill chunk or the whole-prompt bucketed prefill): TTFT,
-        token accounting, prefix-cache publication, and instant-finish
-        retirement. Returns False when the slot finished on the spot
+        prefill chunk): TTFT, token accounting, prefix-cache
+        publication, and instant-finish retirement. Returns False when the slot finished on the spot
         (its lane has been vacated)."""
         req = slot.req
         now = time.perf_counter()
@@ -2551,8 +2390,8 @@ class GenerationEngine:
         self._trace_instant("request.handoff", req,
                             blocks=len(slot.blocks))
 
-    # -- admission: chunked (default) --------------------------------------
-    def _admit_chunked(self):
+    # -- admission ---------------------------------------------------------
+    def _admit(self):
         """Seat queued requests (priority order, FIFO within a class)
         into free lanes: match the longest cached block-aligned prefix,
         take read-only references on those blocks, and leave the tail
@@ -2711,70 +2550,6 @@ class GenerationEngine:
         slot.ahead -= 1
         with self._phase("finish"):
             self._first_token(slot, tok, first.t_dec)
-
-    # -- admission: legacy whole-prompt bucketed prefill -------------------
-    def _admit(self):
-        """Fill free lanes from the queue (priority order): allocate
-        the prompt's blocks, run the bucketed prefill (writes KV into
-        the blocks, yields the first generated token), seat the slot."""
-        admitted = 0
-        while None in self._slots:
-            req = self._peek_request()
-            if req is None:
-                break
-            plen = int(req.prompt.size)
-            with self._phase("schedule"):
-                need = math.ceil(plen / self.block_size)
-                blocks = self.cache.allocate(need)
-            if blocks is None:
-                self._m_stalls.labels(path="admit", shard=self._shard).inc()
-                self.flight.record("stall", req.req_id, path="admit")
-                break                      # pool pressure: retry later
-            self._update_pool_gauges()     # high-water sees the peak
-            # adapter page AFTER the blocks: a block stall must not
-            # have burned a swap-in (or evicted another tenant's warm
-            # page) for an admission that cannot seat anyway
-            page = self._acquire_adapter(req)
-            if page is None:
-                self.cache.free(blocks)    # fresh, unhashed -> free list
-                self._update_pool_gauges()
-                break                  # adapter pressure: retry later
-            self._pop_request()
-            bucket = self._bucket_for(plen)
-            slot = _Slot(req=req, blocks=blocks, prefill_pos=plen,
-                         admit_seq=self._admit_counter,
-                         adapter_page=page,
-                         **self._slot_sampling_fields(req))
-            self._admit_counter += 1
-            self._slots[self._slots.index(None)] = slot
-            self._m_admissions.inc()
-            self.flight.record("admitted", req.req_id, bucket=bucket)
-            self._trace_instant("request.admitted", req, bucket=bucket)
-            admitted += 1
-            t_span = now_us()
-            with self._phase("dispatch"):
-                tokens = np.zeros((1, bucket), np.int32)
-                tokens[0, :plen] = req.prompt
-                row = np.zeros(self.max_blocks, np.int32)
-                row[:need] = blocks
-                args = [jnp.asarray(tokens), jnp.int32(plen),
-                        jnp.asarray(row)]
-                if self.sampling:
-                    args.extend(self._sampling_host_args_one(slot))
-                if self.adapter_pool is not None:
-                    args.append(jnp.asarray(
-                        np.asarray([slot.adapter_page], np.int32)))
-                with RecordEvent("engine.prefill"):
-                    t0 = time.perf_counter()
-                    first = self._dispatch_step(self._prefill, *args)
-                    with self._phase("device_wait"):
-                        first = int(first)   # sync: first token is out
-            self._trace_span("prefill.bucketed", t_span, req=req,
-                             bucket=bucket)
-            with self._phase("finish"):
-                self._first_token(slot, first, t0)
-        self._m_queue.set(self.num_pending)
-        return admitted
 
     # -- decode ------------------------------------------------------------
     def _cow_promote(self, slot, bi, count_stall=True):
@@ -3304,8 +3079,8 @@ class GenerationEngine:
 
     def step(self):
         """One scheduler iteration: admit queued requests into free
-        lanes, run AT MOST one prefill chunk (chunked mode — long
-        prompts never monopolize an iteration), and one batched decode
+        lanes, run AT MOST one prefill chunk (long prompts never
+        monopolize an iteration), and one batched decode
         step over every decode-phase lane. Returns the number of
         admissions/chunks/lanes that made progress.
 
@@ -3317,7 +3092,7 @@ class GenerationEngine:
         the host reads them); the speculative verify step completes
         step N before it launches N+1 (`_step_async`), because the
         next window's content is the accepted prefix, which only the
-        host's walk knows. `async_core=False` / PADDLE_SERVE_ASYNC=0
+        host's walk knows. `async_core=False`
         is the serial order below: launch, read, walk, one after the
         other — what the parity tests compare against."""
         if self.async_core:
@@ -3326,11 +3101,8 @@ class GenerationEngine:
             return self._step_ahead()
         with RecordEvent("engine.step"):
             t_wall = time.perf_counter()
-            if self.chunked_prefill:
-                progressed = self._admit_chunked()
-                progressed += self._prefill_step()
-            else:
-                progressed = self._admit()
+            progressed = self._admit()
+            progressed += self._prefill_step()
             progressed += self._decode_step()
             self._flush_step_phases(time.perf_counter() - t_wall)
             self._end_of_step_gauges()
@@ -3380,11 +3152,8 @@ class GenerationEngine:
         stays sound."""
         with RecordEvent("engine.step"):
             t_wall = time.perf_counter()
-            if self.chunked_prefill:
-                progressed = self._admit_chunked()
-                progressed += self._prefill_step()
-            else:
-                progressed = self._admit()
+            progressed = self._admit()
+            progressed += self._prefill_step()
             prev = self._inflight
             runnable = self._plain_schedule()
             self._inflight = self._plain_dispatch(runnable, prev) \
@@ -3443,11 +3212,8 @@ class GenerationEngine:
             t_wall = time.perf_counter()
             progressed = self._complete_inflight()
             self._spawn_ahead()
-            if self.chunked_prefill:
-                progressed += self._admit_chunked()
-                progressed += self._prefill_step()
-            else:
-                progressed += self._admit()
+            progressed += self._admit()
+            progressed += self._prefill_step()
             self._next_drafts = self._collect_ahead()
             self._dispatch_ahead()
             self._next_drafts = {}
@@ -3811,8 +3577,8 @@ class GenerationEngine:
 # canonical alias).
 _SERVING_BUDGET = "paddle_tpu.jit.introspect:SERVING_STEP_AXIS_BUDGET"
 
-for _step in ("engine_prefill", "engine_prefill_chunk",
-              "engine_decode_step", "engine_verify_step"):
+for _step in ("engine_prefill_chunk", "engine_decode_step",
+              "engine_verify_step"):
     register_contract(TraceContract(
         name=_step,
         declared_at="paddle_tpu/inference/engine.py",
@@ -3821,7 +3587,8 @@ for _step in ("engine_prefill", "engine_prefill_chunk",
         # decode/verify are the host loop body — one dispatch per
         # generated token, so their collectives sit on the per-token
         # latency path (tpu-shard TPU305 gates these against any
-        # future slow/DCN mesh axis); prefills run per admission
+        # future slow/DCN mesh axis); the prefill chunk runs per
+        # admission
         per_token=_step in ("engine_decode_step",
                             "engine_verify_step")))
 del _step

@@ -390,7 +390,7 @@ class ServingFleet:
         if self.tracer is None:
             raise RuntimeError(
                 "tracing is off — build the fleet with tracing=True "
-                "(or PADDLE_SERVE_TRACING=1) to record spans")
+                "to record spans")
         groups = [("fleet.router", self.tracer.snapshot())]
         for rid in sorted(self._replicas):
             rep = self._replicas[rid]

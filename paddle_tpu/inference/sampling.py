@@ -15,8 +15,8 @@ Seeding contract: every sampled request owns one integer seed
 The seed becomes a `[2]` uint32 base key row (`key_row`) the slot
 carries on device; each draw folds the slot's ABSOLUTE position (and a
 draw-purpose salt) into it, so the token at position P+1 is drawn with
-the key folded from P whatever path produced it — chunked or bucketed
-prefill, cold or warm cache, plain decode or a speculative window.
+the key folded from P whatever path produced it — any prefill chunk
+size, cold or warm cache, plain decode or a speculative window.
 Same (seed, trace, config) => same tokens; `temperature=0` (the
 default-off state) is bit-identical to the greedy engine.
 

@@ -115,10 +115,6 @@ class ServingSpec:
     def logits(self, hidden, mp_axis=None):
         raise NotImplementedError
 
-    def prefill(self, tokens, mp_axis=None, lora=None):
-        """Whole-prompt forward -> (hidden, k stack, v stack)."""
-        raise NotImplementedError
-
     def prefill_chunk(self, tokens, start, kpool, vpool, block_row, plen,
                       **kw) -> StepOut:
         raise NotImplementedError

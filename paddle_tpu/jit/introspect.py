@@ -122,7 +122,6 @@ ENGINE_COW_DONATE_ARGNUMS = (0, 1)
 #: argnums declared HERE produce real input/output aliases in each
 #: program's lowered module — no magic `(1, 2)` literals anywhere.
 ENGINE_STEP_DONATION = {
-    "engine_prefill": ENGINE_STEP_DONATE_ARGNUMS,
     "engine_prefill_chunk": ENGINE_STEP_DONATE_ARGNUMS,
     "engine_decode_step": ENGINE_STEP_DONATE_ARGNUMS,
     "engine_verify_step": ENGINE_STEP_DONATE_ARGNUMS,
@@ -466,18 +465,16 @@ class AxisCollectiveBudget:
 #: zero pmax and TPU100's exact op snapshot pins that), bounded by the
 #: full fp32 scale grid. Fixed: one lm-head logits all-gather
 #: (tokens x vocab), one vocab-parallel-embedding psum
-#: (tokens x hidden), and one pmax for the bucketed prefill's
-#: whole-prompt quantized write (all layers folded in a single
-#: scatter). An accidental fifth per-layer gather (or a brand-new
-#: collective kind, or an axis-size-scaling payload) fails the trace
-#: gates instead of stretching every decode step.
+#: (tokens x hidden). An accidental fifth per-layer gather (or a
+#: brand-new collective kind, or an axis-size-scaling payload) fails
+#: the trace gates instead of stretching every decode step.
 GPT_SERVING_AXIS_BUDGET = AxisCollectiveBudget(
     axes=(("mp", "ici"),),
     entries=(
         ("mp", "all_gather", 4, 0, "tokens * intermediate * 4"),
         ("mp", "all_gather", 0, 1, "tokens * vocab * 4"),
         ("mp", "psum", 0, 1, "tokens * hidden * 4"),
-        ("mp", "pmax", 1, 1, "layers * blocks * 2 * 4"),
+        ("mp", "pmax", 1, 0, "layers * blocks * 2 * 4"),
     ),
 )
 

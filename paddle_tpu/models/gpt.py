@@ -225,17 +225,14 @@ class GPTAttention(nn.Layer):
             proj = _mp_all_gather(proj, mp_axis)
         return proj
 
-    def forward_prefill(self, x, mp_axis=None, lora=None, layer=None):
+    def forward_prefill(self, x):
         """Causal forward that ALSO returns this layer's k/v for the
-        whole (padded) buffer — fills the fixed-size decode cache.
-        Under tensor parallel the returned k/v carry only this shard's
-        heads (they feed the shard's pool plane)."""
+        whole (padded) buffer — fills the fixed-size decode cache."""
         B, S, H = x.shape
-        q, k, v = self._qkv_heads(x, mp_axis, lora=lora, layer=layer)
+        q, k, v = self._qkv_heads(x, None)
         out = F.scaled_dot_product_attention(
             q, k, v, is_causal=True, dropout_p=0.0, training=False)
-        return self._attn_out(out, B, S, mp_axis, lora=lora,
-                              layer=layer), k, v
+        return self._attn_out(out, B, S, None), k, v
 
     def forward_prefill_chunk(self, x, kpool, vpool, layer_idx,
                               block_row, start, plen, mp_axis=None,
@@ -437,13 +434,10 @@ class GPTBlock(nn.Layer):
         x = x + self.mlp(self.ln2(x))
         return x
 
-    def forward_prefill(self, x, mp_axis=None, lora=None, layer=None):
-        a, k, v = self.attn.forward_prefill(self.ln1(x),
-                                            mp_axis=mp_axis,
-                                            lora=lora, layer=layer)
+    def forward_prefill(self, x):
+        a, k, v = self.attn.forward_prefill(self.ln1(x))
         x = x + a
-        return x + self.mlp(self.ln2(x), mp_axis=mp_axis, lora=lora,
-                            layer=layer), k, v
+        return x + self.mlp(self.ln2(x)), k, v
 
     def forward_prefill_chunk(self, x, kpool, vpool, layer_idx,
                               block_row, start, plen, mp_axis=None,
@@ -549,18 +543,16 @@ class GPTModel(nn.Layer):
         return _vocab_parallel_embed(self.wte.weight, token_ids,
                                      mp_axis)
 
-    def forward_prefill(self, input_ids, mp_axis=None, lora=None):
+    def forward_prefill(self, input_ids):
         """Fill the decode caches: causal forward over the (padded)
         buffer, collecting per-layer k/v stacked on a leading layer
-        axis (single Tensors, so a compiled decode loop carries them).
-        Under tensor parallel the stacks carry this shard's heads."""
+        axis (single Tensors, so a compiled decode loop carries them)."""
         B, S = input_ids.shape
-        h = self._embed(input_ids, mp_axis) + self.wpe(
+        h = self._embed(input_ids, None) + self.wpe(
             paddle.arange(S, dtype="int32"))
         ks, vs = [], []
-        for i, blk in enumerate(self.blocks):
-            h, k, v = blk.forward_prefill(h, mp_axis=mp_axis,
-                                          lora=lora, layer=i)
+        for blk in self.blocks:
+            h, k, v = blk.forward_prefill(h)
             ks.append(k)
             vs.append(v)
         return self.ln_f(h), mp.stack(ks, axis=0), mp.stack(vs, axis=0)
@@ -979,10 +971,6 @@ class GPTServing(ServingSpec):
     # -- the step functions: GPTModel's, by name -------------------------
     def logits(self, hidden, mp_axis=None):
         return self.model._logits_of(hidden, mp_axis=mp_axis)
-
-    def prefill(self, tokens, mp_axis=None, lora=None):
-        return self.model.gpt.forward_prefill(tokens, mp_axis=mp_axis,
-                                              lora=lora)
 
     @staticmethod
     def _out(r, kv_scales):

@@ -392,9 +392,6 @@ class NemotronHServing(ServingSpec):
             "rolling back a rejected speculative window"),
         "handoff": "the slot's recurrent state would have to travel with "
                    "its blocks, and no export of it is built yet",
-        "bucketed_prefill": "the whole-prompt prefill writes K and V "
-                            "only; the recurrent state is carried by the "
-                            "chunked prefill alone",
         "kv_int8": "the grouped-KV paged path is not quantized",
         "weight_int8": "no int8 plan for the experts and the scan",
     }

@@ -94,7 +94,7 @@ from paddle_tpu.jit import introspect
 from .dispatch import apply, as_tensor
 
 __all__ = ["paged_attention_step", "paged_verify_window",
-           "paged_prefill_write", "paged_prefill_chunk",
+           "paged_prefill_chunk",
            "copy_pool_block", "export_pool_block", "ingest_pool_block",
            "dense_gather_reference",
            "resolve_backend", "PAGED_BACKENDS", "PAGED_PATH_STATS",
@@ -293,7 +293,7 @@ def _dense_step(qa, ka, va, kp, vp, layer, bt, pos, scale):
 # Layout: int8 pools + ONE f32 scale array `[layers, num_blocks, 2]`
 # (column 0 = K scale, column 1 = V scale) riding the compiled steps
 # alongside the pools. Policy, shared verbatim by every write path so
-# cold/warm/chunked/bucketed runs quantize byte-identically:
+# cold/warm/chunked runs quantize byte-identically:
 #
 # - symmetric absmax, clip to +/-127 (-128 unused);
 # - per-block scales are MONOTONE: a write whose row absmax exceeds
@@ -638,84 +638,6 @@ def _dense_verify(qa, ka, va, kp, vp, layer, bt, pos, dlen, scale):
     return out.transpose(0, 2, 1, 3), kp, vp       # [B, W, heads, d]
 
 
-def paged_prefill_write(kpool, vpool, kstack, vstack, block_row, plen,
-                        scales=None, mp_axis=None):
-    """Scatter a prefilled prompt's per-layer k/v into the pools.
-
-    With `scales` (int8 pools) each written block's grid is computed
-    from the rows landing in it this call. The bucketed path always
-    writes into FRESHLY allocated blocks (scale rows reset to
-    KV_QUANT_EPS by the allocator), so the grid only ever grows from
-    the floor via an order-independent scatter-max and the stale int8
-    bytes beyond `plen` — unreachable through position-bounded
-    attention — need no requantization. Returns
-    `(kpool, vpool, scales)`.
-
-    kstack/vstack: `[layers, 1, S, heads, head_dim]` from
-    `GPTModel.forward_prefill` over the (bucket-padded) prompt.
-    block_row: `[max_blocks]` int32 — the slot's block table.
-    plen: true prompt length (may be traced — one compiled program per
-    bucket size S, shared across every prompt length in the bucket).
-
-    Positions >= plen (bucket padding) are routed to the null block 0,
-    so padding never lands in allocated blocks. Returns the updated
-    `(kpool, vpool)`.
-    """
-    kpool, vpool = as_tensor(kpool), as_tensor(vpool)
-    kstack, vstack = as_tensor(kstack), as_tensor(vstack)
-    block_row, plen = as_tensor(block_row), as_tensor(plen)
-
-    if scales is not None:
-        scales = as_tensor(scales)
-
-        def fnq(kp, vp, sc, ks, vs, row, n):
-            L, S = ks.shape[0], ks.shape[2]
-            bs = kp.shape[2]
-            nb = (S - 1) // bs + 1             # static: bucket blocks
-            pos = jnp.arange(S)
-            valid = pos < n
-            bid = jnp.where(valid, row[pos // bs], 0)
-            off = pos % bs
-            seg = pos // bs                    # [S] in [0, nb)
-            rk = jnp.max(jnp.abs(ks[:, 0].astype(jnp.float32)),
-                         axis=(2, 3))          # [L, S]
-            rv = jnp.max(jnp.abs(vs[:, 0].astype(jnp.float32)),
-                         axis=(2, 3))
-            zero = jnp.zeros((L, nb), jnp.float32)
-            need_k = zero.at[:, seg].max(jnp.where(valid, rk, 0.0))
-            need_v = zero.at[:, seg].max(jnp.where(valid, rv, 0.0))
-            need = _fold_amax(
-                jnp.stack([need_k, need_v], axis=-1) / 127.0, mp_axis)
-            # candidate block per segment: null 0 when the segment has
-            # no valid rows (its `need` is 0 there — a no-op max)
-            bids = jnp.where((jnp.arange(nb) * bs) < n, row[:nb], 0)
-            s_fin = jnp.maximum(
-                jnp.maximum(sc[:, bids], need), KV_QUANT_EPS)
-            sc = sc.at[:, bids].max(s_fin)     # order-independent
-            s_row = s_fin[:, seg]              # [L, S, 2]
-            kq = _quant_rows(ks[:, 0], s_row[..., 0][..., None, None])
-            vq = _quant_rows(vs[:, 0], s_row[..., 1][..., None, None])
-            kp = kp.at[:, bid, off].set(kq)    # [layers, S, heads, D]
-            vp = vp.at[:, bid, off].set(vq)
-            return kp, vp, sc
-
-        return apply("paged_prefill_write", fnq, kpool, vpool, scales,
-                     kstack, vstack, block_row, plen)
-
-    def fn(kp, vp, ks, vs, row, n):
-        S = ks.shape[2]
-        bs = kp.shape[2]
-        pos = jnp.arange(S)
-        bid = jnp.where(pos < n, row[pos // bs], 0)
-        off = pos % bs
-        kp = kp.at[:, bid, off].set(ks[:, 0])    # [layers, S, heads, D]
-        vp = vp.at[:, bid, off].set(vs[:, 0])
-        return kp, vp
-
-    return apply("paged_prefill_write", fn, kpool, vpool, kstack, vstack,
-                 block_row, plen)
-
-
 def paged_prefill_chunk(q, k, v, kpool, vpool, layer, block_row, start,
                         plen, scale=None, scales=None, mp_axis=None):
     """One chunked-prefill step for ONE slot, for one layer: write the
@@ -730,7 +652,7 @@ def paged_prefill_chunk(q, k, v, kpool, vpool, layer, block_row, start,
     start: absolute position of the chunk's first token.
     plen: true prompt length. Chunk positions >= plen (tail padding)
     write to the null block 0 and their query outputs are garbage the
-    caller ignores (same contract as bucketed prefill padding).
+    caller ignores.
 
     Work is O(chunk x context-so-far) via the same traced-trip-count
     `fori_loop` online softmax as the dense decode step — identical
@@ -758,9 +680,9 @@ def paged_prefill_chunk(q, k, v, kpool, vpool, layer, block_row, start,
             first = s0 // bs
             seg = jnp.clip(pos // bs - first, 0, nb - 1)   # [C]
             # a chunk may finish a block an EARLIER chunk started, so
-            # the grid must grow + requantize (unlike the bucketed
-            # fresh-block writer). Candidates with no valid rows keep
-            # their scale (need 0) and requantize by factor 1 — exact.
+            # the grid must grow + requantize. Candidates with no valid
+            # rows keep their scale (need 0) and requantize by factor 1
+            # — exact.
             # Candidates past the table route to the NULL block so a
             # clamped index can never scatter-race the real last block.
             cand = first + jnp.arange(nb)
@@ -845,8 +767,7 @@ def paged_prefill_chunk(q, k, v, kpool, vpool, layer, block_row, start,
         kp = kp.at[layer, bid, off].set(ka[0])         # [C, kvh, d]
         vp = vp.at[layer, bid, off].set(va[0])
         s = scale if scale is not None else 1.0 / np.sqrt(d)
-        # QK at pool dtype, fp32 accumulation — the _dense_step policy,
-        # so chunked and bucketed prefill see the same rounding story
+        # QK at pool dtype, fp32 accumulation — the _dense_step policy
         qf = qa[0].astype(kp.dtype)                    # [C, heads, d]
         end = jnp.minimum(s0 + C, n)                   # past-last pos
         hw_blocks = jnp.maximum(end - 1, 0) // bs + 1  # traced scalar

@@ -20,10 +20,10 @@ into it — `fold_in(fold_in(base, position), salt)` — so
   construction: `fold_in` is a key DERIVER, and each derived key feeds
   exactly one sampler);
 - the token at absolute position P+1 is always drawn with the key
-  folded from P, whatever path produced it (chunked prefill's final
-  chunk, bucketed prefill, a full-prefix-hit decode, a speculative
-  bonus draw) — same (seed, trace, config) => same tokens, and the
-  prefill modes / cold / warm runs agree token-for-token;
+  folded from P, whatever path produced it (a prompt's final prefill
+  chunk, a full-prefix-hit decode, a speculative bonus draw) — same
+  (seed, trace, config) => same tokens, and cold / warm runs agree
+  token-for-token;
 - the draws are backend-independent (they consume logits AFTER
   attention), so sampled streams are identical across the dense and
   pallas backends wherever the greedy streams are.

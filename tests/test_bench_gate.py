@@ -176,7 +176,7 @@ def test_pending_smoke_flags_unadopted_opbench_rows():
     for row in ("gpt_decode_kv_350m", "gpt_engine_offered_load",
                 "paged_attention_decode_sweep",
                 "gpt_engine_offered_load_pallas",
-                "gpt_engine_prefix_cache", "gpt_engine_chunked_prefill",
+                "gpt_engine_prefix_cache",
                 "gpt_engine_speculative",
                 "gpt_engine_offered_load_mp2",
                 "gpt_engine_offered_load_int8",

@@ -10,7 +10,7 @@ SCHEDULING refactor, not a numerics change —
 
 - token IDENTITY serial vs async across the whole serving matrix
   ({dense, pallas} x K in {0, 4} x mp in {1, 2} x kv in {fp, int8}),
-  chunked cold + warm and legacy bucketed prefill, with mid-run
+  cold + warm, with mid-run
   admissions, saturation shedding, and adapter-pool evictions in the
   mix.  Sampled lanes hold too: the acceptance coin at each verify
   position is compared against p(draft token), so identical tokens
@@ -22,7 +22,7 @@ SCHEDULING refactor, not a numerics change —
   block/adapter-page leak audits stay green.
 - `decode_traces == 1` per config and steady-state `expect_traces(0)`
   — dispatch-ahead reuses the exact compiled programs.
-- `PADDLE_SERVE_ASYNC` wins over the ctor arg; the default is ON;
+- the default is ON;
   `async_core=False` leaves the engine on the serial path with no
   in-flight machinery engaged.
 - the ahead order's late knowledge is safe: a finish by length is a
@@ -65,14 +65,6 @@ def model():
     return _model()
 
 
-@pytest.fixture(autouse=True)
-def _no_env_overrides(monkeypatch):
-    for var in ("PADDLE_SERVE_ASYNC", "PADDLE_SPEC_DECODE_K",
-                "PADDLE_PAGED_ATTENTION_BACKEND",
-                "PADDLE_SERVE_KV_DTYPE", "PADDLE_SERVE_MP"):
-        monkeypatch.delenv(var, raising=False)
-
-
 def _trace(rng, n=4):
     """Mixed lengths + motif-tiled prompts (so the NgramDrafter
     actually matches and the accept walk sees non-empty windows) + a
@@ -103,11 +95,10 @@ def _spec_counters(eng):
     return (int(eng._m_spec_ok.value), int(eng._m_spec_rej.value))
 
 
-def _assert_async_matrix_cell(model, backend, K, mp=None, kv=None,
-                              bucketed=True):
+def _assert_async_matrix_cell(model, backend, K, mp=None, kv=None):
     """One (backend, K, mp, kv_dtype) cell: the same mixed trace
-    served serial then async over (a) chunked cold, (b) same engine
-    warm, (c) legacy bucketed — token lists identical per mode, ONE
+    served serial then async over (a) a cold cache, (b) the same
+    engine warm — token lists identical per mode, ONE
     decode trace each, and at K>0 identical draft-acceptance counters
     (the direct witness that helper-thread drafts equal serial
     drafts)."""
@@ -116,26 +107,17 @@ def _assert_async_matrix_cell(model, backend, K, mp=None, kv=None,
 
     def serve(async_core):
         quant = dict(kv_dtype=kv, weight_dtype=kv) if kv else {}
-        def mk(**kw):
-            return GenerationEngine(model, num_slots=3, block_size=4,
-                                    num_blocks=64, spec_decode_k=K,
-                                    attention_backend=backend,
-                                    mp_degree=mp, async_core=async_core,
-                                    **quant, **kw)
-
-        eng = mk(prefill_chunk=8)
+        eng = GenerationEngine(model, num_slots=3, block_size=4,
+                               num_blocks=64, spec_decode_k=K,
+                               attention_backend=backend,
+                               mp_degree=mp, async_core=async_core,
+                               prefill_chunk=8, **quant)
         out = [_run_trace(eng, reqs),
                _run_trace(eng, reqs, midrun=False)]   # warm cache
-        engines = [eng]
-        if bucketed:
-            eng_b = mk(prefill_buckets=(16, 64))
-            out.append(_run_trace(eng_b, reqs))
-            engines.append(eng_b)
-        for e in engines:
-            assert e.async_core == async_core
-            assert e.decode_traces == 1, \
-                (f"{backend} K={K} mp={mp} kv={kv} "
-                 f"async={async_core}: decode retraced")
+        assert eng.async_core == async_core
+        assert eng.decode_traces == 1, \
+            (f"{backend} K={K} mp={mp} kv={kv} "
+             f"async={async_core}: decode retraced")
         return out, eng
 
     serial, eng_s = serve(False)
@@ -173,16 +155,16 @@ def _assert_async_matrix_cell(model, backend, K, mp=None, kv=None,
 @pytest.mark.parametrize("K", [0, 4])
 def test_async_token_identity_dense(model, K):
     """Tier-1 cut of THE acceptance gate: (dense, K, mp=1, fp) over
-    chunked cold + warm + bucketed with mid-run admissions."""
+    cold + warm cache with mid-run admissions."""
     _assert_async_matrix_cell(model, "dense", K)
 
 
 @pytest.mark.slow
 def test_async_token_identity_pallas_spec(model):
     """Tier-1 lean probe of the (pallas, K=4) cell — the fused verify
-    kernel under the dispatch-ahead pipeline (chunked legs only; the
-    slow full matrix adds bucketed + mp + int8)."""
-    _assert_async_matrix_cell(model, "pallas", 4, bucketed=False)
+    kernel under the dispatch-ahead pipeline (the slow full matrix
+    adds mp + int8)."""
+    _assert_async_matrix_cell(model, "pallas", 4)
 
 
 @pytest.mark.slow
@@ -318,21 +300,6 @@ def test_async_default_falls_to_serial_where_the_spec_refuses(model,
     assert mk().async_core is False
     with pytest.raises(ValueError, match="async_core is not served"):
         mk(async_core=True)
-
-
-def test_async_env_knob_wins_over_ctor(model, monkeypatch):
-    mk = lambda **kw: GenerationEngine(model, num_slots=2,
-                                       block_size=4, num_blocks=32,
-                                       **kw)
-    monkeypatch.setenv("PADDLE_SERVE_ASYNC", "1")
-    assert mk(async_core=False).async_core is True
-    monkeypatch.setenv("PADDLE_SERVE_ASYNC", "off")
-    assert mk(async_core=True).async_core is False
-    monkeypatch.setenv("PADDLE_SERVE_ASYNC", "")   # '' means unset
-    assert mk(async_core=True).async_core is True
-    monkeypatch.setenv("PADDLE_SERVE_ASYNC", "maybe")
-    with pytest.raises(ValueError, match="PADDLE_SERVE_ASYNC"):
-        mk()
 
 
 @pytest.mark.parametrize("K", [0, 4])
@@ -726,7 +693,7 @@ def test_suite_rows_carry_async_overlap_row():
 
 
 @pytest.mark.slow
-def test_async_overlap_bench_runner_tiny(monkeypatch):
+def test_async_overlap_bench_runner_tiny():
     """The `gpt_engine_async_overlap` runner end-to-end on a tiny
     config — its in-runner gates ARE the acceptance criteria: per-rep
     token identity, async overlappable host gap
@@ -737,7 +704,6 @@ def test_async_overlap_bench_runner_tiny(monkeypatch):
 
     import bench_ops
 
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
     # hidden=256/layers=3 keeps the step device-bound even on the CPU
     # runner: the device-fraction gate (async >= serial) only holds
     # structurally when there IS device time left to hide host work
@@ -758,13 +724,13 @@ def test_async_overlap_bench_runner_tiny(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# satellite: fleet replicas run the async core via the env knob
+# satellite: fleet replicas run the async core
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-def test_fleet_async_replicas_token_exact(model, monkeypatch):
+def test_fleet_async_replicas_token_exact(model):
     """A disaggregated fleet with every replica on the async core
-    (via PADDLE_SERVE_ASYNC — the fleet builds its own engines) stays
+    (`async_core=True` passed through to the engines it builds) stays
     token-exact vs the serial bare engine, and the prestaged handoff
     flush still drains every parked prefill."""
     rng = np.random.RandomState(6)
@@ -772,15 +738,16 @@ def test_fleet_async_replicas_token_exact(model, monkeypatch):
              for _ in range(6)]
 
     def eng_serve():
-        eng = GenerationEngine(model, num_slots=4, block_size=8)
+        eng = GenerationEngine(model, num_slots=4, block_size=8,
+                               async_core=False)
         ids = [eng.add_request(p, max_new_tokens=n) for p, n in trace]
         out = eng.run()
         return {i: list(map(int, out[i])) for i in ids}
 
     ref = eng_serve()
-    monkeypatch.setenv("PADDLE_SERVE_ASYNC", "1")
     fleet = ServingFleet(model, num_slots=4, block_size=8,
-                         num_replicas=1, num_prefill_replicas=1)
+                         num_replicas=1, num_prefill_replicas=1,
+                         async_core=True)
     ids = [fleet.add_request(p, max_new_tokens=n) for p, n in trace]
     out = fleet.run()
     assert {i: list(map(int, out[i])) for i in ids} == ref

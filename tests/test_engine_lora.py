@@ -9,8 +9,8 @@ The contract, proven the way PR 8/11/12 proved theirs:
   scores under the adapted model). No cross-slot adapter leakage, by
   assertion rather than by construction.
 - NULL PATH: adapter id 0 is bit-identical to a pre-adapter engine
-  across {dense,pallas} x {chunked,bucketed} x K in {0,4} x
-  mp in {1,2} (tier-1 runs a 4-cell cut; the full 16-cell product is
+  across {dense,pallas} x K in {0,4} x
+  mp in {1,2} (tier-1 runs a 4-cell cut; the other 4 cells are
   slow-marked), and `decode_traces == 1` per config regardless of how
   many adapters are live.
 - PAGING: the adapter pool's refcount/LRU/stall-and-retry story
@@ -107,15 +107,12 @@ def _serve(eng, reqs, midrun=True):
 
 @pytest.mark.parametrize("backend,K", [("dense", 0), ("pallas", 4)])
 def test_mixed_tenants_token_identical_to_dedicated(model, registry,
-                                                    monkeypatch,
                                                     backend, K):
     """THE acceptance gate: one engine serving three tenants (base +
     two adapters) interleaved, with mid-run admissions, emits per
     request exactly the tokens a dedicated single-tenant engine
     would — both backends, speculation on for one of them, ONE decode
     trace regardless of tenant mix."""
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     rng = np.random.RandomState(11)
     reqs = _mixed_trace(rng)
 
@@ -163,66 +160,54 @@ def test_adapters_actually_change_tokens(model, registry):
 # null path: adapter id 0 bit-identical to the pre-adapter engine
 # ---------------------------------------------------------------------------
 
-_CELLS = [(b, pm, K, mp) for b in ("dense", "pallas")
-          for pm in ("chunked", "bucketed") for K in (0, 4)
+_CELLS = [(b, K, mp) for b in ("dense", "pallas") for K in (0, 4)
           for mp in (1, 2)]
-_T1_CELLS = [("dense", "chunked", 0, 1), ("pallas", "bucketed", 4, 2),
-             ("dense", "bucketed", 4, 1), ("pallas", "chunked", 0, 2)]
+_T1_CELLS = [("dense", 0, 1), ("pallas", 4, 2),
+             ("dense", 4, 1), ("pallas", 0, 2)]
 
 
-def _assert_null_cell(model, registry, backend, pmode, K, mp):
+def _assert_null_cell(model, registry, backend, K, mp):
     rng = np.random.RandomState(5)
     reqs = [(p, n, 0) for p, n, _ in _mixed_trace(rng, adapters=(0,),
                                                   n_per=3)]
 
     def mk(adapters):
-        kw = dict(prefill_chunk=8) if pmode == "chunked" \
-            else dict(prefill_buckets=(16, 64))
         return GenerationEngine(model, num_slots=2, block_size=4,
                                 num_blocks=64, spec_decode_k=K,
                                 attention_backend=backend,
-                                mp_degree=mp, adapters=adapters, **kw)
+                                mp_degree=mp, adapters=adapters,
+                                prefill_chunk=8)
 
     plain = mk(None)
     ref = _serve(plain, reqs)
     lora = mk(registry)
     assert _serve(lora, reqs) == ref, \
-        (f"{backend}/{pmode}/K={K}/mp={mp}: adapter id 0 diverged "
+        (f"{backend}/K={K}/mp={mp}: adapter id 0 diverged "
          "from the pre-adapter engine")
     assert plain.decode_traces == lora.decode_traces == 1
 
 
-@pytest.mark.parametrize("backend,pmode,K,mp", _T1_CELLS)
-def test_null_adapter_bit_identical(model, registry, monkeypatch,
-                                    backend, pmode, K, mp):
+@pytest.mark.parametrize("backend,K,mp", _T1_CELLS)
+def test_null_adapter_bit_identical(model, registry, backend, K, mp):
     """Adapter id 0 through a LoRA-enabled engine emits exactly the
-    pre-adapter engine's tokens (tier-1 cut of the 16-cell matrix)."""
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
-    _assert_null_cell(model, registry, backend, pmode, K, mp)
+    pre-adapter engine's tokens (tier-1 cut of the 8-cell matrix)."""
+    _assert_null_cell(model, registry, backend, K, mp)
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("backend,pmode,K,mp",
+@pytest.mark.parametrize("backend,K,mp",
                          [c for c in _CELLS if c not in _T1_CELLS])
 def test_null_adapter_bit_identical_full_matrix(model, registry,
-                                                monkeypatch, backend,
-                                                pmode, K, mp):
+                                                backend, K, mp):
     """The remaining cells of the null-path matrix (identical
     machinery, outside the timed tier-1 window)."""
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
-    _assert_null_cell(model, registry, backend, pmode, K, mp)
+    _assert_null_cell(model, registry, backend, K, mp)
 
 
-def test_mp2_and_int8_weights_compose(model, registry, monkeypatch):
+def test_mp2_and_int8_weights_compose(model, registry):
     """Adapters under the sharded engine (column-parallel B pages) are
     token-identical to mp=1, and int8 BASE weights compose with fp
     adapters (mixed == dedicated under the same quantized config)."""
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_WEIGHT_DTYPE", raising=False)
     rng = np.random.RandomState(3)
     reqs = _mixed_trace(rng, n_per=1)
 
@@ -293,8 +278,7 @@ def test_prefix_chain_is_adapter_salted(model, registry):
 # paging: eviction under pressure, stall/retry, drain audit
 # ---------------------------------------------------------------------------
 
-def test_adapter_pool_eviction_never_changes_tokens(model, registry,
-                                                    monkeypatch):
+def test_adapter_pool_eviction_never_changes_tokens(model, registry):
     """A 2-page pool (null + ONE tenant page) serving two adapters
     must swap/evict continuously — admissions stall-and-retry on
     page pressure — and still emit exactly the big-pool tokens."""
@@ -591,13 +575,11 @@ def test_lora_delta_matches_the_numpy_oracle(model):
 # observability
 # ---------------------------------------------------------------------------
 
-def test_multitenant_lora_bench_runner_tiny(model, monkeypatch):
+def test_multitenant_lora_bench_runner_tiny(model):
     """The gpt_engine_multitenant_lora SUITE_ROWS runner at test
     scale: mixed-pool engine vs the engine-per-tenant strawman,
     outputs asserted identical inside the runner, per-tenant latency
     series populated, swap-ins visible with a page-tight pool."""
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_WEIGHT_DTYPE", raising=False)
     import bench_ops
 
     assert "gpt_engine_multitenant_lora" in bench_ops.suite_names()

@@ -136,13 +136,12 @@ def _run_trace(eng, reqs, midrun=True):
     return [np.asarray(out[rid]) for rid in ids]
 
 
-def test_chunked_cache_on_off_and_bucketed_all_token_identical(model):
+def test_chunked_cache_on_off_all_token_identical(model):
     """THE acceptance gate: one mixed trace (prompts shorter and longer
-    than the chunk, shared prefixes by construction) through (a) legacy
-    whole-bucket prefill, (b) chunked with the prefix cache off,
-    (c) chunked+cache cold, (d) chunked+cache warm — identical outputs
+    than the chunk, shared prefixes by construction) through (a) the
+    prefix cache off, (b) cache cold, (c) cache warm — identical outputs
     everywhere, equal to the single-request oracle; decode compiles
-    once and the chunked prefill compiles once TOTAL (bounded by the
+    once and the prefill chunk compiles once TOTAL (bounded by the
     chunk shape, not the prompt-length mix); the warm pass serves hit
     tokens without prefill compute."""
     rng = np.random.RandomState(11)
@@ -160,7 +159,6 @@ def test_chunked_cache_on_off_and_bucketed_all_token_identical(model):
         return GenerationEngine(model, num_slots=3, block_size=4,
                                 num_blocks=64, **kw)
 
-    outs_bucketed = _run_trace(mk(prefill_buckets=(16, 64)), reqs)
     eng_off = mk(prefill_chunk=8, enable_prefix_cache=False)
     outs_off = _run_trace(eng_off, reqs)
     eng = mk(prefill_chunk=8)
@@ -172,13 +170,11 @@ def test_chunked_cache_on_off_and_bucketed_all_token_identical(model):
     chunks_warm = series_total(
         snap, "engine_prefill_chunks_total") - chunks_cold
 
-    for (p, n), a, b, c, d in zip(reqs, outs_bucketed, outs_off,
-                                  outs_cold, outs_warm):
+    for (p, n), a, b, c in zip(reqs, outs_off, outs_cold, outs_warm):
         want = _reference(model, p, n)
         np.testing.assert_array_equal(a, want)
         np.testing.assert_array_equal(b, want)
         np.testing.assert_array_equal(c, want)
-        np.testing.assert_array_equal(d, want)
 
     # cache off never hits; cold run hits the shared prefix reqs
     assert eng_off.prefix_hit_tokens == 0
@@ -332,17 +328,16 @@ def test_shed_on_saturation_prefers_high_priority(model):
 # satellite: instant-finish TPOT accounting
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("mode", ["chunked", "bucketed"])
-def test_instant_finish_lands_in_tpot_histogram(model, mode):
+@pytest.mark.parametrize("cache", [True, False])
+def test_instant_finish_lands_in_tpot_histogram(model, cache):
     """A max_new_tokens==1 request produces exactly one token and used
     to vanish from the TPOT histogram while still counting in
     engine_tokens_generated_total; its producing-step latency must now
-    be recorded — in both prefill modes, and on the full-prefix-hit
-    decode path too."""
-    kw = {"prefill_chunk": 8} if mode == "chunked" \
-        else {"prefill_buckets": (16, 64)}
+    be recorded — from the prompt's last chunk (prefix cache on or
+    off), and on the full-prefix-hit decode path too."""
     eng = GenerationEngine(model, num_slots=2, block_size=4,
-                           num_blocks=32, **kw)
+                           num_blocks=32, prefill_chunk=8,
+                           enable_prefix_cache=cache)
     rng = np.random.RandomState(9)
     p = rng.randint(0, VOCAB, 8).astype(np.int32)
     eng.add_request(p, 1)
@@ -352,7 +347,7 @@ def test_instant_finish_lands_in_tpot_histogram(model, mode):
                for s in snap["engine_tpot_seconds"]["series"])
     assert tpot == 1                   # the single token is visible
     assert series_total(snap, "engine_tokens_generated_total") == 1
-    if mode == "chunked":
+    if cache:
         # the same prompt again: full-prefix hit, first token comes
         # from the DECODE step — still visible
         eng.add_request(p, 1)
@@ -366,12 +361,10 @@ def test_instant_finish_lands_in_tpot_histogram(model, mode):
 # satellite: bench rows (CI-scale runners + suite registration)
 # ---------------------------------------------------------------------------
 
-def test_prefix_cache_and_chunked_bench_rows(monkeypatch):
-    """The two new SUITE_ROWS at test scale: the multi-tenant trace
+def test_prefix_cache_and_chunked_bench_rows():
+    """The prefix-cache SUITE_ROW at test scale: the multi-tenant trace
     runner must show warm prefix hits skipping prefill compute (hit
-    tokens > 0, fewer chunk dispatches than cold) and the chunked-
-    prefill row must report tail-TPOT for both prefill modes."""
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
+    tokens > 0, fewer chunk dispatches than cold)."""
     import bench_ops
     from paddle_tpu.models import GPTConfig
 
@@ -385,14 +378,4 @@ def test_prefix_cache_and_chunked_bench_rows(monkeypatch):
     assert rec["prefill_chunks_warm"] < rec["prefill_chunks_cold"]
     assert rec["tokens_per_s"] > 0 and rec["ms"] > 0
 
-    paddle.seed(0)
-    rec = bench_ops._engine_chunked_prefill_case(
-        model_cfg=cfg, long_prompt=24, decode_lanes=1, max_new=6,
-        num_slots=2, block_size=4, prefill_chunk=8)()
-    assert rec["ms"] > 0
-    assert rec["tpot_ms_p99_chunked"] is not None
-    assert rec["tpot_ms_p99_whole"] is not None
-
-    names = bench_ops.suite_names()
-    assert "gpt_engine_prefix_cache" in names
-    assert "gpt_engine_chunked_prefill" in names
+    assert "gpt_engine_prefix_cache" in bench_ops.suite_names()

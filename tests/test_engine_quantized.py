@@ -3,9 +3,9 @@ int8 weights through the backend seam.
 
 The contract, proven the way PR 6/7/8 proved theirs:
 
-- `kv_dtype='int8'` (engine arg + PADDLE_SERVE_KV_DTYPE env) serves
+- `kv_dtype='int8'` serves
   the standard mixed trace TOKEN-PARITY-WITHIN-TOLERANCE vs the fp
-  engine across {dense, pallas} x {chunked cold + warm, bucketed} x
+  engine across {dense, pallas} x {cache cold, warm} x
   K in {0, 4} x mp in {1, 2} — and the int8 engine is token-IDENTICAL
   across mesh shapes (the per-block grids are pmax-folded, so mp=2
   quantizes on mp=1's exact grid);
@@ -96,37 +96,28 @@ def _match_fraction(ref, got):
 # tentpole: tolerance parity across the whole quantized serving matrix
 # ---------------------------------------------------------------------------
 
-def _assert_quantized_matrix(model, backend, K, full=False):
+def _assert_quantized_matrix(model, backend, K):
     """One mixed trace served fp (anchored bit-exact to the generate
     oracle — the fp path must be byte-for-byte pre-PR) and int8 at
-    mp=1 and mp=2 in (a) chunked cold, (b) same engine warm, (c)
-    legacy bucketed — int8 within the tolerance budget vs fp per
+    mp=1 and mp=2 in (a) cache cold, (b) same engine warm
+    — int8 within the tolerance budget vs fp per
     mode, int8 mp=2 token-IDENTICAL to int8 mp=1, decode_traces==1
     per configuration."""
     rng = np.random.RandomState(11)
     reqs = _mixed_trace(rng)
 
-    def serve(mp, kv, bucketed=True):
-        def mk(**kw):
-            quant = dict(kv_dtype="int8", weight_dtype="int8") \
-                if kv else {}
-            return GenerationEngine(model, num_slots=3, block_size=4,
-                                    num_blocks=64, spec_decode_k=K,
-                                    attention_backend=backend,
-                                    mp_degree=mp, **quant, **kw)
-
-        eng = mk(prefill_chunk=8)
+    def serve(mp, kv):
+        quant = dict(kv_dtype="int8", weight_dtype="int8") \
+            if kv else {}
+        eng = GenerationEngine(model, num_slots=3, block_size=4,
+                               num_blocks=64, spec_decode_k=K,
+                               attention_backend=backend,
+                               mp_degree=mp, prefill_chunk=8, **quant)
         out = [_run_trace(eng, reqs),
                _run_trace(eng, reqs, midrun=False)]   # hot cache
-        engines = [eng]
-        if bucketed:
-            eng_b = mk(prefill_buckets=(16, 64))
-            out.append(_run_trace(eng_b, reqs))
-            engines.append(eng_b)
         assert eng.prefix_hit_tokens > 0
-        for e in engines:
-            assert e.decode_traces == 1, \
-                f"mp={mp} {backend} K={K} kv={e.kv_dtype}: retraced"
+        assert eng.decode_traces == 1, \
+            f"mp={mp} {backend} K={K} kv={eng.kv_dtype}: retraced"
         return out
 
     fp = serve(None, kv=False)
@@ -135,42 +126,32 @@ def _assert_quantized_matrix(model, backend, K, full=False):
     assert fp[0][0] == _reference(model, p, n)
     q1 = serve(None, kv=True)
     # tolerance parity vs fp, per serving mode
-    for mode, ref, got in zip(("cold", "warm", "bucketed"), fp, q1):
+    for mode, ref, got in zip(("cold", "warm"), fp, q1):
         frac = _match_fraction(ref, got)
         assert frac >= TOKEN_PARITY_MIN, \
             (f"{backend} K={K} {mode}: int8 matched only {frac:.3f} "
              f"of fp tokens (budget {TOKEN_PARITY_MIN})")
-    # int8 across mesh shapes is EXACT (pmax-folded global grids);
-    # tier-1 proves the chunked cold+warm legs, the slow-marked
-    # full-matrix test adds the bucketed mp=2 cells
-    q2 = serve(2, kv=True, bucketed=full)
-    assert q2 == (q1 if full else q1[:2]), \
+    # int8 across mesh shapes is EXACT (pmax-folded global grids)
+    q2 = serve(2, kv=True)
+    assert q2 == q1, \
         f"{backend} K={K}: int8 mp=2 diverged from int8 mp=1"
 
 
-def test_quantized_tolerance_parity_matrix(model, monkeypatch):
+def test_quantized_tolerance_parity_matrix(model):
     """THE acceptance gate, tier-1 cut: the (dense, K=0) cell across
-    mp in {1, 2} x {chunked cold, warm, bucketed} plus the lean
+    mp in {1, 2} x {cache cold, warm} plus the lean
     pallas/K=4 probe below; the remaining (backend, K) cells run in
     the slow-marked full-matrix test — the test_engine_sharded
     precedent for keeping the timed tier-1 window bounded."""
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_WEIGHT_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     _assert_quantized_matrix(model, "dense", 0)
 
 
-def test_quantized_pallas_spec_decode_tolerance(model, monkeypatch):
+def test_quantized_pallas_spec_decode_tolerance(model):
     """Lean tier-1 probe for the (pallas, K=4) cell: the int8 verify
     kernel serves the mixed trace cold + warm within the tolerance
     budget vs the fp reference (fp tokens are backend- and
     K-invariant by the PR 3/7 exactness contracts, so the dense fp
     K=0 stream is the oracle here too)."""
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     rng = np.random.RandomState(11)
     reqs = _mixed_trace(rng)
 
@@ -195,20 +176,14 @@ def test_quantized_pallas_spec_decode_tolerance(model, monkeypatch):
 @pytest.mark.slow
 @pytest.mark.parametrize("backend,K", [("pallas", 4), ("dense", 4),
                                        ("pallas", 0)])
-def test_quantized_tolerance_parity_full_matrix(model, monkeypatch,
-                                                backend, K):
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_WEIGHT_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
-    _assert_quantized_matrix(model, backend, K, full=True)
+def test_quantized_tolerance_parity_full_matrix(model, backend, K):
+    _assert_quantized_matrix(model, backend, K)
 
 
-def test_quantized_backends_agree_token_for_token(model, monkeypatch):
+def test_quantized_backends_agree_token_for_token(model):
     """dense-int8 and pallas-int8 share one quantization policy and
     one operation order — their token streams must be identical, not
     merely both-within-tolerance."""
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     rng = np.random.RandomState(5)
     reqs = _mixed_trace(rng, n=3)
 
@@ -253,10 +228,13 @@ def test_int8_pool_bytes_half_of_bf16(model):
         == eng.cache.pool_nbytes()
     with pytest.raises(ValueError, match="kv_dtype"):
         PagedKVCache(2, 8, 4, 4, 8, kv_dtype="fp8")
+    for knob in ("kv_dtype", "weight_dtype"):
+        with pytest.raises(ValueError, match=knob):
+            GenerationEngine(model, num_slots=2, block_size=4,
+                             **{knob: "fp8"})
 
 
-def test_dtype_info_gauges_and_utilization_labels(model, monkeypatch):
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
+def test_dtype_info_gauges_and_utilization_labels(model):
     eng = GenerationEngine(model, num_slots=2, block_size=4,
                            num_blocks=16, prefill_chunk=8,
                            kv_dtype="int8", weight_dtype="int8")
@@ -282,29 +260,12 @@ def test_dtype_info_gauges_and_utilization_labels(model, monkeypatch):
         == [{"weight_dtype": "float32"}]
 
 
-def test_kv_dtype_env_override_wins(model, monkeypatch):
-    monkeypatch.setenv("PADDLE_SERVE_KV_DTYPE", "int8")
-    eng = GenerationEngine(model, num_slots=2, block_size=4,
-                           prefill_chunk=8)
-    assert eng.kv_dtype == "int8" and eng.cache.scales is not None
-    monkeypatch.setenv("PADDLE_SERVE_KV_DTYPE", "fp8")
-    with pytest.raises(ValueError, match="PADDLE_SERVE_KV_DTYPE"):
-        GenerationEngine(model, num_slots=2, block_size=4,
-                         prefill_chunk=8)
-    monkeypatch.setenv("PADDLE_SERVE_KV_DTYPE", "")
-    eng = GenerationEngine(model, num_slots=2, block_size=4,
-                           prefill_chunk=8, weight_dtype="int8")
-    assert eng.kv_dtype is None and eng.weight_dtype == "int8"
-
-
 # ---------------------------------------------------------------------------
 # quantized sharing: COW byte-identity + read-only prefix seating
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("backend,mp", [("dense", 1), ("pallas", 2)])
-def test_quantized_cow_keeps_shared_blocks_and_scales(model,
-                                                      monkeypatch,
-                                                      backend, mp):
+def test_quantized_cow_keeps_shared_blocks_and_scales(model, backend, mp):
     """ISSUE 11 satellite: a borrower decoding off shared quantized
     prefix blocks must never mutate the cached int8 CODES or their
     per-block SCALES — COW promotes (copying scale rows with the
@@ -312,16 +273,12 @@ def test_quantized_cow_keeps_shared_blocks_and_scales(model,
     over raw codes, raw scale rows, and dequantized values, across
     both backends and mp in {1, 2} (tier-1 runs the diagonal cells;
     the complementary pair is slow-marked below)."""
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
     _assert_cow_immutable(model, backend, mp)
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("backend,mp", [("pallas", 1), ("dense", 2)])
-def test_quantized_cow_full_matrix(model, monkeypatch, backend, mp):
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
+def test_quantized_cow_full_matrix(model, backend, mp):
     _assert_cow_immutable(model, backend, mp)
 
 
@@ -470,10 +427,9 @@ def test_dequantize_dtype_parameter_regression():
         np.asarray(bf.astype(jnp.float32)), w, atol=0.05)
 
 
-def test_steady_state_and_donation_with_int8(model, monkeypatch):
+def test_steady_state_and_donation_with_int8(model):
     """A warmed int8 engine retraces nothing on churn; the pools stay
     donated ((1, 2) — the scale array rides undonated, it is tiny)."""
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
     rng = np.random.RandomState(9)
     eng = GenerationEngine(model, num_slots=2, block_size=4,
                            num_blocks=64, prefill_chunk=8,
@@ -492,14 +448,10 @@ def test_steady_state_and_donation_with_int8(model, monkeypatch):
 # bench row (CI-scale runner + suite registration)
 # ---------------------------------------------------------------------------
 
-def test_offered_load_int8_bench_row(monkeypatch):
+def test_offered_load_int8_bench_row():
     """The gpt_engine_offered_load_int8 SUITE_ROWS runner at test
     scale: serves the same trace fp then int8 (KV + weights), asserts
     tolerance inside the runner, records tokens/s and pool bytes."""
-    monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_WEIGHT_DTYPE", raising=False)
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     import bench_ops
     from paddle_tpu.models import GPTConfig
 
@@ -508,8 +460,7 @@ def test_offered_load_int8_bench_row(monkeypatch):
     paddle.seed(0)
     rec = bench_ops._engine_offered_load_case(
         model_cfg=cfg, requests=[(3, 4), (6, 4), (10, 3)],
-        num_slots=2, block_size=4, prefill_buckets=(4, 8, 16, 32),
-        kv_dtype="int8")()
+        num_slots=2, block_size=4, kv_dtype="int8")()
     assert rec["kv_dtype"] == "int8" and rec["weight_dtype"] == "int8"
     assert rec["tokens_per_s"] > 0 and rec["tokens_per_s_fp"] > 0
     assert rec["token_match_fraction"] >= bench_ops.INT8_TOKEN_PARITY_MIN

@@ -145,7 +145,7 @@ def test_sampled_streams_reproduce_and_agree_across_paths(model):
     """Same (seed, trace, config) => same tokens; and because draws
     are keyed by (seed, absolute position) on logits both backends
     compute bit-identically, the sampled streams agree across
-    dense/pallas, chunked/bucketed prefill, and cold/warm caches."""
+    dense/pallas, prefill chunk sizes, and cold/warm caches."""
     trace = _trace()
     params = lambda i: dataclasses.replace(SAMPLED, seed=100 + i)
     base, _ = _serve(model, trace, params, sampling=True)
@@ -154,9 +154,9 @@ def test_sampled_streams_reproduce_and_agree_across_paths(model):
     pallas, _ = _serve(model, trace, params, sampling=True,
                        attention_backend="pallas")
     assert pallas == base
-    bucketed, _ = _serve(model, trace, params, sampling=True,
-                         prefill_buckets=(32, 64))
-    assert bucketed == base
+    chunk8, _ = _serve(model, trace, params, sampling=True,
+                       prefill_chunk=8)
+    assert chunk8 == base
     # warm: the same engine serves the same sampled requests twice —
     # the second pass seats the prompts from the prefix cache and
     # must replay the identical stream (keys are position-pure)
@@ -401,11 +401,11 @@ def test_fleet_best_of_n(model):
     fleet.best_of_n(prompt, 2, 2,
                     sampling_params=SamplingParams(temperature=1.0))
     assert fleet._seed_counter == before + 2
-    # the prefix-cache guard holds fleet-side (bucketed-prefill
-    # replicas have no cache — n-1 silent re-prefills otherwise)
+    # the prefix-cache guard holds fleet-side (replicas without a
+    # cache — n-1 silent re-prefills otherwise)
     nocache = ServingFleet(model, num_replicas=1, num_slots=4,
                            block_size=8, sampling=True,
-                           prefill_buckets=(32, 64))
+                           enable_prefix_cache=False)
     with pytest.raises(ValueError, match="prefix cache"):
         nocache.best_of_n(prompt, 2, 4,
                           sampling_params=SamplingParams(
@@ -554,22 +554,9 @@ def test_sampling_params_validation(model):
         eng.best_of_n(np.arange(8, dtype=np.int32), 2, 4)
     with pytest.raises(ValueError, match="prefix cache"):
         GenerationEngine(model, num_slots=2, block_size=8,
-                         sampling=True, prefill_buckets=(64,)
+                         sampling=True, enable_prefix_cache=False
                          ).best_of_n(np.arange(8, dtype=np.int32), 2,
                                      4)
-
-
-def test_env_override_enables_sampling(model, monkeypatch):
-    monkeypatch.setenv("PADDLE_SERVE_SAMPLING", "1")
-    eng = GenerationEngine(model, num_slots=2, block_size=8)
-    assert eng.sampling is True
-    monkeypatch.setenv("PADDLE_SERVE_SAMPLING", "0")
-    eng = GenerationEngine(model, num_slots=2, block_size=8,
-                           sampling=True)
-    assert eng.sampling is False       # env wins, both directions
-    monkeypatch.setenv("PADDLE_SERVE_SAMPLING", "maybe")
-    with pytest.raises(ValueError, match="PADDLE_SERVE_SAMPLING"):
-        GenerationEngine(model, num_slots=2, block_size=8)
 
 
 def test_sampling_metrics(model):

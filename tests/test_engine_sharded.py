@@ -7,7 +7,7 @@ mp=2/mp=4 programs compile and run here). The contract, proven the
 way PR 3/6/7 proved theirs:
 
 - token-EXACT parity vs the mp=1 engine across
-  {dense, pallas} x {chunked, bucketed} x {cold, warm prefix cache}
+  {dense, pallas} x {cold, warm prefix cache}
   x K in {0, 4}, with mid-run admissions and cache evictions in the
   trace — exactness by construction (column-parallel sharding: every
   dot stays full length, activations reassembled by exact gathers),
@@ -84,30 +84,24 @@ def _run_trace(eng, reqs, midrun=True):
 
 def _assert_parity_matrix(model, backend, K):
     """One mixed trace (shared prefixes, a full-prefix hit, mid-run
-    admissions) served at mp=1, mp=2 and mp=4 in (a) chunked + prefix
-    cache cold, (b) same engine warm, (c) legacy bucketed prefill —
+    admissions) served at mp=1, mp=2 and mp=4 in (a) prefix
+    cache cold, (b) same engine warm —
     all token-identical across mesh shapes, with ONE decode trace per
     (backend, K, mesh shape)."""
     rng = np.random.RandomState(11)
     reqs = _mixed_trace(rng)
 
     def serve(mp):
-        def mk(**kw):
-            return GenerationEngine(model, num_slots=3, block_size=4,
-                                    num_blocks=64, spec_decode_k=K,
-                                    attention_backend=backend,
-                                    mp_degree=mp, **kw)
-
-        eng = mk(prefill_chunk=8)
+        eng = GenerationEngine(model, num_slots=3, block_size=4,
+                               num_blocks=64, spec_decode_k=K,
+                               attention_backend=backend,
+                               mp_degree=mp, prefill_chunk=8)
         cold = _run_trace(eng, reqs)
         warm = _run_trace(eng, reqs, midrun=False)   # hot cache
-        eng_b = mk(prefill_buckets=(16, 64))
-        bucketed = _run_trace(eng_b, reqs)
         assert eng.prefix_hit_tokens > 0
-        for e in (eng, eng_b):
-            assert e.decode_traces == 1, \
-                f"mp={mp} {backend} K={K}: decode retraced"
-        return cold, warm, bucketed
+        assert eng.decode_traces == 1, \
+            f"mp={mp} {backend} K={K}: decode retraced"
+        return cold, warm
 
     ref = serve(None)
     for mp in (2, 4):
@@ -120,37 +114,27 @@ def _assert_parity_matrix(model, backend, K):
 
 
 @pytest.mark.parametrize("backend,K", [("dense", 0), ("pallas", 4)])
-def test_sharded_token_identical_across_modes(model, monkeypatch,
-                                              backend, K):
+def test_sharded_token_identical_across_modes(model, backend, K):
     """THE acceptance gate, tier-1 cut: both backends and both K
-    values across mp in {1, 2, 4} x {chunked cold, warm, bucketed}.
+    values across mp in {1, 2, 4} x {cache cold, warm}.
     The two complementary (backend, K) cells run in the slow-marked
     full-matrix test below — together the 2x2 product is covered."""
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     _assert_parity_matrix(model, backend, K)
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("backend,K", [("dense", 4), ("pallas", 0)])
-def test_sharded_token_identical_full_matrix(model, monkeypatch,
-                                             backend, K):
+def test_sharded_token_identical_full_matrix(model, backend, K):
     """The remaining (backend, K) cells of the acceptance matrix —
     identical machinery, kept out of the timed tier-1 window."""
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     _assert_parity_matrix(model, backend, K)
 
 
-def test_sharded_eviction_under_pressure_stays_exact(model,
-                                                     monkeypatch):
+def test_sharded_eviction_under_pressure_stays_exact(model):
     """A pool tight enough to evict cached prefix blocks mid-trace
     (the PR-6 pressure path) behaves identically on the sharded
     engine: same outputs, same host-side allocator story, stalls
     surfaced on the shard-labeled counter."""
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
     rng = np.random.RandomState(7)
     reqs = _mixed_trace(rng, n=3)
 
@@ -175,12 +159,11 @@ def test_sharded_eviction_under_pressure_stays_exact(model,
 # trace stability + donation on the sharded step
 # ---------------------------------------------------------------------------
 
-def test_sharded_steady_state_and_donated_pools(model, monkeypatch):
+def test_sharded_steady_state_and_donated_pools(model):
     """A warmed mp=2 engine retraces NOTHING on further churn, and the
     donated sharded pools compile and run (donation demands matching
     input/output shardings — this is the aliasing contract check the
     virtual mesh can express)."""
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
     rng = np.random.RandomState(3)
     reqs = [(rng.randint(0, VOCAB, 6).astype(np.int32), 4)
             for _ in range(3)]
@@ -223,14 +206,13 @@ def test_refresh_weights_resnapshots_the_sharded_state():
 # satellite: serving-mesh construction + validation
 # ---------------------------------------------------------------------------
 
-def test_serving_mesh_and_divisibility_validation(model, monkeypatch):
+def test_serving_mesh_and_divisibility_validation(model):
     import jax
 
     from paddle_tpu.distributed import serving_mesh
     from paddle_tpu.distributed.topology import HybridCommunicateGroup
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
 
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
     mesh = serving_mesh(2)
     assert mesh.axis_names == ("mp",) and mesh.size == 2
     # the convenience topology builds without a dp/pp/sharding launch
@@ -264,25 +246,12 @@ def test_serving_mesh_and_divisibility_validation(model, monkeypatch):
     with pytest.raises(ValueError, match="'mp' axis"):
         GenerationEngine(model, mesh=Mesh(
             np.asarray(jax.devices()[:2]), ("dp",)))
-
-
-def test_serve_mp_env_override_wins(model, monkeypatch):
-    monkeypatch.setenv("PADDLE_SERVE_MP", "2")
-    eng = GenerationEngine(model, num_slots=2, block_size=4,
-                           prefill_chunk=8)
-    assert eng.mp_degree == 2 and eng.mesh is not None
-    # env conflicting with an explicit mesh fails loudly
-    from paddle_tpu.distributed import serving_mesh
-
-    with pytest.raises(ValueError, match="PADDLE_SERVE_MP"):
-        GenerationEngine(model, mesh=serving_mesh(4))
-    monkeypatch.setenv("PADDLE_SERVE_MP", "x")
-    with pytest.raises(ValueError, match="PADDLE_SERVE_MP"):
-        GenerationEngine(model, prefill_chunk=8)
-    monkeypatch.delenv("PADDLE_SERVE_MP")
-    eng = GenerationEngine(model, num_slots=2, block_size=4,
-                           prefill_chunk=8, mp_degree=1)
-    assert eng.mp_degree == 1 and eng.mesh is None
+    # a mesh and an mp_degree that disagree fail loudly; so does a
+    # degree below one
+    with pytest.raises(ValueError, match="mp_degree=2"):
+        GenerationEngine(model, mesh=serving_mesh(4), mp_degree=2)
+    with pytest.raises(ValueError, match="must be >= 1"):
+        GenerationEngine(model, mp_degree=0)
 
 
 def test_pool_spec_is_the_single_source_of_truth(model):
@@ -312,12 +281,11 @@ def test_pool_spec_is_the_single_source_of_truth(model):
 # satellite: mesh/shard observability (the engine-metrics test at mp=2)
 # ---------------------------------------------------------------------------
 
-def test_engine_metrics_on_the_mp2_virtual_mesh(model, monkeypatch):
+def test_engine_metrics_on_the_mp2_virtual_mesh(model):
     """The PR-2 engine-metrics contract re-proven on the sharded
     engine, plus the mesh-info gauge and shard-labeled pool series;
     merge_snapshots folds two shards' snapshots EXACTLY (side-by-side
     series, summed counters)."""
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
     rng = np.random.RandomState(5)
     reqs = [(rng.randint(0, VOCAB, rng.randint(2, 9)).astype(np.int32),
              int(rng.randint(2, 6))) for _ in range(4)]
@@ -375,12 +343,10 @@ def test_engine_metrics_on_the_mp2_virtual_mesh(model, monkeypatch):
 # satellite: bench row (CI-scale runner + suite registration)
 # ---------------------------------------------------------------------------
 
-def test_offered_load_mp2_bench_row(monkeypatch):
+def test_offered_load_mp2_bench_row():
     """The gpt_engine_offered_load_mp2 SUITE_ROWS runner at test
     scale: serves the same trace at mp=1 then mp=2, asserts the
     outputs identical inside the runner, and records both tokens/s."""
-    monkeypatch.delenv("PADDLE_SERVE_MP", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     import bench_ops
     from paddle_tpu.models import GPTConfig
 
@@ -389,8 +355,7 @@ def test_offered_load_mp2_bench_row(monkeypatch):
     paddle.seed(0)
     rec = bench_ops._engine_offered_load_case(
         model_cfg=cfg, requests=[(3, 4), (6, 4), (10, 3)],
-        num_slots=2, block_size=4, prefill_buckets=(4, 8, 16, 32),
-        mp_degree=2)()
+        num_slots=2, block_size=4, mp_degree=2)()
     assert rec["mp_degree"] == 2 and rec["devices"] == 2
     assert rec["tokens_per_s"] > 0 and rec["tokens_per_s_mp1"] > 0
     assert rec["requests"] == 3

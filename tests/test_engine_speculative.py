@@ -10,8 +10,8 @@ only wastes verify columns. Plus: `decode_traces == 1` per
 into shared/registered prefix blocks COW-promote first (cached KV
 byte-identical via `dense_gather_reference`, rollback never resurrects
 a shared block); multi-token TPOT/accepted-tokens accounting; K=0
-building today's decode step bit-for-bit; the `PADDLE_SPEC_DECODE_K`
-env override; and the n-gram drafter's lookup mechanics.
+building today's decode step bit-for-bit; and the n-gram drafter's
+lookup mechanics.
 """
 import numpy as np
 import pytest
@@ -130,17 +130,15 @@ def _run_trace(eng, reqs, midrun=True):
 
 
 @pytest.mark.parametrize("backend", ["dense", "pallas"])
-def test_spec_token_identical_across_modes(model, monkeypatch, backend):
+def test_spec_token_identical_across_modes(model, backend):
     """THE acceptance gate: one mixed trace (repetitive prompts the
     n-gram drafter hits, shared prefixes, a block-aligned full-prefix
     hit, mid-run admissions) through the speculative engine in
-    (a) chunked + prefix cache, cold, (b) same engine warm,
-    (c) legacy bucketed prefill — all token-identical to the
+    (a) prefix cache cold, (b) same engine warm
+    — all token-identical to the
     single-request oracle, under both paged-attention backends, with
     decode_traces == 1 per (backend, K) and steady state retracing
     NOTHING."""
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     rng = np.random.RandomState(11)
     base = _trace(rng, 4)
     motif = rng.randint(0, VOCAB, 4)
@@ -153,33 +151,25 @@ def test_spec_token_identical_across_modes(model, monkeypatch, backend):
     ]
     K = 2
 
-    def mk(**kw):
-        return GenerationEngine(model, num_slots=3, block_size=4,
-                                num_blocks=64, spec_decode_k=K,
-                                attention_backend=backend, **kw)
-
-    eng = mk(prefill_chunk=8)
+    eng = GenerationEngine(model, num_slots=3, block_size=4,
+                           num_blocks=64, spec_decode_k=K,
+                           attention_backend=backend, prefill_chunk=8)
     outs_cold = _run_trace(eng, reqs)
     outs_warm = _run_trace(eng, reqs, midrun=False)   # same engine
-    eng_bucketed = mk(prefill_buckets=(16, 64))
-    outs_bucketed = _run_trace(eng_bucketed, reqs)
 
-    for (p, n), a, b, c in zip(reqs, outs_cold, outs_warm,
-                               outs_bucketed):
+    for (p, n), a, b in zip(reqs, outs_cold, outs_warm):
         want = _reference(model, p, n)
         np.testing.assert_array_equal(a, want)
         np.testing.assert_array_equal(b, want)
-        np.testing.assert_array_equal(c, want)
 
     # the warm pass re-served the prompts from the prefix cache
     assert eng.prefix_hit_tokens > 0
     # ONE verify program per (backend, K) across all of that churn;
-    # prefill traces stay bounded by the chunk shape (1) / bucket count
-    for e in (eng, eng_bucketed):
-        assert e.decode_traces == 1
-        assert e._decode_pure.__name__ == "engine_verify_step"
+    # prefill traces stay bounded by the chunk shape (1)
+    assert eng.decode_traces == 1
+    assert eng._decode_pure.__name__ == "engine_verify_step"
+    assert isinstance(eng.drafter, NgramDrafter)
     assert eng.prefill_traces == 1
-    assert eng_bucketed.prefill_traces <= 2   # one per bucket hit
     # steady state: a warmed speculative engine retraces NOTHING
     with jit.expect_traces(eng._decode_pure, 0), \
             jit.expect_traces(eng._prefill_pure, 0):
@@ -403,8 +393,7 @@ def test_spec_instant_finish_stays_visible(model):
 # satellite: K=0 recovers today's path; env override
 # ---------------------------------------------------------------------------
 
-def test_spec_k0_is_exactly_todays_decode_path(model, monkeypatch):
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
+def test_spec_k0_is_exactly_todays_decode_path(model):
     eng = GenerationEngine(model, num_slots=2, block_size=4,
                            num_blocks=32, prefill_chunk=8,
                            spec_decode_k=0)
@@ -423,14 +412,12 @@ def test_spec_k0_is_exactly_todays_decode_path(model, monkeypatch):
 # satellite: bench row (CI-scale runner + suite registration)
 # ---------------------------------------------------------------------------
 
-def test_speculative_bench_row(monkeypatch):
+def test_speculative_bench_row():
     """The gpt_engine_speculative SUITE_ROWS runner at test scale: the
     record must carry net tokens/s for both K=spec_k and the K=0
     baseline (token-identical outputs — asserted inside the runner),
     accepted-tokens/step >= 1 (every verify step nets a token), and
     the draft hit rate."""
-    monkeypatch.delenv("PADDLE_SPEC_DECODE_K", raising=False)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     import bench_ops
     from paddle_tpu.models import GPTConfig
 
@@ -447,15 +434,7 @@ def test_speculative_bench_row(monkeypatch):
     assert "gpt_engine_speculative" in bench_ops.suite_names()
 
 
-def test_spec_env_override_wins(model, monkeypatch):
-    monkeypatch.setenv("PADDLE_SPEC_DECODE_K", "3")
-    eng = GenerationEngine(model, num_slots=2, block_size=4,
-                           num_blocks=32, prefill_chunk=8,
-                           spec_decode_k=0)
-    assert eng.spec_decode_k == 3
-    assert eng._decode_pure.__name__ == "engine_verify_step"
-    assert isinstance(eng.drafter, NgramDrafter)
-    monkeypatch.setenv("PADDLE_SPEC_DECODE_K", "-1")
+def test_spec_negative_k_is_refused(model):
     with pytest.raises(ValueError, match="spec_decode_k"):
         GenerationEngine(model, num_slots=2, block_size=4,
-                         prefill_chunk=8)
+                         prefill_chunk=8, spec_decode_k=-1)

@@ -51,14 +51,14 @@ def test_engine_parity_midrun_arrivals_and_zero_recompiles(model):
     requests vacate lanes for later arrivals), per-request output
     exactly equal to single-request greedy_decode; (b) steady-state
     decode compiles ONCE across all that churn and prefill compiles
-    once per length bucket — proven by the jit.count_traces probe, not
-    inferred from timing."""
+    once for every prompt length — proven by the jit.count_traces
+    probe, not inferred from timing."""
     rng = np.random.RandomState(0)
     reqs = [(rng.randint(0, VOCAB, rng.randint(1, 8)).astype(np.int32),
              int(rng.randint(3, 10))) for _ in range(8)]
 
     eng = GenerationEngine(model, num_slots=3, block_size=4,
-                           num_blocks=40, prefill_buckets=(8, 16, 64))
+                           num_blocks=40, prefill_chunk=8)
     ids = [eng.add_request(p, n) for p, n in reqs[:4]]
     for _ in range(3):
         eng.step()                      # decode is mid-stream...
@@ -71,19 +71,15 @@ def test_engine_parity_midrun_arrivals_and_zero_recompiles(model):
         assert got.shape == (len(p) + n,)   # no-EOS: exactly max_new
         np.testing.assert_array_equal(got, _reference(model, p, n))
 
-    # every prompt above was < 8 -> ONE bucket; decode traced once
     assert eng.decode_traces == 1
     assert eng.prefill_traces == 1
-    # steady state: further churn in warmed buckets retraces NOTHING
+    # steady state: further churn retraces NOTHING — a prompt longer
+    # than the chunk included (`start`/`plen` are traced)
     with jit.expect_traces(eng._decode_pure, 0), \
             jit.expect_traces(eng._prefill_pure, 0):
         eng.add_request(rng.randint(0, VOCAB, 5), 3)
+        eng.add_request(rng.randint(0, VOCAB, 12), 2)   # two chunks
         eng.run()
-    # a NEW bucket is the one legitimate extra prefill compile
-    eng.add_request(rng.randint(0, VOCAB, 12), 2)     # bucket 16
-    eng.run()
-    assert eng.prefill_traces == 2
-    assert eng.decode_traces == 1                     # still one program
 
 
 def test_engine_eos_early_stop_and_pool_pressure(model):
@@ -101,7 +97,7 @@ def test_engine_eos_early_stop_and_pool_pressure(model):
     # 8 usable blocks x 4 tokens = 32 cached tokens vs 3 slots x 17
     # max demanded: stalls under full occupancy
     eng = GenerationEngine(model, num_slots=3, block_size=4,
-                           num_blocks=9, prefill_buckets=(8, 64))
+                           num_blocks=9)
     reqs = [(rng.randint(0, VOCAB, rng.randint(2, 7)).astype(np.int32),
              int(rng.randint(4, 9))) for _ in range(4)]
     ids = [eng.add_request(p, n) for p, n in reqs]
@@ -120,11 +116,57 @@ def test_engine_eos_early_stop_and_pool_pressure(model):
     assert eng.cache.num_free == eng.cache.num_blocks - 1
 
 
+def test_one_prefill_strategy_compiles_once_for_every_length(model):
+    """Chunked prefill is the engine's prefill: there is no bucketed
+    strategy to select, and a prompt of every length from 1 token to 3
+    chunks goes through ONE compiled program, oracle-exact."""
+    with pytest.raises(TypeError):
+        GenerationEngine(model, prefill_chunk=None)
+    with pytest.raises(TypeError, match="prefill_buckets"):
+        GenerationEngine(model, prefill_buckets=(8, 64))
+    rng = np.random.RandomState(8)
+    eng = GenerationEngine(model, num_slots=3, block_size=4,
+                           prefill_chunk=8, enable_prefix_cache=False)
+    prompts = [rng.randint(0, VOCAB, plen).astype(np.int32)
+               for plen in range(1, 3 * 8 + 1)]
+    ids = [eng.add_request(p, 2) for p in prompts]
+    out = eng.run()
+    assert eng.prefill_traces == 1 and eng.decode_traces == 1
+    for p, rid in list(zip(prompts, ids))[::5]:
+        np.testing.assert_array_equal(np.asarray(out[rid]),
+                                      _reference(model, p, 2))
+
+
+@pytest.mark.parametrize("name,value", [
+    ("PADDLE_SERVE_MP", "2"),
+    ("PADDLE_SERVE_KV_DTYPE", "int8"),
+    ("PADDLE_SERVE_WEIGHT_DTYPE", "int8"),
+    ("PADDLE_SERVE_SAMPLING", "1"),
+    ("PADDLE_SERVE_TRACING", "1"),
+    ("PADDLE_SERVE_ASYNC", "0"),
+    ("PADDLE_PAGED_ATTENTION_BACKEND", "pallas"),
+    ("PADDLE_SPEC_DECODE_K", "3"),
+])
+def test_retired_environment_names_change_nothing(model, monkeypatch,
+                                                  name, value):
+    """The constructor is the one way to set the engine: a variable
+    that used to override it builds the default engine."""
+    monkeypatch.setenv(name, value)
+    eng = GenerationEngine(model, num_slots=2, block_size=4)
+    assert eng.mp_degree == 1 and eng.mesh is None
+    assert eng.kv_dtype is None and eng.weight_dtype is None
+    assert eng.sampling is False
+    assert eng.tracing is False and eng.tracer is None
+    assert eng.async_core is True
+    assert eng.spec_decode_k == 0 and eng.drafter is None
+    assert eng.attention_backend == "dense"       # auto off-TPU
+
+
 def test_engine_deadlock_is_loud(model):
     """A request whose prompt can never fit the pool must fail with
     sizing guidance, not spin forever."""
     eng = GenerationEngine(model, num_slots=2, block_size=4,
-                           num_blocks=3, prefill_buckets=(16, 64))
+                           num_blocks=3)
     eng.add_request(np.arange(12) % VOCAB, 4)     # needs 3 blocks, has 2
     with pytest.raises(RuntimeError, match="grow num_blocks"):
         eng.run()
@@ -152,14 +194,13 @@ def test_paged_attention_step_matches_dense_attention():
     import jax.numpy as jnp
 
     from paddle_tpu.ops.paged_attention import (
-        dense_gather_reference, paged_attention_step,
-        paged_prefill_write)
+        dense_gather_reference, paged_attention_step)
 
     L, nb, bs, H, D = 2, 9, 4, 2, 8
     B, maxb = 3, 4
     rng = np.random.RandomState(7)
-    kpool = jnp.zeros((L, nb, bs, H, D), jnp.float32)
-    vpool = jnp.zeros((L, nb, bs, H, D), jnp.float32)
+    kpool = np.zeros((L, nb, bs, H, D), np.float32)
+    vpool = np.zeros((L, nb, bs, H, D), np.float32)
     # three slots with distinct context depths and disjoint blocks
     plens = [5, 2, 9]
     tables = np.zeros((B, maxb), np.int32)
@@ -169,14 +210,10 @@ def test_paged_attention_step_matches_dense_attention():
     ctx_k = rng.randn(B, maxb * bs, H, D).astype(np.float32)
     ctx_v = rng.randn(B, maxb * bs, H, D).astype(np.float32)
     for b in range(B):                 # seed each slot's prior context
-        ks = np.zeros((L, 1, 16, H, D), np.float32)
-        vs = np.zeros((L, 1, 16, H, D), np.float32)
-        ks[:, 0, :plens[b]] = ctx_k[b, :plens[b]]
-        vs[:, 0, :plens[b]] = ctx_v[b, :plens[b]]
-        kpool, vpool = paged_prefill_write(
-            kpool, vpool, ks, vs, np.asarray(tables[b]),
-            np.int32(plens[b]))
-        kpool, vpool = kpool._array, vpool._array
+        for pos in range(plens[b]):
+            kpool[:, tables[b, pos // bs], pos % bs] = ctx_k[b, pos]
+            vpool[:, tables[b, pos // bs], pos % bs] = ctx_v[b, pos]
+    kpool, vpool = jnp.asarray(kpool), jnp.asarray(vpool)
 
     q = rng.randn(B, 1, H, D).astype(np.float32)
     k_new = rng.randn(B, 1, H, D).astype(np.float32)
@@ -260,12 +297,11 @@ def test_count_traces_probe_and_expect_traces():
             pass
 
 
-def test_engine_offered_load_bench_runner_tiny(monkeypatch):
+def test_engine_offered_load_bench_runner_tiny():
     """The OPBENCH engine row's runner, at test scale: mixed
     prompt/output lengths through the engine, aggregate tokens/s out
     (the TPU run uses the representative 350M defaults)."""
     # isolate from the deploy knob: the default row must resolve auto
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     import bench_ops
 
     model_cfg = GPTConfig.tiny(vocab=32, hidden=16, layers=1, heads=2,
@@ -274,19 +310,18 @@ def test_engine_offered_load_bench_runner_tiny(monkeypatch):
     rec = bench_ops._engine_offered_load_case(
         model_cfg=model_cfg,
         requests=[(3, 4), (6, 4), (10, 5)],
-        num_slots=2, block_size=4, prefill_buckets=(4, 8, 16, 32))()
+        num_slots=2, block_size=4)()
     assert rec["requests"] == 3
     assert rec["tokens_per_s"] > 0 and rec["ms"] > 0
     assert rec["attention_backend"] == "dense"     # auto off-TPU
     # the pallas variant row runs the same trace on the fused kernel
     # (interpreted off-TPU) and must serve every request too; ONE
-    # request/bucket — interpret-mode compiles dominate, and the
+    # request — interpret-mode compiles dominate, and the
     # backend itself is parity-tested in test_paged_attention_backends
     paddle.seed(0)
     rec_p = bench_ops._engine_offered_load_case(
         model_cfg=model_cfg, requests=[(3, 3)],
-        num_slots=1, block_size=4, prefill_buckets=(4, 32),
-        attention_backend="pallas")()
+        num_slots=1, block_size=4, attention_backend="pallas")()
     assert rec_p["attention_backend"] == "pallas"
     assert rec_p["requests"] == 1 and rec_p["tokens_per_s"] > 0
     # names the gate will track are emitted by the suite
@@ -311,7 +346,7 @@ def test_engine_metrics_spans_and_steady_state_recompiles(model):
     reqs = [(rng.randint(0, VOCAB, rng.randint(2, 8)).astype(np.int32),
              int(rng.randint(3, 9))) for _ in range(6)]
     eng = GenerationEngine(model, num_slots=3, block_size=4,
-                           num_blocks=40, prefill_buckets=(8, 64))
+                           num_blocks=40)
     prof = Profiler()
     with prof:
         for p, n in reqs:
@@ -361,12 +396,12 @@ def test_engine_pool_pressure_stall_counter(model):
 
     rng = np.random.RandomState(4)
     # 5 usable blocks, 3 slots: two 6-token prompts occupy 4 blocks;
-    # the third has a free LANE but cannot get its 2 blocks until a
-    # lane finishes — a deterministic admit-path stall with decode
+    # the third has a LANE but cannot get its chunk's 2 blocks until a
+    # lane finishes — a deterministic prefill-path stall with decode
     # still progressing (no deadlock)
     eng = GenerationEngine(model, num_slots=3, block_size=4,
-                           num_blocks=6, prefill_buckets=(8, 64))
-    reqs = [(rng.randint(0, VOCAB, 6).astype(np.int32), 2)
+                           num_blocks=6)
+    reqs = [(rng.randint(0, VOCAB, 6).astype(np.int32), 4)
             for _ in range(3)]
     ids = [eng.add_request(p, n) for p, n in reqs]
     out = eng.run()
@@ -376,12 +411,12 @@ def test_engine_pool_pressure_stall_counter(model):
     snap = eng.metrics_snapshot()
     stalls = {s["labels"]["path"]: s["value"]
               for s in snap["engine_block_stalls_total"]["series"]}
-    assert stalls.get("admit", 0) >= 1
+    assert stalls.get("prefill", 0) >= 1
     assert series_total(snap, "engine_block_stalls_total") > 0
     assert series_total(snap, "engine_decode_recompiles_total") == 0
-    # pressure showed up as pool saturation at the admission peak
+    # pressure showed up as pool saturation at the peak
     assert snap["engine_pool_used_high_water_blocks"]["series"][0][
-        "value"] == 4
+        "value"] == 5
     assert snap["engine_pool_used_blocks"]["series"][0]["value"] == 0
 
     # the engine registry speaks prometheus end-to-end

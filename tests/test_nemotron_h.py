@@ -313,8 +313,6 @@ def test_engine_counts_the_experts_load_and_the_state_rows():
 @pytest.mark.parametrize("kwargs,feature", [
     (dict(enable_prefix_cache=True), "prefix_cache"),
     (dict(spec_decode_k=2), "spec_decode"),
-    (dict(prefill_buckets=(16, 64), prefill_chunk="auto"),
-     "bucketed_prefill"),
     (dict(kv_dtype="int8"), "kv_int8"),
     (dict(weight_dtype="int8"), "weight_int8"),
 ])
@@ -532,12 +530,14 @@ def test_dropless_dispatch_and_grouped_matmul(crowded):
 
 def test_engine_source_names_no_architecture():
     """C1's "done when": the engine schedules, allocates and dispatches,
-    and what it knows of a model is its serving spec."""
+    and what it knows of a model is its serving spec. C3's: what it
+    knows of its options is its constructor's arguments."""
     import inspect
 
     from paddle_tpu.inference import engine
 
     source = inspect.getsource(engine).lower()
     for word in ("gpt", "nemotron", "mamba", "cfg.num_heads",
-                 "model.config"):
+                 "model.config", "os.environ", "paddle_serve_",
+                 "paddle_paged_attention_backend", "paddle_spec_decode_k"):
         assert word not in source, word

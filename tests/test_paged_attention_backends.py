@@ -340,12 +340,11 @@ def test_pages_per_step_follows_from_shapes_alone(model, monkeypatch):
             held = 4 * n * block * heads * head_dim * jnp.dtype(dt).itemsize
             assert n == 1 or held <= kernels._WALK_VMEM_BYTES
 
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     for backend, want in (
             ("pallas", kernels.pages_per_step(4, 2, 16, jnp.float32)),
             ("dense", 0)):
         eng = GenerationEngine(model, num_slots=2, block_size=4,
-                               num_blocks=20, prefill_buckets=(8, 64),
+                               num_blocks=20,
                                attention_backend=backend)
         (series,) = eng.metrics_snapshot()[
             "engine_paged_decode_pages_per_step"]["series"]
@@ -422,7 +421,7 @@ def _lockstep_engines(model, **kw):
             for b in ("dense", "pallas")}
 
 
-def test_engine_run_token_exact_across_backends(model, monkeypatch):
+def test_engine_run_token_exact_across_backends(model):
     """The tentpole acceptance: a full engine run — mid-run admissions,
     an EOS early-stop, finished lanes vacated for later arrivals — is
     TOKEN-EXACT between the dense and pallas (interpret) backends, each
@@ -432,9 +431,6 @@ def test_engine_run_token_exact_across_backends(model, monkeypatch):
     from paddle_tpu.ops.paged_attention import (
         PAGED_PATH_STATS, dense_gather_reference, reset_paged_path_stats)
 
-    # the deploy knob must not silently collapse both engines onto one
-    # backend (env wins over the constructor by design)
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
     rng = np.random.RandomState(0)
     reqs = [(rng.randint(0, VOCAB, rng.randint(1, 8)).astype(np.int32),
              int(rng.randint(3, 10))) for _ in range(8)]
@@ -445,8 +441,7 @@ def test_engine_run_token_exact_across_backends(model, monkeypatch):
 
     reset_paged_path_stats()
     engines = _lockstep_engines(model, num_slots=3, block_size=4,
-                                num_blocks=40,
-                                prefill_buckets=(8, 16, 64))
+                                num_blocks=40)
     ids = {}
     for b, eng in engines.items():
         ids[b] = [eng.add_request(p, n) for p, n in reqs[:4]]
@@ -498,14 +493,12 @@ def test_engine_run_token_exact_across_backends(model, monkeypatch):
                                       _reference(model, p, n))
 
 
-def test_engine_backend_metrics_and_env_override(model, monkeypatch):
+def test_engine_backend_metrics_and_bad_value(model):
     """The kernel-backend gauge + per-backend decode-span labels land
-    in the engine's registry; PADDLE_PAGED_ATTENTION_BACKEND overrides
-    the constructor; `auto` resolves to dense off-TPU; bad values are
-    rejected loudly."""
-    monkeypatch.delenv("PADDLE_PAGED_ATTENTION_BACKEND", raising=False)
+    in the engine's registry; `auto` resolves to dense off-TPU; bad
+    values are rejected loudly."""
     eng = GenerationEngine(model, num_slots=2, block_size=4,
-                           num_blocks=20, prefill_buckets=(8, 64),
+                           num_blocks=20,
                            attention_backend="pallas")
     assert eng.attention_backend == "pallas"
     eng.add_request([1, 2, 3], 4)
@@ -522,18 +515,12 @@ def test_engine_backend_metrics_and_env_override(model, monkeypatch):
     assert 'engine_decode_step_seconds_bucket{backend="pallas"' in text
 
     # off-TPU `auto` resolves dense (the DESIGN_DECISIONS crossover)
-    auto = GenerationEngine(model, num_slots=2, prefill_buckets=(8, 64))
+    auto = GenerationEngine(model, num_slots=2)
     assert auto.attention_backend == "dense"
     assert auto.attention_backend_requested == "auto"
 
-    monkeypatch.setenv("PADDLE_PAGED_ATTENTION_BACKEND", "pallas")
-    over = GenerationEngine(model, num_slots=2, prefill_buckets=(8, 64),
-                            attention_backend="dense")
-    assert over.attention_backend == "pallas"    # env wins: deploy knob
-
-    monkeypatch.setenv("PADDLE_PAGED_ATTENTION_BACKEND", "cuda")
     with pytest.raises(ValueError, match="backend"):
-        GenerationEngine(model, num_slots=2, prefill_buckets=(8, 64))
+        GenerationEngine(model, num_slots=2, attention_backend="cuda")
 
 
 # -- CI / tooling satellites ----------------------------------------------

@@ -150,18 +150,15 @@ def test_n_replica_fleet_per_request_identical(model, n_replicas):
 
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
-@pytest.mark.parametrize("bucketed", [False, True])
-def test_disaggregated_fleet_token_exact(model, kv_dtype, bucketed):
+def test_disaggregated_fleet_token_exact(model, kv_dtype):
     """The ambitious end state: dedicated prefill replicas hand
     finished KV blocks (+ int8 scale rows) into a decode replica's
     pool via the compiled export/ingest path, and the output stays
     EXACTLY what a colocated engine of the same config produces —
-    both prefill modes, fp and quantized pools."""
+    fp and quantized pools."""
     rng = np.random.RandomState(2)
     trace = _mixed_trace(rng, n=6)
     kw = {"kv_dtype": kv_dtype}
-    if bucketed:
-        kw["prefill_buckets"] = (16, 64)
     ref = _serve_engine(model, trace, eos=5, **kw)
     fleet, got = _serve_fleet(
         model, trace, eos=5,

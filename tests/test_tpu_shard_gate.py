@@ -1,4 +1,4 @@
-"""Tier-1 tpu-shard gate: the full 44-program harvest runs self-clean
+"""Tier-1 tpu-shard gate: the full 38-program harvest runs self-clean
 against the committed SHARD_BASELINE.json through the real CLI, the
 two flagship rules (TPU301 undeclared-resharding, TPU302
 replicated-large-buffer) are proven against deliberately broken
@@ -82,7 +82,7 @@ def test_cli_acceptance_command_exits_zero():
          os.path.join(REPO, "paddle_tpu")],
         env=_env(), capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stdout + res.stderr
-    assert "tpu-shard clean: 44 programs" in res.stdout
+    assert "tpu-shard clean: 38 programs" in res.stdout
 
 
 def test_shard_baseline_is_committed_and_covers_the_matrix():
@@ -94,9 +94,9 @@ def test_shard_baseline_is_committed_and_covers_the_matrix():
     matches these totals exactly."""
     with open(DEFAULT_SHARD_BASELINE) as f:
         snap = json.load(f)["programs"]
-    assert len(snap) == 44
+    assert len(snap) == 38
     moving = {k for k, v in snap.items() if v["axes"]}
-    assert len(moving) == 14
+    assert len(moving) == 12
     for key in moving:
         assert "mp=2" in key, key
         assert set(snap[key]["axes"]) == {"mp"}
@@ -189,7 +189,7 @@ def test_axis_budget_table_pins_real_surfaces(tiny_mp2_engine):
     budget = introspect.GPT_SERVING_AXIS_BUDGET
     assert gpt.GPT_SERVING_COLLECTIVES is budget
     for step in ("engine_decode_step", "engine_verify_step",
-                 "engine_prefill", "engine_prefill_chunk"):
+                 "engine_prefill_chunk"):
         assert resolve_budget(T.get_contract(step)) is budget
     assert budget.axis_names() == ("mp",)
     assert budget.link_of("mp") == "ici"
@@ -201,13 +201,12 @@ def test_axis_budget_table_pins_real_surfaces(tiny_mp2_engine):
         assert bounds, kind
         assert all(eval_payload(b, geom) > 0 for b in bounds), kind
     # the TPU104 count surface, unchanged through the refactor: 9
-    # gathers (4/layer x 2 + 1 lm-head), 1 psum, 3 pmax at L=2
+    # gathers (4/layer x 2 + 1 lm-head), 1 psum, 2 pmax at L=2
     assert budget.allowed("all_gather", 2) == 9
     assert budget.allowed("psum", 2) == 1
-    assert budget.allowed("pmax", 2) == 3
+    assert budget.allowed("pmax", 2) == 2
     assert dict(budget.per_layer) == {"all_gather": 4, "pmax": 1}
-    assert dict(budget.fixed) == {"all_gather": 1, "psum": 1,
-                                  "pmax": 1}
+    assert dict(budget.fixed) == {"all_gather": 1, "psum": 1}
 
 
 def test_per_token_contracts_mark_the_decode_loop():
@@ -216,7 +215,6 @@ def test_per_token_contracts_mark_the_decode_loop():
     per_token; prefills and the COW copy run per admission."""
     for step, hot in (("engine_decode_step", True),
                       ("engine_verify_step", True),
-                      ("engine_prefill", False),
                       ("engine_prefill_chunk", False),
                       ("engine_cow_copy", False)):
         assert T.get_contract(step).per_token is hot, step

@@ -65,29 +65,29 @@ def test_matrix_is_contract_clean(matrix_result):
     assert new == [], "tpu-verify findings:\n" + "\n".join(
         f.render() for f in new)
     # the matrix must actually cover the serving stack: the 16
-    # backend/K/kv-divergent decode/verify steps plus the 12 per-
+    # backend/K/kv-divergent decode/verify steps plus the 8 per-
     # (mp, kv_dtype) backend-invariant programs, every contract seen
     # — the kv=int8 half is the PR-11 quantized serving config (int8
-    # per-block-scaled KV pools + int8 weights) — plus the 4 PR-13
+    # per-block-scaled KV pools + int8 weights) — plus the 3 PR-13
     # adapter-threaded programs (LORA_CONFIGS: a plain fp mp=1
-    # decode + both prefills, and the composed
-    # pallas/K=4/mp=2/int8 verify step) — plus the 4 PR-15
+    # decode + its prefill chunk, and the composed
+    # pallas/K=4/mp=2/int8 verify step) — plus the 3 PR-15
     # sampling-threaded programs (SAMPLING_CONFIGS: a plain fp mp=1
-    # sampled decode + both sampled prefills, and the composed
+    # sampled decode + its sampled prefill chunk, and the composed
     # pallas/K=4/mp=2/int8 rejection-sampling verify step) — plus the
     # 4 PR-14 fused Pallas conv programs (both kernel families x
     # stride) — plus the 4 PR-16 backward programs (the train-mode
     # custom_vjp grad jaxprs, both families x stride; TPU103 must
     # walk the fused dInput/dWeight kernels too)
-    assert len(res.programs) == 44
-    assert sum(",int8" in p.config for p in res.programs) == 16
-    assert sum(",lora" in p.config for p in res.programs) == 4
-    assert sum(",sampling" in p.config for p in res.programs) == 4
+    assert len(res.programs) == 38
+    assert sum(",int8" in p.config for p in res.programs) == 14
+    assert sum(",lora" in p.config for p in res.programs) == 3
+    assert sum(",sampling" in p.config for p in res.programs) == 3
     assert sum(p.contract.name.startswith("conv_bn_relu")
                for p in res.programs) == 8
     names = {p.contract.name for p in res.programs}
     assert names == {"engine_decode_step", "engine_verify_step",
-                     "engine_prefill", "engine_prefill_chunk",
+                     "engine_prefill_chunk",
                      "engine_cow_copy", "conv_bn_relu_1x1",
                      "conv_bn_relu_3x3", "conv_bn_relu_1x1_bwd",
                      "conv_bn_relu_3x3_bwd"}
@@ -108,8 +108,8 @@ def test_engine_consumes_introspect_donation_table(tiny_mp2_engine):
     must consume it, not restate magic argnums."""
     eng = tiny_mp2_engine
     assert eng._donate_argnums == introspect.ENGINE_STEP_DONATE_ARGNUMS
-    for step in ("engine_prefill", "engine_prefill_chunk",
-                 "engine_decode_step", "engine_verify_step"):
+    for step in ("engine_prefill_chunk", "engine_decode_step",
+                 "engine_verify_step"):
         assert introspect.ENGINE_STEP_DONATION[step] == \
             introspect.ENGINE_STEP_DONATE_ARGNUMS
         assert T.get_contract(step).donate_argnums == \
@@ -235,14 +235,12 @@ def test_sharded_engine_still_token_exact_after_donation_fix():
 def test_harvest_accepts_legacy_matrix_shapes():
     """Pre-sampling callers hold 3/4/5-tuple explicit matrix entries:
     the normalizer must pad the MISSING trailing fields with their
-    defaults (kv=None, lora=False, sampling=False) — positional
-    slicing once handed a 5-tuple samp=None and tripped the
-    PADDLE_SERVE_SAMPLING leak guard on a clean environment."""
+    defaults (kv=None, lora=False, sampling=False)."""
     from paddle_tpu.analysis.trace.harvest import harvest
 
     programs = harvest(matrix=(("dense", 0, 1, None, False),))
-    # a dense K=0 mp=1 fp config: decode + both prefills + cow
-    assert len(programs) == 4
+    # a dense K=0 mp=1 fp config: decode + prefill chunk + cow
+    assert len(programs) == 3
     assert all(",sampling" not in p.config for p in programs)
 
 
@@ -255,4 +253,4 @@ def test_cli_acceptance_command_exits_zero():
         [sys.executable, os.path.join(REPO, "tools", "tpu_verify.py")],
         env=env, capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stdout + res.stderr
-    assert "tpu-verify clean: 44 programs" in res.stdout
+    assert "tpu-verify clean: 38 programs" in res.stdout

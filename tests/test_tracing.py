@@ -223,14 +223,11 @@ def test_merge_trace_events_repids_and_names():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("K", [0, 4])
-def test_tracing_off_is_token_identical_and_traces_hold(model,
-                                                        monkeypatch,
-                                                        K):
+def test_tracing_off_is_token_identical_and_traces_hold(model, K):
     """THE acceptance gate: tracing never changes tokens (host-side
     only, the sampling=False precedent) and `decode_traces == 1`
     holds with tracing ON — the spans ride outside the compiled
     programs."""
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
     reqs = _trace(3)
 
     def mk(on):
@@ -254,23 +251,12 @@ def test_tracing_off_is_token_identical_and_traces_hold(model,
         == eng_on.tracer.total_recorded
 
 
-def test_tracing_env_knob_wins(model, monkeypatch):
-    monkeypatch.setenv("PADDLE_SERVE_TRACING", "1")
-    assert GenerationEngine(model, num_slots=2,
-                            block_size=8).tracer is not None
-    monkeypatch.setenv("PADDLE_SERVE_TRACING", "0")
-    assert GenerationEngine(model, num_slots=2, block_size=8,
-                            tracing=True).tracer is None
-
-
 @pytest.mark.parametrize("K", [0, 4])
-def test_host_gap_histogram_and_device_fraction(model, monkeypatch,
-                                                K):
+def test_host_gap_histogram_and_device_fraction(model, K):
     """The measured baseline for ROADMAP item 3: every step folds its
     phase clock into `engine_step_host_gap_seconds{phase}` — tracing
     knob OFF (the histogram is always on) — and the device fraction
     is a real fraction."""
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
     eng = GenerationEngine(model, num_slots=2, block_size=8,
                            spec_decode_k=K)
     assert eng.tracer is None
@@ -289,9 +275,7 @@ def test_host_gap_histogram_and_device_fraction(model, monkeypatch,
     assert 0.0 <= frac <= 1.0
 
 
-def test_request_lifecycle_spans_share_one_trace_id(model,
-                                                    monkeypatch):
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
+def test_request_lifecycle_spans_share_one_trace_id(model):
     eng = GenerationEngine(model, num_slots=2, block_size=8,
                            tracing=True)
     reqs = _trace(5, n=3)
@@ -314,8 +298,7 @@ def test_request_lifecycle_spans_share_one_trace_id(model,
     assert any(e.get("cat") == "phase" for e in events)
 
 
-def test_flight_recorder_lifecycle_and_shed(model, monkeypatch):
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
+def test_flight_recorder_lifecycle_and_shed(model):
     eng = GenerationEngine(model, num_slots=1, block_size=8,
                            max_queue=1)
     reqs = _trace(7, n=3)
@@ -328,11 +311,9 @@ def test_flight_recorder_lifecycle_and_shed(model, monkeypatch):
     assert "shed" in events      # max_queue=1 shed the overflow
 
 
-def test_drain_leak_audit_attaches_flight_recorder(model,
-                                                   monkeypatch):
+def test_drain_leak_audit_attaches_flight_recorder(model):
     """The postmortem contract: a failed leak audit arrives WITH the
     recent request history, not as a bare assertion."""
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
     eng = GenerationEngine(model, num_slots=2, block_size=8)
     _serve(eng, _trace(2, n=2))
     eng.cache.allocate(1)              # drop a block on the floor
@@ -351,7 +332,6 @@ def test_export_trace_merges_profiler_stream(model, monkeypatch,
     re-pidded track groups (same monotonic clock, no offsets)."""
     from paddle_tpu.profiler.profiler import _recorder
 
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
     eng = GenerationEngine(model, num_slots=2, block_size=8,
                            tracing=True)
     monkeypatch.setattr(_recorder, "enabled", True)
@@ -378,8 +358,7 @@ def test_export_trace_merges_profiler_stream(model, monkeypatch,
             .export_trace(str(tmp_path / "nope.json"))
 
 
-def test_trace_ring_bound_holds_under_load(model, monkeypatch):
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
+def test_trace_ring_bound_holds_under_load(model):
     eng = GenerationEngine(model, num_slots=2, block_size=8,
                            tracing=True, trace_capacity=16)
     _serve(eng, _trace(6, n=4))
@@ -394,14 +373,11 @@ def test_trace_ring_bound_holds_under_load(model, monkeypatch):
 # fleet: trace context across replicas
 # ---------------------------------------------------------------------------
 
-def test_disaggregated_handoff_exports_single_timeline(model,
-                                                       monkeypatch,
-                                                       tmp_path):
+def test_disaggregated_handoff_exports_single_timeline(model, tmp_path):
     """THE cross-replica gate: a disaggregated request's routing,
     prefill, handoff export/ingest, and decode spans share ONE trace
     id across the router's and both replicas' track groups — one
     Perfetto file shows the request crossing engines."""
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
     fleet = ServingFleet(model, num_replicas=1,
                          num_prefill_replicas=1, num_slots=2,
                          block_size=8, tracing=True)
@@ -433,8 +409,7 @@ def test_disaggregated_handoff_exports_single_timeline(model,
     assert len(pids) >= 3
 
 
-def test_fleet_route_spans_annotate_affinity(model, monkeypatch):
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
+def test_fleet_route_spans_annotate_affinity(model):
     fleet = ServingFleet(model, num_replicas=2, num_slots=2,
                          block_size=8, tracing=True)
     rng = np.random.RandomState(1)
@@ -450,12 +425,11 @@ def test_fleet_route_spans_annotate_affinity(model, monkeypatch):
     assert routes[1]["args"]["affinity_tokens"] > 0
 
 
-def test_fleet_folds_host_gap_and_trace_series(model, monkeypatch):
+def test_fleet_folds_host_gap_and_trace_series(model):
     """PR 12's fold contract re-proven with the NEW series present:
     replica-labeled `engine_step_host_gap_seconds{phase}` buckets sum
     exactly across a 2-replica fleet, trace counters fold, and an
     unlabeled collision still raises."""
-    monkeypatch.delenv("PADDLE_SERVE_TRACING", raising=False)
     fleet = ServingFleet(model, num_replicas=2, num_slots=2,
                          block_size=8, tracing=True)
     reqs = _trace(9, n=4)

@@ -810,6 +810,9 @@ class _InFlight:
     slots: list                        # the _Slot objects, snapshotted
     drafts: dict = None                # lane -> draft (verify steps)
     counters: object = None            # the step's counters, on device
+    first: object = None               # a decode step that carried a
+    #                                    chunk: the record of the first
+    #                                    token of the prompt it ended
     t_dec: float = 0.0                 # perf_counter at dispatch
     t_span: int = 0                    # now_us at schedule end
     seq: int = 0                       # pipeline sequence number
@@ -1029,6 +1032,23 @@ class GenerationEngine:
         self._prefill = jax.jit(self._prefill_pure,
                                 donate_argnums=self._donate_argnums,
                                 out_shardings=self._step_out_shardings(1))
+        # a model whose spec offers one step for a chunk's rows and the
+        # decode rows together: the ahead order launches it in place of
+        # the two programs in an iteration that holds both kinds of work
+        # (`_step_ahead`). It is a decode step too: `decode_traces`
+        # counts its traces.
+        self._decode_pures = [self._decode_pure]
+        self._fused = None
+        if spec.offers_decode_with_chunk and self._goes_ahead:
+            if self.kv_dtype == "int8" or self._mp_axis is not None \
+                    or self.adapter_pool is not None:
+                raise ValueError(
+                    "`decode_with_chunk` takes neither int8 KV, a mesh "
+                    "nor adapters: a spec that offers it refuses them")
+            pure = count_traces(self._build_decode_with_chunk())
+            self._decode_pures.append(pure)
+            self._fused = jax.jit(pure,
+                                  donate_argnums=self._donate_argnums)
         # copy-on-write promotion: one tiny compiled gather/scatter,
         # traced src/dst so every COW reuses the same program
         cow = count_traces(copy_pool_block)
@@ -1631,7 +1651,7 @@ class GenerationEngine:
             "paged-attention backend.", labelnames=("backend",),
             buckets=LATENCY_BUCKETS).labels(
                 backend=self.attention_backend)
-        self._decode_traces_seen = 0
+        self._decode_retraces_seen = 0
         self._m_steps_ahead = m.counter(
             "engine_decode_steps_ahead_total",
             "Decode steps launched while the previous decode step was "
@@ -1779,12 +1799,11 @@ class GenerationEngine:
     def _sample_traces(self):
         """Mirror the count_traces probes into metrics; a decode trace
         beyond the first is a recompile (the ==0 steady-state SLO)."""
-        t = self._decode_pure.traces
-        if t > self._decode_traces_seen:
-            if self._decode_traces_seen >= 1:
-                self._m_recompiles.inc(t - self._decode_traces_seen)
-            self._decode_traces_seen = t
-        self._m_decode_traces.set(t)
+        again = sum(max(p.traces - 1, 0) for p in self._decode_pures)
+        if again > self._decode_retraces_seen:
+            self._m_recompiles.inc(again - self._decode_retraces_seen)
+            self._decode_retraces_seen = again
+        self._m_decode_traces.set(self.decode_traces)
         self._m_prefill_traces.set(self._prefill_pure.traces)
 
     def metrics_snapshot(self):
@@ -2026,40 +2045,77 @@ class GenerationEngine:
                     mp_axis=mp_axis,
                     kv_scales=None if scales is None
                     else Tensor._wrap(scales), lora=lora, **kw)
-                # the LAST REAL prompt position's logits yield the
-                # first generated token; it lives in the final chunk —
-                # for earlier chunks the one-hot selects nothing and
-                # the host ignores the returned token
-                sel = (start + jnp.arange(C) == plen - 1) \
-                    .astype(r.hidden._array.dtype)
-                h_last = (r.hidden._array * sel[None, :, None]) \
-                    .sum(axis=1, keepdims=True)
-                logits = spec.logits(Tensor._wrap(h_last),
-                                     mp_axis=mp_axis)
-                if use_s:
-                    # the first generated token's draw folds plen-1
-                    # (it lands at position plen) — identical to the
-                    # full-prefix-hit decode's key for that token
-                    from paddle_tpu.ops.sampling import sample_token
-
-                    nxt = sample_token(
-                        logits._array[:, 0], temps, tks, tps, krows,
-                        jnp.maximum(plen - 1, 0).reshape(1))[0]
-                else:
-                    nxt = jnp.argmax(logits._array[0, 0]) \
-                        .astype(jnp.int32)
+                nxt = _last_prompt_row_token(
+                    spec, r.hidden, start, plen, C,
+                    (temps, tks, tps, krows) if use_s else None,
+                    mp_axis=mp_axis)
                 return self._step_outputs((nxt,), r)
 
         prefill_chunk_fn.__name__ = "engine_prefill_chunk"
         return self._shard_steps(prefill_chunk_fn,
                                  n_repl=8 if use_s else 4)
 
+    def _build_decode_with_chunk(self):
+        """One program for an iteration's prefill chunk AND its decode
+        step (`ServingSpec.decode_with_chunk`): the chunk's host args,
+        then the decode step's, each as its own program takes them; it
+        leads with the chunk's token and the decode rows' tokens, both
+        drawn as the two programs draw them."""
+        state = self._state
+        spec = self.spec
+        C = self.prefill_chunk
+        backend = self.attention_backend
+        use_s = self.sampling
+        n_c = 8 if use_s else 4
+        stateful = bool(self.cache.state)
+
+        def fused_fn(state_arrays, kpool, vpool, *rest):
+            kw, rest = self._state_args(rest)
+            chunk, rest = rest[:n_c], rest[n_c:]
+            if stateful:
+                # the chunk's row of state trails its args, the lanes'
+                # rows the decode step's
+                kw.update(state_row=rest[0], state_rows=rest[-1])
+                rest = rest[1:-1]
+            chunk_tokens, start, plen, table_row = chunk[:4]
+            tokens, positions, tables = rest[:3]
+            arrays = self._materialize_state(state_arrays)
+            with bound_state(zip(state, arrays), state):
+                r, hidden = spec.decode_with_chunk(
+                    Tensor._wrap(chunk_tokens), Tensor._wrap(start),
+                    Tensor._wrap(table_row), Tensor._wrap(plen),
+                    Tensor._wrap(tokens), Tensor._wrap(positions),
+                    Tensor._wrap(tables), Tensor._wrap(kpool),
+                    Tensor._wrap(vpool), backend=backend, **kw)
+                first = _last_prompt_row_token(
+                    spec, r.hidden, start, plen, C,
+                    chunk[4:] if use_s else None)
+                logits = spec.logits(hidden)._array[:, 0]
+                if use_s:
+                    from paddle_tpu.ops.sampling import sample_token
+
+                    nxt = sample_token(logits, *rest[3:], positions)
+                else:
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return self._step_outputs((first, nxt), r)
+
+        fused_fn.__name__ = "engine_decode_step_with_chunk"
+        return fused_fn
+
     # -- recompile probes (CI contract) ------------------------------------
     @property
     def decode_traces(self):
         """Times the decode step traced. Steady-state contract: 1,
-        regardless of arrivals/evictions."""
-        return self._decode_pure.traces
+        regardless of arrivals/evictions — and 1 more where the model
+        offers a decode step that carries a chunk, once an iteration
+        has held both."""
+        return sum(p.traces for p in self._decode_pures)
+
+    @property
+    def decode_steps_with_chunk(self):
+        """Decode steps read so far that carried a prefill chunk's rows:
+        the spec's step counter of that name (0 where it names none)."""
+        return self.step_counter_totals.get("decode_steps_with_chunk", 0)
 
     @property
     def prefill_traces(self):
@@ -2469,24 +2525,53 @@ class GenerationEngine:
 
     def _prefill_step(self):
         """Run at most ONE compiled prefill chunk: pick the neediest
-        prefilling lane (priority, then admission order), allocate the
-        chunk's blocks (evicting cold cache blocks if necessary), and
-        push `prefill_chunk` prompt positions through the fixed-shape
-        chunk program. The final chunk yields the first generated
-        token. A lane that cannot get blocks stalls and the next
-        candidate gets the chunk."""
+        prefilling lane (`_chunk_schedule`) and push `prefill_chunk`
+        prompt positions through the fixed-shape chunk program. The
+        final chunk yields the first generated token."""
+        chunk = self._chunk_schedule()
+        if chunk is None:
+            return 0
+        self._chunk_dispatch(*chunk)
+        return 1
+
+    def _chunk_dispatch(self, slot, start, end):
+        """Launch the chunk program over `_chunk_schedule`'s pick."""
+        t_span = now_us()
+        with self._phase("dispatch"):
+            args = self._chunk_args(slot, start, end)
+            if self.adapter_pool is not None:
+                # the chunk serves ONE slot: its adapter page,
+                # [1]-row
+                args.append(jnp.asarray(
+                    np.asarray([slot.adapter_page], np.int32)))
+            with RecordEvent("engine.prefill"):
+                t0 = time.perf_counter()
+                nxt = self._dispatch_step(self._prefill, *args)
+        first = self._chunk_launched(slot, start, end, nxt, t0, t_span)
+        # the prompt's last chunk: its output is the request's first
+        # token. The serial order reads it here; the ahead order feeds
+        # this iteration's decode step from it where it lies and reads
+        # it after that launch (`_step_ahead`).
+        if self._goes_ahead:
+            self._first = first
+        elif first is not None:
+            self._first_complete(first)
+
+    def _chunk_schedule(self):
+        """The lane that gets this iteration's chunk, by priority, then
+        admission order, with the chunk's blocks allocated (evicting
+        cold cache blocks if necessary): `(slot, start, end)`, or None.
+        A lane that cannot get blocks stalls and the next candidate
+        gets the chunk."""
         with self._phase("schedule"):
             cands = [s for s in self._slots
                      if s is not None and s.prefilling]
             cands.sort(key=lambda s: (
                 PRIORITY_CLASSES.index(s.req.priority), s.admit_seq))
-        C = self.prefill_chunk
-        for slot in cands:
-            req = slot.req
-            plen = int(req.prompt.size)
-            start = slot.prefill_pos
-            end = min(start + C, plen)
-            with self._phase("schedule"):
+            for slot in cands:
+                start = slot.prefill_pos
+                end = min(start + self.prefill_chunk,
+                          int(slot.req.prompt.size))
                 need = math.ceil(end / self.block_size) \
                     - len(slot.blocks)
                 if need > 0:
@@ -2494,52 +2579,48 @@ class GenerationEngine:
                     if got is None:
                         self._m_stalls.labels(
                             path="prefill", shard=self._shard).inc()
-                        self.flight.record("stall", req.req_id,
+                        self.flight.record("stall", slot.req.req_id,
                                            path="prefill")
                         continue       # pool pressure: next candidate
                     slot.blocks.extend(got)
                     self._update_pool_gauges()
-            t_span = now_us()
-            with self._phase("dispatch"):
-                tokens = np.zeros((1, C), np.int32)
-                tokens[0, :end - start] = req.prompt[start:end]
-                row = np.zeros(self.max_blocks, np.int32)
-                row[:len(slot.blocks)] = slot.blocks
-                args = [jnp.asarray(tokens), jnp.int32(start),
-                        jnp.int32(plen), jnp.asarray(row)]
-                if self.sampling:
-                    # the chunk serves ONE slot: its sampling rows, [1]
-                    args.extend(self._sampling_host_args_one(slot))
-                if self.cache.state:
-                    args.append(jnp.int32(slot.state_row))
-                if self.adapter_pool is not None:
-                    # the chunk serves ONE slot: its adapter page,
-                    # [1]-row
-                    args.append(jnp.asarray(
-                        np.asarray([slot.adapter_page], np.int32)))
-                with RecordEvent("engine.prefill"):
-                    t0 = time.perf_counter()
-                    nxt = self._dispatch_step(self._prefill, *args)
-                    self._m_prefill_chunks.inc()
-                    slot.prefill_pos = end
-            self._trace_span("prefill.chunk", t_span, req=req,
-                             start=start, end=end,
-                             **({"final": True} if end == plen else {}))
-            if end < plen:             # mid-prompt: no token to read
-                return 1
-            # the prompt's last chunk: its output is the request's
-            # first token. The serial order reads it here; the ahead
-            # order feeds this iteration's decode step from it where
-            # it lies and reads it after that launch (`_step_ahead`).
-            slot.ahead = 1
-            first = _InFlight(out=nxt, runnable=[self._slots.index(slot)],
-                              slots=[slot], t_dec=t0, t_span=t_span)
-            if self._goes_ahead:
-                self._first = first
-            else:
-                self._first_complete(first)
-            return 1
-        return 0
+                return slot, start, end
+        return None
+
+    def _chunk_args(self, slot, start, end):
+        """The chunk program's host args but the adapter row: tokens
+        `[1, C]`, start, prompt length, the lane's block row, its
+        sampling rows, its row of state."""
+        req = slot.req
+        tokens = np.zeros((1, self.prefill_chunk), np.int32)
+        tokens[0, :end - start] = req.prompt[start:end]
+        row = np.zeros(self.max_blocks, np.int32)
+        row[:len(slot.blocks)] = slot.blocks
+        args = [jnp.asarray(tokens), jnp.int32(start),
+                jnp.int32(req.prompt.size), jnp.asarray(row)]
+        if self.sampling:
+            # the chunk serves ONE slot: its sampling rows, [1]
+            args.extend(self._sampling_host_args_one(slot))
+        if self.cache.state:
+            args.append(jnp.int32(slot.state_row))
+        return args
+
+    def _chunk_launched(self, slot, start, end, out, t0, t_span):
+        """A chunk over `slot` is on the device, alone or inside a decode
+        step: the lane's prompt advances. Where it ended the prompt,
+        returns the unread record of the request's first token."""
+        req = slot.req
+        self._m_prefill_chunks.inc()
+        slot.prefill_pos = end
+        final = end == req.prompt.size
+        self._trace_span("prefill.chunk", t_span, req=req,
+                         start=start, end=end,
+                         **({"final": True} if final else {}))
+        if not final:                  # mid-prompt: no token to read
+            return None
+        slot.ahead = 1
+        return _InFlight(out=out, runnable=[self._slots.index(slot)],
+                         slots=[slot], t_dec=t0, t_span=t_span)
 
     def _first_complete(self, first):
         """Read the first token a prompt's last chunk produced (the
@@ -2647,18 +2728,30 @@ class GenerationEngine:
                 runnable.append(i)
         return runnable
 
-    def _feed_rows(self, runnable):
+    def _unread_first(self, prev):
+        """The unread record of a prompt's first token a decode step
+        may feed from: of the chunk launched alone this iteration, or
+        of the chunk the unread decode step carried. Never both over
+        runnable lanes: a chunk runs alone only where no lane could
+        decode beside it."""
+        if self._first is not None or prev is None:
+            return self._first
+        return prev.first
+
+    def _feed_rows(self, runnable, prev):
         """The ahead order's feed column: the host's column holds the
         lanes whose newest token the host has read; every other
         runnable lane's newest token is still on the device — in the
         unread decode step's output or in the output of the chunk
-        that ended its prompt this iteration. Returns the two host
+        that ended its prompt (this iteration's, or the one the unread
+        step carried). Returns the two host
         rows of the select that merges the three on the device (which
         lanes take the unread step's token, which lane the chunk's),
         or [] where the host has read every token."""
         from_prev = np.zeros(self.num_slots, bool)
         first_lane = -1
-        first = None if self._first is None else self._first.slots[0]
+        first = self._unread_first(prev)
+        first = None if first is None else first.slots[0]
         for i in runnable:
             slot = self._slots[i]
             if slot is first:
@@ -2672,11 +2765,11 @@ class GenerationEngine:
     def _unread_outputs(self, prev):
         """The two device values `_feed_select` merges: the unread
         decode step's tokens and the first token of the chunk that
-        ended a prompt this iteration. Where one is absent, zeros
+        ended a prompt (`_unread_first`). Where one is absent, zeros
         placed as the other, a compiled step's output, is (committed
         to its sharding or not), so the select compiles once."""
         outs = [None if r is None else r.out
-                for r in (prev, self._first)]
+                for r in (prev, self._unread_first(prev))]
         if self._no_unread is None:
             like = outs[0] if outs[1] is None else outs[1]
             where = (like.sharding,) if like.committed else ()
@@ -2687,13 +2780,15 @@ class GenerationEngine:
         return [h if o is None else o
                 for o, h in zip(outs, self._no_unread)]
 
-    def _plain_dispatch(self, runnable, prev=None):
+    def _plain_dispatch(self, runnable, prev=None, chunk=None):
         """Dispatch stage of a plain decode step: build the dynamic
         host rows, move them in one `_put_host_args` batch, and issue
         the compiled step WITHOUT waiting on its output. With `prev`
         (the ahead order: the decode step still unread) the feed
         column comes from the device wherever the host has not read
-        the token (`_feed_rows`). Returns the `_InFlight`
+        the token (`_feed_rows`). With `chunk` (`_chunk_schedule`'s)
+        the step is the one program that carries that chunk's rows
+        too. Returns the `_InFlight`
         record the complete stage consumes."""
         t_span = now_us()
         with self._phase("dispatch"):
@@ -2725,17 +2820,23 @@ class GenerationEngine:
                 # the null page 0 — exact-zero delta, like the null
                 # block)
                 rows.append(arows)
-            select = self._feed_rows(runnable) if self._goes_ahead \
-                else []
+            select = self._feed_rows(runnable, prev) \
+                if self._goes_ahead else []
             args = self._put_host_args(rows + select)
             if select:
                 *args, from_prev, first_lane = args
                 args[0] = self._feed_select(
                     args[0], from_prev, first_lane,
                     *self._unread_outputs(prev))
+            if chunk is not None:
+                args = self._chunk_args(*chunk) + args
             with RecordEvent("engine.decode"):
                 t_dec = time.perf_counter()
-                nxt = self._dispatch_step(self._decode, *args)
+                if chunk is None:
+                    nxt = self._dispatch_step(self._decode, *args)
+                else:
+                    first, nxt = self._dispatch_step(self._fused, *args,
+                                                     n_out=2)
         for i in runnable:
             self._slots[i].ahead += 1
         self._step_seq += 1
@@ -2743,11 +2844,15 @@ class GenerationEngine:
         if prev is not None:
             self.decode_steps_ahead += 1
             self._m_steps_ahead.inc()
-        return _InFlight(out=nxt, runnable=runnable,
-                         slots=[self._slots[i] for i in runnable],
-                         counters=self._step_counters,
-                         t_dec=t_dec, t_span=t_span,
-                         seq=self._step_seq)
+        inflight = _InFlight(out=nxt, runnable=runnable,
+                             slots=[self._slots[i] for i in runnable],
+                             counters=self._step_counters,
+                             t_dec=t_dec, t_span=t_span,
+                             seq=self._step_seq)
+        if chunk is not None:
+            inflight.first = self._chunk_launched(*chunk, first, t_dec,
+                                                  t_span)
+        return inflight
 
     def _plain_complete(self, inflight):
         """Complete stage of a plain decode step: read the device
@@ -3146,6 +3251,17 @@ class GenerationEngine:
         the end of the call after the one that launched its last
         step.
 
+        Where the model's spec offers ONE step for a chunk's rows and
+        the decode rows (`decode_with_chunk`: every weight crosses
+        once for both) and the iteration holds both kinds of work, (a)
+        and (b) are one program M(c), launched where D(c) is and
+        unread like it when the call returns. The lane whose prompt
+        it ended decodes from D(c+1) on, fed from M(c)'s first token
+        on the device; that token is read with M(c)'s decode tokens by
+        call c+1's one wait. A chunk with no lane to decode beside it,
+        and a model that offers no such step, run the two programs as
+        above.
+
         `progressed` counts admissions, prefill chunks and COMPLETED
         decode lanes, as the serial order does; a call that only
         launched counts that launch, so `run()`'s no-progress check
@@ -3153,13 +3269,32 @@ class GenerationEngine:
         with RecordEvent("engine.step"):
             t_wall = time.perf_counter()
             progressed = self._admit()
-            progressed += self._prefill_step()
             prev = self._inflight
-            runnable = self._plain_schedule()
-            self._inflight = self._plain_dispatch(runnable, prev) \
+            chunk = None
+            if self._fused is None:
+                progressed += self._prefill_step()
+                runnable = self._plain_schedule()
+            else:
+                # the decode lanes BESIDE the chunk: its own lane is
+                # still in its prompt, and joins the next step
+                chunk = self._chunk_schedule()
+                runnable = self._plain_schedule()
+                progressed += chunk is not None
+                if chunk is not None and not runnable:
+                    # nothing decodes beside it: the chunk's own
+                    # program, and where it ended its prompt that
+                    # lane decodes behind it, as without the fused
+                    # step
+                    self._chunk_dispatch(*chunk)
+                    chunk = None
+                    runnable = self._plain_schedule()
+            self._inflight = self._plain_dispatch(runnable, prev, chunk) \
                 if runnable else None
             if prev is not None:
                 progressed += self._plain_complete(prev)
+                if prev.first is not None:
+                    # the same program's: no second wait
+                    self._first_complete(prev.first)
             first, self._first = self._first, None
             if first is not None:
                 self._first_complete(first)
@@ -3564,6 +3699,29 @@ class GenerationEngine:
         return out
 
 
+def _last_prompt_row_token(spec, hidden, start, plen, width, sampling,
+                           mp_axis=None):
+    """The token a chunk's hidden rows `[1, width, hidden]` yield: the
+    LAST REAL prompt position's logits give the first generated token;
+    it lives in the final chunk — for earlier chunks the one-hot selects
+    nothing and the host ignores the returned token. `sampling` is the
+    one slot's `(temps, tks, tps, krows)` rows, or None for the argmax."""
+    sel = (start + jnp.arange(width) == plen - 1) \
+        .astype(hidden._array.dtype)
+    h_last = (hidden._array * sel[None, :, None]) \
+        .sum(axis=1, keepdims=True)
+    logits = spec.logits(Tensor._wrap(h_last), mp_axis=mp_axis)
+    if sampling is None:
+        return jnp.argmax(logits._array[0, 0]).astype(jnp.int32)
+    # the first generated token's draw folds plen-1 (it lands at
+    # position plen) — identical to the full-prefix-hit decode's key
+    # for that token
+    from paddle_tpu.ops.sampling import sample_token
+
+    return sample_token(logits._array[:, 0], *sampling,
+                        jnp.maximum(plen - 1, 0).reshape(1))[0]
+
+
 # -- trace contracts (tpu-verify) ---------------------------------------
 # Declared HERE, next to the step builders, so the contract and the
 # program evolve in one diff. The harvester
@@ -3578,7 +3736,7 @@ class GenerationEngine:
 _SERVING_BUDGET = "paddle_tpu.jit.introspect:SERVING_STEP_AXIS_BUDGET"
 
 for _step in ("engine_prefill_chunk", "engine_decode_step",
-              "engine_verify_step"):
+              "engine_decode_step_with_chunk", "engine_verify_step"):
     register_contract(TraceContract(
         name=_step,
         declared_at="paddle_tpu/inference/engine.py",
@@ -3589,6 +3747,5 @@ for _step in ("engine_prefill_chunk", "engine_decode_step",
         # latency path (tpu-shard TPU305 gates these against any
         # future slow/DCN mesh axis); the prefill chunk runs per
         # admission
-        per_token=_step in ("engine_decode_step",
-                            "engine_verify_step")))
+        per_token=_step != "engine_prefill_chunk"))
 del _step

@@ -63,7 +63,9 @@ class ServingSpec:
     #: feature -> reason; the engine raises a ValueError that gives it
     refuses: dict = {}
     #: `(name, "sum" | "max")` of the int32 counters a decode step
-    #: returns (`StepOut.counters`), published as engine metrics
+    #: returns (`StepOut.counters`), published as engine metrics. A spec
+    #: that offers `decode_with_chunk` can tell how often it engaged: a
+    #: counter that reads 1 from the fused step and 0 from the plain one
     step_counters: tuple = ()
     slot_state: tuple = ()
 
@@ -126,3 +128,23 @@ class ServingSpec:
     def verify(self, tokens, positions, draft_lens, kpool, vpool,
                block_tables, **kw) -> StepOut:
         raise NotImplementedError
+
+    def decode_with_chunk(self, chunk_tokens, start, block_row, plen,
+                          tokens, positions, block_tables, kpool, vpool,
+                          **kw):
+        """OPTIONAL: `prefill_chunk` and `decode` as ONE step whose
+        layers see the chunk's rows and the decode rows together, for a
+        model whose weights are worth reading once an iteration and not
+        twice. -> (`StepOut` of the chunk: its hidden rows, the pools
+        and slot state after BOTH, the decode step's counters; the
+        decode rows' hidden). The engine runs it in place of the two
+        programs in an iteration of the ahead order that holds a chunk
+        and decode lanes. The step takes neither int8 KV scales, adapters
+        nor a mesh axis: a spec that offers it refuses them. This base
+        offers none."""
+        raise NotImplementedError
+
+    @property
+    def offers_decode_with_chunk(self):
+        return type(self).decode_with_chunk \
+            is not ServingSpec.decode_with_chunk

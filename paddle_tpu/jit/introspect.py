@@ -124,6 +124,7 @@ ENGINE_COW_DONATE_ARGNUMS = (0, 1)
 ENGINE_STEP_DONATION = {
     "engine_prefill_chunk": ENGINE_STEP_DONATE_ARGNUMS,
     "engine_decode_step": ENGINE_STEP_DONATE_ARGNUMS,
+    "engine_decode_step_with_chunk": ENGINE_STEP_DONATE_ARGNUMS,
     "engine_verify_step": ENGINE_STEP_DONATE_ARGNUMS,
     "engine_cow_copy": ENGINE_COW_DONATE_ARGNUMS,
 }

@@ -191,13 +191,9 @@ class MambaMixer(nn.Layer):
     def _a(self):
         return -jnp.exp(self.A_log._array.astype(_F32))
 
-    def chunk(self, u, conv_state, ssm_state, n_valid):
-        """One slot's chunk `u [C, hidden]` from its carried state
-        (`conv_state [K-1, D]`, `ssm_state [heads, P, N]`); rows at and
-        past `n_valid` are padding. -> (out, conv_state, ssm_state)."""
+    def _scan_chunk(self, xbc, dt, conv_state, ssm_state, n_valid):
         from paddle_tpu.ops import ssm
 
-        gate, xbc, dt = self._split(u)
         xbc, conv_state = ssm.causal_conv_chunk(
             xbc, conv_state, self.conv.weight._array,
             self.conv.bias._array, n_valid)
@@ -205,14 +201,11 @@ class MambaMixer(nn.Layer):
         y, ssm_state = ssm.ssd_chunk_scan(
             x, dt, self._a(), b, c, self.D._array, ssm_state, n_valid,
             self.cfg.chunk_size)
-        return self._gate_out(y, gate, u.dtype), conv_state, ssm_state
+        return y, conv_state, ssm_state
 
-    def step(self, u, conv_pool, ssm_pool, layer, rows):
-        """One token a slot, `u [slots, hidden]`, over the state pools
-        (`rows [slots]`, 0 = the null row)."""
+    def _scan_step(self, xbc, dt, conv_pool, ssm_pool, layer, rows):
         from paddle_tpu.ops import ssm
 
-        gate, xbc, dt = self._split(u)
         xbc, carried = ssm.causal_conv_step(
             xbc, conv_pool[layer, rows], self.conv.weight._array,
             self.conv.bias._array)
@@ -221,6 +214,42 @@ class MambaMixer(nn.Layer):
         y, ssm_pool = ssm.ssm_decode_step(
             ssm_pool, layer, rows, x, dt, self._a(),
             self.D._array.astype(_F32), b, c)
+        return y, conv_pool, ssm_pool
+
+    def chunk(self, u, conv_state, ssm_state, n_valid):
+        """One slot's chunk `u [C, hidden]` from its carried state
+        (`conv_state [K-1, D]`, `ssm_state [heads, P, N]`); rows at and
+        past `n_valid` are padding. -> (out, conv_state, ssm_state)."""
+        gate, xbc, dt = self._split(u)
+        y, conv_state, ssm_state = self._scan_chunk(
+            xbc, dt, conv_state, ssm_state, n_valid)
+        return self._gate_out(y, gate, u.dtype), conv_state, ssm_state
+
+    def step(self, u, conv_pool, ssm_pool, layer, rows):
+        """One token a slot, `u [slots, hidden]`, over the state pools
+        (`rows [slots]`, 0 = the null row)."""
+        gate, xbc, dt = self._split(u)
+        y, conv_pool, ssm_pool = self._scan_step(
+            xbc, dt, conv_pool, ssm_pool, layer, rows)
+        return self._gate_out(y, gate, u.dtype), conv_pool, ssm_pool
+
+    def chunk_and_step(self, u, width, conv_pool, ssm_pool, layer, row,
+                       n_valid, rows):
+        """`chunk` over the first `width` rows of `u` (one slot's chunk,
+        from and to row `row` of the pools) and `step` over the others
+        (one token a slot, `rows`), with ONE input and ONE output
+        projection over all the rows. The chunk's row is written before
+        the step's kernel updates the pool in place: one chain, no copy
+        of the pool."""
+        gate, xbc, dt = self._split(u)
+        y_c, conv_new, ssm_new = self._scan_chunk(
+            xbc[:width], dt[:width], conv_pool[layer, row],
+            ssm_pool[layer, row], n_valid)
+        y_s, conv_pool, ssm_pool = self._scan_step(
+            xbc[width:], dt[width:],
+            conv_pool.at[layer, row].set(conv_new),
+            ssm_pool.at[layer, row].set(ssm_new), layer, rows)
+        y = jnp.concatenate([y_c.astype(_F32), y_s.astype(_F32)])
         return self._gate_out(y, gate, u.dtype), conv_pool, ssm_pool
 
 
@@ -415,18 +444,36 @@ class NemotronHServing(ServingSpec):
         ) if n_m else ()
         # a decode step's: lanes that decoded; over the E layers, the
         # assignments its experts took, the experts touched, the largest
-        # expert's load
+        # expert's load; whether a prefill chunk's rows rode the step
+        # (then the experts' counts are of the ONE product over both)
         self.step_counters = (("decode_live_lanes", "sum"),) + ((
             ("moe_assignments_held", "sum"),
             ("moe_experts_touched", "sum"),
-            ("moe_max_expert_load", "max")) if "E" in kinds else ())
+            ("moe_max_expert_load", "max")) if "E" in kinds else ()) \
+            + (("decode_steps_with_chunk", "sum"),)
 
     def logits(self, hidden, mp_axis=None):
         return Tensor._wrap(self.model._head(hidden._array))
 
+    @property
+    def offers_decode_with_chunk(self):
+        """Where the experts' product is the chip's kernel, which reads
+        an expert's weights once a CALL: that is what one product over
+        both row sets saves. The other form gathers a weight block a
+        TILE (`distributed/moe._grouped_xla`: off the chip, or widths the
+        kernel does not take), as many for one product as for two, and
+        keeps the two plain steps."""
+        from paddle_tpu.distributed.moe import resolve_moe_backend
+
+        cfg = self.model.config
+        return resolve_moe_backend("auto", min(
+            cfg.moe_latent_size, cfg.moe_intermediate_size)) == "pallas"
+
     def _walk(self, h, mamba, attention, moe_live):
         """The layers in order; `mamba(mixer, u, index)` and
-        `attention(mixer, u, index)` are the caller's (chunk or step)."""
+        `attention(mixer, u, index)` are the caller's (chunk, step or
+        both). -> (final norm'd rows, the E layers' counters `[3]` or
+        None)."""
         model, cfg = self.model, self.model.config
         i_m = i_a = 0
         counters = []
@@ -445,12 +492,20 @@ class NemotronHServing(ServingSpec):
                 counters.append(c)
             h = h + out
         h = _rms_norm(h, model.norm_f.weight._array, cfg.norm_eps)
-        lanes = jnp.sum(moe_live, dtype=jnp.int32).reshape(1)
         if not counters:
-            return h, lanes
+            return h, None
         c = jnp.stack(counters)
-        return h, jnp.concatenate([lanes, jnp.sum(c[:, :2], axis=0),
+        return h, jnp.concatenate([jnp.sum(c[:, :2], axis=0),
                                    jnp.max(c[:, 2:], axis=0)])
+
+    @staticmethod
+    def _counters(lanes_live, moe, with_chunk):
+        """`step_counters`' values of one decode step."""
+        parts = [jnp.sum(lanes_live, dtype=jnp.int32).reshape(1)]
+        if moe is not None:
+            parts.append(moe)
+        return jnp.concatenate(
+            parts + [jnp.full(1, with_chunk, jnp.int32)])
 
     def prefill_chunk(self, tokens, start, kpool, vpool, block_row, plen,
                       mp_axis=None, kv_scales=None, lora=None,
@@ -485,16 +540,22 @@ class NemotronHServing(ServingSpec):
         return StepOut(Tensor._wrap(h), pools[0], pools[1],
                        slot_state=tuple(state))
 
+    def _lanes_live(self, slots, slot_state, state_rows):
+        """(the lanes' rows of state, which lanes decode): a lane that
+        decodes holds a state row; where the model keeps no such state
+        every lane counts."""
+        rows = jnp.zeros(slots, jnp.int32) if state_rows is None \
+            else state_rows
+        return rows, rows > 0 if slot_state else jnp.ones(slots, bool)
+
     def decode(self, tokens, positions, kpool, vpool, block_tables,
                backend="auto", mp_axis=None, kv_scales=None, lora=None,
                slot_state=(), state_rows=None):
         from paddle_tpu.ops.paged_attention import paged_attention_step
 
-        cfg = self.model.config
         ids = tokens._array                               # [slots, 1]
-        slots = ids.shape[0]
-        rows = jnp.zeros(slots, jnp.int32) if state_rows is None \
-            else state_rows
+        rows, live = self._lanes_live(ids.shape[0], slot_state,
+                                      state_rows)
         state = list(slot_state)
         pools = [kpool, vpool]
 
@@ -510,10 +571,55 @@ class NemotronHServing(ServingSpec):
                 backend=backend)
             return mixer.out(o._array)
 
-        # a lane that decodes holds a state row; where the model keeps
-        # no such state every lane counts
-        live = rows > 0 if slot_state else jnp.ones(slots, bool)
-        h, counters = self._walk(
+        h, moe = self._walk(
             self.model.embed.weight._array[ids], mamba, attention, live)
         return StepOut(Tensor._wrap(h), pools[0], pools[1],
-                       slot_state=tuple(state), counters=counters)
+                       slot_state=tuple(state),
+                       counters=self._counters(live, moe, 0))
+
+    def decode_with_chunk(self, chunk_tokens, start, block_row, plen,
+                          tokens, positions, block_tables, kpool, vpool,
+                          backend="auto", slot_state=(), state_row=None,
+                          state_rows=None):
+        """`prefill_chunk` and `decode` as one walk over the chunk's
+        rows and the decode rows together: every weight crosses once —
+        above all the experts', which a chunk and a decode step each
+        touch nearly all of. The scan and the attention run each row
+        set's own form (`mixer.chunk_and_step`, the two paged ops); an
+        `E` layer makes ONE product over all the rows."""
+        from paddle_tpu.ops.paged_attention import \
+            paged_attention_step, paged_prefill_chunk
+
+        ids_c, ids_s = chunk_tokens._array[0], tokens._array[:, 0]
+        width = ids_c.shape[0]
+        n_valid = jnp.clip(plen._array - start._array, 0, width)
+        rows, live = self._lanes_live(ids_s.shape[0], slot_state,
+                                      state_rows)
+        state = list(slot_state)
+        pools = [kpool, vpool]
+
+        def mamba(mixer, u, i):
+            out, state[0], state[1] = mixer.chunk_and_step(
+                u, width, state[0], state[1], i, state_row, n_valid, rows)
+            return out
+
+        def attention(mixer, u, i):
+            q, k, v = mixer.qkv(u)                  # [C + slots, h, D]
+            o_c, pools[0], pools[1] = paged_prefill_chunk(
+                q[None, :width], k[None, :width], v[None, :width],
+                pools[0], pools[1], i, block_row, start, plen)
+            o_s, pools[0], pools[1] = paged_attention_step(
+                q[width:, None], k[width:, None], v[width:, None],
+                pools[0], pools[1], i, block_tables, positions,
+                backend=backend)
+            return mixer.out(jnp.concatenate(
+                [o_c._array[0], o_s._array[:, 0]]))
+
+        h, moe = self._walk(
+            self.model.embed.weight._array[
+                jnp.concatenate([ids_c, ids_s])], mamba, attention,
+            jnp.concatenate([jnp.arange(width) < n_valid, live]))
+        return (StepOut(Tensor._wrap(h[None, :width]), pools[0], pools[1],
+                        slot_state=tuple(state),
+                        counters=self._counters(live, moe, 1)),
+                Tensor._wrap(h[width:, None]))

@@ -38,3 +38,14 @@ def _seed_all():
     paddle_tpu.seed(42)
     np.random.seed(42)
     yield
+
+
+@pytest.fixture
+def fused_step_offered(monkeypatch):
+    """The hybrid decoder's spec offers `decode_with_chunk` where the
+    experts' product is the chip's kernel, which this CPU never takes:
+    a test of the fused order asks for the offer itself. The step then
+    runs the forms this platform resolves."""
+    from paddle_tpu.models.nemotron_h import NemotronHServing
+
+    monkeypatch.setattr(NemotronHServing, "offers_decode_with_chunk", True)

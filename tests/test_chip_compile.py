@@ -206,11 +206,19 @@ def _instructions(text):
     """The compiled text cut into instructions, each starting at its
     `%name = ` as a trace's `XLA Ops` event does (the `kernel_metadata`
     attribute spreads one instruction over several lines)."""
+    return [c for c in _all_instructions(text, root=False)
+            if "tpu_custom_call" in c]
+
+
+def _all_instructions(text, root=True):
+    """Every instruction of the compiled text; with `root` a
+    computation's ROOT too, its prefix taken off (a trace names an
+    event by the instruction)."""
     import re
 
     flat = "\n".join(line.strip() for line in text.splitlines())
-    return [c for c in re.split(r"\n(?=%\S+ = )", flat)
-            if "tpu_custom_call" in c]
+    start = r"\n(?=(?:ROOT )?%\S+ = )" if root else r"\n(?=%\S+ = )"
+    return [c.removeprefix("ROOT ") for c in re.split(start, flat)]
 
 
 def test_paged_decode_kernel_is_named_and_still_found_by_the_benchmark(
@@ -311,13 +319,14 @@ def test_ssm_decode_kernel_compiles_and_the_benchmark_finds_it(chip):
     assert "ssm_decode_update" in trace_reduce.op_group(call)
 
 
-@pytest.mark.parametrize("tokens", [N_SLOTS, 256], ids=["decode", "chunk"])
+@pytest.mark.parametrize("tokens", [N_SLOTS, 256, 256 + N_SLOTS],
+                         ids=["decode", "chunk", "decode_with_chunk"])
 def test_moe_grouped_matmul_compiles_and_the_benchmark_finds_it(chip,
                                                                 tokens):
     """Both products of an expert layer (up with relu^2, down) over the
-    dropless buffer of a decode step (128 rows) and of a prefill chunk
-    (256): every assignment could land here, each expert padded to a
-    tile."""
+    dropless buffer of a decode step (128 rows), of a prefill chunk
+    (256) and of both together (384): every assignment could land here,
+    each expert padded to a tile."""
     import re
 
     from benchmarks.lib import trace_reduce
@@ -337,16 +346,17 @@ def test_moe_grouped_matmul_compiles_and_the_benchmark_finds_it(chip,
         N_SLOTS: ("engine_decode_step", "moe_experts_roofline",
                   "moe_prefill_experts_busy_pct"),
         256: ("engine_prefill_chunk", "moe_prefill_experts_busy_pct",
-              "moe_experts_roofline")}[tokens]
+              "moe_experts_roofline"),
+        256 + N_SLOTS: ("engine_decode_step_with_chunk",
+                        "moe_experts_roofline",
+                        "moe_prefill_experts_busy_pct")}[tokens]
     text = chip(product, ((rows, E_LATENT), BF16),
                 ((E_HELD, E_LATENT, E_INTER), BF16),
                 ((E_HELD, E_INTER, E_LATENT), BF16),
                 ((rows // E_TILE,), I32), ((1,), I32))
     # the second product is this test function's ROOT; in the engine's
     # step neither is, and a trace names an event by the instruction
-    flat = "\n".join(line.strip() for line in text.splitlines())
-    calls = [c.removeprefix("ROOT ") for c in
-             re.split(r"\n(?=(?:ROOT )?%\S+ = )", flat)
+    calls = [c for c in _all_instructions(text)
              if "tpu_custom_call" in c]
     assert len(calls) == 2
     for call in calls:
@@ -369,3 +379,109 @@ def test_auto_takes_the_new_kernels_on_a_tpu_only(monkeypatch):
     assert ssm.resolve_ssm_backend("auto", 16) == "xla"
     assert moe.resolve_moe_backend("auto", E_LATENT) == "pallas"
     assert moe.resolve_moe_backend("auto", 48) == "xla"
+
+
+# -- the hybrid decoder's engine programs, whole ---------------------------------
+
+def _engine_programs_for_chip(topo, monkeypatch):
+    """The chunk program, the decode step and the decode step that
+    carries a chunk of a small hybrid decoder at the kernels' widths
+    (state 128, experts 128 -> 256 -> 128), compiled for the described
+    chip as the engine jits them (pools and state donated):
+    `{name: compiled text}`, and the pools' shapes."""
+    import numpy as np
+
+    from paddle_tpu.inference.engine import GenerationEngine
+    from paddle_tpu.models.nemotron_h import (NemotronHConfig,
+                                              NemotronHForCausalLM)
+
+    cfg = NemotronHConfig(
+        vocab_size=512, hidden_size=256, hybrid_override_pattern="ME*",
+        mamba_num_heads=16, mamba_head_dim=64, n_groups=2,
+        ssm_state_size=128, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=128, n_routed_experts=8, router_experts=16,
+        num_experts_per_tok=4, moe_latent_size=128,
+        moe_intermediate_size=256,
+        moe_shared_expert_intermediate_size=256, max_seq_len=128,
+        dtype="bfloat16", init="zeros")
+    model = NemotronHForCausalLM(cfg)
+    model.eval()
+    eng = GenerationEngine(model, num_slots=16, block_size=16,
+                           prefill_chunk=32, donate=True)
+    one = SingleDeviceSharding(topo.devices[0])
+    c, i32 = eng.cache, np.int32
+    slots, blocks = eng.num_slots, eng.max_blocks
+    chunk = (np.zeros((1, eng.prefill_chunk), i32), i32(0), i32(0),
+             np.zeros(blocks, i32), i32(0))
+    decode = (np.zeros((slots, 1), i32), np.zeros(slots, i32),
+              np.zeros((slots, blocks), i32), np.zeros(slots, i32))
+    head = (eng._state_arrays(), c.kpool, c.vpool, c.state)
+    # what the engine's steps ask at trace time: on the chip `auto`
+    # takes the kernels, and none runs under the interpreter
+    monkeypatch.setattr("paddle_tpu.core.device.platform", lambda: "tpu")
+    texts = {}
+    for name, jitted, host in (("chunk", eng._prefill, chunk),
+                               ("decode", eng._decode, decode),
+                               ("fused", eng._fused, chunk + decode)):
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one), head + host)
+        with jax.default_matmul_precision("default"):
+            texts[name] = jitted.lower(*args).compile().as_text()
+    shapes = lambda arrays: {tuple(a.shape) for a in arrays}
+    return texts, shapes((c.kpool, c.vpool)), shapes(c.state)
+
+
+def test_decode_step_with_chunk_keeps_the_kernels_names_and_copies_no_pool(
+        topo, monkeypatch, fused_step_offered):
+    """The one program for a chunk and a decode step is named with the
+    decode step's prefix, so the benchmark's two decode-step patterns
+    find its Mosaic calls (and the chunk program's pattern does not);
+    and where the chunk's `.at[row].set` and the decode kernel's aliased
+    update meet in one program, no pool of state is copied beyond what
+    the two programs it replaces copy. (The K/V pools are: the compiler
+    keeps the pool the chunk's attention loop reads apart from the one
+    the decode rows' write updates — 2 x 33.5 MB in and out at the
+    cell's size, 0.16 ms of a step; `PERF.md` section 7.) The engine is
+    built on this CPU, where the spec offers no fused step: the fixture
+    offers it, as the chip does at these widths."""
+    import re
+
+    texts, kv_pools, state_pools = _engine_programs_for_chip(topo,
+                                                             monkeypatch)
+
+    instructions = _all_instructions
+
+    def calls(text, metric):
+        return [c for c in instructions(text) if "tpu_custom_call" in c
+                and any(re.search(p, c) for p in _metric_patterns(metric))]
+
+    def copies(text, pools):
+        """Copies of a pool-shaped array within the device's memory, in
+        line or started async (a prefetch into the fast memory space
+        `S(1)` and its way back are the compiler's staging, which only a
+        pool of a test's size fits)."""
+        found = []
+        for c in instructions(text):
+            m = re.match(
+                r"%\S+ = (\(?\w+\[([\d,]+)\].*) copy(?:-start)?\(", c)
+            if m and "S(1)" not in m.group(1) and \
+                    tuple(map(int, m.group(2).split(","))) in pools:
+                found.append(c[:80])
+        return found
+
+    fused = texts["fused"]
+    assert len(calls(fused, "ssm_decode_roofline")) == 1        # 1 M layer
+    assert len(calls(fused, "moe_experts_roofline")) == 2       # 1 E layer
+    assert calls(fused, "moe_prefill_experts_busy_pct") == []
+    assert all(c.startswith("%engine_decode_step_with_chunk.")
+               for c in instructions(fused) if "tpu_custom_call" in c)
+    # the same kernels as the two programs', once
+    assert len(calls(texts["decode"], "ssm_decode_roofline")) == 1
+    assert len(calls(texts["decode"], "moe_experts_roofline")) == 2
+    assert len(calls(texts["chunk"], "moe_prefill_experts_busy_pct")) == 2
+    assert len(copies(fused, state_pools)) <= \
+        len(copies(texts["chunk"], state_pools)) \
+        + len(copies(texts["decode"], state_pools)), \
+        copies(fused, state_pools)
+    assert len(copies(fused, kv_pools)) <= 4, copies(fused, kv_pools)

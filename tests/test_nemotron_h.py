@@ -109,7 +109,7 @@ def engine_for(model, **kw):
 
 @pytest.mark.parametrize("pattern", ["ME*E", "M*", "E*M"])
 def test_engine_chunked_prefill_then_decode_agrees_with_the_reference(
-        pattern):
+        pattern, fused_step_offered):
     """Prompts shorter and longer than a chunk, more requests than
     slots: every served token is the reference's own first choice given
     the tokens before it, by the reference's full forward over the whole
@@ -121,7 +121,10 @@ def test_engine_chunked_prefill_then_decode_agrees_with_the_reference(
     for p in asked:
         eng.add_request(p, max_new_tokens=9)
     out = eng.run()
-    assert eng.decode_traces == 1 and eng.prefill_traces == 1
+    # one decode step, and one that carries a chunk (the second request
+    # is admitted beside a lane that decodes)
+    assert eng.decode_traces == 2 and eng.prefill_traces == 1
+    assert eng.decode_steps_with_chunk > 0
     assert eng.cache.state_rows_used == 0 and eng.cache.num_free == \
         eng.cache.num_blocks - 1
     compared = 0
@@ -187,6 +190,154 @@ def test_engine_step_functions_give_the_references_logits():
                                    err_msg=f"position {pos}")
 
 
+def _prefilled(spec, eng, seq, plen, chunk):
+    """Blocks and a state row for one prompt, its first `plen` tokens
+    pushed through `prefill_chunk`: -> (block row, state row)."""
+    from paddle_tpu.core.tensor import Tensor
+
+    cache, wrap = eng.cache, Tensor._wrap
+    blocks = cache.allocate(-(-len(seq) // eng.block_size))
+    row = np.zeros(eng.max_blocks, np.int32)
+    row[:len(blocks)] = blocks
+    kw, state_row = {}, 0
+    if cache.state:
+        state_row = cache.allocate_state()
+        kw["state_row"] = jnp.int32(state_row)
+    for start in range(0, plen, chunk):
+        ids = np.zeros((1, chunk), np.int32)
+        n = min(chunk, plen - start)
+        ids[0, :n] = seq[start:start + n]
+        if cache.state:
+            kw["slot_state"] = cache.state
+        r = spec.prefill_chunk(
+            wrap(jnp.asarray(ids)), wrap(jnp.int32(start)),
+            wrap(cache.kpool), wrap(cache.vpool), wrap(jnp.asarray(row)),
+            wrap(jnp.int32(plen)), **kw)
+        cache.kpool, cache.vpool = r.kpool._array, r.vpool._array
+        cache.state = r.slot_state
+    return row, state_row
+
+
+@pytest.mark.parametrize("start,plen", [(8, 13), (0, 13)],
+                         ids=["last_chunk_padded", "first_chunk_full"])
+@pytest.mark.parametrize("pattern", ["ME*EM", "M*", "E*E"])
+def test_decode_with_chunk_is_the_chunk_then_the_decode_step(pattern,
+                                                             start, plen):
+    """The fused step against `prefill_chunk` then `decode` over the same
+    pools, rows of state and ids: two lanes decode (one idle between
+    them) while a third lane's chunk runs — the chunk's hidden rows, the
+    decode rows', the K/V pools and every row of state agree; the
+    counters are the decode lanes' and ONE expert product's; a chunk's
+    padding and an idle lane are routed nowhere."""
+    from paddle_tpu.core.tensor import Tensor
+
+    model, cfg = seeded(pattern)
+    chunk = 8
+    eng = engine_for(model, prefill_chunk=chunk, num_slots=4)
+    spec, cache, wrap = eng.spec, eng.cache, Tensor._wrap
+    a, b, c = prompts(cfg, [7, 11, plen])
+    row_a, state_a = _prefilled(spec, eng, a, 6, chunk)
+    row_b, state_b = _prefilled(spec, eng, b, 10, chunk)
+    row_c, state_c = _prefilled(spec, eng, c, start, chunk)
+    kp, vp, state = cache.kpool, cache.vpool, cache.state
+    has_state = bool(state)
+
+    def host_rows(idle_token, pad_token):
+        ids = np.full((1, chunk), pad_token, np.int32)
+        n = min(chunk, plen - start)
+        ids[0, :n] = c[start:start + n]
+        tokens = np.full((4, 1), idle_token, np.int32)
+        tokens[0, 0], tokens[2, 0] = a[6], b[10]
+        tables = np.zeros((4, eng.max_blocks), np.int32)
+        tables[0], tables[2] = row_a, row_b
+        return (jnp.asarray(ids), jnp.asarray(tokens),
+                jnp.asarray(np.array([6, 0, 10, 0], np.int32)),
+                jnp.asarray(tables))
+
+    def lanes(rows):
+        return dict(slot_state=state, state_rows=jnp.asarray(
+            np.array(rows, np.int32))) if has_state else {}
+
+    def fused(ids, tokens, positions, tables, rows):
+        kw = lanes(rows)
+        if has_state:
+            kw["state_row"] = jnp.int32(state_c)
+        r, hidden = spec.decode_with_chunk(
+            wrap(ids), wrap(jnp.int32(start)), wrap(jnp.asarray(row_c)),
+            wrap(jnp.int32(plen)), wrap(tokens), wrap(positions),
+            wrap(tables), wrap(kp), wrap(vp),
+            backend=eng.attention_backend, **kw)
+        return r, np.asarray(hidden._array)
+
+    ids, tokens, positions, tables = host_rows(0, 0)
+    live_rows = [state_a, 0, state_b, 0]
+    r1 = spec.prefill_chunk(
+        wrap(ids), wrap(jnp.int32(start)), wrap(kp), wrap(vp),
+        wrap(jnp.asarray(row_c)), wrap(jnp.int32(plen)),
+        **(dict(slot_state=state, state_row=jnp.int32(state_c))
+           if has_state else {}))
+    kw = lanes(live_rows)
+    if has_state:
+        kw["slot_state"] = r1.slot_state
+    r2 = spec.decode(wrap(tokens), wrap(positions), r1.kpool, r1.vpool,
+                     wrap(tables), backend=eng.attention_backend, **kw)
+    got, hidden = fused(ids, tokens, positions, tables, live_rows)
+
+    n = min(chunk, plen - start)
+    live = [0, 2] if has_state else [0, 1, 2, 3]
+    tol = dict(atol=2e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got.hidden._array)[0, :n],
+                               np.asarray(r1.hidden._array)[0, :n], **tol)
+    np.testing.assert_allclose(hidden[live],
+                               np.asarray(r2.hidden._array)[live], **tol)
+    for mine, theirs in zip(
+            (got.kpool._array, got.vpool._array) + tuple(got.slot_state),
+            (r2.kpool._array, r2.vpool._array) + tuple(r2.slot_state)):
+        np.testing.assert_allclose(np.asarray(mine), np.asarray(theirs),
+                                   **tol)
+
+    names = [name for name, _ in spec.step_counters]
+    mine = dict(zip(names, np.asarray(got.counters).tolist()))
+    plain = dict(zip(names, np.asarray(r2.counters).tolist()))
+    assert mine["decode_live_lanes"] == len(live) == \
+        plain["decode_live_lanes"]
+    assert (mine["decode_steps_with_chunk"],
+            plain["decode_steps_with_chunk"]) == (1, 0)
+    # other ids in the padding and the idle lanes move nothing (where
+    # no state tells an idle lane, every lane counts: its id stays)
+    other, hidden2 = fused(*host_rows(5 if has_state else 0, 7),
+                           live_rows)
+    np.testing.assert_array_equal(hidden2[live], hidden[live])
+    if "E" not in pattern:
+        return
+    np.testing.assert_array_equal(np.asarray(other.counters),
+                                  np.asarray(got.counters))
+    if has_state:
+        # the chunk's rows alone: every lane idle
+        alone, _ = fused(ids, tokens, positions, tables, [0, 0, 0, 0])
+        alone = dict(zip(names, np.asarray(alone.counters).tolist()))
+        layers = pattern.count("E")
+        assert 0 < alone["moe_assignments_held"] <= \
+            n * layers * cfg.num_experts_per_tok
+        assert mine["moe_assignments_held"] == \
+            alone["moe_assignments_held"] + plain["moe_assignments_held"]
+        # an expert the chunk and a lane both touch counts once
+        assert max(alone["moe_experts_touched"],
+                   plain["moe_experts_touched"]) \
+            <= mine["moe_experts_touched"] \
+            < alone["moe_experts_touched"] + plain["moe_experts_touched"]
+
+
+def test_a_spec_without_the_fused_step_says_so():
+    from paddle_tpu.inference.serving_spec import ServingSpec
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+    spec = GPTForCausalLM(GPTConfig.tiny()).serving_spec()
+    assert not spec.offers_decode_with_chunk
+    with pytest.raises(NotImplementedError):
+        ServingSpec.decode_with_chunk(spec, *[None] * 9)
+
+
 def test_a_slot_another_request_just_left_starts_from_nought():
     """One lane: the second request sits where the first sat. Its tokens
     are what it gets alone on a fresh engine — the row of recurrent state
@@ -222,7 +373,8 @@ def _serve_with_admissions_midrun(model, cfg, eos=None, **kw):
 
 
 @pytest.mark.parametrize("pattern", ["ME*E", "M*"])
-def test_ahead_and_serial_orders_serve_the_same_tokens(pattern):
+def test_ahead_and_serial_orders_serve_the_same_tokens(
+        pattern, fused_step_offered):
     """The hybrid decoder rides the default loop: decode step N+1 is
     launched from step N's tokens on the device, the rows of state are
     host rows, the program that zeroes a row, the chunk that first reads
@@ -238,11 +390,15 @@ def test_ahead_and_serial_orders_serve_the_same_tokens(pattern):
     assert eng_s.decode_steps_ahead == 0
     assert eng.overshoot_tokens == 0
     assert eng.tokens_generated == 9 * 6 == eng_s.tokens_generated
-    # per-token counters agree; the per-step ones (experts touched,
-    # the largest load) depend on which lanes share a step
-    for name in ("decode_live_lanes", "moe_assignments_held"):
-        assert eng.step_counter_totals.get(name) == \
-            eng_s.step_counter_totals.get(name)
+    # the lanes that decoded agree; the experts' counts depend on which
+    # rows share a step, and the ahead order's also hold the rows of
+    # the chunks that rode a decode step
+    assert eng.step_counter_totals["decode_live_lanes"] == \
+        eng_s.step_counter_totals["decode_live_lanes"]
+    if "E" in pattern:
+        assert eng.step_counter_totals["moe_assignments_held"] > \
+            eng_s.step_counter_totals["moe_assignments_held"]
+    assert eng.decode_steps_with_chunk > 0 == eng_s.decode_steps_with_chunk
     assert eng.cache.state_rows_used == 0
 
 
@@ -285,23 +441,29 @@ def test_a_state_row_left_unzeroed_changes_the_tokens(async_core,
     assert faulty != sound
 
 
-def test_engine_counts_the_experts_load_and_the_state_rows():
+def test_engine_counts_the_experts_load_and_the_state_rows(
+        fused_step_offered):
     model, cfg = seeded("ME*E")
     eng = engine_for(model)
     for p in prompts(cfg, [9, 9]):
         eng.add_request(p, max_new_tokens=6)
     eng.run()
     totals = eng.step_counter_totals
-    # one prefill chunk an iteration: the second request starts a step
-    # later, 5 decode steps each
-    assert eng.decode_steps == 6 and totals["decode_live_lanes"] == 10
-    # 2 E layers, 3 of 16 experts a token, 8 held: at most 3 a token
-    assert 0 < totals["moe_assignments_held"] <= 10 * 2 * 3
+    # one prefill chunk an iteration, the second request's inside the
+    # first's first decode step: its first token leaves with that step
+    # and it decodes from the next, 5 decode steps each
+    assert eng.decode_steps == 7 and totals["decode_live_lanes"] == 10
+    assert totals["decode_steps_with_chunk"] == 1 == \
+        eng.decode_steps_with_chunk
+    # 2 E layers, 3 of 16 experts a token, 8 held: at most 3 a token,
+    # and the 9 rows of the chunk that rode a decode step
+    assert 0 < totals["moe_assignments_held"] <= (10 + 9) * 2 * 3
     assert 0 < totals["moe_experts_touched"] <= \
         totals["moe_assignments_held"]
-    assert 1 <= totals["moe_max_expert_load"] <= 2
+    assert 1 <= totals["moe_max_expert_load"] <= 1 + 9
     text = eng.metrics.render_prometheus()
     for name in ("engine_moe_assignments_held_total",
+                 "engine_decode_steps_with_chunk_total 1",
                  "engine_moe_experts_touched_total",
                  "engine_moe_max_expert_load",
                  "engine_state_slots_used"):
@@ -526,6 +688,51 @@ def test_dropless_dispatch_and_grouped_matmul(crowded):
         assert counters.tolist() == [here.sum(), (sizes > 0).sum(),
                                      sizes.max()]
     assert moe.MOE_PATH_STATS == {"xla": 1, "pallas": 1}
+
+
+@pytest.mark.parametrize("on_chip,widths,offered", [
+    (False, (1024, 2688), False), (True, (1024, 2688), True),
+    (True, (1024, 48), False)],
+    ids=["off_the_chip", "the_kernels_widths", "a_narrow_width"])
+def test_the_fused_step_is_offered_where_the_experts_run_the_kernel(
+        monkeypatch, on_chip, widths, offered):
+    """`decode_with_chunk` saves what the grouped kernel saves, an
+    expert's weights crossing once a call: the spec offers it where
+    `auto` resolves the experts' product to that kernel, and nowhere
+    else."""
+    monkeypatch.setattr("paddle_tpu.core.device.on_tpu", lambda: on_chip)
+    model, cfg = seeded("ME*E")
+    cfg.moe_latent_size, cfg.moe_intermediate_size = widths
+    assert model.serving_spec().offers_decode_with_chunk is offered
+
+
+def test_an_engine_off_the_chip_keeps_the_two_plain_programs():
+    model, cfg = seeded("ME*E")
+    eng = engine_for(model)
+    for p in prompts(cfg, [9, 9]):
+        eng.add_request(p, max_new_tokens=6)
+    eng.run()
+    assert eng._fused is None and eng.decode_steps_with_chunk == 0
+    assert (eng.decode_traces, eng.prefill_traces) == (1, 1)
+
+
+def test_the_fused_steps_kernel_forms_are_counted_like_any_programs(
+        fused_step_offered):
+    """`SSM_PATH_STATS` / `MOE_PATH_STATS` are what the benchmark's
+    `unexpected_kernel_path` reads: the fused program's trace adds its
+    scan steps and its expert products to them, so a form it resolved
+    that the cell does not expect would show."""
+    model, cfg = seeded("ME*E")
+    ssm.reset_ssm_path_stats()
+    moe.reset_moe_path_stats()
+    eng = engine_for(model)
+    for p in prompts(cfg, [9, 9]):
+        eng.add_request(p, max_new_tokens=6)
+    eng.run()
+    assert eng.decode_traces == 2 and eng.prefill_traces == 1
+    # one M layer in the two decode programs; two E layers in all three
+    assert ssm.SSM_PATH_STATS == {"xla": 2, "pallas": 0}
+    assert moe.MOE_PATH_STATS == {"xla": 6, "pallas": 0}
 
 
 def test_engine_source_names_no_architecture():

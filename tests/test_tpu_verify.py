@@ -412,7 +412,7 @@ def test_trace_import_has_no_backend_init():
         "import paddle_tpu.ops.paged_attention\n"
         "from jax._src import xla_bridge\n"
         "assert not xla_bridge._backends, 'import initialized a backend'\n"
-        "assert len(T.registered_contracts()) == 4\n"
+        "assert len(T.registered_contracts()) == 5\n"
         "assert len(T.all_trace_rule_ids()) == 7\n"
         "print('TRACE_SMOKE_OK')\n")
     env = dict(os.environ)

@@ -337,16 +337,24 @@ def _grouped_xla(x, w, tile_expert, live_tiles, tile_rows, relu_squared):
     return out.reshape(m, -1).astype(x.dtype)
 
 
+#: an expert's function, by the name a model gives: `relu2` is
+#: `relu(x W1)^2 W2`; `swiglu` is `(silu(x W_g) * (x W_u)) W2` with gate
+#: and up as ONE grouped product, `W1 = [W_g | W_u]` `[held, d, 2 h]`
+EXPERT_ACTIVATIONS = ("relu2", "swiglu")
+
+
 def expert_share(x, ids, weights, w1, w2, first_expert, backend="auto",
-                 tile_rows=TILE_ROWS):
+                 tile_rows=TILE_ROWS, activation="relu2"):
     """What the experts held here add for every token:
-    `sum_{e chosen and held} weight_e * relu(x W1_e)^2 W2_e`.
+    `sum_{e chosen and held} weight_e * f_e(x)`, `f` the expert's
+    function (`activation`, one of `EXPERT_ACTIVATIONS`).
 
     x `[T, d]`; ids, weights `[T, k]` (the router's choice among ALL its
     experts, and the weights as normalised over the whole chosen set);
-    w1 `[held, d, h]`, w2 `[held, h, d]`: experts `first_expert ..
-    first_expert + held`. -> (`[T, d]` float32, counters `[3]` int32:
-    assignments held, experts touched, the largest expert's load)."""
+    w1 `[held, d, h]` (`[held, d, 2 h]` for `swiglu`), w2 `[held, h, d]`:
+    experts `first_expert .. first_expert + held`. -> (`[T, d]` float32,
+    counters `[3]` int32: assignments held, experts touched, the largest
+    expert's load)."""
     from paddle_tpu.core.device import pallas_interpret
 
     num_held = w1.shape[0]
@@ -364,7 +372,19 @@ def expert_share(x, ids, weights, w1, w2, first_expert, backend="auto",
     else:
         gmm = lambda a, w, sq: _grouped_xla(  # noqa: E731
             a, w, plan["tile_expert"], plan["live_tiles"], tile_rows, sq)
-    out = gmm(gmm(rows, w1, True), w2, False)
+    if activation == "relu2":
+        hidden = gmm(rows, w1, True)
+    elif activation == "swiglu":
+        # the gate's and the up's halves of one product, joined in
+        # float32 between the two grouped products
+        gate_up = gmm(rows, w1, False).astype(jnp.float32)
+        h = w1.shape[2] // 2
+        hidden = (jax.nn.silu(gate_up[:, :h]) * gate_up[:, h:]) \
+            .astype(rows.dtype)
+    else:
+        raise ValueError(f"activation must be one of "
+                         f"{EXPERT_ACTIVATIONS}, got {activation!r}")
+    out = gmm(hidden, w2, False)
     out = jnp.concatenate([out, jnp.zeros((1, out.shape[1]), out.dtype)])
     m = out.shape[0] - 1
     w_held = jnp.where(plan["slot_row"] < m, weights, 0.0)

@@ -346,6 +346,16 @@ def _best_of_n_fanout(add, run, params, n, base):
     return out, stash
 
 
+def _wrap_pool(pool):
+    """A pool as the step functions take it; None where the spec keeps
+    one pool and this is the place of the other."""
+    return None if pool is None else Tensor._wrap(pool)
+
+
+def _pool_array(pool):
+    return None if pool is None else pool._array
+
+
 class PagedKVCache:
     """Global paged KV pool + host-side block allocator, refcounts, and
     hash-based prefix cache.
@@ -355,6 +365,11 @@ class PagedKVCache:
     so updated in place on device). Block 0 is reserved as the null
     block — `allocate` never returns it. `layers` are the layers that
     keep K and V, `kv_heads` what they keep (a model's spec says both).
+    A spec that keeps ONE latent row a token a layer (`kv_heads` None)
+    gets one pool `[layers, num_blocks, block_size, head_dim]` — no head
+    axis, and `vpool` is None: the steps thread it as the empty argument
+    it is. Everything below the pools (blocks,
+    refcounts, prefix hashes, the LRU, copy-on-write) is the same code.
 
     Beside the paged blocks the same manager holds the slots' state of
     FIXED size (`slot_state`: recurrent layers' windows and state
@@ -397,8 +412,11 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.num_layers = int(num_layers)
-        self.kv_heads = int(kv_heads)
+        self.kv_heads = None if kv_heads is None else int(kv_heads)
         self.head_dim = int(head_dim)
+        if self.kv_heads is None and (mesh is not None or kv_dtype):
+            raise ValueError("a pool of latent rows has no head axis to "
+                             "shard and no int8 grid")
         # int8 per-block-scaled KV (PR 11): the pools store int8 codes
         # and `self.scales` `[layers, num_blocks, 2]` f32 carries each
         # block's symmetric K/V absmax grid (column 0 = K, 1 = V),
@@ -427,7 +445,8 @@ class PagedKVCache:
             self.vpool = jax.device_put(jnp.zeros(shape, dt), sharding)
         else:
             self.kpool = jnp.zeros(shape, dt)
-            self.vpool = jnp.zeros(shape, dt)
+            self.vpool = None if self.kv_heads is None \
+                else jnp.zeros(shape, dt)
         if self.kv_dtype == "int8":
             from paddle_tpu.ops.paged_attention import KV_QUANT_EPS
 
@@ -510,8 +529,10 @@ class PagedKVCache:
         cannot drift. Under `kv_dtype='int8'` the dtype is int8 (the
         codes); the per-block grids live in `scale_spec()`."""
         dt = jnp.int8 if self.kv_dtype == "int8" else self.dtype
-        return ((self.num_layers, self.num_blocks, self.block_size,
-                 self.kv_heads, self.head_dim), dt)
+        row = (self.head_dim,) if self.kv_heads is None \
+            else (self.kv_heads, self.head_dim)
+        return ((self.num_layers, self.num_blocks, self.block_size)
+                + row, dt)
 
     def scale_spec(self):
         """Layout of the int8 pools' per-block scale array:
@@ -525,7 +546,8 @@ class PagedKVCache:
         """Total bytes of the paged KV state: both pool planes plus
         (int8 mode) the per-block scale array — the number the
         capacity claim and the `engine_pool_bytes` gauge report."""
-        n = int(self.kpool.nbytes) + int(self.vpool.nbytes)
+        n = int(self.kpool.nbytes) + (
+            int(self.vpool.nbytes) if self.vpool is not None else 0)
         if self.scales is not None:
             n += int(self.scales.nbytes)
         return n
@@ -1621,16 +1643,14 @@ class GenerationEngine:
         # what engaged inside the kernel: the fp decode walk's pages a
         # compute step, from the function the kernel itself asks; the
         # int8 and verify kernels walk one page a step, dense none
-        from ..ops.pallas.paged_attention import pages_per_step
         if self.attention_backend != "pallas":
             pages = 0
         elif self.kv_dtype == "int8" or self.spec_decode_k:
             pages = 1
         else:
-            (*_, heads, head_dim), pool_dtype = self.cache.pool_spec()
-            pages = pages_per_step(self.block_size,
-                                   heads // self.mp_degree, head_dim,
-                                   pool_dtype)
+            pages = self.spec.decode_pages_per_step(
+                self.block_size, self.mp_degree,
+                self.cache.pool_spec()[1])
         m.gauge("engine_paged_decode_pages_per_step",
                 "Pool pages the paged decode kernel fetches and scores "
                 "per compute step (0 = the dense path serves).").set(pages)
@@ -1906,7 +1926,7 @@ class GenerationEngine:
         """The outputs of a compiled step in the one order
         `_dispatch_step` reads: the leading replicated outputs, the
         pools, then what rides beside them."""
-        out = tuple(lead) + (r.kpool._array, r.vpool._array)
+        out = tuple(lead) + (r.kpool._array, _pool_array(r.vpool))
         if r.kv_scales is not None:
             out += (r.kv_scales._array,)
         out += tuple(r.slot_state)
@@ -1937,7 +1957,7 @@ class GenerationEngine:
             with bound_state(zip(state, arrays), state):
                 r = spec.decode(
                     Tensor._wrap(tokens), Tensor._wrap(positions),
-                    Tensor._wrap(kpool), Tensor._wrap(vpool),
+                    Tensor._wrap(kpool), _wrap_pool(vpool),
                     Tensor._wrap(tables), backend=backend,
                     mp_axis=mp_axis,
                     kv_scales=None if scales is None
@@ -1990,7 +2010,7 @@ class GenerationEngine:
                 r = spec.verify(
                     Tensor._wrap(tokens), Tensor._wrap(positions),
                     Tensor._wrap(dlens), Tensor._wrap(kpool),
-                    Tensor._wrap(vpool), Tensor._wrap(tables),
+                    _wrap_pool(vpool), Tensor._wrap(tables),
                     backend=backend, mp_axis=mp_axis,
                     kv_scales=None if scales is None
                     else Tensor._wrap(scales), lora=lora)
@@ -2040,7 +2060,7 @@ class GenerationEngine:
             with bound_state(zip(state, arrays), state):
                 r = spec.prefill_chunk(
                     Tensor._wrap(tokens), Tensor._wrap(start),
-                    Tensor._wrap(kpool), Tensor._wrap(vpool),
+                    Tensor._wrap(kpool), _wrap_pool(vpool),
                     Tensor._wrap(table_row), Tensor._wrap(plen),
                     mp_axis=mp_axis,
                     kv_scales=None if scales is None
@@ -2086,7 +2106,7 @@ class GenerationEngine:
                     Tensor._wrap(table_row), Tensor._wrap(plen),
                     Tensor._wrap(tokens), Tensor._wrap(positions),
                     Tensor._wrap(tables), Tensor._wrap(kpool),
-                    Tensor._wrap(vpool), backend=backend, **kw)
+                    _wrap_pool(vpool), backend=backend, **kw)
                 first = _last_prompt_row_token(
                     spec, r.hidden, start, plen, C,
                     chunk[4:] if use_s else None)

@@ -7,12 +7,19 @@ run dtype), WHAT STATE A SLOT HOLDS per kind of layer, the step
 functions the compiled programs call, and the plans for the options a
 model may or may not support (tensor parallel, int8 weights, adapters).
 
-Two kinds of per-slot state live side by side in one manager
+Three kinds of per-slot state live side by side in one manager
 (`engine.PagedKVCache`):
 
-* `paged_kv` — keys and values of the attention layers, paged in blocks
-  that grow with the context (`PagedKV`: how many layers, KV heads and
-  the head size; the query heads only to choose the kernel);
+* `paged_kv` — what the attention layers cache, paged in blocks that
+  grow with the context. Either keys and values (`PagedKV`: how many
+  layers, KV heads and the head size; the query heads only to choose the
+  kernel): a K pool and a V pool `[layers, blocks, block, kv_heads,
+  head_dim]`. Or ONE latent row a token a layer that every query head
+  reads (`PagedLatent`: the row's width and how many of its leading
+  values are the "value" part): one pool `[layers, blocks, block,
+  row_width]`, no head axis and no second pool (`StepOut.vpool` is
+  None). Blocks, refcounts, the prefix cache and copy-on-write are the
+  same host code for both;
 * `slot_state` — state of fixed size a slot (`SlotState`: recurrent
   layers' convolution window and state matrices), one row a slot in
   arrays `[layers, 1 + slots, ...]`, row 0 the null row that idle lanes
@@ -37,6 +44,24 @@ class PagedKV:
 
 
 @dataclass(frozen=True)
+class PagedLatent:
+    """One compressed row a cached token a layer, shared by all query
+    heads (latent attention): `row_width` values of which the leading
+    `value_width` are also what the probabilities sum (the rest only
+    enter the scores: the rotated key)."""
+    layers: int
+    row_width: int
+    value_width: int
+    query_heads: int
+
+    kv_heads = None        # a row has no head axis: one pool, no V
+
+    @property
+    def head_dim(self):
+        return self.row_width
+
+
+@dataclass(frozen=True)
 class SlotState:
     name: str
     layers: int
@@ -50,7 +75,7 @@ class StepOut:
     pools (and what rides beside them) as updated."""
     hidden: object
     kpool: object
-    vpool: object
+    vpool: object                    # None where the spec keeps one pool
     kv_scales: object = None
     slot_state: tuple = ()
     counters: object = None          # int32 `[len(step_counters)]`
@@ -112,6 +137,15 @@ class ServingSpec:
         return resolve_backend(requested, head_dim=kv.head_dim,
                                block_size=block_size,
                                num_heads=kv.query_heads // mp_degree)
+
+    def decode_pages_per_step(self, block_size, mp_degree, pool_dtype):
+        """Pool pages the fused decode walk fetches and scores a compute
+        step (the engine's gauge of that name)."""
+        from paddle_tpu.ops.pallas.paged_attention import pages_per_step
+
+        kv = self.paged_kv
+        return pages_per_step(block_size, kv.kv_heads // mp_degree,
+                              kv.head_dim, pool_dtype)
 
     # -- the step functions ------------------------------------------------
     def logits(self, hidden, mp_axis=None):

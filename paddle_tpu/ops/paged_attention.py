@@ -95,6 +95,9 @@ from .dispatch import apply, as_tensor
 
 __all__ = ["paged_attention_step", "paged_verify_window",
            "paged_prefill_chunk",
+           "paged_latent_decode", "paged_latent_prefill_chunk",
+           "resolve_latent_backend", "latent_chunk_form",
+           "LATENT_PATH_STATS",
            "copy_pool_block", "export_pool_block", "ingest_pool_block",
            "dense_gather_reference",
            "resolve_backend", "PAGED_BACKENDS", "PAGED_PATH_STATS",
@@ -834,10 +837,11 @@ def copy_pool_block(kpool, vpool, src, dst, scales=None):
                                          keepdims=False)
     kpool = jax.lax.dynamic_update_index_in_dim(kpool, srows, dst,
                                                 axis=1)
-    srows = jax.lax.dynamic_index_in_dim(vpool, src, axis=1,
-                                         keepdims=False)
-    vpool = jax.lax.dynamic_update_index_in_dim(vpool, srows, dst,
-                                                axis=1)
+    if vpool is not None:      # None: the spec keeps one (latent) pool
+        srows = jax.lax.dynamic_index_in_dim(vpool, src, axis=1,
+                                             keepdims=False)
+        vpool = jax.lax.dynamic_update_index_in_dim(vpool, srows, dst,
+                                                    axis=1)
     if scales is None:
         return kpool, vpool
     srow = jax.lax.dynamic_index_in_dim(scales, src, axis=1,
@@ -916,3 +920,222 @@ def dense_gather_reference(kpool, vpool, layer, block_row, length,
                 vp[bids, pos % bs].astype(np.float32)
                 * sc[bids, 1][:, None, None])
     return (kp[bids, pos % bs], vp[bids, pos % bs])
+
+
+# ---------------------------------------------------------------------------
+# latent attention: ONE row a cached token a layer, shared by all heads
+#
+# The pool is `[layers, num_blocks, block_size, row_width]` (no head axis,
+# no V pool: `inference/serving_spec.PagedLatent`). A row is
+# `[c ; k_rope]`: `value_width` compressed values that every head both
+# scores against (through its absorbed query) and sums (the
+# probabilities' product), then the rotated key that only enters the
+# scores. Decode runs ABSORBED — `heads` queries of `row_width` values
+# against the rows as they lie, two products a page; a prefill chunk
+# chooses (`latent_chunk_form`). Raw jnp arrays in and out: these are
+# compiled-step bodies.
+# ---------------------------------------------------------------------------
+
+#: which form of the latent decode walk was traced (per trace, as
+#: `PAGED_PATH_STATS`): never a silent fallback
+LATENT_PATH_STATS = {"dense": 0, "pallas": 0}
+#: which form a prefill chunk took (per trace)
+LATENT_CHUNK_STATS = {"expanded": 0, "absorbed": 0}
+#: keys one iteration of the chunk's loop gathers and scores
+_LATENT_CHUNK_KEYS = 512
+
+
+def reset_latent_path_stats():
+    for stats in (LATENT_PATH_STATS, LATENT_CHUNK_STATS):
+        for k in stats:
+            stats[k] = 0
+
+
+def resolve_latent_backend(backend, row_width, value_width, block_size,
+                           num_heads):
+    """`auto` takes the fused walk on a TPU at the geometry compiled for
+    a described v5e (`tests/test_chip_compile.py`): rows and their value
+    part of whole 128-lane tiles (the chip's copy engine moves whole
+    tiles: a page of 576-value rows is refused, one of 640 lanes is
+    not), pages of whole bf16 tiles (16 rows), query heads a multiple of
+    8. An explicit choice always wins (off the chip `pallas`
+    runs the interpreter)."""
+    if backend not in PAGED_BACKENDS:
+        raise ValueError(f"backend must be one of {PAGED_BACKENDS}, "
+                         f"got {backend!r}")
+    if backend != "auto":
+        return backend
+    if on_tpu() and value_width % 128 == 0 and row_width % 128 == 0 \
+            and block_size % 16 == 0 and num_heads % 8 == 0:
+        return "pallas"
+    return "dense"
+
+
+def paged_latent_decode(q, new_rows, pool, layer, block_tables,
+                        positions, value_width, scale, backend="auto"):
+    """One batched decode step of latent attention, one layer.
+
+    q `[slots, heads, row_width]`: each head's ABSORBED query
+    (`[W_UK_h q_nope_h ; q_rope_h]`); new_rows `[slots, row_width]`: this
+    token's row, written at `(block_tables[s, pos // bs], pos % bs)`;
+    pool `[layers, num_blocks, block_size, row_width]`. Every head scores
+    the slot's rows at positions `<= positions[s]` (`q . row * scale`,
+    float32 online softmax) and sums their leading `value_width` values.
+    -> (`[slots, heads, value_width]` in q's dtype, the pool). Idle lanes
+    (position 0, all-null table) write the null block and read their own
+    row."""
+    resolved = resolve_latent_backend(
+        backend, q.shape[2], value_width, pool.shape[2], q.shape[1])
+    LATENT_PATH_STATS[resolved] += 1
+    if resolved == "pallas":
+        from .pallas.paged_attention import mla_paged_decode
+
+        return mla_paged_decode(
+            q, new_rows, pool, layer, block_tables, positions,
+            value_width, scale, interpret=pallas_interpret())
+    return _latent_dense_step(q, new_rows, pool, layer, block_tables,
+                              positions, value_width, scale)
+
+
+def _latent_dense_step(qa, new, pool, layer, bt, pos, value_width, scale):
+    """The XLA walk, and the kernel's parity probe: `_dense_step`'s loop
+    (online softmax a block, trip count the batch's high-water mark) over
+    rows without a head axis."""
+    B, heads, _ = qa.shape
+    bs = pool.shape[2]
+    bid_w = jnp.take_along_axis(bt, (pos // bs)[:, None], axis=1)[:, 0]
+    pool = pool.at[layer, bid_w, pos % bs].set(new.astype(pool.dtype))
+    qf = qa.astype(pool.dtype)
+    hw_blocks = jnp.max(pos) // bs + 1             # traced scalar
+
+    def body(j, carry):
+        m, l, acc = carry
+        bid = jax.lax.dynamic_index_in_dim(bt, j, axis=1, keepdims=False)
+        rows = pool[layer, bid]                    # [B, bs, width]
+        logits = jnp.einsum("bhr,bkr->bhk", qf, rows,
+                            preferred_element_type=jnp.float32) * scale
+        allowed = (j * bs + jnp.arange(bs))[None, :] <= pos[:, None]
+        logits = jnp.where(allowed[:, None, :], logits, -1e30)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.einsum("bhk,bkv->bhv", p.astype(rows.dtype),
+                        rows[..., :value_width],
+                        preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
+
+    m0 = jnp.full((B, heads, 1), -1e30, jnp.float32)
+    l0 = jnp.zeros((B, heads, 1), jnp.float32)
+    acc0 = jnp.zeros((B, heads, value_width), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, hw_blocks, body, (m0, l0, acc0))
+    return (acc / jnp.maximum(l, 1e-30)).astype(qa.dtype), pool
+
+
+def latent_chunk_form(chunk_rows, nope_dim, rope_dim, value_dim,
+                      value_width):
+    """Which form a prefill chunk of `chunk_rows` rows takes, from shapes
+    alone. EXPANDING a cached row to every head's key and value costs
+    `2 x value_width x heads x (nope_dim + value_dim)` FLOPs once a
+    chunk; the ABSORBED form scores and sums the row as it lies, which
+    costs `2 x heads x (2 x value_width - nope_dim - value_dim)` more a
+    (chunk row, cached row) pair. So expanding pays from
+    `value_width x (nope_dim + value_dim) / (2 x value_width - nope_dim
+    - value_dim)` rows on: 171 at 512 / 128 / 128. The chunk's width is
+    static (every row of it is computed, valid or padding), so the choice
+    is too."""
+    more_a_pair = 2 * value_width - nope_dim - value_dim
+    if more_a_pair <= 0:
+        return "absorbed"
+    crossover = value_width * (nope_dim + value_dim) / more_a_pair
+    return "expanded" if chunk_rows >= crossover else "absorbed"
+
+
+def paged_latent_prefill_chunk(q_nope, q_rope, new_rows, w_kvb, pool,
+                               layer, block_row, start, plen, scale,
+                               form=None):
+    """One chunk of ONE slot's prompt, one layer: write the chunk's rows,
+    then attend its queries over everything the slot's table covers so
+    far (shared prefix blocks, earlier chunks, the chunk itself,
+    causally).
+
+    q_nope `[C, heads, nope_dim]`, q_rope `[C, heads, rope_dim]` (rotated),
+    new_rows `[C, value_width + rope_dim]`, w_kvb `[value_width, heads,
+    nope_dim + value_dim]` (a head's `W_UK` then `W_UV`), pool `[layers,
+    num_blocks, block_size, row_width]`, block_row `[max_blocks]`;
+    `start`, `plen` traced. Rows at and past `plen` write the null block.
+    `form` None: `latent_chunk_form` of the shapes. -> (`[C, heads,
+    value_dim]`, the pool). Numerics as `paged_prefill_chunk`: operands
+    at the pool's dtype, float32 accumulation and softmax state."""
+    C, heads, dn = q_nope.shape
+    dr = q_rope.shape[2]
+    rank = w_kvb.shape[0]
+    dv = w_kvb.shape[2] - dn
+    bs, maxb = pool.shape[2], block_row.shape[0]
+    if form is None:
+        form = latent_chunk_form(C, dn, dr, dv, rank)
+    LATENT_CHUNK_STATS[form] += 1
+    dt = pool.dtype
+    pos = start + jnp.arange(C)
+    valid = pos < plen
+    bid = jnp.where(valid, block_row[jnp.minimum(pos // bs, maxb - 1)], 0)
+    pool = pool.at[layer, bid, pos % bs].set(new_rows.astype(dt))
+    group = max(1, min(_LATENT_CHUNK_KEYS // bs, maxb))    # pages a trip
+    keys = group * bs
+    end = jnp.minimum(start + C, plen)
+    trips = (jnp.maximum(end - 1, 0) // bs) // group + 1   # traced
+    w_kvb = w_kvb.astype(dt)
+    qn, qr = q_nope.astype(dt), q_rope.astype(dt)
+    if form == "absorbed":
+        # each head's query through its W_UK, once a chunk
+        qn = jnp.einsum("chd,rhd->chr", qn, w_kvb[..., :dn],
+                        preferred_element_type=jnp.float32).astype(dt)
+        width = rank
+    else:
+        width = dv
+
+    def body(j, carry):
+        m, l, acc = carry
+        at = j * group + jnp.arange(group)
+        rows = pool[layer, block_row[jnp.minimum(at, maxb - 1)]] \
+            .reshape(keys, -1)                     # [keys, row_width]
+        c, k_rope = rows[:, :rank], rows[:, rank:rank + dr]
+        if form == "absorbed":
+            k_nope, vals = c, c
+            nope = jnp.einsum("chr,kr->hck", qn, k_nope,
+                              preferred_element_type=jnp.float32)
+        else:
+            kv = jnp.einsum("kr,rhd->khd", c, w_kvb,
+                            preferred_element_type=jnp.float32).astype(dt)
+            k_nope, vals = kv[..., :dn], kv[..., dn:]
+            nope = jnp.einsum("chd,khd->hck", qn, k_nope,
+                              preferred_element_type=jnp.float32)
+        logits = (nope + jnp.einsum(
+            "chr,kr->hck", qr, k_rope,
+            preferred_element_type=jnp.float32)) * scale
+        key_pos = j * keys + jnp.arange(keys)
+        allowed = key_pos[None, :] <= pos[:, None]
+        logits = jnp.where(allowed[None], logits, -1e30)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.exp(logits - m_new)                # [heads, C, keys]
+        alpha = jnp.exp(m - m_new)
+        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        if form == "absorbed":
+            pv = jnp.einsum("hck,kr->hcr", p.astype(dt), vals,
+                            preferred_element_type=jnp.float32)
+        else:
+            pv = jnp.einsum("hck,khd->hcd", p.astype(dt), vals,
+                            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc * alpha + pv
+
+    m0 = jnp.full((heads, C, 1), -1e30, jnp.float32)
+    l0 = jnp.zeros((heads, C, 1), jnp.float32)
+    acc0 = jnp.zeros((heads, C, width), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, trips, body, (m0, l0, acc0))
+    out = acc / jnp.maximum(l, 1e-30)
+    if form == "absorbed":
+        out = jnp.einsum("hcr,rhd->chd", out.astype(dt), w_kvb[..., dn:],
+                         preferred_element_type=jnp.float32)
+    else:
+        out = out.transpose(1, 0, 2)
+    return out.astype(q_nope.dtype), pool

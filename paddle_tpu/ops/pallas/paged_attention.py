@@ -75,7 +75,7 @@ import jax.numpy as jnp
 from paddle_tpu.ops.pallas.naming import kernel_name
 
 __all__ = ["paged_decode_attention", "paged_verify_attention",
-           "pages_per_step"]
+           "pages_per_step", "mla_paged_decode", "latent_pages_per_step"]
 
 _NEG_INF = -1e30
 
@@ -760,3 +760,174 @@ def paged_verify_attention(q, knew, vnew, kpool, vpool, layer,
         interpret=interpret,
     )(*prefetch, q, k4, v4, kpool, vpool)
     return out, new_kpool, new_vpool
+
+
+# ---------------------------------------------------------------------------
+# latent attention (`ops/paged_attention.paged_latent_decode`)
+# ---------------------------------------------------------------------------
+
+#: the name the device trace shows, and the benchmark's
+#: `mla_decode_roofline` looks for
+MLA_KERNEL_NAME = "mla_paged_decode"
+#: keys one compute step of the latent walk scores. A row is read ONCE
+#: for all the heads, so a step's two products are `heads x keys x
+#: (row_width + value_width)` each way: at 128 heads a step of 512 keys
+#: is 0.14 GFLOP against 0.59 MB, both about 0.7 us on a v5e
+_LATENT_WALK_KEYS = 512
+
+
+def latent_pages_per_step(block_size, row_width, dtype):
+    """Pages of a slot's table that one compute step of
+    `_mla_decode_kernel` fetches and scores: `_LATENT_WALK_KEYS` keys, as
+    far as its two step buffers fit `_WALK_VMEM_BYTES` (8 pages of 64
+    rows x 576 bf16 = 1.2 MB of scratch). A function of shapes alone."""
+    lanes = -(-row_width // 128) * 128             # as VMEM tiles it
+    page = block_size * lanes * jnp.dtype(dtype).itemsize
+    return max(1, min(_LATENT_WALK_KEYS // block_size,
+                      _WALK_VMEM_BYTES // (2 * page)))
+
+
+def _mla_decode_kernel(bt_ref, pos_ref, q_ref, new_ref, pool_ref, o_ref,
+                       buf, copy_sems, buf_ref, *, layer, block_size,
+                       value_width, scale):
+    """One program a slot; `_decode_kernel`'s walk over ONE pool whose
+    rows have no head axis. q_ref `[1, heads, width]` holds every head's
+    absorbed query, new_ref `[1, 1, width]` this token's row, buf
+    `[2, pages * block_size, width]` the two step buffers; pool_ref is
+    the whole pool (HBM), only read.
+
+    The token's own row is not read from the pool: the softmax state
+    STARTS from it (`m = q . new`, `l = 1`, `acc = new`'s value part),
+    and the walk covers the rows strictly below the position, so a
+    masked key weighs exactly 0 and the kernel does not care whether the
+    row has reached the pool. (It is written beside the kernel, by XLA in
+    place: one row of a packed bf16 tile is half a sublane, which the
+    chip's copy engine does not address — "Slice shape along dimension 1
+    must be aligned to tiling (2), but is 1".)
+    A step scores its keys as they lie: `[heads, width] x [keys,
+    width]^T`, then `[heads, keys] x [keys, value_width]` — the rows are
+    read once for all the heads, and both products fill the MXU."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    div, rem = jax.lax.div, jax.lax.rem
+    s = pl.program_id(0)
+    pos = pos_ref[s]
+    step_keys = buf.shape[1]
+    pages = step_keys // block_size
+
+    def blocks_below(slot):     # blocks that hold a row under the position
+        return div(pos_ref[slot] + block_size - 1, block_size)
+
+    nsteps = jnp.maximum(div(blocks_below(s) + pages - 1, pages), 1)
+
+    def step_copies(slot, c, b, start):
+        first = c * pages
+        live = jnp.clip(blocks_below(slot) - first, 0, pages)
+
+        def page(i, _):
+            copy = pltpu.make_async_copy(
+                pool_ref.at[layer, bt_ref[slot, first + i]],
+                buf.at[b, pl.ds(pl.multiple_of(i * block_size,
+                                               block_size), block_size)],
+                copy_sems.at[b])
+            copy.start() if start else copy.wait()
+
+        jax.lax.fori_loop(0, live, page, None)
+
+    @pl.when(s == 0)
+    def _cold():
+        # rows no copy ever fills meet probability 0 in the second
+        # product: they must be finite
+        buf[...] = jnp.zeros_like(buf)
+        buf_ref[0] = 0
+        step_copies(s, 0, 0, start=True)
+
+    buf0 = buf_ref[0]
+    q = q_ref[0].astype(buf.dtype)                 # [heads, width]
+    heads = q.shape[0]
+    new = new_ref[0].astype(jnp.float32)           # [1, width]
+    key = jax.lax.broadcasted_iota(jnp.int32, (heads, step_keys), 1)
+
+    def body(c, carry):
+        m, l, acc = carry
+        b = rem(buf0 + c, 2)
+        more = c + 1 < nsteps
+
+        @pl.when(jnp.logical_or(more, s + 1 < pl.num_programs(0)))
+        def _prefetch():
+            step_copies(jnp.where(more, s, s + 1),
+                        jnp.where(more, c + 1, 0), 1 - b, start=True)
+
+        step_copies(s, c, b, start=False)
+        rows = buf[b]                              # [keys, width]
+        sc = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        sc = jnp.where(key < pos - c * step_keys, sc, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)                    # [heads, keys] fp32
+        alpha = jnp.exp(m - m_new)
+        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jnp.dot(
+            p.astype(rows.dtype), rows[:, :value_width],
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    m0 = jnp.sum(q.astype(jnp.float32) * new, axis=-1,
+                 keepdims=True) * scale            # [heads, 1]
+    l0 = jnp.ones((heads, 1), jnp.float32)
+    acc0 = jnp.broadcast_to(new[:, :value_width], (heads, value_width))
+    _, l, acc = jax.lax.fori_loop(0, nsteps, body, (m0, l0, acc0))
+    buf_ref[0] = rem(buf0 + nsteps, 2)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def mla_paged_decode(q, new_rows, pool, layer, block_tables, positions,
+                     value_width, scale, interpret: bool = False):
+    """Fused latent-attention decode over the one pool, one layer: see
+    `ops/paged_attention.paged_latent_decode` for the operands.
+    -> (`[slots, heads, value_width]`, the pool with the step's rows
+    written: an XLA scatter, in place under the engine's donation)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, heads, width = q.shape
+    block_size = pool.shape[2]
+    step_keys = block_size * latent_pages_per_step(block_size, width,
+                                                   pool.dtype)
+    prefetch = (block_tables.astype(jnp.int32),
+                positions.astype(jnp.int32))
+    new_rows = new_rows.astype(pool.dtype)
+    written = jnp.take_along_axis(
+        prefetch[0], (prefetch[1] // block_size)[:, None], axis=1)[:, 0]
+    pool = pool.at[layer, written, prefetch[1] % block_size].set(new_rows)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(slots,),
+        in_specs=[
+            pl.BlockSpec((1, heads, width), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec((1, 1, width), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, heads, value_width),
+                               lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, step_keys, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),         # a step buffer each
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_mla_decode_kernel, layer=int(layer),
+                          block_size=block_size,
+                          value_width=int(value_width), scale=scale),
+        **kernel_name(MLA_KERNEL_NAME, rename=False),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((slots, heads, value_width),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*prefetch, q, new_rows[:, None], pool)
+    return out, pool

@@ -485,3 +485,93 @@ def test_decode_step_with_chunk_keeps_the_kernels_names_and_copies_no_pool(
         + len(copies(texts["decode"], state_pools)), \
         copies(fused, state_pools)
     assert len(copies(fused, kv_pools)) <= 4, copies(fused, kv_pools)
+
+
+# -- the latent-attention decoder's kernel and engine programs -------------------
+
+def test_mla_decode_kernel_compiles_at_the_cells_geometry(chip):
+    """`pangu_ultra_serve_docqa` as it runs: 96 slots of 128 heads over
+    rows of 640 lanes (576 values), pages of 64, 8 pages a compute step;
+    a pool of 576-wide rows is what the chip's copy engine refuses."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        latent_pages_per_step, mla_paged_decode)
+
+    slots, heads, width, value, block, blocks, tables = \
+        96, 128, 640, 512, 64, 10240, 136
+    assert latent_pages_per_step(block, width, BF16) == 8
+    text = chip(
+        lambda q, new, pool, bt, pos: mla_paged_decode(
+            q, new, pool, 2, bt, pos, value, 192 ** -0.5),
+        ((slots, heads, width), BF16), ((slots, width), BF16),
+        ((5, blocks, block, width), BF16), ((slots, tables), I32),
+        ((slots,), I32))
+    assert "mla_paged_decode" in text
+
+
+def test_latent_engine_programs_hold_the_kernel_and_copy_no_pool(
+        topo, monkeypatch):
+    """The decode step of a small latent-attention decoder at the
+    kernels' widths (rows of 128 + 64 values in 256 lanes, experts 256 ->
+    2 x 128 -> 256), compiled for the described chip as the engine jits
+    it (the ONE pool donated): the latent walk's Mosaic call of every
+    layer lies under `%engine_decode_step`, where `mla_decode_roofline`
+    looks for it, the experts' two under `moe_experts_roofline`'s, and
+    neither program copies a pool-shaped array."""
+    import re
+
+    import numpy as np
+
+    from paddle_tpu.inference.engine import GenerationEngine
+    from paddle_tpu.models.pangu_ultra_moe import (
+        PanguUltraMoEConfig, PanguUltraMoEForCausalLM)
+
+    cfg = PanguUltraMoEConfig(
+        vocab_size=512, hidden_size=256, num_hidden_layers=2,
+        first_k_dense_replace=1, num_attention_heads=8, q_lora_rank=128,
+        kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, intermediate_size=512, moe_intermediate_size=128,
+        n_routed_experts=8, router_experts=16, num_experts_per_tok=4,
+        max_seq_len=512, dtype="bfloat16", init="zeros")
+    assert cfg.pool_row_width == 256
+    model = PanguUltraMoEForCausalLM(cfg)
+    model.eval()
+    monkeypatch.setattr("paddle_tpu.core.device.platform", lambda: "tpu")
+    monkeypatch.setattr("paddle_tpu.ops.paged_attention.on_tpu",
+                        lambda: True)
+    eng = GenerationEngine(model, num_slots=16, block_size=16,
+                           prefill_chunk=256, donate=True)
+    assert eng.attention_backend == "pallas"
+    one = SingleDeviceSharding(topo.devices[0])
+    c, i32 = eng.cache, np.int32
+    assert c.vpool is None
+    slots, blocks = eng.num_slots, eng.max_blocks
+    chunk = (np.zeros((1, eng.prefill_chunk), i32), i32(0), i32(0),
+             np.zeros(blocks, i32))
+    decode = (np.zeros((slots, 1), i32), np.zeros(slots, i32),
+              np.zeros((slots, blocks), i32))
+    head = (eng._state_arrays(), c.kpool, None)
+    texts = {}
+    for name, jitted, host in (("chunk", eng._prefill, chunk),
+                               ("decode", eng._decode, decode)):
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one), head + host)
+        with jax.default_matmul_precision("default"):
+            texts[name] = jitted.lower(*args).compile().as_text()
+
+    def calls(text, metric):
+        return [c for c in _all_instructions(text)
+                if "tpu_custom_call" in c and any(
+                    re.search(p, c) for p in _metric_patterns(metric))]
+
+    assert len(calls(texts["decode"], "mla_decode_roofline")) == 2
+    assert len(calls(texts["decode"], "moe_experts_roofline")) == 2
+    assert calls(texts["chunk"], "mla_decode_roofline") == []
+    pool = tuple(c.kpool.shape)
+    for name, text in texts.items():
+        copied = [i[:80] for i in _all_instructions(text)
+                  if (m := re.match(
+                      r"%\S+ = (\(?\w+\[([\d,]+)\].*) copy(?:-start)?\(",
+                      i)) and "S(1)" not in m.group(1)
+                  and tuple(map(int, m.group(2).split(","))) == pool]
+        assert copied == [], (name, copied)

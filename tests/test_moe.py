@@ -142,3 +142,81 @@ def test_switch_gate_ep2():
     set_hybrid_communicate_group(HybridCommunicateGroup())
     assert y.shape == [2, 8, 16]
     assert np.all(np.isfinite(np.asarray(y._array)))
+
+
+# -- the experts' function as a parameter of `expert_share` ----------------------
+
+def _dense_expert_loop(x, ids, weights, w1, w2, first, activation):
+    """Every held expert over every token, weighted where chosen."""
+    import jax
+    import jax.numpy as jnp
+
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w1.shape[0]):
+        h = x @ w1[e]
+        if activation == "swiglu":
+            half = h.shape[-1] // 2
+            h = jax.nn.silu(h[:, :half]) * h[:, half:]
+        else:
+            h = jnp.square(jnp.maximum(h, 0.0))
+        w_e = jnp.sum(jnp.where(ids == first + e, weights, 0.0), -1)
+        out = out + w_e[:, None] * (h @ w2[e])
+    return out
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("activation", ["relu2", "swiglu"])
+def test_expert_share_computes_the_experts_function_it_is_told(
+        backend, activation):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.distributed.moe import MOE_PATH_STATS, expert_share, \
+        reset_moe_path_stats
+
+    t, d, h, held, router, k, first = 21, 32, 24, 4, 16, 5, 8
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(keys[0], (t, d))
+    width = 2 * h if activation == "swiglu" else h
+    w1 = jax.random.normal(keys[1], (held, d, width)) * 0.3
+    w2 = jax.random.normal(keys[2], (held, h, d)) * 0.3
+    ids = jnp.argsort(jax.random.uniform(keys[3], (t, router)))[:, :k]
+    ids = ids.at[3].set(-1)                    # a row routed nowhere
+    weights = jax.random.uniform(keys[4], (t, k))
+    reset_moe_path_stats()
+    got, counters = expert_share(x, ids, weights, w1, w2, first,
+                                 backend=backend, activation=activation)
+    assert MOE_PATH_STATS[backend] == 1
+    want = _dense_expert_loop(x, ids, weights, w1, w2, first, activation)
+    assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5)
+    held_here = int(jnp.sum((ids >= first) & (ids < first + held)))
+    assert int(counters[0]) == held_here > 0
+    assert not np.asarray(got)[3].any()
+
+
+def test_expert_share_keeps_relu2_as_what_it_computes_untold():
+    """The hybrid's call names no function: its traced program is the
+    `relu2` one, operation for operation."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.distributed.moe import expert_share
+
+    x = jnp.ones((6, 16))
+    w1, w2 = jnp.ones((2, 16, 8)), jnp.ones((2, 8, 16))
+    ids = jnp.zeros((6, 2), jnp.int32)
+    weights = jnp.ones((6, 2))
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda *a: expert_share(
+            *a, 0, backend="xla", **kw))(x, ids, weights, w1, w2))
+
+    assert text() == text(activation="relu2")
+    assert "logistic" not in text() and "logistic" in str(
+        jax.make_jaxpr(lambda *a: expert_share(
+            *a, 0, backend="xla", activation="swiglu"))(
+                x, ids, weights, jnp.ones((2, 16, 16)), w2))
+    with pytest.raises(ValueError, match="activation"):
+        expert_share(x, ids, weights, w1, w2, 0, activation="gelu")

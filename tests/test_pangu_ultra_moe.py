@@ -1,0 +1,392 @@
+"""The `pangu_ultra_moe` decoder (latent attention over ONE paged pool of
+compressed rows, sandwich norms, dense then sigmoid-routed SwiGLU experts)
+at a tiny size on the CPU, float32: the whole model against the
+benchmark's plain reference on seeded weights; absorbed against expanded
+attention; chunked prefill then decode through `GenerationEngine`'s latent
+pool against the reference's one pass; the Pallas walk (interpreter)
+against the XLA walk; a request served through prefix hits against the
+same request served cold; the pool's size; idle lanes and padding routed
+nowhere; the counters; the share test.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib import weights
+from benchmarks.reference import common, pangu_ultra_moe as ref, stepwise
+from paddle_tpu.inference.engine import GenerationEngine, PagedKVCache
+from paddle_tpu.inference.serving_spec import PagedLatent
+from paddle_tpu.models.pangu_ultra_moe import (PanguUltraMoEConfig,
+                                               PanguUltraMoEForCausalLM)
+from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.ops.pallas.paged_attention import (latent_pages_per_step,
+                                                   mla_paged_decode)
+
+SEED = 7
+REF_KEYS = (
+    "hidden_size", "num_hidden_layers", "first_k_dense_replace",
+    "num_attention_heads", "q_lora_rank", "kv_lora_rank",
+    "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+    "intermediate_size", "moe_intermediate_size", "n_routed_experts",
+    "router_experts", "expert_offset", "num_experts_per_tok",
+    "routed_scaling_factor", "rope_theta", "rms_norm_eps", "vocab_size",
+    "initializer_range", "attn_query_init_std", "attn_key_init_std",
+    "router_init_std")
+
+
+def ref_cfg(cfg):
+    return {k: getattr(cfg, k) for k in REF_KEYS}
+
+
+def seeded(seed=SEED, **kw):
+    """The program's model with the reference's seeded weights bound: 8
+    experts held (4-11) of a 16-wide router."""
+    kw = dict(dict(router_experts=16, n_routed_experts=8, expert_offset=4),
+              **kw)
+    cfg = PanguUltraMoEConfig.tiny(**kw)
+    model = PanguUltraMoEForCausalLM(cfg)
+    model.eval()
+    arrays = weights.make_all(seed, ref.param_spec(ref_cfg(cfg)),
+                              jnp.float32)
+    named = dict(model.named_parameters())
+    assert set(named) == set(arrays)
+    for name, p in named.items():
+        assert tuple(p.shape) == tuple(arrays[name].shape), name
+        p._in_place_update(arrays[name])
+    return model, cfg
+
+
+def reference_logits(cfg, ids, seed=SEED, fault=None):
+    return np.asarray(stepwise.logits_of(
+        ref.build(ref_cfg(cfg), common.MM["f32"], fault=fault), seed,
+        np.asarray(ids, np.int32), jnp.float32))
+
+
+def prompts(cfg, lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
+            for n in lengths]
+
+
+def engine_for(model, **kw):
+    kw = dict(dict(num_slots=3, block_size=8, prefill_chunk=16,
+                   max_model_len=96), **kw)
+    return GenerationEngine(model, **kw)
+
+
+# -- the whole model against the reference -------------------------------------
+
+@pytest.mark.parametrize("layers,dense", [(1, 1), (1, 0), (3, 1)],
+                         ids=["dense_layer", "expert_layer", "three"])
+@pytest.mark.parametrize("absorbed", [False, True],
+                         ids=["expanded", "absorbed"])
+def test_forward_matches_the_reference(layers, dense, absorbed):
+    """Both forms of the attention: the same numbers as the reference's
+    expanded pass (so absorbed against expanded too)."""
+    model, cfg = seeded(layers=layers, dense=dense)
+    ids = np.stack(prompts(cfg, [29, 29]))
+    got = np.asarray(model.forward(ids, absorbed=absorbed)._array)
+    want = reference_logits(cfg, ids)
+    assert np.abs(want).max() > 0.5
+    np.testing.assert_allclose(got, want, atol=2e-4)
+
+
+def test_the_positions_and_the_routing_matter_to_the_logits():
+    """What the seeded scales are for: with the rotation left off the
+    keys, or a page of keys hidden, the reference's own logits move far
+    more than any tolerance used here."""
+    _, cfg = seeded()
+    ids = np.stack(prompts(cfg, [40]))
+    want = reference_logits(cfg, ids)
+    for fault in ("no_key_rotation", ("skip_keys", 8, 16),
+                  ("late_keys", 24, 8)):
+        off = reference_logits(cfg, ids, fault=fault)
+        assert np.abs(off - want)[0, 30:].max() > 0.05, fault
+
+
+# -- through the engine ---------------------------------------------------------
+
+@pytest.mark.parametrize("backend", ["dense", "pallas"])
+@pytest.mark.parametrize("chunk,lengths", [
+    (32, [21]),            # a prompt of one chunk, ending mid-block
+    (8, [37, 16]),         # of several (absorbed form), and whole blocks
+    (64, [40, 33, 5]),     # expanded form, more requests than one chunk
+], ids=["one_chunk", "several_chunks", "expanded_chunk"])
+def test_engine_chunked_prefill_then_decode_agrees_with_the_reference(
+        backend, chunk, lengths):
+    model, cfg = seeded()
+    eng = engine_for(model, prefill_chunk=chunk, attention_backend=backend)
+    pa.reset_latent_path_stats()
+    ps = prompts(cfg, lengths, seed=3)
+    rids = [eng.add_request(p, max_new_tokens=7) for p in ps]
+    out = eng.run()
+    assert eng.decode_traces == 1 and eng.prefill_traces == 1
+    assert pa.LATENT_PATH_STATS[backend] == cfg.num_hidden_layers
+    form = "expanded" if chunk >= 32 else "absorbed"
+    assert pa.LATENT_CHUNK_STATS[form] == cfg.num_hidden_layers
+    for rid, p in zip(rids, ps):
+        seq = np.asarray(out[rid], np.int32)
+        logits = reference_logits(cfg, seq[None, :-1])[0, len(p) - 1:]
+        served = seq[len(p):]
+        gap = logits.max(-1) - logits[np.arange(len(served)), served]
+        assert gap.max() < 1e-3
+
+
+def test_the_pallas_walk_serves_the_xla_walks_tokens():
+    model, cfg = seeded()
+    ps = prompts(cfg, [30, 9, 44, 17], seed=5)
+    streams = []
+    for backend in ("dense", "pallas"):
+        eng = engine_for(model, attention_backend=backend)
+        rids = [eng.add_request(p, max_new_tokens=9) for p in ps]
+        out = eng.run()
+        streams.append([out[r] for r in rids])
+    assert streams[0] == streams[1]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_kernel_matches_the_xla_walk_and_writes_the_same_pool(dtype):
+    rng = np.random.default_rng(0)
+    slots, heads, width, value, bs, blocks, layers, maxb = \
+        5, 8, 48, 32, 16, 40, 2, 6
+    pool = jnp.asarray(rng.normal(size=(layers, blocks, bs, width)), dtype)
+    q = jnp.asarray(rng.normal(size=(slots, heads, width)), dtype)
+    new = jnp.asarray(rng.normal(size=(slots, width)), dtype)
+    pos = np.array([0, 5, 16, 33, 95], np.int32)   # lane 0 idle
+    bt = np.zeros((slots, maxb), np.int32)
+    nxt = 1
+    for s in range(1, slots):
+        n = pos[s] // bs + 1
+        bt[s, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    bt, pos = jnp.asarray(bt), jnp.asarray(pos)
+    want, pool_x = pa._latent_dense_step(q, new, pool, 1, bt, pos, value,
+                                         0.2)
+    got, pool_k = mla_paged_decode(q, new, pool, 1, bt, pos, value, 0.2,
+                                   interpret=True)
+    assert bool(jnp.all(pool_x == pool_k))
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=1e-5 if dtype == jnp.float32 else 1e-2)
+    # a lane's output does not depend on rows past its position
+    dirty = pool.at[1, bt[3, 2], 2:].set(1e4)
+    again, _ = mla_paged_decode(q, new, dirty, 1, bt, pos, value, 0.2,
+                                interpret=True)
+    np.testing.assert_allclose(np.asarray(again[3], np.float32),
+                               np.asarray(got[3], np.float32), atol=1e-6)
+
+
+@pytest.mark.parametrize("block,width,want", [
+    (64, 640, 8), (64, 576, 8), (16, 48, 32), (256, 640, 2)])
+def test_pages_a_step_follow_from_shapes(block, width, want):
+    assert latent_pages_per_step(block, width, jnp.bfloat16) == want
+
+
+@pytest.mark.parametrize("rows,form", [(512, "expanded"), (256, "expanded"),
+                                       (171, "expanded"), (170, "absorbed"),
+                                       (64, "absorbed")])
+def test_a_chunks_form_follows_from_its_width(rows, form):
+    """At the published head sizes expanding a cached row pays from 171
+    rows on."""
+    assert pa.latent_chunk_form(rows, 128, 64, 128, 512) == form
+
+
+# -- the prefix cache over the latent pool ---------------------------------------
+
+@pytest.mark.parametrize("backend", ["dense", "pallas"])
+def test_a_request_served_through_prefix_hits_is_the_request_served_cold(
+        backend):
+    model, cfg = seeded()
+    doc = prompts(cfg, [43], seed=9)[0]
+    questions = prompts(cfg, [6, 11, 3], seed=10)
+
+    def serve(engine):
+        outs = []
+        for q in questions:
+            rid = engine.add_request(np.concatenate([doc, q]),
+                                     max_new_tokens=8)
+            outs.append(engine.run()[rid])
+        return outs
+
+    cold = [serve(engine_for(model, attention_backend=backend,
+                             enable_prefix_cache=False))]
+    warm_engine = engine_for(model, attention_backend=backend)
+    assert warm_engine.enable_prefix_cache
+    warm = serve(warm_engine)
+    assert warm == cold[0]
+    # the document's 5 full blocks of 8 hit on the second and third turn
+    assert warm_engine.prefix_hit_tokens == 2 * 40
+    assert warm_engine.cache.num_cached_blocks >= 5
+    # and every served token is the reference's
+    seq = np.asarray(warm[2], np.int32)
+    plen = len(doc) + len(questions[2])
+    logits = reference_logits(cfg, seq[None, :-1])[0, plen - 1:]
+    gap = logits.max(-1) - logits[np.arange(8), seq[plen:]]
+    assert gap.max() < 1e-3
+
+
+def test_a_shared_block_is_copied_before_a_hit_lane_writes_into_it():
+    """A prompt that IS a cached prefix (whole blocks): the first decode
+    feeds its last token into a shared block, which copy-on-write makes
+    private through the one-pool copy program."""
+    model, cfg = seeded()
+    eng = engine_for(model)
+    p = prompts(cfg, [32], seed=2)[0]
+    first = eng.add_request(p, max_new_tokens=5)
+    a = eng.run()[first]
+    second = eng.add_request(p, max_new_tokens=5)
+    b = eng.run()[second]
+    assert a == b and eng.prefix_hit_tokens == 32
+    assert eng._cow_pure.traces == 1
+    assert eng.cache.vpool is None
+
+
+# -- the pool ------------------------------------------------------------------
+
+def test_the_pool_is_one_array_of_rows_and_nothing_beside_it():
+    model, cfg = seeded()
+    eng = engine_for(model, num_blocks=20)
+    spec = eng.spec.paged_kv
+    assert spec == PagedLatent(cfg.num_hidden_layers, 40, 32, 4)
+    shape, dtype = eng.cache.pool_spec()
+    assert shape == (3, 20, 8, 40) and eng.cache.vpool is None
+    assert eng.cache.pool_nbytes() == 20 * 8 * 40 * 4 * 3 \
+        == int(eng.cache.kpool.nbytes)
+    assert "engine_pool_bytes" in eng.metrics.render_prometheus()
+
+
+def test_the_published_rows_are_576_values_in_640_lanes():
+    cfg = PanguUltraMoEConfig()
+    assert cfg.row_values == 576 and cfg.pool_row_width == 640
+    assert PanguUltraMoEConfig.tiny().pool_row_width == 40
+    cache = PagedKVCache(5, 4, 64, None, cfg.pool_row_width,
+                         dtype=jnp.bfloat16)
+    assert cache.pool_spec()[0] == (5, 4, 64, 640)
+    assert cache.pool_nbytes() == 4 * 64 * 640 * 2 * 5
+
+
+def test_a_latent_pool_takes_neither_a_mesh_nor_int8():
+    with pytest.raises(ValueError, match="no head axis"):
+        PagedKVCache(1, 4, 8, None, 40, kv_dtype="int8")
+
+
+@pytest.mark.parametrize("kwargs,feature", [
+    (dict(kv_dtype="int8"), "kv_int8"),
+    (dict(weight_dtype="int8"), "weight_int8"),
+    (dict(spec_decode_k=2), "spec_decode"),
+    (dict(mp_degree=2), "mp_degree"),
+])
+def test_what_the_spec_refuses_is_refused_at_construction(kwargs, feature):
+    model, _ = seeded(layers=1)
+    with pytest.raises(ValueError, match=feature.split("_")[0]):
+        engine_for(model, **kwargs)
+
+
+# -- routed nowhere, and the counters ---------------------------------------------
+
+def test_idle_lanes_and_padding_are_routed_nowhere():
+    model, cfg = seeded(layers=1, dense=0)
+    mlp = model.layers[0].mlp
+    u = jax.random.normal(jax.random.PRNGKey(1), (6, cfg.hidden_size))
+    live = jnp.array([True, False, True, False, False, True])
+    out, counters = mlp.forward_rows(u, live)
+    alone, c3 = mlp.forward_rows(u[jnp.array([0, 2, 5])],
+                                 jnp.ones(3, bool))
+    np.testing.assert_allclose(np.asarray(out)[[0, 2, 5]],
+                               np.asarray(alone), atol=1e-5)
+    assert int(counters[0]) == int(c3[0]) > 0
+    # a dead row gets the shared expert's part alone
+    from paddle_tpu.models.pangu_ultra_moe import _gated_mlp
+
+    shared = _gated_mlp(u, mlp.shared.gate_up._array,
+                        mlp.shared.down._array)
+    np.testing.assert_allclose(np.asarray(out)[1], np.asarray(shared)[1],
+                               atol=1e-6)
+
+
+def test_engine_counts_the_experts_load_and_the_rows_walked():
+    model, cfg = seeded()
+    eng = engine_for(model, async_core=False)
+    ps = prompts(cfg, [10, 20], seed=4)
+    for p in ps:
+        eng.add_request(p, max_new_tokens=4)
+    eng.run()
+    t = eng.step_counter_totals
+    assert set(t) == {"decode_live_lanes", "moe_assignments_held",
+                      "moe_experts_touched", "moe_max_expert_load",
+                      "mla_context_rows"}
+    # three decode steps a request (the first token is the chunk's); a
+    # step at position p walks p + 1 rows
+    assert t["decode_live_lanes"] == 2 * 3
+    assert t["mla_context_rows"] == sum(
+        len(p) + k + 1 for p in ps for k in range(3))
+    # 2 expert layers, 3 of 16 experts a token, 8 held: at most 3 a lane
+    assert 0 < t["moe_assignments_held"] <= 6 * 2 * 3
+    assert 0 < t["moe_experts_touched"] <= t["moe_assignments_held"]
+    assert 1 <= t["moe_max_expert_load"] <= 2
+    text = eng.metrics.render_prometheus()
+    assert "mla_context_rows" in text
+
+
+def test_ahead_and_serial_orders_serve_the_same_tokens():
+    model, cfg = seeded()
+    ps = prompts(cfg, [12, 31, 7, 22, 18], seed=6)
+    streams = []
+    for async_core in (None, False):
+        eng = engine_for(model, async_core=async_core)
+        rids = [eng.add_request(p, max_new_tokens=6) for p in ps[:3]]
+        eng.step()
+        rids += [eng.add_request(p, max_new_tokens=6) for p in ps[3:]]
+        out = eng.run()
+        streams.append([out[r] for r in rids])
+    assert streams[0] == streams[1]
+
+
+# -- the share test -----------------------------------------------------------
+
+def _sparse_leaves(cfg, first, held, seed=3):
+    """Reference leaves of one expert layer's MLP that holds `held`
+    experts from `first`, cut out of ONE uncut layer's seeded arrays."""
+    whole = dict(ref_cfg(cfg), num_hidden_layers=1, first_k_dense_replace=0,
+                 n_routed_experts=cfg.router_experts, expert_offset=0)
+    arrays = weights.make_all(seed, ref.param_spec(whole), jnp.float32)
+    p = [arrays[f"layers.0.mlp.{leaf}"] for leaf in ref.MLP_LEAVES["sparse"]]
+    p[3], p[4] = p[3][first:first + held], p[4][first:first + held]
+    return p
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """The routed parts that all 4 shares give, plus the shared expert
+    counted once, are the uncut layer — for the reference, and for the
+    program's layer told which experts it holds."""
+    kw = dict(layers=1, dense=0, router_experts=16, n_routed_experts=4,
+              num_experts_per_tok=5)
+    cfg = PanguUltraMoEConfig.tiny(**kw)
+    u = jax.random.normal(jax.random.PRNGKey(0), (2, 7, 64))
+    mm = common.mm_f32
+    uncut = dict(ref_cfg(cfg), n_routed_experts=16, expert_offset=0)
+    whole = ref.ffn(_sparse_leaves(cfg, 0, 16), u, ref.sizes(uncut), mm)
+    parts = []
+    for first in (0, 4, 8, 12):
+        z = ref.sizes(dict(ref_cfg(cfg), expert_offset=first))
+        parts.append(ref.moe_routed_part(_sparse_leaves(cfg, first, 4), u,
+                                         z, mm))
+    shared = ref.moe_shared_part(_sparse_leaves(cfg, 0, 4), u, mm)
+    np.testing.assert_allclose(sum(parts) + shared, whole, atol=1e-5)
+    assert all(np.abs(p).max() > 1e-3 for p in parts)
+
+    flat = u.reshape(-1, 64)
+    got = 0
+    for first in (0, 4, 8, 12):
+        layer = PanguUltraMoEForCausalLM(PanguUltraMoEConfig.tiny(
+            expert_offset=first, **kw)).layers[0].mlp
+        for leaf, a in zip(ref.MLP_LEAVES["sparse"],
+                           _sparse_leaves(cfg, first, 4)):
+            owner, name = leaf.split(".")
+            getattr(getattr(layer, owner), name)._in_place_update(a)
+        out, counters = layer.forward_rows(flat, jnp.ones(14, bool))
+        got = got + np.asarray(out).reshape(2, 7, 64) \
+            - (np.asarray(shared) if first else 0)
+        assert counters[0] > 0
+    np.testing.assert_allclose(got, whole, atol=1e-5)

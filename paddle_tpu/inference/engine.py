@@ -2039,6 +2039,7 @@ class GenerationEngine:
         model, state = self.model, self._state
         spec = self.spec
         C = self.prefill_chunk
+        backend = self.attention_backend
         mp_axis = self._mp_axis
         use_q = self.kv_dtype == "int8"
         use_s = self.sampling
@@ -2062,7 +2063,7 @@ class GenerationEngine:
                     Tensor._wrap(tokens), Tensor._wrap(start),
                     Tensor._wrap(kpool), _wrap_pool(vpool),
                     Tensor._wrap(table_row), Tensor._wrap(plen),
-                    mp_axis=mp_axis,
+                    backend=backend, mp_axis=mp_axis,
                     kv_scales=None if scales is None
                     else Tensor._wrap(scales), lora=lora, **kw)
                 nxt = _last_prompt_row_token(

@@ -153,6 +153,9 @@ class ServingSpec:
 
     def prefill_chunk(self, tokens, start, kpool, vpool, block_row, plen,
                       **kw) -> StepOut:
+        """`backend` among `kw` is the engine's one resolved attention
+        backend, as `decode` takes it: a spec whose chunk has one form
+        (a K/V pool's XLA loop) takes no notice of it."""
         raise NotImplementedError
 
     def decode(self, tokens, positions, kpool, vpool, block_tables,

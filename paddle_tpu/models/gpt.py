@@ -979,7 +979,8 @@ class GPTServing(ServingSpec):
         return StepOut(r[0], r[1], r[2], kv_scales=r[3])
 
     def prefill_chunk(self, tokens, start, kpool, vpool, block_row, plen,
-                      mp_axis=None, kv_scales=None, lora=None):
+                      backend="auto", mp_axis=None, kv_scales=None,
+                      lora=None):
         return self._out(self.model.gpt.forward_prefill_chunk(
             tokens, start, kpool, vpool, block_row, plen,
             mp_axis=mp_axis, kv_scales=kv_scales, lora=lora), kv_scales)

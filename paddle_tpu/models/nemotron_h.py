@@ -508,8 +508,8 @@ class NemotronHServing(ServingSpec):
             parts + [jnp.full(1, with_chunk, jnp.int32)])
 
     def prefill_chunk(self, tokens, start, kpool, vpool, block_row, plen,
-                      mp_axis=None, kv_scales=None, lora=None,
-                      slot_state=(), state_row=None):
+                      backend="auto", mp_axis=None, kv_scales=None,
+                      lora=None, slot_state=(), state_row=None):
         from paddle_tpu.ops.paged_attention import paged_prefill_chunk
 
         ids = tokens._array                               # [1, C]

@@ -242,7 +242,7 @@ class LatentAttention(nn.Layer):
                            preferred_element_type=_F32)
         return self.out(o.astype(u.dtype))
 
-    def chunk(self, u, pool, layer, block_row, start, plen):
+    def chunk(self, u, pool, layer, block_row, start, plen, backend):
         """One slot's chunk `u [C, hidden]` at positions `start ..`."""
         from paddle_tpu.ops.paged_attention import \
             paged_latent_prefill_chunk
@@ -251,7 +251,7 @@ class LatentAttention(nn.Layer):
         q_nope, q_rope, row = self.project(u, positions)
         o, pool = paged_latent_prefill_chunk(
             q_nope, q_rope, self._padded(row), self.w_kvb(), pool, layer,
-            block_row, start, plen, self.scale)
+            block_row, start, plen, self.scale, backend=backend)
         return self.out(o), pool
 
     def step(self, u, pool, layer, block_tables, positions, backend):
@@ -457,14 +457,16 @@ class PanguUltraMoEServing(ServingSpec):
         return Tensor._wrap(self.model._head(hidden._array))
 
     def prefill_chunk(self, tokens, start, kpool, vpool, block_row, plen,
-                      mp_axis=None, kv_scales=None, lora=None):
+                      backend="auto", mp_axis=None, kv_scales=None,
+                      lora=None):
         ids = tokens._array                               # [1, C]
         width = ids.shape[1]
         pool = [kpool._array]
         row, s0, n = block_row._array, start._array, plen._array
 
         def attention(mixer, u, i):
-            out, pool[0] = mixer.chunk(u[0], pool[0], i, row, s0, n)
+            out, pool[0] = mixer.chunk(u[0], pool[0], i, row, s0, n,
+                                       backend)
             return out[None]
 
         h, _ = self.model._walk(

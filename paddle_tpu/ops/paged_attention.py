@@ -939,8 +939,9 @@ def dense_gather_reference(kpool, vpool, layer, block_row, length,
 #: which form of the latent decode walk was traced (per trace, as
 #: `PAGED_PATH_STATS`): never a silent fallback
 LATENT_PATH_STATS = {"dense": 0, "pallas": 0}
-#: which form a prefill chunk took (per trace)
-LATENT_CHUNK_STATS = {"expanded": 0, "absorbed": 0}
+#: which form a prefill chunk took (per trace): the XLA loop's two, and
+#: the fused kernel's (`mla_paged_prefill`, which expands in VMEM)
+LATENT_CHUNK_STATS = {"expanded": 0, "absorbed": 0, "pallas_expanded": 0}
 #: keys one iteration of the chunk's loop gathers and scores
 _LATENT_CHUNK_KEYS = 512
 
@@ -1043,7 +1044,11 @@ def latent_chunk_form(chunk_rows, nope_dim, rope_dim, value_dim,
     `value_width x (nope_dim + value_dim) / (2 x value_width - nope_dim
     - value_dim)` rows on: 171 at 512 / 128 / 128. The chunk's width is
     static (every row of it is computed, valid or padding), so the choice
-    is too."""
+    is too. The fused kernel changes neither side of it: its products
+    are the expanded form's, and the 64 lanes by which it pads the
+    rotated part to a tile the absorbed form's 640-wide product pads
+    too (on a v5e, rows scored as they lie in the same kernel took 3.27
+    ms a layer call where expanding took 2.45, at 256 rows)."""
     more_a_pair = 2 * value_width - nope_dim - value_dim
     if more_a_pair <= 0:
         return "absorbed"
@@ -1053,7 +1058,7 @@ def latent_chunk_form(chunk_rows, nope_dim, rope_dim, value_dim,
 
 def paged_latent_prefill_chunk(q_nope, q_rope, new_rows, w_kvb, pool,
                                layer, block_row, start, plen, scale,
-                               form=None):
+                               form=None, backend="auto"):
     """One chunk of ONE slot's prompt, one layer: write the chunk's rows,
     then attend its queries over everything the slot's table covers so
     far (shared prefix blocks, earlier chunks, the chunk itself,
@@ -1066,7 +1071,15 @@ def paged_latent_prefill_chunk(q_nope, q_rope, new_rows, w_kvb, pool,
     `start`, `plen` traced. Rows at and past `plen` write the null block.
     `form` None: `latent_chunk_form` of the shapes. -> (`[C, heads,
     value_dim]`, the pool). Numerics as `paged_prefill_chunk`: operands
-    at the pool's dtype, float32 accumulation and softmax state."""
+    at the pool's dtype, float32 accumulation and softmax state.
+
+    `backend` as `paged_latent_decode`'s (`resolve_latent_backend`: the
+    engine hands down its one resolved choice): under `pallas` an
+    expanded chunk attends in the fused kernel (`mla_paged_prefill`:
+    the same rows, the same numerics, the scores kept in VMEM), and the
+    XLA loop below is the off-chip path and the kernel's parity probe. A
+    chunk narrow enough to take the absorbed form runs the loop under
+    either backend. `LATENT_CHUNK_STATS` says which was traced."""
     C, heads, dn = q_nope.shape
     dr = q_rope.shape[2]
     rank = w_kvb.shape[0]
@@ -1074,12 +1087,20 @@ def paged_latent_prefill_chunk(q_nope, q_rope, new_rows, w_kvb, pool,
     bs, maxb = pool.shape[2], block_row.shape[0]
     if form is None:
         form = latent_chunk_form(C, dn, dr, dv, rank)
-    LATENT_CHUNK_STATS[form] += 1
+    fused = form == "expanded" and resolve_latent_backend(
+        backend, pool.shape[3], rank, bs, heads) == "pallas"
+    LATENT_CHUNK_STATS["pallas_expanded" if fused else form] += 1
     dt = pool.dtype
     pos = start + jnp.arange(C)
     valid = pos < plen
     bid = jnp.where(valid, block_row[jnp.minimum(pos // bs, maxb - 1)], 0)
     pool = pool.at[layer, bid, pos % bs].set(new_rows.astype(dt))
+    if fused:
+        from .pallas.paged_attention import mla_paged_prefill
+
+        return mla_paged_prefill(
+            q_nope, q_rope, w_kvb, pool, layer, block_row, start, plen,
+            scale, interpret=pallas_interpret()), pool
     group = max(1, min(_LATENT_CHUNK_KEYS // bs, maxb))    # pages a trip
     keys = group * bs
     end = jnp.minimum(start + C, plen)

@@ -68,6 +68,7 @@ the virtual-mesh CPU CI proves the sharded kernel token-exact.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +76,9 @@ import jax.numpy as jnp
 from paddle_tpu.ops.pallas.naming import kernel_name
 
 __all__ = ["paged_decode_attention", "paged_verify_attention",
-           "pages_per_step", "mla_paged_decode", "latent_pages_per_step"]
+           "pages_per_step", "mla_paged_decode", "latent_pages_per_step",
+           "mla_paged_prefill", "latent_prefill_heads",
+           "latent_prefill_pages_per_step"]
 
 _NEG_INF = -1e30
 
@@ -776,14 +779,16 @@ MLA_KERNEL_NAME = "mla_paged_decode"
 _LATENT_WALK_KEYS = 512
 
 
-def latent_pages_per_step(block_size, row_width, dtype):
+def latent_pages_per_step(block_size, row_width, dtype,
+                          keys=_LATENT_WALK_KEYS):
     """Pages of a slot's table that one compute step of
     `_mla_decode_kernel` fetches and scores: `_LATENT_WALK_KEYS` keys, as
     far as its two step buffers fit `_WALK_VMEM_BYTES` (8 pages of 64
-    rows x 576 bf16 = 1.2 MB of scratch). A function of shapes alone."""
+    rows x 576 bf16 = 1.2 MB of scratch). A function of shapes alone.
+    The prefill kernel asks with its own `keys`."""
     lanes = -(-row_width // 128) * 128             # as VMEM tiles it
     page = block_size * lanes * jnp.dtype(dtype).itemsize
-    return max(1, min(_LATENT_WALK_KEYS // block_size,
+    return max(1, min(keys // block_size,
                       _WALK_VMEM_BYTES // (2 * page)))
 
 
@@ -931,3 +936,222 @@ def mla_paged_decode(q, new_rows, pool, layer, block_tables, positions,
         interpret=interpret,
     )(*prefetch, q, new_rows[:, None], pool)
     return out, pool
+
+
+#: the name the device trace shows for the prefill chunk's kernel
+#: (`mla_decode_roofline`'s pattern matches none of it)
+MLA_PREFILL_KERNEL_NAME = "mla_paged_prefill"
+#: (chunk row, key) pairs of one head that one compute step of the prefill
+#: kernel scores: 1,024 keys at a chunk of 256 rows (1 MB of float32
+#: scores a head). On a v5e a step of 512 keys took 3.02 ms a layer call
+#: at 256 rows x 128 heads x 6.1k keys and one of 1,024 keys 2.54: a
+#: head's chain of products and softmax pays its latencies once a step
+_LATENT_PREFILL_PAIRS = 256 * 1024
+
+
+def latent_prefill_heads(heads):
+    """Heads ONE program of `_mla_prefill_kernel` attends. A step's rows
+    are copied once a program, so 8 heads a program fetch a slot's rows
+    `heads / 8` times a layer (124 MB for 6k rows at 128 heads: 0.4 ms of
+    copies behind 2.5 ms of products), and their weights and softmax
+    state (2 MB + 1 MB at a chunk of 256) fit beside the step buffers."""
+    return math.gcd(heads, 8)
+
+
+def latent_prefill_pages_per_step(chunk, block_size, row_width, dtype):
+    """Pages one compute step of `_mla_prefill_kernel` fetches and every
+    head of the program scores: `_LATENT_PREFILL_PAIRS / chunk` keys (at
+    least 128, at most 1,024: a wider chunk takes fewer keys a step, so
+    a head's scores stay 1 MB), as far as the two step buffers fit
+    `_WALK_VMEM_BYTES`. A function of shapes alone."""
+    keys = min(1024, max(128, _LATENT_PREFILL_PAIRS // chunk))
+    return latent_pages_per_step(block_size, row_width, dtype, keys)
+
+
+def _mla_prefill_kernel(row_ref, lim_ref, qn_ref, qr_ref, w_ref, pool_ref,
+                        o_ref, buf, wbuf, m_ref, l_ref, acc_ref,
+                        copy_sems, w_sem, *, layer, block_size, rank, dn,
+                        scale):
+    """One program a group of heads; the chunk's own rows are in the pool
+    already (XLA's scatter beside the kernel, as the decode step's row).
+    row_ref `[max_blocks]` and lim_ref `[start, end]` are scalar
+    prefetch; qn_ref `[G, C, dn]` and qr_ref `[G, C, row_width - rank]`
+    (the rotated part, in the lanes of the row's tail) this group's
+    queries; w_ref `[rank, heads * (dn + dv)]` (HBM) every head's `W_UK`
+    then `W_UV` side by side, this group's copied to wbuf `[G, rank,
+    dn + dv]` once; pool_ref the whole pool (HBM), only read; buf
+    `[2, pages * block_size, row_width]` the two step buffers.
+
+    A step's rows are copied ONCE (the next step's behind this step's
+    products) and EXPANDED in VMEM a head at a time: `c x W_h` gives the
+    head's keys and values of the step, then `q_nope . k`, `q_rope .
+    k_rope` (the row's tail as it lies: its padding lanes meet the
+    query's padding, both nought), the float32 online softmax and
+    `p x v`. Scores, probabilities and the running state of the G heads
+    never leave VMEM. Every row of the chunk is computed; a key is seen
+    by the rows at or past its position. Measured on a v5e against the
+    rows scored as they lie (absorbed queries, 640- and 512-wide
+    products): 2.45 ms a layer call against 3.27.
+
+    Two heads a trip of the (rolled) head loop: the two chains of
+    products and softmax are independent, so one's exponentials run
+    under the other's products (2.70 ms against 3.02 at 512 keys)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    div, rem = jax.lax.div, jax.lax.rem
+    g = pl.program_id(0)
+    start, end = lim_ref[0], lim_ref[1]
+    group, chunk, _ = qn_ref.shape
+    step_keys = buf.shape[1]
+    pages = step_keys // block_size
+    kvw = wbuf.shape[2]
+    blocks = div(jnp.maximum(end - 1, 0), block_size) + 1
+    nsteps = div(blocks + pages - 1, pages)
+
+    def w_copy(h):
+        return pltpu.make_async_copy(
+            w_ref.at[:, pl.ds(pl.multiple_of((g * group + h) * kvw, kvw),
+                              kvw)],
+            wbuf.at[h], w_sem)
+
+    def step_copies(c, b, start_it):
+        first = c * pages
+        live = jnp.clip(blocks - first, 0, pages)
+
+        def page(i, _):
+            copy = pltpu.make_async_copy(
+                pool_ref.at[layer, row_ref[first + i]],
+                buf.at[b, pl.ds(pl.multiple_of(i * block_size,
+                                               block_size), block_size)],
+                copy_sems.at[b])
+            copy.start() if start_it else copy.wait()
+
+        jax.lax.fori_loop(0, live, page, None)
+
+    @pl.when(g == 0)
+    def _cold():
+        # rows no copy ever fills meet probability 0 in the value
+        # product: they must be finite
+        buf[...] = jnp.zeros_like(buf)
+
+    for h in range(group):
+        w_copy(h).start()
+    step_copies(0, 0, True)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    for h in range(group):
+        w_copy(h).wait()
+
+    row_pos = start + jax.lax.broadcasted_iota(
+        jnp.int32, (chunk, step_keys), 0)
+    key = jax.lax.broadcasted_iota(jnp.int32, (chunk, step_keys), 1)
+    nt = (((1,), (1,)), ((), ()))
+    together = 1 if group % 2 else 2
+
+    def step(c, _):
+        b = rem(c, 2)
+
+        @pl.when(c + 1 < nsteps)
+        def _prefetch():
+            step_copies(c + 1, 1 - b, True)
+
+        step_copies(c, b, False)
+        rows = buf[b]                              # [keys, row_width]
+        c_kv, tail = rows[:, :rank], rows[:, rank:]
+        seen = key + c * step_keys <= row_pos
+
+        def head(h):
+            kv = jnp.dot(c_kv, wbuf[h],
+                         preferred_element_type=jnp.float32
+                         ).astype(rows.dtype)      # [keys, dn + dv]
+            sc = (jax.lax.dot_general(
+                qn_ref[h], kv[:, :dn], nt,
+                preferred_element_type=jnp.float32)
+                + jax.lax.dot_general(
+                    qr_ref[h], tail, nt,
+                    preferred_element_type=jnp.float32)) * scale
+            sc = jnp.where(seen, sc, _NEG_INF)     # [C, keys] fp32
+            m = m_ref[h]
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                p.astype(rows.dtype), kv[:, dn:],
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+        def heads(i, _):
+            for j in range(together):
+                head(i * together + j)
+
+        jax.lax.fori_loop(0, group // together, heads, None)
+
+    jax.lax.fori_loop(0, nsteps, step, None)
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                  ).astype(o_ref.dtype)
+
+
+def mla_paged_prefill(q_nope, q_rope, w_kvb, pool, layer, block_row, start,
+                      plen, scale, interpret: bool = False):
+    """The latent prefill chunk's attention over the one pool, one layer,
+    the chunk's own rows already written to it: see
+    `ops/paged_attention.paged_latent_prefill_chunk` for the operands.
+    -> `[C, heads, value_dim]` in q_nope's dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    chunk, heads, dn = q_nope.shape
+    rank, _, kvw = w_kvb.shape
+    dv = kvw - dn
+    block_size, width = pool.shape[2:]
+    dt = pool.dtype
+    group = latent_prefill_heads(heads)
+    tail = width - rank
+    step_keys = block_size * latent_prefill_pages_per_step(
+        chunk, block_size, width, dt)
+    # heads lead (Mosaic's products take no head axis in the middle); the
+    # rotated part in the lanes of the row's tail, its padding nought
+    qn = jnp.swapaxes(q_nope.astype(dt), 0, 1)
+    qr = jnp.swapaxes(jnp.pad(
+        q_rope.astype(dt),
+        ((0, 0), (0, 0), (0, tail - q_rope.shape[2]))), 0, 1)
+    lims = jnp.stack([start, jnp.minimum(start + chunk, plen)]
+                     ).astype(jnp.int32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(heads // group,),
+        in_specs=[
+            pl.BlockSpec((group, chunk, dn), lambda g, *_: (g, 0, 0)),
+            pl.BlockSpec((group, chunk, tail), lambda g, *_: (g, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((group, chunk, dv),
+                               lambda g, *_: (g, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, step_keys, width), dt),
+            pltpu.VMEM((group, rank, kvw), dt),
+            pltpu.VMEM((group, chunk, 1), jnp.float32),
+            pltpu.VMEM((group, chunk, 1), jnp.float32),
+            pltpu.VMEM((group, chunk, dv), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),         # a step buffer each
+            pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_mla_prefill_kernel, layer=int(layer),
+                          block_size=block_size, rank=rank, dn=dn,
+                          scale=scale),
+        **kernel_name(MLA_PREFILL_KERNEL_NAME, rename=False),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((heads, chunk, dv), q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(block_row.astype(jnp.int32), lims, qn, qr,
+      w_kvb.astype(dt).reshape(rank, heads * kvw), pool)
+    return jnp.swapaxes(out, 0, 1)

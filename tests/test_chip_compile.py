@@ -508,15 +508,47 @@ def test_mla_decode_kernel_compiles_at_the_cells_geometry(chip):
     assert "mla_paged_decode" in text
 
 
+#: what a per-layer metric of the prefill kernel's time would look for
+#: (as `moe_prefill_experts_busy_pct` does for the experts' product)
+MLA_PREFILL_PATTERN = \
+    r"(?s)^%engine_prefill_chunk\S* = .*kernel_name\W+mla_paged_prefill"
+
+
+@pytest.mark.parametrize("chunk,pages", [(256, 16), (512, 8)])
+def test_mla_prefill_kernel_compiles_at_the_cells_geometry(chip, chunk,
+                                                           pages):
+    """`pangu_ultra_serve_docqa`'s chunk as it runs (256 rows of 128
+    heads against rows of 640 lanes in pages of 64, 8 heads a program,
+    16 pages = 1,024 keys a step), and a chunk twice as wide, which takes
+    half the keys a step."""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        latent_prefill_heads, latent_prefill_pages_per_step,
+        mla_paged_prefill)
+
+    heads, width, rank, block, blocks, tables = 128, 640, 512, 64, 10240, 136
+    assert latent_prefill_heads(heads) == 8
+    assert latent_prefill_pages_per_step(chunk, block, width, BF16) == pages
+    text = chip(
+        lambda qn, qr, w, pool, row, start, plen: mla_paged_prefill(
+            qn, qr, w, pool, 2, row, start, plen, 192 ** -0.5),
+        ((chunk, heads, 128), BF16), ((chunk, heads, 64), BF16),
+        ((rank, heads, 256), BF16), ((5, blocks, block, width), BF16),
+        ((tables,), I32), ((), I32), ((), I32))
+    assert "mla_paged_prefill" in text
+
+
 def test_latent_engine_programs_hold_the_kernel_and_copy_no_pool(
         topo, monkeypatch):
     """The decode step of a small latent-attention decoder at the
-    kernels' widths (rows of 128 + 64 values in 256 lanes, experts 256 ->
-    2 x 128 -> 256), compiled for the described chip as the engine jits
+    kernels' widths (rows of 256 + 64 values in 384 lanes, so that a
+    chunk of 256 rows takes the expanded form; experts 256 -> 2 x 128 ->
+    256), compiled for the described chip as the engine jits
     it (the ONE pool donated): the latent walk's Mosaic call of every
     layer lies under `%engine_decode_step`, where `mla_decode_roofline`
-    looks for it, the experts' two under `moe_experts_roofline`'s, and
-    neither program copies a pool-shaped array."""
+    looks for it, the experts' two under `moe_experts_roofline`'s, the
+    chunk's attention kernel of every layer under
+    `%engine_prefill_chunk` (the engine's one backend reaches both
+    programs), and neither program copies a pool-shaped array."""
     import re
 
     import numpy as np
@@ -528,11 +560,11 @@ def test_latent_engine_programs_hold_the_kernel_and_copy_no_pool(
     cfg = PanguUltraMoEConfig(
         vocab_size=512, hidden_size=256, num_hidden_layers=2,
         first_k_dense_replace=1, num_attention_heads=8, q_lora_rank=128,
-        kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        kv_lora_rank=256, qk_nope_head_dim=128, qk_rope_head_dim=64,
         v_head_dim=128, intermediate_size=512, moe_intermediate_size=128,
         n_routed_experts=8, router_experts=16, num_experts_per_tok=4,
         max_seq_len=512, dtype="bfloat16", init="zeros")
-    assert cfg.pool_row_width == 256
+    assert cfg.pool_row_width == 384
     model = PanguUltraMoEForCausalLM(cfg)
     model.eval()
     monkeypatch.setattr("paddle_tpu.core.device.platform", lambda: "tpu")
@@ -567,6 +599,12 @@ def test_latent_engine_programs_hold_the_kernel_and_copy_no_pool(
     assert len(calls(texts["decode"], "mla_decode_roofline")) == 2
     assert len(calls(texts["decode"], "moe_experts_roofline")) == 2
     assert calls(texts["chunk"], "mla_decode_roofline") == []
+    prefill = [c for c in _all_instructions(texts["chunk"])
+               if "tpu_custom_call" in c
+               and re.search(MLA_PREFILL_PATTERN, c)]
+    assert len(prefill) == 2
+    assert not any(re.search(MLA_PREFILL_PATTERN, c)
+                   for c in _all_instructions(texts["decode"]))
     pool = tuple(c.kpool.shape)
     for name, text in texts.items():
         copied = [i[:80] for i in _all_instructions(text)

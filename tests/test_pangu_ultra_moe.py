@@ -20,8 +20,9 @@ from paddle_tpu.inference.serving_spec import PagedLatent
 from paddle_tpu.models.pangu_ultra_moe import (PanguUltraMoEConfig,
                                                PanguUltraMoEForCausalLM)
 from paddle_tpu.ops import paged_attention as pa
-from paddle_tpu.ops.pallas.paged_attention import (latent_pages_per_step,
-                                                   mla_paged_decode)
+from paddle_tpu.ops.pallas import paged_attention as pk
+from paddle_tpu.ops.pallas.paged_attention import (
+    latent_pages_per_step, latent_prefill_pages_per_step, mla_paged_decode)
 
 SEED = 7
 REF_KEYS = (
@@ -123,8 +124,12 @@ def test_engine_chunked_prefill_then_decode_agrees_with_the_reference(
     out = eng.run()
     assert eng.decode_traces == 1 and eng.prefill_traces == 1
     assert pa.LATENT_PATH_STATS[backend] == cfg.num_hidden_layers
-    form = "expanded" if chunk >= 32 else "absorbed"
-    assert pa.LATENT_CHUNK_STATS[form] == cfg.num_hidden_layers
+    # a chunk wide enough to expand attends in the kernel under `pallas`
+    form = "absorbed" if chunk < 32 else \
+        "pallas_expanded" if backend == "pallas" else "expanded"
+    assert pa.LATENT_CHUNK_STATS == dict(
+        {"expanded": 0, "absorbed": 0, "pallas_expanded": 0},
+        **{form: cfg.num_hidden_layers})
     for rid, p in zip(rids, ps):
         seq = np.asarray(out[rid], np.int32)
         logits = reference_logits(cfg, seq[None, :-1])[0, len(p) - 1:]
@@ -133,16 +138,94 @@ def test_engine_chunked_prefill_then_decode_agrees_with_the_reference(
         assert gap.max() < 1e-3
 
 
-def test_the_pallas_walk_serves_the_xla_walks_tokens():
+@pytest.mark.parametrize("chunk,form", [(16, "absorbed"),
+                                        (32, "pallas_expanded")])
+def test_the_pallas_walk_serves_the_xla_walks_tokens(chunk, form):
+    """A `dense` engine runs XLA in both programs, a `pallas` engine the
+    decode kernel and, from a chunk wide enough to expand, the prefill
+    kernel: the same tokens through chunked prefill and decode."""
     model, cfg = seeded()
-    ps = prompts(cfg, [30, 9, 44, 17], seed=5)
+    ps = prompts(cfg, [30, 9, 44, 17, 70], seed=5)
     streams = []
     for backend in ("dense", "pallas"):
-        eng = engine_for(model, attention_backend=backend)
+        pa.reset_latent_path_stats()
+        eng = engine_for(model, attention_backend=backend,
+                         prefill_chunk=chunk)
         rids = [eng.add_request(p, max_new_tokens=9) for p in ps]
         out = eng.run()
         streams.append([out[r] for r in rids])
+        assert pa.LATENT_PATH_STATS[backend] == cfg.num_hidden_layers
+        assert pa.LATENT_CHUNK_STATS["pallas_expanded"] == (
+            cfg.num_hidden_layers
+            if (backend, form) == ("pallas", "pallas_expanded") else 0)
     assert streams[0] == streams[1]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("start,plen,shared", [
+    (0, 32, 0),        # a prompt's first chunk, full
+    (0, 21, 0),        # ... that ends short of the chunk
+    (64, 96, 0),       # from a page's edge
+    (160, 185, 0),     # a later chunk, two steps of keys below it
+    (40, 72, 5),       # after a prefix hit: the leading blocks another's
+    (283, 300, 3),     # three steps, the last one partly masked
+], ids=["first", "short", "page_edge", "later", "shared_prefix", "steps"])
+def test_the_prefill_kernel_matches_the_xla_loop_and_the_pool(
+        monkeypatch, dtype, start, plen, shared):
+    """`paged_latent_prefill_chunk` under `pallas` (the fused kernel,
+    interpreted) against `dense` (the XLA loop): the valid rows' outputs
+    and the whole pool, with steps of 128 keys so that a context of a few
+    hundred rows takes several."""
+    monkeypatch.setattr(pk, "_LATENT_PREFILL_PAIRS", 32 * 128)
+    rng = np.random.default_rng(1)
+    C, heads, dn, dr, dv, rank, width, bs, blocks, layers, maxb = \
+        32, 4, 16, 8, 16, 32, 40, 8, 60, 2, 40
+    assert latent_prefill_pages_per_step(C, bs, width, dtype) == 16
+    pool = jnp.asarray(rng.normal(size=(layers, blocks, bs, width)), dtype)
+    qn = jnp.asarray(rng.normal(size=(C, heads, dn)), dtype)
+    qr = jnp.asarray(rng.normal(size=(C, heads, dr)), dtype)
+    new = jnp.asarray(rng.normal(size=(C, width)), dtype)
+    w = jnp.asarray(rng.normal(size=(rank, heads, dn + dv)) * 0.2, dtype)
+    held = -(-plen // bs)
+    row = np.zeros(maxb, np.int32)
+    # the leading `shared` blocks are low-numbered ones another slot
+    # filled (a prefix hit), the rest this slot's own, in no order
+    row[:shared] = np.arange(1, 1 + shared)
+    row[shared:held] = rng.permutation(
+        np.arange(1 + shared, blocks))[:held - shared]
+    args = (jnp.asarray(row), jnp.int32(start), jnp.int32(plen), 0.2)
+    pa.reset_latent_path_stats()
+    want, pool_x = pa.paged_latent_prefill_chunk(
+        qn, qr, new, w, pool, 1, *args, backend="dense")
+    got, pool_k = pa.paged_latent_prefill_chunk(
+        qn, qr, new, w, pool, 1, *args, backend="pallas")
+    assert pa.LATENT_CHUNK_STATS == {"expanded": 1, "absorbed": 0,
+                                     "pallas_expanded": 1}
+    assert bool(jnp.all(pool_x == pool_k))
+    valid = plen - start
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.isfinite(got).all()              # padding rows too
+    np.testing.assert_allclose(
+        got[:valid], want[:valid],
+        atol=1e-5 if dtype == jnp.float32 else 2e-2)
+    # no row's output depends on what lies past its own position
+    dirty = pool.at[1, row[held - 1], (plen - 1) % bs + 1:].set(1e4)
+    again, _ = pa.paged_latent_prefill_chunk(
+        qn, qr, new, w, dirty, 1, *args, backend="pallas")
+    np.testing.assert_allclose(np.asarray(again, np.float32)[:valid],
+                               got[:valid], atol=1e-6)
+
+
+@pytest.mark.parametrize("chunk,block,width,want", [
+    (256, 64, 640, 16), (512, 64, 640, 8), (32, 16, 256, 64),
+    (4096, 64, 640, 2)])
+def test_the_prefill_kernels_pages_a_step_follow_from_shapes(
+        chunk, block, width, want):
+    """1,024 keys a step at the cell's chunk of 256; a wider chunk takes
+    fewer, so that a head's scores stay 1 MB."""
+    assert latent_prefill_pages_per_step(chunk, block, width,
+                                         jnp.bfloat16) == want
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -347,8 +347,9 @@ def _best_of_n_fanout(add, run, params, n, base):
 
 
 def _wrap_pool(pool):
-    """A pool as the step functions take it; None where the spec keeps
-    one pool and this is the place of the other."""
+    """A pool (or a block table) as the step functions take it; None
+    where the spec keeps one pool and this is the place of the other, or
+    keeps no paged cache at all."""
     return None if pool is None else Tensor._wrap(pool)
 
 
@@ -375,7 +376,11 @@ class PagedKVCache:
     FIXED size (`slot_state`: recurrent layers' windows and state
     matrices), one row a slot in arrays `[layers, 1 + state_rows, ...]`.
     Row 0 is the null row (idle lanes write there); `allocate_state`
-    hands a row out ZEROED, `free_state` takes it back.
+    hands a row out ZEROED, `free_state` takes it back. A model none of
+    whose layers caches by position has `num_layers` 0: the manager then
+    holds NO pool (`kpool` and `vpool` are None, `num_blocks` is 1: the
+    null block alone, nothing to allocate) and the state rows are all it
+    manages.
 
     Every live block carries a reference count: `allocate` hands blocks
     out at refcount 1, `share` seats an existing block in another
@@ -402,9 +407,11 @@ class PagedKVCache:
     def __init__(self, num_layers, num_blocks, block_size, kv_heads,
                  head_dim, dtype=jnp.float32, mesh=None, mp_axis="mp",
                  kv_dtype=None, slot_state=(), state_rows=0):
-        if num_blocks < 2:
+        if num_layers and num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the null "
                              "block)")
+        if not num_layers:
+            num_blocks = 1             # no pool: nothing to allocate
         if kv_dtype not in (None, "int8"):
             raise ValueError(
                 f"kv_dtype must be None (fp pools) or 'int8', got "
@@ -432,7 +439,9 @@ class PagedKVCache:
         self.mesh = mesh
         self.mp_axis = mp_axis if mesh is not None else None
         shape, dt = self.pool_spec()
-        if mesh is not None:
+        if not self.num_layers:
+            self.kpool = self.vpool = None
+        elif mesh is not None:
             from jax.sharding import NamedSharding
 
             mp = mesh.shape[mp_axis]
@@ -546,8 +555,8 @@ class PagedKVCache:
         """Total bytes of the paged KV state: both pool planes plus
         (int8 mode) the per-block scale array — the number the
         capacity claim and the `engine_pool_bytes` gauge report."""
-        n = int(self.kpool.nbytes) + (
-            int(self.vpool.nbytes) if self.vpool is not None else 0)
+        n = sum(int(p.nbytes) for p in (self.kpool, self.vpool)
+                if p is not None)
         if self.scales is not None:
             n += int(self.scales.nbytes)
         return n
@@ -891,7 +900,12 @@ class GenerationEngine:
             raise ValueError(
                 f"max_model_len={self.max_model_len} exceeds the "
                 f"model's position table ({spec.max_seq_len})")
-        self.max_blocks = math.ceil(self.max_model_len / self.block_size)
+        # a model that keeps no paged cache (`spec.paged_kv` None) has no
+        # blocks: no tables are built, nothing is allocated a step, and
+        # the one limit on a context is `max_model_len`
+        self._paged = spec.paged_kv is not None
+        self.max_blocks = math.ceil(
+            self.max_model_len / self.block_size) if self._paged else 0
         self.eos_token_id = eos_token_id
         self.max_queue = None if max_queue is None else int(max_queue)
         # prefill runs the prompt through a FIXED-shape compiled chunk
@@ -973,9 +987,10 @@ class GenerationEngine:
         # expectations and lean on the stall/retry path under pressure
         kv = spec.paged_kv
         self.cache = PagedKVCache(
-            kv.layers,
+            kv.layers if self._paged else 0,
             int(num_blocks or 1 + self.num_slots * self.max_blocks),
-            self.block_size, kv.kv_heads, kv.head_dim,
+            self.block_size, kv.kv_heads if self._paged else None,
+            kv.head_dim if self._paged else 0,
             dtype=spec.dtype, mesh=self.mesh, kv_dtype=self.kv_dtype,
             slot_state=spec.slot_state, state_rows=self.num_slots)
         self.cache.on_evict = lambda b: self.flight.record(
@@ -1114,6 +1129,9 @@ class GenerationEngine:
         # the model's own counters, summed (or the largest) over every
         # decode step: `spec.step_counters` names them
         self.step_counter_totals = {n: 0 for n, _ in spec.step_counters}
+        # and over every prefill chunk: `spec.chunk_counters`
+        self._chunk_totals = {n: 0 for n, _ in spec.chunk_counters}
+        self._chunk_counters = []      # chunks' counters, still unread
         # serving telemetry: per-engine registry by default so counter
         # exactness survives multiple engines in one process; pass
         # observability.get_registry() to publish on the process default
@@ -1239,7 +1257,7 @@ class GenerationEngine:
         are identical either way, so the compiled step programs — and
         TRACE_BASELINE.json — cannot move."""
         if not self.async_core:
-            return [jnp.asarray(a) for a in rows]
+            return [None if a is None else jnp.asarray(a) for a in rows]
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -1691,12 +1709,22 @@ class GenerationEngine:
                 "engine_state_slots_used",
                 "Rows of the slots' fixed-size state (recurrent layers) "
                 "held by live lanes.")
+            self._m_state_bytes = m.gauge(
+                "engine_state_bytes",
+                "Bytes of the slots' fixed-size state that live lanes "
+                "hold (rows held x bytes a row).")
+            self._state_row_bytes = self.cache.state_nbytes() \
+                // (1 + self.cache.state_rows)
         self._m_step_counters = {
             name: (m.counter if how == "sum" else m.gauge)(
                 f"engine_{name}" + ("_total" if how == "sum" else ""),
-                f"The model's decode-step counter `{name}` "
-                f"({how} over decode steps).")
-            for name, how in self.spec.step_counters}
+                f"The model's {step} counter `{name}` "
+                f"({how} over {steps}).")
+            for step, steps, counters in (
+                ("decode-step", "decode steps", self.spec.step_counters),
+                ("prefill-chunk", "prefill chunks",
+                 self.spec.chunk_counters))
+            for name, how in counters}
         # step-phase decomposition (ISSUE 17 / ROADMAP item 3): the
         # host work between compiled steps, per named phase — what
         # the pipelined orders run behind a device step. Always
@@ -1815,6 +1843,8 @@ class GenerationEngine:
         self._m_cached_blocks.set(self.cache.num_cached_blocks)
         if self._m_state_used is not None:
             self._m_state_used.set(self.cache.state_rows_used)
+            self._m_state_bytes.set(
+                self.cache.state_rows_used * self._state_row_bytes)
 
     def _sample_traces(self):
         """Mirror the count_traces probes into metrics; a decode trace
@@ -1922,15 +1952,18 @@ class GenerationEngine:
             return {}, rest
         return {"slot_state": rest[0]}, rest[1:]
 
-    def _step_outputs(self, lead, r):
+    def _step_outputs(self, lead, r, counters=None):
         """The outputs of a compiled step in the one order
         `_dispatch_step` reads: the leading replicated outputs, the
-        pools, then what rides beside them."""
-        out = tuple(lead) + (r.kpool._array, _pool_array(r.vpool))
+        pools, then what rides beside them; last the model's counters
+        where the spec names any for this kind of step (`counters`: a
+        decode step's unless the caller says the chunk's)."""
+        out = tuple(lead) + (_pool_array(r.kpool), _pool_array(r.vpool))
         if r.kv_scales is not None:
             out += (r.kv_scales._array,)
         out += tuple(r.slot_state)
-        if self.spec.step_counters and r.counters is not None:
+        names = self.spec.step_counters if counters is None else counters
+        if names and r.counters is not None:
             out += (r.counters,)
         return out
 
@@ -1957,8 +1990,8 @@ class GenerationEngine:
             with bound_state(zip(state, arrays), state):
                 r = spec.decode(
                     Tensor._wrap(tokens), Tensor._wrap(positions),
-                    Tensor._wrap(kpool), _wrap_pool(vpool),
-                    Tensor._wrap(tables), backend=backend,
+                    _wrap_pool(kpool), _wrap_pool(vpool),
+                    _wrap_pool(tables), backend=backend,
                     mp_axis=mp_axis,
                     kv_scales=None if scales is None
                     else Tensor._wrap(scales), lora=lora, **kw)
@@ -2061,8 +2094,8 @@ class GenerationEngine:
             with bound_state(zip(state, arrays), state):
                 r = spec.prefill_chunk(
                     Tensor._wrap(tokens), Tensor._wrap(start),
-                    Tensor._wrap(kpool), _wrap_pool(vpool),
-                    Tensor._wrap(table_row), Tensor._wrap(plen),
+                    _wrap_pool(kpool), _wrap_pool(vpool),
+                    _wrap_pool(table_row), Tensor._wrap(plen),
                     backend=backend, mp_axis=mp_axis,
                     kv_scales=None if scales is None
                     else Tensor._wrap(scales), lora=lora, **kw)
@@ -2070,7 +2103,8 @@ class GenerationEngine:
                     spec, r.hidden, start, plen, C,
                     (temps, tks, tps, krows) if use_s else None,
                     mp_axis=mp_axis)
-                return self._step_outputs((nxt,), r)
+                return self._step_outputs((nxt,), r,
+                                          spec.chunk_counters)
 
         prefill_chunk_fn.__name__ = "engine_prefill_chunk"
         return self._shard_steps(prefill_chunk_fn,
@@ -2104,9 +2138,9 @@ class GenerationEngine:
             with bound_state(zip(state, arrays), state):
                 r, hidden = spec.decode_with_chunk(
                     Tensor._wrap(chunk_tokens), Tensor._wrap(start),
-                    Tensor._wrap(table_row), Tensor._wrap(plen),
+                    _wrap_pool(table_row), Tensor._wrap(plen),
                     Tensor._wrap(tokens), Tensor._wrap(positions),
-                    Tensor._wrap(tables), Tensor._wrap(kpool),
+                    _wrap_pool(tables), _wrap_pool(kpool),
                     _wrap_pool(vpool), backend=backend, **kw)
                 first = _last_prompt_row_token(
                     spec, r.hidden, start, plen, C,
@@ -2345,19 +2379,39 @@ class GenerationEngine:
                 and req.sampling is not None and not req.sampling.greedy:
             self._m_sampled_tokens.inc(n)
 
-    def _note_step_counters(self, values):
+    def _note_step_counters(self, values, chunk=False):
         """Fold one decode step's counters (the model's: how many
-        assignments its experts took, ...) into the totals and the
-        metrics, each summed or kept as the largest, as its spec says."""
-        for (name, how), v in zip(self.spec.step_counters, values):
+        assignments its experts took, ...) or one prefill chunk's into
+        the totals and the metrics, each summed or kept as the largest,
+        as its spec says."""
+        names, totals = (self.spec.chunk_counters, self._chunk_totals) \
+            if chunk else (self.spec.step_counters,
+                           self.step_counter_totals)
+        for (name, how), v in zip(names, values):
             v = int(v)
             if how == "sum":
-                self.step_counter_totals[name] += v
+                totals[name] += v
                 self._m_step_counters[name].inc(v)
             else:
-                self.step_counter_totals[name] = max(
-                    self.step_counter_totals[name], v)
+                totals[name] = max(totals[name], v)
                 self._m_step_counters[name].set_max(v)
+
+    @property
+    def chunk_counter_totals(self):
+        """The model's counters over every prefill chunk launched
+        (`spec.chunk_counters`). A chunk's counters stay on the device
+        until somebody asks: reading them here waits for the newest
+        chunk."""
+        self._fold_chunk_counters(keep=0)
+        return dict(self._chunk_totals)
+
+    def _fold_chunk_counters(self, keep):
+        """Read all but the newest `keep` chunks' counters (long
+        finished: no wait) into the totals."""
+        unread = self._chunk_counters
+        while len(unread) > keep:
+            self._note_step_counters(np.asarray(unread.pop(0)),
+                                     chunk=True)
 
     def _publish(self, slot, reason):
         """The host holds the lane's last token: the result is out."""
@@ -2568,6 +2622,11 @@ class GenerationEngine:
             with RecordEvent("engine.prefill"):
                 t0 = time.perf_counter()
                 nxt = self._dispatch_step(self._prefill, *args)
+        if self._step_counters is not None:
+            # the chunk's own counters: read once it is long finished
+            self._chunk_counters.append(self._step_counters)
+            if len(self._chunk_counters) >= 64:
+                self._fold_chunk_counters(keep=8)
         first = self._chunk_launched(slot, start, end, nxt, t0, t_span)
         # the prompt's last chunk: its output is the request's first
         # token. The serial order reads it here; the ahead order feeds
@@ -2594,7 +2653,7 @@ class GenerationEngine:
                 end = min(start + self.prefill_chunk,
                           int(slot.req.prompt.size))
                 need = math.ceil(end / self.block_size) \
-                    - len(slot.blocks)
+                    - len(slot.blocks) if self._paged else 0
                 if need > 0:
                     got = self.cache.allocate(need)
                     if got is None:
@@ -2615,10 +2674,13 @@ class GenerationEngine:
         req = slot.req
         tokens = np.zeros((1, self.prefill_chunk), np.int32)
         tokens[0, :end - start] = req.prompt[start:end]
-        row = np.zeros(self.max_blocks, np.int32)
-        row[:len(slot.blocks)] = slot.blocks
+        row = None
+        if self._paged:
+            row = np.zeros(self.max_blocks, np.int32)
+            row[:len(slot.blocks)] = slot.blocks
+            row = jnp.asarray(row)
         args = [jnp.asarray(tokens), jnp.int32(start),
-                jnp.int32(req.prompt.size), jnp.asarray(row)]
+                jnp.int32(req.prompt.size), row]
         if self.sampling:
             # the chunk serves ONE slot: its sampling rows, [1]
             args.extend(self._sampling_host_args_one(slot))
@@ -2727,7 +2789,9 @@ class GenerationEngine:
                         or slot.dispatched >= slot.req.max_new_tokens:
                     continue
                 bi = slot.feed_pos // self.block_size
-                if bi >= len(slot.blocks):
+                if not self._paged:
+                    pass               # no pool: nothing to grow or copy
+                elif bi >= len(slot.blocks):
                     # on-demand growth: the feed position opens a new
                     # block
                     got = self.cache.allocate(1)
@@ -2827,7 +2891,8 @@ class GenerationEngine:
                 tables[i, :len(slot.blocks)] = slot.blocks
                 arows[i] = slot.adapter_page
                 srows[i] = slot.state_row
-            rows = [tokens, positions, tables]
+            # no pool, no tables: the step takes None in their place
+            rows = [tokens, positions, tables if self._paged else None]
             if self.sampling:
                 # per-slot sampling rows (idle/greedy lanes ride temp
                 # 0 — the argmax select, like the null block)

@@ -25,6 +25,12 @@ Three kinds of per-slot state live side by side in one manager
   arrays `[layers, 1 + slots, ...]`, row 0 the null row that idle lanes
   write to, allocated, zeroed and freed with the slot.
 
+A model none of whose layers caches by position sets `paged_kv=None`:
+the engine then builds NO pool and no block table, allocates nothing a
+step, and hands the step functions None for `kpool`, `vpool` and the
+block tables; a slot is admitted when a state row is free, and a context
+is limited by `max_seq_len` alone.
+
 State of the second kind is not bounded by a position: a prefix hit, a
 copy-on-write fork or a speculative window would each need a snapshot of
 it. A spec lists under `refuses` what the engine must not serve for the
@@ -92,6 +98,10 @@ class ServingSpec:
     #: that offers `decode_with_chunk` can tell how often it engaged: a
     #: counter that reads 1 from the fused step and 0 from the plain one
     step_counters: tuple = ()
+    #: the same for what `prefill_chunk` returns in `StepOut.counters`,
+    #: summed (or the largest) over every chunk launched
+    #: (`engine.chunk_counter_totals`)
+    chunk_counters: tuple = ()
     slot_state: tuple = ()
 
     def __init__(self, model, vocab_size, max_seq_len, dtype, paged_kv,
@@ -144,6 +154,8 @@ class ServingSpec:
         from paddle_tpu.ops.pallas.paged_attention import pages_per_step
 
         kv = self.paged_kv
+        if kv is None:
+            return 0
         return pages_per_step(block_size, kv.kv_heads // mp_degree,
                               kv.head_dim, pool_dtype)
 

@@ -30,6 +30,8 @@ import paddle_tpu.nn as nn
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.inference.serving_spec import PagedKV, ServingSpec, \
     SlotState, StepOut
+from paddle_tpu.models._blocks import Leaves as _Leaves, dot as _dot, \
+    leaf as _leaf, rms_norm as _rms_norm
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
@@ -101,38 +103,6 @@ class NemotronHConfig:
             initializer_range=0.1, conv_init_std=0.3)
         base.update(kw)
         return NemotronHConfig(**base)
-
-
-def _leaf(layer, cfg, shape, std, mean=0.0):
-    """A parameter in the run dtype: mean + N(0, std), or nought where
-    the caller binds every leaf itself."""
-    init = nn.initializer.Constant(0.0) if cfg.init == "zeros" \
-        else nn.initializer.Normal(mean, std)
-    return layer.create_parameter(list(shape), dtype=cfg.dtype,
-                                  default_initializer=init)
-
-
-class _Leaves(nn.Layer):
-    """Named parameters and nothing else: `weight=(shape, std)` draws
-    N(0, std); `(shape, std, 1.0)` draws 1 + N(0, std)."""
-
-    def __init__(self, cfg, **leaves):
-        super().__init__(dtype=cfg.dtype)
-        for name, spec in leaves.items():
-            setattr(self, name, _leaf(self, cfg, *spec))
-
-
-def _dot(a, w):
-    """Operands as they are stored, float32 accumulation, the result in
-    the activations' type."""
-    return jnp.dot(a, w, preferred_element_type=_F32).astype(a.dtype)
-
-
-def _rms_norm(x, gain, eps):
-    xf = x.astype(_F32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True)
-                            + eps)
-    return (xf * gain.astype(_F32)).astype(x.dtype)
 
 
 def _relu2(x):

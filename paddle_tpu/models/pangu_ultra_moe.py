@@ -41,7 +41,8 @@ import paddle_tpu.nn as nn
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.inference.serving_spec import PagedLatent, ServingSpec, \
     StepOut
-from paddle_tpu.models.nemotron_h import _Leaves, _dot, _rms_norm
+from paddle_tpu.models._blocks import Leaves as _Leaves, dot as _dot, \
+    gated_mlp as _gated_mlp, rms_norm as _rms_norm, rotary
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
@@ -123,29 +124,6 @@ class PanguUltraMoEConfig:
             attn_key_init_std=0.4, router_init_std=0.5)
         base.update(kw)
         return PanguUltraMoEConfig(**base)
-
-
-def _gated_mlp(u, w_gate_up, w_down):
-    """`(silu(u W_g) * (u W_u)) W_d` with `[W_g | W_u]` side by side; the
-    gate in float32."""
-    gate_up = _dot(u, w_gate_up)
-    h = gate_up.shape[-1] // 2
-    hidden = jax.nn.silu(gate_up[..., :h].astype(_F32)) \
-        * gate_up[..., h:].astype(_F32)
-    return _dot(hidden.astype(u.dtype), w_down)
-
-
-def rotary(x, positions, theta):
-    """x `[.., n, d]` at `positions [.., ]` (broadcast over `n`): value i
-    of the first half pairs with value i of the second, turned by
-    `positions * theta^(-2 i / d)`; float32, the result in x's dtype."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=_F32) / d)
-    ang = positions.astype(_F32)[..., None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :d // 2].astype(_F32), x[..., d // 2:].astype(_F32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
 
 
 class LatentAttention(nn.Layer):

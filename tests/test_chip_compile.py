@@ -613,3 +613,100 @@ def test_latent_engine_programs_hold_the_kernel_and_copy_no_pool(
                       i)) and "S(1)" not in m.group(1)
                   and tuple(map(int, m.group(2).split(","))) == pool]
         assert copied == [], (name, copied)
+
+
+# -- power retention: the decode kernel and the engine with no pool -------------
+
+R_SLOTS, R_KVH, R_READERS, R_WIDTH = 20, 8, 5, 8704
+
+
+def test_retention_decode_kernel_compiles_and_the_benchmark_finds_it(chip):
+    """The retention's decode kernel at the cell's geometry (20 slots, 8
+    KV heads each read by 5 query heads, a state of 8,704 x 128 float32 a
+    head: 4.5 MB a block, in and out): Mosaic takes the transposes that
+    build `phi`, the dynamic tile slices and the 18 MB of VMEM, and
+    `retention_decode_roofline` finds the call inside the engine's decode
+    step."""
+    import re
+
+    from benchmarks.lib import trace_reduce
+    from paddle_tpu.ops.pallas.retention import retention_decode_update
+
+    def engine_decode_step(pool, rows, q, k, v, g):
+        return retention_decode_update(pool, 3, rows, q, k, v, g)
+
+    row = ((R_SLOTS, R_KVH, HD), F32)
+    text = chip(
+        engine_decode_step,
+        ((8, R_SLOTS + 1, R_KVH, R_WIDTH, HD), F32), ((R_SLOTS,), I32),
+        ((R_SLOTS, R_KVH, R_READERS, HD), F32), row, row,
+        ((R_SLOTS, R_KVH), F32))
+    (call,) = _instructions(text)
+    assert any(re.search(p, call)
+               for p in _metric_patterns("retention_decode_roofline"))
+    assert not any(re.search(p, call)
+                   for p in _metric_patterns("ssm_decode_roofline"))
+    assert "power_retention_decode" in trace_reduce.op_group(call)
+
+
+def test_retention_engine_programs_hold_no_pool_and_copy_no_state(
+        topo, monkeypatch):
+    """The two programs of an engine built for a model with NO paged
+    cache (heads of 128 as published, a narrow body), compiled for the
+    described chip as the engine jits them (the state rows donated, None
+    in the pools' and the tables' places): the decode step holds the
+    retention's kernel once a layer and no loop; the chunk holds the
+    sub-chunk loop once a layer, which is what
+    `retention_chunk_busy_pct` reads, and no kernel; neither copies an
+    array of the state's shape."""
+    import re
+
+    import numpy as np
+
+    from paddle_tpu.inference.engine import GenerationEngine
+    from paddle_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
+
+    cfg = BrumbyConfig(
+        vocab_size=512, hidden_size=256, num_hidden_layers=2,
+        intermediate_size=512, max_seq_len=512, dtype="bfloat16",
+        init="zeros")
+    model = BrumbyForCausalLM(cfg)
+    model.eval()
+    monkeypatch.setattr("paddle_tpu.core.device.platform", lambda: "tpu")
+    eng = GenerationEngine(model, num_slots=4, prefill_chunk=256,
+                           donate=True)
+    assert eng.attention_backend == "pallas"
+    one = SingleDeviceSharding(topo.devices[0])
+    c, i32 = eng.cache, np.int32
+    assert c.kpool is None and c.vpool is None and eng.max_blocks == 0
+    slots = eng.num_slots
+    chunk = (np.zeros((1, eng.prefill_chunk), i32), i32(0), i32(0), None,
+             i32(0))
+    decode = (np.zeros((slots, 1), i32), np.zeros(slots, i32), None,
+              np.zeros(slots, i32))
+    head = (eng._state_arrays(), None, None, c.state)
+    texts = {}
+    for name, jitted, host in (("chunk", eng._prefill, chunk),
+                               ("decode", eng._decode, decode)):
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=one), head + host)
+        with jax.default_matmul_precision("default"):
+            texts[name] = jitted.lower(*args).compile().as_text()
+
+    def found(text, metric):
+        return [i for i in _all_instructions(text) if any(
+            re.search(p, i) for p in _metric_patterns(metric))]
+
+    assert len(found(texts["decode"], "retention_decode_roofline")) == 2
+    assert found(texts["decode"], "retention_chunk_busy_pct") == []
+    assert len(found(texts["chunk"], "retention_chunk_busy_pct")) == 2
+    assert "tpu_custom_call" not in texts["chunk"]
+    held = {tuple(a.shape) for a in c.state}
+    for name, text in texts.items():
+        copied = [i[:80] for i in _all_instructions(text)
+                  if (m := re.match(
+                      r"%\S+ = (\(?\w+\[([\d,]+)\].*) copy(?:-start)?\(",
+                      i)) and "S(1)" not in m.group(1)
+                  and tuple(map(int, m.group(2).split(","))) in held]
+        assert copied == [], (name, copied)

@@ -121,13 +121,15 @@ class PowerRetention(nn.Layer):
         return dot(y.reshape(y.shape[:-3] + (-1,)).astype(dtype),
                    self.o_proj.weight._array)
 
-    def chunk(self, u, state, norm, start, n_valid):
+    def chunk(self, u, state, norm, start, n_valid, backend):
         """One slot's chunk `u [C, hidden]` at positions `start ..` from
         its carried `state` and `norm`; rows at and past `n_valid` are
         padding. -> (out, state, norm)."""
         q, k, v, log_g = self.project(u, start + jnp.arange(u.shape[0]))
+        # positional: the benchmark's fault seams wrap this call
         y, state, norm = retention.power_retention_chunk(
-            q, k, v, log_g, state, norm, n_valid, self.cfg.chunk_size)
+            q, k, v, log_g, state, norm, n_valid, self.cfg.chunk_size,
+            backend)
         return self.out(y, u.dtype), state, norm
 
     def step(self, u, pool, norm_pool, layer, rows, positions, backend):
@@ -199,7 +201,7 @@ class BrumbyForCausalLM(nn.Layer):
 
         def mixer(m, u, i):
             return jax.vmap(lambda row: m.chunk(
-                row, state0, norm0, 0, s)[0])(u)
+                row, state0, norm0, 0, s, "xla")[0])(u)
 
         return Tensor._wrap(self._head(self._walk(
             self.embed.weight._array[ids], mixer)))
@@ -245,8 +247,9 @@ class BrumbyServing(ServingSpec):
             SlotState("ret_norm", layers, (kvh, cfg.state_width), _F32))
 
     def attention_backend(self, requested, block_size, mp_degree):
-        """The engine's one backend choice picks the decode form of the
-        retention: `pallas` the kernel, `dense` the XLA form."""
+        """The engine's one backend choice picks the retention's form,
+        the decode step's and the prefill chunk's alike: `pallas` the
+        kernels, `dense` the XLA forms."""
         if requested not in ("auto", "dense", "pallas"):
             raise ValueError("attention_backend must be auto, dense or "
                              f"pallas, got {requested!r}")
@@ -266,11 +269,12 @@ class BrumbyServing(ServingSpec):
         s0 = start._array
         n_valid = jnp.clip(plen._array - s0, 0, width)
         state = list(slot_state)
+        form = "xla" if backend == "dense" else backend
 
         def mixer(m, u, i):
             out, s_new, z_new = m.chunk(
                 u[0], state[0][i, state_row], state[1][i, state_row],
-                s0, n_valid)
+                s0, n_valid, form)
             state[0] = state[0].at[i, state_row].set(s_new)
             state[1] = state[1].at[i, state_row].set(z_new)
             return out[None]

@@ -1,4 +1,6 @@
-"""Pallas kernel of power retention's decode step: one token a slot.
+"""Pallas kernels of power retention: the decode step (one token a slot,
+`retention_decode_update`) and a prefill chunk (one slot's rows,
+`retention_chunk`, at the end of this file).
 
     S <- g S + phi(k) v^T        S: [D_run, d] float32 a KV head a slot
     num_i = phi(q_i)^T S         for the r query heads of the KV head
@@ -25,6 +27,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .naming import kernel_name
 
@@ -140,3 +143,204 @@ def retention_decode_update(pool, layer, rows, q, k, v, g,
       jnp.broadcast_to(v.astype(f32)[:, :, None], over_rows),
       jnp.broadcast_to(g.astype(f32)[:, :, None, None], over_rows), pool)
     return y, pool
+
+
+# -- a prefill chunk of one slot ----------------------------------------------
+#
+# A program a (KV head, sub-chunk), the sub-chunks in order: the head's
+# state `[D_run, d]` and normaliser stay in VMEM across them (the same
+# block index: fetched once, written once). `phi` never reaches HBM: a
+# step of 8 tile pairs builds `phi(x)^T` `[512, N]` in VMEM from x `[d,
+# N]` down the sublanes (the decode kernel's trick, no lane moves), and
+# the product contracts over those 512 state rows — `phi(q)^T S` for the
+# r readers' rows at once (N = r C), and the update `phi(k)^T (tail v)`.
+# The normaliser is read and updated as the `[d, d]` matrix of the
+# products (`phi(q) . z = q^T (W Z) q`, `Z += W (tail k)^T k`): one
+# small product each, where `z` as a column would double the products'
+# width. Float32 operands, products at `highest`, as the XLA form.
+# The steps are a `fori_loop`, a pair's tiles read from two scalar
+# tables: unrolled, the kernel took 13.5 s to trace and lower for the 8
+# layers of the cell's chunk program, paid in every process's set-up
+# before the compile cache is asked (`setup_s` +16%); rolled, 2.6 s.
+# Microbenchmarked at the cell's geometry (my chip runs, PR 36): 1.15 ms
+# a layer call rolled, 1.22 unrolled, against 1.89 for the XLA form; 2,
+# 4 or 8 pairs a step, the state (not `phi`) as the transposed operand,
+# two `phi` buffers, or rows loaded from the ref: all 1.22-1.23 ms
+# unrolled, so the plain form stays.
+
+#: the name `breakdown.device_ops` shows for the chunk's kernel
+CHUNK_KERNEL_NAME = "power_retention_chunk"
+#: tile pairs a product: 512 rows of the state
+STEP_PAIRS = 8
+
+
+def _phi_rows(x_ref, pair_a, pair_b, first, count, out_ref, width):
+    """`phi(x)^T` of the `count` pairs from `first` on into out_ref `[64
+    count, N]`: x_ref `[d, N]` holds the d values down the sublanes, so a
+    tile `(a, b, i)` of eight state rows is row `8a + i` spread over the
+    sublanes times rows `8b ..` — no lane moves. The pairs' tiles are
+    read from the scalar tables `pair_a`, `pair_b` (the state's row
+    order), so a loop over the steps traces one step."""
+    from jax.experimental import pallas as pl
+
+    for j in range(count):
+        a, b = pair_a[first + j], pair_b[first + j]
+        xa = x_ref[pl.ds(pl.multiple_of(a * TILE, TILE), TILE), :width]
+        xb = x_ref[pl.ds(pl.multiple_of(b * TILE, TILE), TILE), :width] \
+            * jnp.where(a == b, 1.0, _SQRT2).astype(jnp.float32)
+        for i in range(TILE):
+            at = (j * TILE + i) * TILE
+            out_ref[at:at + TILE, :width] = xa[i:i + 1] * xb
+
+
+def _pair_weights_matrix(d):
+    """`[d, d]`: w_ab at `(8a + i, 8b + j)` where `a <= b` (1 on the
+    diagonal tiles, sqrt 2 above), 0 below."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (d, d), 0) // TILE
+    cols = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1) // TILE
+    return jnp.where(cols > rows, _SQRT2,
+                     jnp.where(cols == rows, 1.0, 0.0)).astype(jnp.float32)
+
+
+def _chunk_kernel(pair_a, pair_b, q_ref, qt_ref, k_ref, kt_ref, v_ref,
+                  dec_ref, gq_ref, tail_ref, s_ref, z_ref, y_ref, s_out,
+                  z_out, xq_ref, phi_ref, acc_ref, *, r, eps):
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    c, d = q_ref.shape[2], q_ref.shape[3]
+    steps, rest = divmod(pair_a.shape[0], STEP_PAIRS)
+    leading = (((0,), (0,)), ((), ()))         # contract both row axes
+
+    def over_steps(step):
+        """`step(first pair, pairs)` for every step of the state's rows."""
+        if steps:
+            jax.lax.fori_loop(0, steps, lambda g, _: step(
+                g * STEP_PAIRS, STEP_PAIRS), None)
+        if rest:
+            step(steps * STEP_PAIRS, rest)
+
+    def state_rows(first, count):
+        at = pl.multiple_of(first * TILE * TILE, TILE * TILE)
+        return s_out.at[0, pl.ds(at, count * TILE * TILE)]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _load():
+        s_out[...] = s_ref[...]
+        z_out[...] = z_ref[...]
+
+    w = _pair_weights_matrix(d)
+    k, v, dec, gq = k_ref[0], v_ref[0], dec_ref[0], gq_ref[0]
+    for i in range(r):
+        xq_ref[:, i * c:(i + 1) * c] = qt_ref[0, i]
+
+    # the carried part, phi(q)^T S, for the r readers' rows at once
+    acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    def read(first, count):
+        _phi_rows(xq_ref, pair_a, pair_b, first, count, phi_ref, r * c)
+        acc_ref[...] += jax.lax.dot_general(
+            phi_ref[:count * TILE * TILE], state_rows(first, count)[...],
+            leading, precision=hi, preferred_element_type=f32)
+
+    over_steps(read)
+    zw = w * z_out[0]
+    for i in range(r):
+        q = q_ref[0, i]                                   # [C, d]
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 precision=hi, preferred_element_type=f32)
+        a = sc * sc * dec
+        num = jnp.dot(a, v, precision=hi, preferred_element_type=f32) \
+            + gq * acc_ref[i * c:(i + 1) * c]
+        qz = jnp.dot(q, zw, precision=hi, preferred_element_type=f32)
+        den = jnp.sum(a, axis=1, keepdims=True) \
+            + gq * jnp.sum(q * qz, axis=1, keepdims=True)
+        y_ref[0, i] = num / (den + eps)
+
+    # the update, from the state this sub-chunk read
+    last = gq[c - 1:c]                                    # [1, d]
+    tail = tail_ref[0]                                    # [C, 1]
+    vt = v * tail
+
+    def update(first, count):
+        _phi_rows(kt_ref.at[0], pair_a, pair_b, first, count, phi_ref, c)
+        rows = state_rows(first, count)
+        rows[...] = last * rows[...] + jnp.dot(
+            phi_ref[:count * TILE * TILE, :c], vt, precision=hi,
+            preferred_element_type=f32)
+
+    over_steps(update)
+    z_out[0] = last * z_out[0] + w * jax.lax.dot_general(
+        k * tail, k, leading, precision=hi, preferred_element_type=f32)
+
+
+def retention_chunk(q, qt, k, kt, v, dec, gq, tail, state, zmat, eps,
+                    interpret=False):
+    """q `[h, r, T, d]` float32 SCALED, qt its `[h, r, d, T]`; k, v `[h,
+    T, d]`, kt `[h, d, T]`; dec `[h, T, C]` the gated causal weights
+    inside a sub-chunk of C rows; gq `[h, T, d]` a row's decay since the
+    sub-chunk began, `exp(la)`, over the lanes (its last row is the
+    sub-chunk's whole decay); tail `[h, T, 1]` a key's decay to the
+    sub-chunk's end (0 on padding); state `[h, D_run, d]`, zmat `[h, d,
+    d]` the normaliser as a matrix (`ops/retention._norm_to_matrix`).
+    All float32. -> (y `[h, r, T, d]`, state, zmat), the last two in
+    place."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    h, r, t, d = q.shape
+    c = dec.shape[-1]
+    d_run = state.shape[1]
+    f32 = jnp.float32
+    step_rows = STEP_PAIRS * TILE * TILE
+    block = d_run * d * 4
+    n = d // TILE
+    pair_a, pair_b = np.triu_indices(n)              # the state's row order
+    def per_sub(shape, at):
+        """A block of each (KV head, sub-chunk); the tables unused."""
+        return pl.BlockSpec(shape, lambda hh, s, *_: at(hh, s))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(h, t // c),
+        in_specs=[
+            per_sub((1, r, c, d), lambda hh, s: (hh, 0, s, 0)),
+            per_sub((1, r, d, c), lambda hh, s: (hh, 0, 0, s)),
+            per_sub((1, c, d), lambda hh, s: (hh, s, 0)),
+            per_sub((1, d, c), lambda hh, s: (hh, 0, s)),
+            per_sub((1, c, d), lambda hh, s: (hh, s, 0)),
+            per_sub((1, c, c), lambda hh, s: (hh, s, 0)),
+            per_sub((1, c, d), lambda hh, s: (hh, s, 0)),
+            per_sub((1, c, 1), lambda hh, s: (hh, s, 0)),
+            per_sub((1, d_run, d), lambda hh, s: (hh, 0, 0)),
+            per_sub((1, d, d), lambda hh, s: (hh, 0, 0)),
+        ],
+        out_specs=[
+            per_sub((1, r, c, d), lambda hh, s: (hh, 0, s, 0)),
+            per_sub((1, d_run, d), lambda hh, s: (hh, 0, 0)),
+            per_sub((1, d, d), lambda hh, s: (hh, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((d, r * c), f32),
+                        pltpu.VMEM((step_rows, r * c), f32),
+                        pltpu.VMEM((r * c, d), f32)],
+    )
+    y, state, zmat = pl.pallas_call(
+        functools.partial(_chunk_kernel, r=r, eps=eps),
+        **kernel_name(CHUNK_KERNEL_NAME, rename=False),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
+                   jax.ShapeDtypeStruct(state.shape, f32),
+                   jax.ShapeDtypeStruct(zmat.shape, f32)],
+        # flat inputs: the two tables, then q .. zmat: the state and the
+        # normaliser are outputs 1 and 2, updated in place
+        input_output_aliases={10: 1, 11: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            # the state's block in and out, each twice (the pipeline's
+            # two buffers), `phi`'s step and room for the rest
+            vmem_limit_bytes=4 * block + (24 << 20)),
+        interpret=interpret,
+    )(jnp.asarray(pair_a, jnp.int32), jnp.asarray(pair_b, jnp.int32),
+      q, qt, k, kt, v, dec, gq, tail, state, zmat)
+    return y, state, zmat

@@ -25,15 +25,18 @@ Two forms from a CARRIED state, as `ops/ssm.py` has for the scan:
 
 * `power_retention_chunk` — a prefill chunk of one slot: inside a
   sub-chunk the gated, squared, lower-triangular `Q K^T`, between
-  sub-chunks the state. Plain XLA, float32 at `highest`.
+  sub-chunks the state, float32 at `highest`. On the chip one Pallas
+  kernel (`ops/pallas/retention.py` `retention_chunk`: `phi` built in
+  VMEM, the state held there across the sub-chunks); elsewhere, and as
+  the kernel's reference, a `lax.scan` of XLA over the sub-chunks.
 * `power_retention_decode` — one token for every slot over the pools
   `[layers, rows, kv_heads, D_run, d]` and `[layers, rows, kv_heads,
   D_run]`: the state's part is a Pallas kernel on the chip
   (`ops/pallas/retention.py`), gather / scatter in XLA elsewhere; the
   normaliser (1/128 of the bytes) is XLA in both.
 
-`RETENTION_PATH_STATS` counts which decode form was traced: never a
-silent fallback.
+`RETENTION_PATH_STATS` counts which decode form was traced,
+`RETENTION_CHUNK_STATS` which chunk form: never a silent fallback.
 """
 from __future__ import annotations
 
@@ -45,6 +48,7 @@ from paddle_tpu.core.device import on_tpu, pallas_interpret
 
 RETENTION_BACKENDS = ("auto", "xla", "pallas")
 RETENTION_PATH_STATS = {"xla": 0, "pallas": 0}
+RETENTION_CHUNK_STATS = {"xla": 0, "pallas": 0}
 TILE = 8
 EPS = 1e-6
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -52,8 +56,9 @@ _F32 = jnp.float32
 
 
 def reset_retention_path_stats():
-    for k in RETENTION_PATH_STATS:
-        RETENTION_PATH_STATS[k] = 0
+    for stats in (RETENTION_PATH_STATS, RETENTION_CHUNK_STATS):
+        for k in stats:
+            stats[k] = 0
 
 
 def resolve_retention_backend(backend, head_dim=128):
@@ -155,37 +160,102 @@ def _sub_chunk(state, norm, q, k, v, lg, valid):
     return num / (den[..., None] + EPS), state, norm
 
 
+def _pair_grid(n):
+    """`[n, n]`: the pair `(a, b)`'s index in the tiled order where `a <=
+    b`, else the index one past the last pair (a row of zeros)."""
+    grid = np.full((n, n), n * (n + 1) // 2, np.int32)
+    a, b = np.triu_indices(n)
+    grid[a, b] = np.arange(a.size)
+    return grid
+
+
+def _norm_to_matrix(norm, d):
+    """norm `[h, D_run]` (tiled `phi` form) -> `[h, d, d]`, value `(a, b,
+    i, j)` at `(8a + i, 8b + j)` and 0 below the diagonal tiles: the
+    matrix the kernel reads `phi(q) . z` from. Whole pairs of 64 values
+    are gathered, never single values: a gather of single values would
+    have the whole norm pool laid out anew round the program (PR 36's
+    first trace)."""
+    h, n = norm.shape[0], d // TILE
+    pairs = jnp.pad(norm.reshape(h, -1, TILE * TILE), ((0, 0), (0, 1),
+                                                       (0, 0)))
+    full = jnp.take(pairs, _pair_grid(n).reshape(-1), axis=1)
+    return jnp.transpose(full.reshape(h, n, n, TILE, TILE),
+                         (0, 1, 3, 2, 4)).reshape(h, n * TILE, -1)
+
+
+def _matrix_to_norm(zmat):
+    """`_norm_to_matrix`'s inverse."""
+    h, n = zmat.shape[0], zmat.shape[1] // TILE
+    full = jnp.transpose(zmat.reshape(h, n, TILE, n, TILE),
+                         (0, 1, 3, 2, 4)).reshape(h, n * n, -1)
+    a, b = np.triu_indices(n)
+    return jnp.take(full, a * n + b, axis=1).reshape(h, -1)
+
+
+def _chunk_pallas(q, k, v, lg, valid, state, norm, chunk_size):
+    """The kernel's form: q `[T, h, r, d]` scaled, k, v `[T, h, d]`, lg
+    `[T, h]` (0 on padding rows), valid `[T]`, T a whole number of
+    sub-chunks; the terms that only the gate makes are made here."""
+    from .pallas.retention import retention_chunk
+
+    t, h, r, d = q.shape
+    c = chunk_size
+    la = jnp.cumsum(lg.T.reshape(h, -1, c), axis=-1)     # [h, n, C]
+    causal = np.arange(c)[:, None] >= np.arange(c)[None, :]
+    gap = la[..., :, None] - la[..., None, :]            # [h, n, t, s]
+    dec = jnp.exp(jnp.where(causal, gap, -jnp.inf)) \
+        * valid.reshape(-1, 1, c)
+    tail = jnp.exp(la[..., -1:] - la) * valid.reshape(-1, c)
+    y, state, zmat = retention_chunk(
+        jnp.transpose(q, (1, 2, 0, 3)), jnp.transpose(q, (1, 2, 3, 0)),
+        jnp.transpose(k, (1, 0, 2)), jnp.transpose(k, (1, 2, 0)),
+        jnp.transpose(v, (1, 0, 2)), dec.reshape(h, t, c),
+        jnp.broadcast_to(jnp.exp(la).reshape(h, t, 1), (h, t, d)),
+        tail.reshape(h, t, 1), state, _norm_to_matrix(norm, d), EPS,
+        interpret=pallas_interpret())
+    return jnp.transpose(y, (2, 0, 1, 3)), state, _matrix_to_norm(zmat)
+
+
 def power_retention_chunk(q, k, v, log_g, state, norm, n_valid,
-                          chunk_size=128):
+                          chunk_size=128, backend="auto"):
     """A chunk of ONE slot's prompt from the carried `state` and `norm`.
     q `[T, kv_heads, r, d]` (r query heads a KV head, not yet scaled);
     k, v `[T, kv_heads, d]`; log_g `[T, kv_heads]` float32; state
     `[kv_heads, D_run, d]` and norm `[kv_heads, D_run]` float32; rows at
-    and past `n_valid` (the prompt's padding) leave both as they are.
-    -> (y `[T, kv_heads, r, d]` float32, state, norm after the last real
-    row)."""
+    and past `n_valid` (the prompt's padding) leave both as they are;
+    `backend` as `resolve_retention_backend` reads it. -> (y `[T,
+    kv_heads, r, d]` float32, state, norm after the last real row)."""
     t, d = q.shape[0], q.shape[-1]
     pad = -t % chunk_size
     valid = jnp.arange(t + pad) < n_valid
 
-    def rows(x, scale=None):
-        """`[T, ..]` -> float32 `[sub-chunks, chunk_size, ..]`."""
+    def padded(x, scale=None):
+        """`[T, ..]` -> float32 `[T + pad, ..]`: whole sub-chunks."""
         x = x.astype(_F32) if scale is None else x.astype(_F32) * scale
-        x = jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
-        return x.reshape((-1, chunk_size) + x.shape[1:])
+        return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
 
-    def step(carry, sub):
-        y, st, nm = _sub_chunk(*carry, *sub)
-        return (st, nm), y
+    q, k, v = padded(q, d ** -0.5), padded(k), padded(v)
+    lg = padded(jnp.where(valid[:t, None], log_g.astype(_F32), 0.0))
+    resolved = resolve_retention_backend(backend, d)
+    RETENTION_CHUNK_STATS[resolved] += 1
+    if resolved == "pallas":
+        y, st, nm = _chunk_pallas(q, k, v, lg, valid, state.astype(_F32),
+                                  norm.astype(_F32), chunk_size)
+    else:
+        def rows(x):
+            return x.reshape((-1, chunk_size) + x.shape[1:])
 
-    # the state is handed from one sub-chunk to the next
-    (st, nm), y = jax.lax.scan(
-        step, (state.astype(_F32), norm.astype(_F32)),
-        (rows(q, d ** -0.5), rows(k), rows(v),
-         rows(jnp.where(valid[:t, None], log_g.astype(_F32), 0.0)),
-         valid.reshape(-1, chunk_size)))
-    return y.reshape((-1,) + y.shape[2:])[:t], st.astype(state.dtype), \
-        nm.astype(norm.dtype)
+        def step(carry, sub):
+            y, st, nm = _sub_chunk(*carry, *sub)
+            return (st, nm), y
+
+        # the state is handed from one sub-chunk to the next
+        (st, nm), y = jax.lax.scan(
+            step, (state.astype(_F32), norm.astype(_F32)),
+            (rows(q), rows(k), rows(v), rows(lg), rows(valid)))
+        y = y.reshape((-1,) + y.shape[2:])
+    return y[:t], st.astype(state.dtype), nm.astype(norm.dtype)
 
 
 def _state_step_xla(pool, layer, rows, q, pk, v, g):

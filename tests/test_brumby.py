@@ -7,7 +7,8 @@ forward, logits compared, and the same with the state kept in bf16 shown
 to fail; `phi` in the layout the kernel uses; the decode kernel
 (interpreter) against the `jnp` form; padding rows and the null row; a
 slot's rows zeroed for the next request; the refusals; an engine with NO
-paged pool.
+paged pool; the chunk kernel through the engine (its own tests are
+`tests/test_retention_chunk_kernel.py`).
 """
 import jax
 import jax.numpy as jnp
@@ -116,7 +117,7 @@ def test_engine_chunked_prefill_then_decode_agrees_with_the_reference(
     slots: every served token's logit lies within the tolerance of the
     reference's best, by the reference's full forward over the whole
     sequence (no state, no chunks). `pallas` runs the decode kernel
-    under the interpreter."""
+    and the chunk kernel under the interpreter."""
     model, cfg = seeded()
     asked = prompts(cfg, [5, 19, 33, 12, 16])
     eng, served = _serve(model, asked, 9, attention_backend=backend)
@@ -277,10 +278,11 @@ def test_decode_is_the_attention_form_one_token_on():
     assert np.abs(np.asarray(z - norm[0, 1])).max() < 1e-4
 
 
-def test_padding_rows_leave_the_carried_state_as_it_is():
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_padding_rows_leave_the_carried_state_as_it_is(backend):
     """A chunk of nothing but padding hands back the state it was given,
     bit for bit; a chunk whose prompt ends inside it carries what the real
-    rows made of it."""
+    rows made of it. The XLA form and the kernel alike."""
     rng = np.random.default_rng(4)
     h, r, d, t = 2, 2, 16, 8
     width = retention.state_width(d)
@@ -288,13 +290,13 @@ def test_padding_rows_leave_the_carried_state_as_it_is():
     q, k, v, lg = f(t, h, r, d), f(t, h, d), f(t, h, d), -jnp.abs(f(t, h))
     state, norm = f(h, width, d), jnp.abs(f(h, width))
     _, s0, z0 = retention.power_retention_chunk(
-        q, k, v, lg, state, norm, jnp.int32(0), 4)
+        q, k, v, lg, state, norm, jnp.int32(0), 4, backend)
     assert np.array_equal(np.asarray(s0), np.asarray(state))
     assert np.array_equal(np.asarray(z0), np.asarray(norm))
     _, s5, z5 = retention.power_retention_chunk(
-        q, k, v, lg, state, norm, jnp.int32(5), 4)
+        q, k, v, lg, state, norm, jnp.int32(5), 4, backend)
     _, s5b, z5b = retention.power_retention_chunk(
-        q[:5], k[:5], v[:5], lg[:5], state, norm, jnp.int32(5), 5)
+        q[:5], k[:5], v[:5], lg[:5], state, norm, jnp.int32(5), 5, "xla")
     assert np.abs(np.asarray(s5 - s5b)).max() < 1e-4
     assert np.abs(np.asarray(z5 - z5b)).max() < 1e-5
 
@@ -367,6 +369,38 @@ def test_a_slot_another_request_just_left_starts_from_nought():
     alone = engine_for(model, num_slots=1)
     c = alone.add_request(second, max_new_tokens=8)
     assert eng.run()[b] == alone.run()[c]
+
+
+def test_the_kernels_serve_the_xla_forms_tokens():
+    """One backend choice picks both retention forms: a `pallas` engine
+    runs the chunk kernel (and the decode kernel) under the interpreter
+    and serves the tokens a `dense` engine serves; the chunk's form is
+    counted apart from the decode step's."""
+    model, cfg = seeded()
+    asked = prompts(cfg, [5, 19, 33, 12, 40])
+    retention.reset_retention_path_stats()
+    _, dense = _serve(model, asked, 9, attention_backend="dense")
+    assert retention.RETENTION_CHUNK_STATS == {"xla": 2, "pallas": 0}
+    eng, fused = _serve(model, asked, 9, attention_backend="pallas")
+    assert retention.RETENTION_CHUNK_STATS == {"xla": 2, "pallas": 2}
+    assert retention.RETENTION_PATH_STATS == {"xla": 2, "pallas": 2}
+    assert eng.prefill_traces == 1
+    assert [t.tolist() for t in fused] == [t.tolist() for t in dense]
+
+
+def test_the_benchmarks_dropped_carry_still_reaches_the_kernel():
+    """`calibrate_recurrent.py` plants its faults by wrapping
+    `retention.power_retention_chunk` (positional arguments, the backend
+    among them): the carry it drops changes what a `pallas` engine
+    serves, so the seam still wraps the kernel's path."""
+    from benchmarks import calibrate_recurrent
+
+    model, cfg = seeded()
+    asked = prompts(cfg, [37, 40])
+    _, whole = _serve(model, asked, 6, attention_backend="pallas")
+    with calibrate_recurrent.planted("carry_dropped"):
+        _, dropped = _serve(model, asked, 6, attention_backend="pallas")
+    assert [t.tolist() for t in dropped] != [t.tolist() for t in whole]
 
 
 def test_ahead_and_serial_orders_serve_the_same_tokens():

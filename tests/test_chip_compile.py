@@ -618,6 +618,9 @@ def test_latent_engine_programs_hold_the_kernel_and_copy_no_pool(
 # -- power retention: the decode kernel and the engine with no pool -------------
 
 R_SLOTS, R_KVH, R_READERS, R_WIDTH = 20, 8, 5, 8704
+# the chunk kernel as the device trace names it inside the prefill program
+CHUNK_PATTERN = \
+    r"(?s)^%engine_prefill_chunk\S* = .*kernel_name\W+power_retention_chunk"
 
 
 def test_retention_decode_kernel_compiles_and_the_benchmark_finds_it(chip):
@@ -655,10 +658,11 @@ def test_retention_engine_programs_hold_no_pool_and_copy_no_state(
     cache (heads of 128 as published, a narrow body), compiled for the
     described chip as the engine jits them (the state rows donated, None
     in the pools' and the tables' places): the decode step holds the
-    retention's kernel once a layer and no loop; the chunk holds the
-    sub-chunk loop once a layer, which is what
-    `retention_chunk_busy_pct` reads, and no kernel; neither copies an
-    array of the state's shape."""
+    retention's decode kernel once a layer and no loop; the chunk holds
+    the chunk kernel once a layer (`CHUNK_PATTERN`) and no loop, so
+    `retention_chunk_busy_pct`, which reads the XLA form's sub-chunk
+    `while`s, finds nothing there since PR 36; neither copies an array
+    of the state's shape."""
     import re
 
     import numpy as np
@@ -700,8 +704,14 @@ def test_retention_engine_programs_hold_no_pool_and_copy_no_state(
 
     assert len(found(texts["decode"], "retention_decode_roofline")) == 2
     assert found(texts["decode"], "retention_chunk_busy_pct") == []
-    assert len(found(texts["chunk"], "retention_chunk_busy_pct")) == 2
-    assert "tpu_custom_call" not in texts["chunk"]
+    assert found(texts["chunk"], "retention_chunk_busy_pct") == []
+    assert " while(" not in texts["chunk"]
+    chunk_calls = [i for i in _all_instructions(texts["chunk"])
+                   if "tpu_custom_call" in i]
+    assert len(chunk_calls) == 2
+    assert all(re.search(CHUNK_PATTERN, i) for i in chunk_calls)
+    assert not any(re.search(CHUNK_PATTERN, i)
+                   for i in _all_instructions(texts["decode"]))
     held = {tuple(a.shape) for a in c.state}
     for name, text in texts.items():
         copied = [i[:80] for i in _all_instructions(text)

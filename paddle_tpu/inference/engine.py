@@ -206,10 +206,15 @@ from paddle_tpu.observability.metrics import LATENCY_BUCKETS, \
     MetricsRegistry
 from paddle_tpu.observability.tracing import (STEP_PHASES,
                                               FlightRecorder,
-                                              PhaseTimer, TraceRecorder,
+                                              STALL_MIN_EXCESS_S,
+                                              STALL_RATIO, STALL_WINDOW,
+                                              PhaseTimer, StallDetector,
+                                              TraceRecorder,
                                               export_timeline,
+                                              install_host_pause_hooks,
                                               new_trace_id, now_us,
-                                              profiler_host_events)
+                                              profiler_host_events,
+                                              stall_owner)
 from paddle_tpu.profiler import RecordEvent
 
 __all__ = ["PagedKVCache", "GenerationEngine", "Request",
@@ -982,6 +987,16 @@ class GenerationEngine:
         # leak-audit postmortem and host-gap histograms
         self.flight = FlightRecorder(capacity=flight_capacity)
         self._phases = PhaseTimer()
+        # and so are the process's pause hooks (a collection inside a
+        # `host.gc` span, compiles counted by function) and the stall
+        # detector that reads them: a step far over the median of the
+        # steps before it is counted under its owner (`_note_stall`)
+        self._pauses = install_host_pause_hooks()
+        self._stalls = StallDetector()
+        # the newest compiled step's output: a launch that finds it
+        # ready found the chip idle (`_dispatch_step`)
+        self._last_launch = None
+        self._launch_series = {}
         # default pool covers every slot at full context (+ null block):
         # correctness-first; serving deployments size it to live-context
         # expectations and lean on the stall/retry path under pressure
@@ -1740,11 +1755,34 @@ class GenerationEngine:
             "is host work, which the pipelined orders run behind a "
             "device step.",
             labelnames=("phase",), buckets=LATENCY_BUCKETS)
-        self._m_device_fraction = m.gauge(
-            "engine_step_device_fraction",
-            "Fraction of the last step's wall time spent waiting on "
-            "the device (device_wait / step wall): 1.0 = device-bound "
-            "(host gap hidden), small = host-serial tax dominates.")
+        self._m_step_seconds = m.counter(
+            "engine_step_seconds_total",
+            "Wall seconds of every engine.step(). Over any window the "
+            "device fraction is engine_step_host_gap_seconds_sum"
+            "{phase=\"device_wait\"} over this: near 1 when the host "
+            "keeps ahead.")
+        self._m_step_stalls = m.counter(
+            "engine_stalls_total",
+            f"Steps whose wall was at least {STALL_RATIO:g}x the "
+            f"median of the last {STALL_WINDOW} steps and "
+            f"{STALL_MIN_EXCESS_S * 1e3:g} ms over it, by owner: gc, "
+            "compile (that pause covered half the excess), device "
+            "(device_wait) or the host phase with the most exclusive "
+            "seconds.",
+            labelnames=("owner",))
+        self._m_step_stall_seconds = m.counter(
+            "engine_stall_seconds_total",
+            "Seconds the stalled steps took over the median, by owner.",
+            labelnames=("owner",))
+        self._m_launches = m.counter(
+            "engine_launches_total",
+            "Compiled steps launched, by program.",
+            labelnames=("program",))
+        self._m_launches_idle = m.counter(
+            "engine_launches_device_idle_total",
+            "Launches that found the previously launched step's output "
+            "ready: the chip had drained its queue before the host "
+            "launched again.", labelnames=("program",))
         # trace-count series: registered only when tracing is on, so a
         # plain engine's exposition is unchanged (adapter precedent)
         self._m_trace_spans = None
@@ -1891,17 +1929,48 @@ class GenerationEngine:
             args={"req_id": str(req.req_id), **attrs} if req is not None
             else (attrs or None))
 
+    def _step_begin(self):
+        """Mark the start of one iteration for the stall detector
+        (the process's pause totals, the span clock and this thread's
+        CPU clock); returns the `perf_counter` its wall is taken from."""
+        p = self._pauses
+        self._pause_mark = (p.gc_seconds, p.compile_seconds, now_us(),
+                            time.thread_time())
+        return time.perf_counter()
+
     def _flush_step_phases(self, wall):
         """Fold the finished step's phase clock into the host-gap
-        histogram and the device-fraction gauge."""
+        histogram and its wall into `engine_step_seconds_total`, and
+        judge it against the steps before it (`StallDetector`)."""
         totals = self._phases.reset()
-        if not totals:
-            return
+        self._m_step_seconds.inc(wall)
+        median = self._stalls.observe(wall)
+        if median is not None:
+            self._note_stall(wall, median, totals)
         for phase, dt in totals.items():
             self._m_host_gap.labels(phase=phase).observe(dt)
-        dev = totals.get("device_wait", 0.0)
-        self._m_device_fraction.set(
-            min(dev / wall, 1.0) if wall > 0 else 0.0)
+
+    def _note_stall(self, wall, median, phases):
+        """Count a stalled step under its owner (`stall_owner`) and
+        leave a `stall` flight event (and, with tracing on, an instant)
+        with what the step held: its phases, the collections and the
+        functions compiled inside it, and the CPU seconds its thread
+        ran (far under the wall: the thread was held off the CPU)."""
+        gc0, compile0, t_us, cpu0 = self._pause_mark
+        cpu_s = time.thread_time() - cpu0
+        p = self._pauses
+        gc_s, compile_s = p.gc_seconds - gc0, p.compile_seconds - compile0
+        owner = stall_owner(wall, median, phases, gc_s, compile_s)
+        self._m_step_stalls.labels(owner=owner).inc()
+        self._m_step_stall_seconds.labels(owner=owner).inc(wall - median)
+        gens, compiled = p.since(t_us)
+        detail = {"owner": owner, "wall_s": wall, "median_s": median,
+                  "phases": phases, "gc_s": gc_s,
+                  "gc_generations": gens, "compile_s": compile_s,
+                  "compiled": compiled, "cpu_s": cpu_s}
+        self.flight.record("stall", **detail)
+        if self.tracer is not None:
+            self.tracer.add_instant("stall", cat="engine", args=detail)
 
     def dump_flight_recorder(self):
         """The bounded ring of recent request-lifecycle events
@@ -2324,7 +2393,9 @@ class GenerationEngine:
             args.append(c.scales)
         if self.adapter_pool is not None:
             args.append(self.adapter_pool.arrays())
+        self._count_launch(jitted)
         out = jitted(*args, *host_args)
+        self._last_launch = out[0]
         c.kpool, c.vpool = out[n_out:n_out + 2]
         tail = n_out + 2
         if c.scales is not None:
@@ -2336,6 +2407,20 @@ class GenerationEngine:
         # what is left is the model's counters (a decode step's)
         self._step_counters = out[tail] if len(out) > tail else None
         return out[0] if n_out == 1 else out[:n_out]
+
+    def _count_launch(self, jitted):
+        """Count a compiled step's launch, and whether the step
+        launched before it had finished already (`is_ready`, which
+        does not block): the chip then idled until this launch."""
+        series = self._launch_series.get(id(jitted))
+        if series is None:
+            series = self._launch_series[id(jitted)] = (
+                self._m_launches.labels(program=jitted.__name__),
+                self._m_launches_idle.labels(program=jitted.__name__))
+        series[0].inc()
+        prev = self._last_launch
+        if prev is not None and prev.is_ready():
+            series[1].inc()
 
     def _in_flight(self):
         """Ids that would collide with a new request: queued, seated in
@@ -3291,7 +3376,7 @@ class GenerationEngine:
                 return self._step_async()
             return self._step_ahead()
         with RecordEvent("engine.step"):
-            t_wall = time.perf_counter()
+            t_wall = self._step_begin()
             progressed = self._admit()
             progressed += self._prefill_step()
             progressed += self._decode_step()
@@ -3353,7 +3438,7 @@ class GenerationEngine:
         launched counts that launch, so `run()`'s no-progress check
         stays sound."""
         with RecordEvent("engine.step"):
-            t_wall = time.perf_counter()
+            t_wall = self._step_begin()
             progressed = self._admit()
             prev = self._inflight
             chunk = None
@@ -3430,7 +3515,7 @@ class GenerationEngine:
         no-progress deadlock check stays sound (an outstanding
         in-flight step always progresses on the next call)."""
         with RecordEvent("engine.step"):
-            t_wall = time.perf_counter()
+            t_wall = self._step_begin()
             progressed = self._complete_inflight()
             self._spawn_ahead()
             progressed += self._admit()

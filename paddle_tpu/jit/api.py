@@ -31,6 +31,7 @@ from contextlib import contextmanager
 from paddle_tpu.core import autograd
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.jit import introspect
+from paddle_tpu.observability.tracing import install_host_pause_hooks
 from paddle_tpu.profiler import RecordEvent
 
 
@@ -694,6 +695,9 @@ class TrainStep:
         self.optimizer = optimizer
         self.loss_fn = loss_fn
         self.with_outputs = with_outputs
+        # the process's pause hooks: every collection inside a
+        # `host.gc` span, every compile counted (always on)
+        install_host_pause_hooks()
         # observability.TrainingTelemetry: when attached, each __call__
         # is timed end-to-end (blocking on the loss so the histogram
         # sees device time, not async dispatch) and recorded as one

@@ -28,7 +28,10 @@ class _HostEventRecorder:
 
     def __init__(self):
         self.events: List[dict] = []
-        self._lock = threading.Lock()
+        # reentrant: a garbage collection can start inside `record`
+        # (it allocates under the lock), and its `host.gc` span ends by
+        # recording itself on the same thread
+        self._lock = threading.RLock()
         self.enabled = False
 
     def record(self, name, start_us, end_us, tid, cat="host"):

@@ -337,6 +337,24 @@ def _grouped_xla(x, w, tile_expert, live_tiles, tile_rows, relu_squared):
     return out.reshape(m, -1).astype(x.dtype)
 
 
+#: what `expert_share` counts, in its order, and how a step folds its
+#: layers' counts: the assignments the experts held here took, the
+#: experts touched, the largest expert's load, the row tiles of the
+#: grouped product (live tiles; a tile past its expert's first finds the
+#: weight block the kernel already holds, where the whole expert is one)
+EXPERT_COUNTERS = (("moe_assignments_held", "sum"),
+                   ("moe_experts_touched", "sum"),
+                   ("moe_max_expert_load", "max"),
+                   ("moe_row_tiles", "sum"))
+
+
+def fold_expert_counters(per_layer):
+    """`[layers, len(EXPERT_COUNTERS)]` int32 -> one step's counts, each
+    folded over the layers as `EXPERT_COUNTERS` names."""
+    return jnp.stack([jnp.max(c) if kind == "max" else jnp.sum(c)
+                      for c, (_, kind) in zip(per_layer.T, EXPERT_COUNTERS)])
+
+
 #: an expert's function, by the name a model gives: `relu2` is
 #: `relu(x W1)^2 W2`; `swiglu` is `(silu(x W_g) * (x W_u)) W2` with gate
 #: and up as ONE grouped product, `W1 = [W_g | W_u]` `[held, d, 2 h]`
@@ -353,8 +371,7 @@ def expert_share(x, ids, weights, w1, w2, first_expert, backend="auto",
     experts, and the weights as normalised over the whole chosen set);
     w1 `[held, d, h]` (`[held, d, 2 h]` for `swiglu`), w2 `[held, h, d]`:
     experts `first_expert .. first_expert + held`. -> (`[T, d]` float32,
-    counters `[3]` int32: assignments held, experts touched, the largest
-    expert's load)."""
+    counters `[4]` int32, as `EXPERT_COUNTERS` names them)."""
     from paddle_tpu.core.device import pallas_interpret
 
     num_held = w1.shape[0]
@@ -391,6 +408,7 @@ def expert_share(x, ids, weights, w1, w2, first_expert, backend="auto",
     picked = out[plan["slot_row"]].astype(jnp.float32)   # [T, k, d]
     sizes = plan["group_sizes"]
     counters = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
-                          jnp.max(sizes)]).astype(jnp.int32)
+                          jnp.max(sizes), plan["live_tiles"][0]]) \
+        .astype(jnp.int32)
     return jnp.einsum("tk,tkd->td", w_held.astype(jnp.float32), picked,
                       precision=jax.lax.Precision.HIGHEST), counters

@@ -296,7 +296,8 @@ class LatentMoE(nn.Layer):
 
     def forward_rows(self, u, live):
         """u `[T, hidden]`; rows where `live` is False (idle lanes, a
-        prompt's padding) are routed nowhere. -> (out, counters [3])."""
+        prompt's padding) are routed nowhere. -> (out, counters [4],
+        as `distributed/moe.EXPERT_COUNTERS` names them)."""
         from paddle_tpu.distributed.moe import expert_share
 
         ids, weights = self.route(u)
@@ -412,14 +413,14 @@ class NemotronHServing(ServingSpec):
                                    cfg.mamba_head_dim,
                                    cfg.ssm_state_size), _F32),
         ) if n_m else ()
+        from paddle_tpu.distributed.moe import EXPERT_COUNTERS
+
         # a decode step's: lanes that decoded; over the E layers, the
-        # assignments its experts took, the experts touched, the largest
-        # expert's load; whether a prefill chunk's rows rode the step
-        # (then the experts' counts are of the ONE product over both)
-        self.step_counters = (("decode_live_lanes", "sum"),) + ((
-            ("moe_assignments_held", "sum"),
-            ("moe_experts_touched", "sum"),
-            ("moe_max_expert_load", "max")) if "E" in kinds else ()) \
+        # experts' counts (`EXPERT_COUNTERS`); whether a prefill chunk's
+        # rows rode the step (then the experts' counts are of the ONE
+        # product over both)
+        self.step_counters = (("decode_live_lanes", "sum"),) \
+            + (EXPERT_COUNTERS if "E" in kinds else ()) \
             + (("decode_steps_with_chunk", "sum"),)
 
     def logits(self, hidden, mp_axis=None):
@@ -428,8 +429,11 @@ class NemotronHServing(ServingSpec):
     @property
     def offers_decode_with_chunk(self):
         """Where the experts' product is the chip's kernel, which reads
-        an expert's weights once a CALL: that is what one product over
-        both row sets saves. The other form gathers a weight block a
+        an expert's weights once a CALL where the whole expert is one
+        weight block (`ops/pallas/moe.column_tile`: this model's experts
+        are, at the published widths), once a row tile where it is cut
+        into columns: one product over both row sets saves a read of
+        every expert either way. The other form gathers a weight block a
         TILE (`distributed/moe._grouped_xla`: off the chip, or widths the
         kernel does not take), as many for one product as for two, and
         keeps the two plain steps."""
@@ -442,7 +446,7 @@ class NemotronHServing(ServingSpec):
     def _walk(self, h, mamba, attention, moe_live):
         """The layers in order; `mamba(mixer, u, index)` and
         `attention(mixer, u, index)` are the caller's (chunk, step or
-        both). -> (final norm'd rows, the E layers' counters `[3]` or
+        both). -> (final norm'd rows, the E layers' counters `[4]` or
         None)."""
         model, cfg = self.model, self.model.config
         i_m = i_a = 0
@@ -464,9 +468,9 @@ class NemotronHServing(ServingSpec):
         h = _rms_norm(h, model.norm_f.weight._array, cfg.norm_eps)
         if not counters:
             return h, None
-        c = jnp.stack(counters)
-        return h, jnp.concatenate([jnp.sum(c[:, :2], axis=0),
-                                   jnp.max(c[:, 2:], axis=0)])
+        from paddle_tpu.distributed.moe import fold_expert_counters
+
+        return h, fold_expert_counters(jnp.stack(counters))
 
     @staticmethod
     def _counters(lanes_live, moe, with_chunk):

@@ -293,7 +293,8 @@ class SparseMLP(nn.Layer):
 
     def forward_rows(self, u, live):
         """u `[T, hidden]`; rows where `live` is False (idle lanes, a
-        prompt's padding) are routed nowhere. -> (out, counters [3])."""
+        prompt's padding) are routed nowhere. -> (out, counters [4],
+        as `distributed/moe.EXPERT_COUNTERS` names them)."""
         from paddle_tpu.distributed.moe import expert_share
 
         ids, weights = self.route(u)
@@ -344,8 +345,8 @@ class PanguUltraMoEForCausalLM(nn.Layer):
     def _walk(self, h, attention, live):
         """The layers in order over rows `h [.., hidden]`;
         `attention(mixer, u, index)` is the caller's (whole, chunk or
-        step). -> (final norm'd rows, the expert layers' counters `[3]`:
-        assignments held and experts touched summed, the largest load)."""
+        step). -> (final norm'd rows, the expert layers' counters `[4]`,
+        folded over the layers by `distributed/moe.fold_expert_counters`)."""
         eps = self.config.rms_norm_eps
 
         def norm(x, leaves):
@@ -363,9 +364,9 @@ class PanguUltraMoEForCausalLM(nn.Layer):
         h = norm(h, self.norm_f)
         if not counters:
             return h, None
-        c = jnp.stack(counters)
-        return h, jnp.concatenate([jnp.sum(c[:, :2], axis=0),
-                                   jnp.max(c[:, 2:], axis=0)])
+        from paddle_tpu.distributed.moe import fold_expert_counters
+
+        return h, fold_expert_counters(jnp.stack(counters))
 
     def forward(self, input_ids, absorbed=False):
         """Whole sequences, no cache: `[B, S]` ids -> float32 logits
@@ -405,15 +406,15 @@ class PanguUltraMoEServing(ServingSpec):
             PagedLatent(cfg.num_hidden_layers, cfg.pool_row_width,
                         cfg.kv_lora_rank, cfg.num_attention_heads),
             dropout=cfg.dropout)
+        from paddle_tpu.distributed.moe import EXPERT_COUNTERS
+
         sparse = cfg.num_hidden_layers > cfg.first_k_dense_replace
         # a decode step's: lanes that decoded; over the expert layers,
-        # the assignments its experts took, the experts touched, the
-        # largest expert's load; the cached rows the lanes' walks covered
-        # (one layer's: every layer walks the same rows)
-        self.step_counters = (("decode_live_lanes", "sum"),) + ((
-            ("moe_assignments_held", "sum"),
-            ("moe_experts_touched", "sum"),
-            ("moe_max_expert_load", "max")) if sparse else ()) \
+        # the experts' counts (`EXPERT_COUNTERS`); the cached rows the
+        # lanes' walks covered (one layer's: every layer walks the same
+        # rows)
+        self.step_counters = (("decode_live_lanes", "sum"),) \
+            + (EXPERT_COUNTERS if sparse else ()) \
             + (("mla_context_rows", "sum"),)
 
     def attention_backend(self, requested, block_size, mp_degree):

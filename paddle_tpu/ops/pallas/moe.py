@@ -6,8 +6,13 @@ tile belongs to ONE expert: `tile_expert[i]`, a scalar-prefetch operand,
 picks the expert's weight block for tile `i`. Tiles at and past
 `live_tiles` hold no row of anyone: they skip the product, and point at
 the last live tile's last weight block, so nothing is fetched for them.
-Every touched expert's weights cross the chip once a call, which is what
-bounds the kernel at decode (a handful of rows an expert).
+
+Where an expert's whole `[K, N]` fits `_EXPERT_BLOCK_BYTES` it is ONE
+block: the row tiles of one expert follow one another and name the same
+block, which the pipeline then does not fetch again, so every touched
+expert's weights cross the chip once a call however many tiles it holds.
+A larger expert is cut into column blocks walked inside each row tile,
+and crosses once a row tile (`column_tile`).
 """
 from __future__ import annotations
 
@@ -22,15 +27,18 @@ from .naming import kernel_name
 #: `moe_experts_roofline` looks for
 KERNEL_NAME = "moe_grouped_matmul"
 
-#: VMEM one weight block may take (two are in flight)
+#: VMEM an expert's whole weights may take as one block (two are in
+#: flight)
+_EXPERT_BLOCK_BYTES = 8 * 1024 * 1024
+#: VMEM one column block of a larger expert may take (two are in flight)
 _WEIGHT_BLOCK_BYTES = 3 * 1024 * 1024
 
 
 def column_tile(k, n, itemsize):
-    """Columns of a weight block: the whole of `n`, or its largest
-    divisor that is a multiple of 128 lanes and keeps `[k, tile]` inside
-    `_WEIGHT_BLOCK_BYTES`."""
-    if k * n * itemsize <= _WEIGHT_BLOCK_BYTES or n % 128:
+    """Columns of a weight block: the whole of `n` where `[k, n]` fits
+    `_EXPERT_BLOCK_BYTES`, else its largest divisor that is a multiple of
+    128 lanes and keeps `[k, tile]` inside `_WEIGHT_BLOCK_BYTES`."""
+    if k * n * itemsize <= _EXPERT_BLOCK_BYTES or n % 128:
         return n
     fits = [t for t in range(128, n, 128)
             if n % t == 0 and k * t * itemsize <= _WEIGHT_BLOCK_BYTES]
@@ -87,6 +95,9 @@ def moe_grouped_matmul(x, w, tile_expert, live_tiles, tile_rows,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # the weight block twice (the pipeline's two buffers), and
+            # room for the row tiles and the product
+            vmem_limit_bytes=2 * k * tn * w.dtype.itemsize + (16 << 20)),
         interpret=interpret,
     )(tile_expert.astype(jnp.int32), live_tiles.astype(jnp.int32), x, w)

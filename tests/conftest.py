@@ -49,3 +49,30 @@ def fused_step_offered(monkeypatch):
     from paddle_tpu.models.nemotron_h import NemotronHServing
 
     monkeypatch.setattr(NemotronHServing, "offers_decode_with_chunk", True)
+
+
+@pytest.fixture
+def expert_loads(monkeypatch):
+    """Every expert layer's load as its product runs, jitted or not:
+    a list of (rows of the call, each held expert's assignments), filled
+    from the device. The products are cut into tiles of 4 rows (the
+    default 16 holds every load of a tiny engine in one tile, where a
+    count of tiles could not tell loads from experts)."""
+    import functools
+
+    from paddle_tpu.distributed import moe
+
+    seen = []
+    dispatch, share = moe.sorted_dispatch, moe.expert_share
+
+    def recorded(ids, first_expert, num_held, tile_rows=moe.TILE_ROWS):
+        plan = dispatch(ids, first_expert, num_held, tile_rows)
+        rows = ids.shape[0]
+        jax.debug.callback(lambda s: seen.append((rows, np.asarray(s))),
+                           plan["group_sizes"])
+        return plan
+
+    monkeypatch.setattr(moe, "sorted_dispatch", recorded)
+    monkeypatch.setattr(moe, "expert_share",
+                        functools.partial(share, tile_rows=4))
+    return seen

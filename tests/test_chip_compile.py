@@ -333,8 +333,10 @@ def test_moe_grouped_matmul_compiles_and_the_benchmark_finds_it(chip,
     from paddle_tpu.ops.pallas.moe import column_tile, moe_grouped_matmul
 
     rows = -(-(tokens * E_TOPK + E_HELD * (E_TILE - 1)) // E_TILE) * E_TILE
-    assert column_tile(E_LATENT, E_INTER, 2) == 896
-    assert column_tile(E_INTER, E_LATENT, 2) == 512
+    # a whole expert a block (5.5 MB each way): an expert's row tiles
+    # share it, and its weights cross once a call
+    assert column_tile(E_LATENT, E_INTER, 2) == E_INTER
+    assert column_tile(E_INTER, E_LATENT, 2) == E_LATENT
 
     def product(x, w1, w2, tile_expert, live):
         hid = moe_grouped_matmul(x, w1, tile_expert, live, E_TILE,
@@ -350,10 +352,14 @@ def test_moe_grouped_matmul_compiles_and_the_benchmark_finds_it(chip,
         256 + N_SLOTS: ("engine_decode_step_with_chunk",
                         "moe_experts_roofline",
                         "moe_prefill_experts_busy_pct")}[tokens]
-    text = chip(product, ((rows, E_LATENT), BF16),
-                ((E_HELD, E_LATENT, E_INTER), BF16),
-                ((E_HELD, E_INTER, E_LATENT), BF16),
-                ((rows // E_TILE,), I32), ((1,), I32))
+    shapes = (((rows, E_LATENT), BF16),
+              ((E_HELD, E_LATENT, E_INTER), BF16),
+              ((E_HELD, E_INTER, E_LATENT), BF16),
+              ((rows // E_TILE,), I32), ((1,), I32))
+    assert _grouped_blocks(product, *shapes) == [
+        ((rows // E_TILE, 1), (1, E_LATENT, E_INTER)),
+        ((rows // E_TILE, 1), (1, E_INTER, E_LATENT))]
+    text = chip(product, *shapes)
     # the second product is this test function's ROOT; in the engine's
     # step neither is, and a trace names an event by the instruction
     calls = [c for c in _all_instructions(text)
@@ -365,6 +371,56 @@ def test_moe_grouped_matmul_compiles_and_the_benchmark_finds_it(chip,
         assert not any(re.search(p, call)
                        for p in _metric_patterns(other))
         assert "moe_grouped_matmul" in trace_reduce.op_group(call)
+
+
+def _grouped_blocks(fn, *shapes):
+    """(grid, weight block) of every grouped matmul `fn` traces, in
+    order: what the kernel is built with, which the compiled text keeps
+    inside the serialized Mosaic module."""
+    found = []
+    jaxpr = jax.make_jaxpr(fn)(*[jax.ShapeDtypeStruct(s, d)
+                                 for s, d in shapes])
+    for eqn in jaxpr.jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grid = eqn.params["grid_mapping"]
+            weights = grid.block_mappings[1].block_shape
+            found.append((tuple(grid.grid), tuple(
+                getattr(b, "block_size", b) for b in weights)))
+    return found
+
+
+# the latent decoder's experts (`pangu_ultra_serve_docqa`): 8 held,
+# 7,680 -> [gate | up] 2 x 2,048 -> 7,680, 8 a token
+P_HELD, P_HIDDEN, P_INTER, P_TOPK = 8, 7680, 2048, 8
+
+
+@pytest.mark.parametrize("tokens", [96, 256], ids=["decode", "chunk"])
+def test_moe_grouped_matmul_cuts_a_large_expert_into_columns(chip, tokens):
+    """An expert too large for one block (63 MB and 31.5 MB) keeps the
+    column blocks of 3 MB at most, walked inside each row tile: 128
+    columns of gate-and-up, 768 of down. Its experts hold a tile or two
+    a step, so there is little to re-fetch; swapping the grid would read
+    the row tiles once a column block instead."""
+    from paddle_tpu.ops.pallas.moe import moe_grouped_matmul
+
+    rows = -(-(tokens * P_TOPK + P_HELD * (E_TILE - 1)) // E_TILE) \
+        * E_TILE
+
+    def product(x, w1, w2, tile_expert, live):
+        gate_up = moe_grouped_matmul(x, w1, tile_expert, live, E_TILE)
+        return moe_grouped_matmul(gate_up[:, :P_INTER], w2, tile_expert,
+                                  live, E_TILE)
+
+    shapes = (((rows, P_HIDDEN), BF16),
+              ((P_HELD, P_HIDDEN, 2 * P_INTER), BF16),
+              ((P_HELD, P_INTER, P_HIDDEN), BF16),
+              ((rows // E_TILE,), I32), ((1,), I32))
+    assert _grouped_blocks(product, *shapes) == [
+        ((rows // E_TILE, 2 * P_INTER // 128), (1, P_HIDDEN, 128)),
+        ((rows // E_TILE, P_HIDDEN // 768), (1, P_INTER, 768))]
+    text = chip(product, *shapes)
+    assert len([c for c in _all_instructions(text)
+                if "tpu_custom_call" in c]) == 2
 
 
 def test_auto_takes_the_new_kernels_on_a_tpu_only(monkeypatch):

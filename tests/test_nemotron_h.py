@@ -326,6 +326,11 @@ def test_decode_with_chunk_is_the_chunk_then_the_decode_step(pattern,
                    plain["moe_experts_touched"]) \
             <= mine["moe_experts_touched"] \
             < alone["moe_experts_touched"] + plain["moe_experts_touched"]
+        # and its rows share tiles: no more than the two products' tiles
+        assert max(alone["moe_row_tiles"], plain["moe_row_tiles"],
+                   mine["moe_experts_touched"]) \
+            <= mine["moe_row_tiles"] \
+            <= alone["moe_row_tiles"] + plain["moe_row_tiles"]
 
 
 def test_a_spec_without_the_fused_step_says_so():
@@ -461,15 +466,43 @@ def test_engine_counts_the_experts_load_and_the_state_rows(
     assert 0 < totals["moe_experts_touched"] <= \
         totals["moe_assignments_held"]
     assert 1 <= totals["moe_max_expert_load"] <= 1 + 9
+    assert totals["moe_experts_touched"] <= totals["moe_row_tiles"] \
+        <= totals["moe_assignments_held"]
     text = eng.metrics.render_prometheus()
     for name in ("engine_moe_assignments_held_total",
                  "engine_decode_steps_with_chunk_total 1",
                  "engine_moe_experts_touched_total",
+                 "engine_moe_row_tiles_total",
                  "engine_moe_max_expert_load",
                  "engine_state_slots_used"):
         assert name in text
     for phase in ("state_alloc", "state_free"):
         assert f'phase="{phase}"' in text
+
+
+def test_the_row_tiles_are_each_experts_load_in_whole_tiles(
+        fused_step_offered, expert_loads):
+    """`moe_row_tiles` over a run: the sum over the E layers and the
+    decode steps (a fused step's one product included, a chunk's alone
+    not) of ceil(load / tile rows), each load as the product saw it."""
+    import jax
+
+    model, cfg = seeded("ME*E")
+    eng = engine_for(model)
+    for p in prompts(cfg, [9, 9]):
+        eng.add_request(p, max_new_tokens=6)
+    eng.run()
+    jax.effects_barrier()
+    totals = eng.step_counter_totals
+    assert totals["decode_steps_with_chunk"] == 1
+    # 2 lanes decode, 16 rows a chunk: a decode step's product holds 2
+    # rows, a fused step's 18, a chunk's alone 16
+    stepped = [s for rows, s in expert_loads if rows in (2, 2 + 16)]
+    assert len(stepped) == 2 * eng.decode_steps
+    assert totals["moe_row_tiles"] == sum(
+        int((-(-s // 4)).sum()) for s in stepped)
+    assert totals["moe_row_tiles"] > totals["moe_experts_touched"] \
+        == sum(int((s > 0).sum()) for s in stepped)
 
 
 @pytest.mark.parametrize("kwargs,feature", [
@@ -686,7 +719,7 @@ def test_dropless_dispatch_and_grouped_matmul(crowded):
         np.testing.assert_allclose(out, want, atol=1e-4)
         sizes = np.bincount(local[here], minlength=held)
         assert counters.tolist() == [here.sum(), (sizes > 0).sum(),
-                                     sizes.max()]
+                                     sizes.max(), (-(-sizes // 8)).sum()]
     assert moe.MOE_PATH_STATS == {"xla": 1, "pallas": 1}
 
 

@@ -398,7 +398,7 @@ def test_engine_counts_the_experts_load_and_the_rows_walked():
     t = eng.step_counter_totals
     assert set(t) == {"decode_live_lanes", "moe_assignments_held",
                       "moe_experts_touched", "moe_max_expert_load",
-                      "mla_context_rows"}
+                      "moe_row_tiles", "mla_context_rows"}
     # three decode steps a request (the first token is the chunk's); a
     # step at position p walks p + 1 rows
     assert t["decode_live_lanes"] == 2 * 3
@@ -408,8 +408,32 @@ def test_engine_counts_the_experts_load_and_the_rows_walked():
     assert 0 < t["moe_assignments_held"] <= 6 * 2 * 3
     assert 0 < t["moe_experts_touched"] <= t["moe_assignments_held"]
     assert 1 <= t["moe_max_expert_load"] <= 2
+    # a load of at most 2 fills one 16-row tile
+    assert t["moe_row_tiles"] == t["moe_experts_touched"]
     text = eng.metrics.render_prometheus()
     assert "mla_context_rows" in text
+    assert "engine_moe_row_tiles_total" in text
+
+
+def test_the_row_tiles_are_each_experts_load_in_whole_tiles(expert_loads):
+    """`moe_row_tiles` over a run: the sum over the expert layers and the
+    decode steps of ceil(load / tile rows); a chunk's products are not
+    counted."""
+    model, cfg = seeded()
+    eng = engine_for(model, async_core=False)
+    for p in prompts(cfg, [10, 20], seed=4):
+        eng.add_request(p, max_new_tokens=8)
+    eng.run()
+    jax.effects_barrier()
+    t = eng.step_counter_totals
+    slots = eng.num_slots
+    stepped = [s for rows, s in expert_loads if rows == slots]
+    assert len(stepped) == 2 * eng.decode_steps
+    assert t["moe_row_tiles"] == sum(int((-(-s // 4)).sum())
+                                     for s in stepped)
+    assert t["moe_experts_touched"] == sum(int((s > 0).sum())
+                                           for s in stepped)
+    assert t["moe_row_tiles"] >= t["moe_experts_touched"]
 
 
 def test_ahead_and_serial_orders_serve_the_same_tokens():

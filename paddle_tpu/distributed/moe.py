@@ -280,6 +280,21 @@ def resolve_moe_backend(backend, width=128):
     return "pallas" if on_tpu() and width % 128 == 0 else "xla"
 
 
+def one_product_reads_less(width):
+    """Whether ONE grouped product over two row sets (a prefill chunk's
+    and a decode step's) reads the experts' weights fewer times than two
+    products, at the experts' narrower `width`: where `auto` takes the
+    chip's kernel, which reads an expert's weights once a CALL where the
+    whole expert is one weight block (`ops/pallas/moe.column_tile`), and
+    once a row tile where it is cut into columns, so one product saves a
+    read of every expert that both row sets touch either way (ceil(a +
+    b) <= ceil(a) + ceil(b) tiles). The other form gathers a weight block
+    a TILE (`_grouped_xla`: off the chip, or widths the kernel does not
+    take), as many for one product as for two. A serving spec offers
+    `decode_with_chunk` where this holds."""
+    return resolve_moe_backend("auto", width) == "pallas"
+
+
 def sorted_dispatch(ids, first_expert, num_held, tile_rows=TILE_ROWS):
     """Where every assignment goes. ids `[T, k]` int32, experts among
     ALL the router's; this layer holds `first_expert .. first_expert +

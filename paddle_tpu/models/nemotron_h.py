@@ -428,20 +428,15 @@ class NemotronHServing(ServingSpec):
 
     @property
     def offers_decode_with_chunk(self):
-        """Where the experts' product is the chip's kernel, which reads
-        an expert's weights once a CALL where the whole expert is one
-        weight block (`ops/pallas/moe.column_tile`: this model's experts
-        are, at the published widths), once a row tile where it is cut
-        into columns: one product over both row sets saves a read of
-        every expert either way. The other form gathers a weight block a
-        TILE (`distributed/moe._grouped_xla`: off the chip, or widths the
-        kernel does not take), as many for one product as for two, and
-        keeps the two plain steps."""
-        from paddle_tpu.distributed.moe import resolve_moe_backend
+        """Where one expert product over both row sets reads the experts
+        less than two (`distributed/moe.one_product_reads_less`: the
+        chip's kernel; this model's experts are each one weight block at
+        the published widths); elsewhere the two plain steps."""
+        from paddle_tpu.distributed.moe import one_product_reads_less
 
         cfg = self.model.config
-        return resolve_moe_backend("auto", min(
-            cfg.moe_latent_size, cfg.moe_intermediate_size)) == "pallas"
+        return one_product_reads_less(min(cfg.moe_latent_size,
+                                          cfg.moe_intermediate_size))
 
     def _walk(self, h, mamba, attention, moe_live):
         """The layers in order; `mamba(mixer, u, index)` and

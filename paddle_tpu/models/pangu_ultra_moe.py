@@ -222,33 +222,60 @@ class LatentAttention(nn.Layer):
 
     def chunk(self, u, pool, layer, block_row, start, plen, backend):
         """One slot's chunk `u [C, hidden]` at positions `start ..`."""
-        from paddle_tpu.ops.paged_attention import \
-            paged_latent_prefill_chunk
-
-        positions = start + jnp.arange(u.shape[0])
-        q_nope, q_rope, row = self.project(u, positions)
-        o, pool = paged_latent_prefill_chunk(
-            q_nope, q_rope, self._padded(row), self.w_kvb(), pool, layer,
-            block_row, start, plen, self.scale, backend=backend)
+        q_nope, q_rope, row = self.project(
+            u, start + jnp.arange(u.shape[0]))
+        o, pool = self._chunk_heads(q_nope, q_rope, row, pool, layer,
+                                    block_row, start, plen, backend)
         return self.out(o), pool
 
     def step(self, u, pool, layer, block_tables, positions, backend):
         """One token a slot, `u [slots, hidden]`, absorbed."""
+        q_nope, q_rope, row = self.project(u, positions)
+        o, pool = self._step_heads(q_nope, q_rope, row, pool, layer,
+                                   block_tables, positions, backend)
+        return self.out(o), pool
+
+    def chunk_and_step(self, u, width, pool, layer, block_row, start,
+                       plen, tables, positions, backend):
+        """`chunk` over the first `width` rows of `u [width + slots,
+        hidden]` and `step` over the rest, with ONE pass of every
+        projection: the chunk's rows attend in their own form, then the
+        decode rows absorbed over the pool the chunk has written."""
+        q_nope, q_rope, row = self.project(u, jnp.concatenate(
+            [start + jnp.arange(width), positions]))
+        o_c, pool = self._chunk_heads(
+            q_nope[:width], q_rope[:width], row[:width], pool, layer,
+            block_row, start, plen, backend)
+        o_s, pool = self._step_heads(
+            q_nope[width:], q_rope[width:], row[width:], pool, layer,
+            tables, positions, backend)
+        return self.out(jnp.concatenate([o_c, o_s])), pool
+
+    def _chunk_heads(self, q_nope, q_rope, row, pool, layer, block_row,
+                     start, plen, backend):
+        from paddle_tpu.ops.paged_attention import \
+            paged_latent_prefill_chunk
+
+        return paged_latent_prefill_chunk(
+            q_nope, q_rope, self._padded(row), self.w_kvb(), pool, layer,
+            block_row, start, plen, self.scale, backend=backend)
+
+    def _step_heads(self, q_nope, q_rope, row, pool, layer, block_tables,
+                    positions, backend):
         from paddle_tpu.ops.paged_attention import paged_latent_decode
 
         cfg = self.cfg
         dn = cfg.qk_nope_head_dim
-        q_nope, q_rope, row = self.project(u, positions)
         w = self.w_kvb()
         q_abs = jnp.einsum("bhd,rhd->bhr", q_nope, w[..., :dn],
-                           preferred_element_type=_F32).astype(u.dtype)
+                           preferred_element_type=_F32).astype(q_nope.dtype)
         o, pool = paged_latent_decode(
             self._padded(jnp.concatenate([q_abs, q_rope], -1)),
             self._padded(row), pool, layer, block_tables, positions,
             cfg.kv_lora_rank, self.scale, backend=backend)
         o = jnp.einsum("bhr,rhd->bhd", o, w[..., dn:],
-                       preferred_element_type=_F32).astype(u.dtype)
-        return self.out(o), pool
+                       preferred_element_type=_F32).astype(q_nope.dtype)
+        return o, pool
 
 
 class DenseMLP(nn.Layer):
@@ -408,14 +435,29 @@ class PanguUltraMoEServing(ServingSpec):
             dropout=cfg.dropout)
         from paddle_tpu.distributed.moe import EXPERT_COUNTERS
 
-        sparse = cfg.num_hidden_layers > cfg.first_k_dense_replace
+        self._sparse = cfg.num_hidden_layers > cfg.first_k_dense_replace
         # a decode step's: lanes that decoded; over the expert layers,
         # the experts' counts (`EXPERT_COUNTERS`); the cached rows the
         # lanes' walks covered (one layer's: every layer walks the same
-        # rows)
+        # rows); whether a prefill chunk's rows rode the step (then the
+        # experts' counts are of the ONE product over both)
         self.step_counters = (("decode_live_lanes", "sum"),) \
-            + (EXPERT_COUNTERS if sparse else ()) \
-            + (("mla_context_rows", "sum"),)
+            + (EXPERT_COUNTERS if self._sparse else ()) \
+            + (("mla_context_rows", "sum"),
+               ("decode_steps_with_chunk", "sum"))
+
+    @property
+    def offers_decode_with_chunk(self):
+        """Where one expert product over both row sets reads the experts
+        less than two (`distributed/moe.one_product_reads_less`: the
+        chip's kernel, at the narrower of an expert's gate-and-up
+        product's widths, as `expert_share` resolves it); elsewhere, and
+        in a model with no expert layer, the two plain steps."""
+        from paddle_tpu.distributed.moe import one_product_reads_less
+
+        cfg = self.model.config
+        return self._sparse and one_product_reads_less(
+            min(cfg.hidden_size, 2 * cfg.moe_intermediate_size))
 
     def attention_backend(self, requested, block_size, mp_degree):
         from paddle_tpu.ops.paged_attention import resolve_latent_backend
@@ -469,10 +511,46 @@ class PanguUltraMoEServing(ServingSpec):
 
         h, moe = self.model._walk(
             self.model.embed.weight._array[ids], attention, live)
+        return StepOut(Tensor._wrap(h), Tensor._wrap(pool[0]), None,
+                       counters=self._counters(live, pos, moe, 0))
+
+    def decode_with_chunk(self, chunk_tokens, start, block_row, plen,
+                          tokens, positions, block_tables, kpool, vpool,
+                          backend="auto"):
+        """`prefill_chunk` and `decode` as one walk over the chunk's
+        rows and the decode rows together: every projection, the dense
+        layers, the shared expert, the router and the experts' products
+        cross their weights once for both row sets; each row set keeps
+        its own attention (`LatentAttention.chunk_and_step`)."""
+        ids_c, ids_s = chunk_tokens._array[0], tokens._array[:, 0]
+        width = ids_c.shape[0]
+        row, s0, n = block_row._array, start._array, plen._array
+        pos, tables = positions._array, block_tables._array
+        live = tables[:, 0] != 0
+        pool = [kpool._array]
+
+        def attention(mixer, u, i):
+            out, pool[0] = mixer.chunk_and_step(
+                u, width, pool[0], i, row, s0, n, tables, pos, backend)
+            return out
+
+        h, moe = self.model._walk(
+            self.model.embed.weight._array[
+                jnp.concatenate([ids_c, ids_s])], attention,
+            jnp.concatenate([jnp.arange(width) < jnp.clip(n - s0, 0, width),
+                             live]))
+        return (StepOut(Tensor._wrap(h[None, :width]),
+                        Tensor._wrap(pool[0]), None,
+                        counters=self._counters(live, pos, moe, 1)),
+                Tensor._wrap(h[width:, None]))
+
+    @staticmethod
+    def _counters(live, pos, moe, with_chunk):
+        """`step_counters`' values of one decode step over the lanes
+        `live` at `pos`."""
         parts = [jnp.sum(live, dtype=jnp.int32).reshape(1)]
         if moe is not None:
             parts.append(moe)
         parts.append(jnp.sum(jnp.where(live, pos + 1, 0),
                              dtype=jnp.int32).reshape(1))
-        return StepOut(Tensor._wrap(h), Tensor._wrap(pool[0]), None,
-                       counters=jnp.concatenate(parts))
+        return jnp.concatenate(parts + [jnp.full(1, with_chunk, jnp.int32)])

@@ -42,13 +42,15 @@ def _seed_all():
 
 @pytest.fixture
 def fused_step_offered(monkeypatch):
-    """The hybrid decoder's spec offers `decode_with_chunk` where the
-    experts' product is the chip's kernel, which this CPU never takes:
-    a test of the fused order asks for the offer itself. The step then
-    runs the forms this platform resolves."""
+    """The hybrid and the latent decoders' specs offer
+    `decode_with_chunk` where the experts' product is the chip's kernel,
+    which this CPU never takes: a test of the fused order asks for the
+    offer itself. The step then runs the forms this platform resolves."""
     from paddle_tpu.models.nemotron_h import NemotronHServing
+    from paddle_tpu.models.pangu_ultra_moe import PanguUltraMoEServing
 
-    monkeypatch.setattr(NemotronHServing, "offers_decode_with_chunk", True)
+    for spec in (NemotronHServing, PanguUltraMoEServing):
+        monkeypatch.setattr(spec, "offers_decode_with_chunk", True)
 
 
 @pytest.fixture

@@ -594,17 +594,22 @@ def test_mla_prefill_kernel_compiles_at_the_cells_geometry(chip, chunk,
 
 
 def test_latent_engine_programs_hold_the_kernel_and_copy_no_pool(
-        topo, monkeypatch):
-    """The decode step of a small latent-attention decoder at the
+        topo, monkeypatch, fused_step_offered):
+    """The programs of a small latent-attention decoder at the
     kernels' widths (rows of 256 + 64 values in 384 lanes, so that a
     chunk of 256 rows takes the expanded form; experts 256 -> 2 x 128 ->
     256), compiled for the described chip as the engine jits
-    it (the ONE pool donated): the latent walk's Mosaic call of every
+    them (the ONE pool donated): the latent walk's Mosaic call of every
     layer lies under `%engine_decode_step`, where `mla_decode_roofline`
     looks for it, the experts' two under `moe_experts_roofline`'s, the
     chunk's attention kernel of every layer under
     `%engine_prefill_chunk` (the engine's one backend reaches both
-    programs), and neither program copies a pool-shaped array."""
+    programs); the step that carries a chunk holds all three under
+    `%engine_decode_step_with_chunk`, where the two decode-step
+    patterns find its decode walk and its experts' products; and no
+    program copies a pool-shaped array. The engine is built on this
+    CPU, where the spec offers no fused step: the fixture offers it, as
+    the chip does at these widths."""
     import re
 
     import numpy as np
@@ -640,7 +645,8 @@ def test_latent_engine_programs_hold_the_kernel_and_copy_no_pool(
     head = (eng._state_arrays(), c.kpool, None)
     texts = {}
     for name, jitted, host in (("chunk", eng._prefill, chunk),
-                               ("decode", eng._decode, decode)):
+                               ("decode", eng._decode, decode),
+                               ("fused", eng._fused, chunk + decode)):
         args = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                            sharding=one), head + host)
@@ -661,6 +667,14 @@ def test_latent_engine_programs_hold_the_kernel_and_copy_no_pool(
     assert len(prefill) == 2
     assert not any(re.search(MLA_PREFILL_PATTERN, c)
                    for c in _all_instructions(texts["decode"]))
+    fused = texts["fused"]
+    assert len(calls(fused, "mla_decode_roofline")) == 2
+    assert len(calls(fused, "moe_experts_roofline")) == 2
+    assert len([c for c in _all_instructions(fused)
+                if "tpu_custom_call" in c and re.search(
+                    r"kernel_name\W+mla_paged_prefill", c)]) == 2
+    assert all(c.startswith("%engine_decode_step_with_chunk.")
+               for c in _all_instructions(fused) if "tpu_custom_call" in c)
     pool = tuple(c.kpool.shape)
     for name, text in texts.items():
         copied = [i[:80] for i in _all_instructions(text)

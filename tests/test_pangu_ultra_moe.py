@@ -6,7 +6,10 @@ attention; chunked prefill then decode through `GenerationEngine`'s latent
 pool against the reference's one pass; the Pallas walk (interpreter)
 against the XLA walk; a request served through prefix hits against the
 same request served cold; the pool's size; idle lanes and padding routed
-nowhere; the counters; the share test.
+nowhere; the counters; the share test; the step that walks a prefill
+chunk's rows and the decode rows together (`decode_with_chunk`) against
+the chunk program then the decode step, and its order against the two
+programs', prefix hits included.
 """
 import jax
 import jax.numpy as jnp
@@ -398,7 +401,9 @@ def test_engine_counts_the_experts_load_and_the_rows_walked():
     t = eng.step_counter_totals
     assert set(t) == {"decode_live_lanes", "moe_assignments_held",
                       "moe_experts_touched", "moe_max_expert_load",
-                      "moe_row_tiles", "mla_context_rows"}
+                      "moe_row_tiles", "mla_context_rows",
+                      "decode_steps_with_chunk"}
+    assert t["decode_steps_with_chunk"] == 0
     # three decode steps a request (the first token is the chunk's); a
     # step at position p walks p + 1 rows
     assert t["decode_live_lanes"] == 2 * 3
@@ -497,3 +502,253 @@ def test_the_shares_add_up_to_the_uncut_layer():
             - (np.asarray(shared) if first else 0)
         assert counters[0] > 0
     np.testing.assert_allclose(got, whole, atol=1e-5)
+
+
+# -- the fused step: a chunk's rows and the decode rows in one walk ----------
+
+def _step_inputs(cfg, slots, width, bs=8, seed=0):
+    """A pool of random rows and the host args of one chunk (one lane's,
+    past two cached blocks, its last 3 rows padding) and of one decode
+    step (every lane but the last decodes; the last is idle)."""
+    rng = np.random.default_rng(seed)
+    per = 6                                        # blocks a lane
+    pool = rng.normal(size=(cfg.num_hidden_layers, 1 + per * (slots + 1),
+                            bs, cfg.pool_row_width)).astype(np.float32)
+    row = np.zeros(per, np.int32)
+    row[:] = np.arange(1, 1 + per)
+    start = 2 * bs
+    chunk = rng.integers(0, cfg.vocab_size, (1, width), dtype=np.int32)
+    tables = np.zeros((slots, per), np.int32)
+    positions = np.zeros(slots, np.int32)
+    for j in range(slots - 1):
+        tables[j] = np.arange(1 + per * (j + 1), 1 + per * (j + 2))
+        positions[j] = 5 + 9 * j
+    tokens = rng.integers(0, cfg.vocab_size, (slots, 1), dtype=np.int32)
+    return (pool, chunk, np.int32(start), row,
+            np.int32(start + width - 3), tokens, positions, tables)
+
+
+@pytest.mark.parametrize("slots,width,backend", [
+    (2, 16, "dense"), (3, 32, "dense"), (3, 16, "pallas"),
+    (2, 32, "pallas")],
+    ids=["absorbed_2", "expanded_3", "absorbed_pallas_3",
+         "expanded_pallas_2"])
+def test_the_fused_step_is_the_chunk_then_the_decode_step(slots, width,
+                                                          backend):
+    """`decode_with_chunk` against `prefill_chunk` then `decode` on the
+    same inputs: the same pool rows written, the same hidden rows for
+    the chunk's valid rows and the lanes that decode, the decode step's
+    own counts of lanes and rows walked, and the flag of the step that
+    carried a chunk. A chunk of 16 rows attends absorbed at these widths,
+    one of 32 expanded."""
+    from paddle_tpu.core.tensor import Tensor
+
+    model, cfg = seeded()
+    spec = model.serving_spec()
+    pool, chunk, start, row, plen, tokens, positions, tables = \
+        _step_inputs(cfg, slots, width, seed=slots * width)
+    w = lambda a: Tensor._wrap(jnp.asarray(a))  # noqa: E731
+    pa.reset_latent_path_stats()
+
+    @jax.jit
+    def two(pool):
+        c = spec.prefill_chunk(w(chunk), w(start), w(pool), None, w(row),
+                               w(plen), backend=backend)
+        s = spec.decode(w(tokens), w(positions), c.kpool, None, w(tables),
+                        backend=backend)
+        return c.hidden._array, s.hidden._array, s.kpool._array, \
+            s.counters
+
+    @jax.jit
+    def one(pool):
+        r, h = spec.decode_with_chunk(
+            w(chunk), w(start), w(row), w(plen), w(tokens), w(positions),
+            w(tables), w(pool), None, backend=backend)
+        return r.hidden._array, h._array, r.kpool._array, r.counters
+
+    h_c, h_s, pool_two, c_two = two(jnp.asarray(pool))
+    form = "absorbed" if width < 32 else \
+        "pallas_expanded" if backend == "pallas" else "expanded"
+    assert pa.LATENT_CHUNK_STATS[form] == cfg.num_hidden_layers
+    assert pa.LATENT_PATH_STATS[backend] == cfg.num_hidden_layers
+    f_c, f_s, pool_one, c_one = one(jnp.asarray(pool))
+    assert pa.LATENT_CHUNK_STATS[form] == 2 * cfg.num_hidden_layers
+    assert pa.LATENT_PATH_STATS[backend] == 2 * cfg.num_hidden_layers
+    np.testing.assert_allclose(np.asarray(pool_one), np.asarray(pool_two),
+                               atol=2e-5)
+    # the chunk's rows reached its blocks, past the cached two
+    assert np.abs(np.asarray(pool_one) - pool)[:, 3].max() > 0.1
+    valid = int(plen - start)
+    np.testing.assert_allclose(np.asarray(f_c)[:, :valid],
+                               np.asarray(h_c)[:, :valid], atol=2e-5)
+    np.testing.assert_allclose(np.asarray(f_s)[:-1], np.asarray(h_s)[:-1],
+                               atol=2e-5)
+    names = [n for n, _ in spec.step_counters]
+    one_c, two_c = dict(zip(names, c_one.tolist())), \
+        dict(zip(names, c_two.tolist()))
+    for name in ("decode_live_lanes", "mla_context_rows"):
+        assert one_c[name] == two_c[name]
+    assert two_c["decode_live_lanes"] == slots - 1
+    assert (one_c["decode_steps_with_chunk"],
+            two_c["decode_steps_with_chunk"]) == (1, 0)
+    # the chunk's valid rows are routed in the one product too
+    assert one_c["moe_assignments_held"] > two_c["moe_assignments_held"]
+
+
+LENGTHS = [5, 19, 33, 12, 16, 7]
+
+
+def serve_late(model, cfg, n_new=9, late=3, **kw):
+    """More requests than slots, `late` of them added after two
+    iterations, so chunks meet lanes that decode. -> (streams in request
+    order, engine)."""
+    asked = prompts(cfg, LENGTHS)
+    eng = engine_for(model, **kw)
+    ids = [eng.add_request(p, max_new_tokens=n_new)
+           for p in asked[:len(asked) - late]]
+    for _ in range(2):
+        eng.step()
+    ids += [eng.add_request(p, max_new_tokens=n_new)
+            for p in asked[len(asked) - late:]]
+    out = eng.drain()                     # audits the blocks
+    return [out[i] for i in ids], eng
+
+
+@pytest.mark.parametrize("slots,chunk", [(2, 8), (3, 16), (4, 32)],
+                         ids=["absorbed_2", "absorbed_3", "expanded_4"])
+def test_the_fused_order_serves_the_two_program_orders_tokens(
+        fused_step_offered, slots, chunk):
+    model, cfg = seeded()
+    kw = dict(num_slots=slots, prefill_chunk=chunk)
+    serial, eng_s = serve_late(model, cfg, async_core=False, **kw)
+    ahead, eng = serve_late(model, cfg, **kw)
+    assert ahead == serial
+    assert [len(a) for a in ahead] == [n + 9 for n in LENGTHS]
+    assert eng._fused is not None and eng_s._fused is None
+    assert 0 < eng.decode_steps_with_chunk <= eng.decode_steps
+    assert eng.overshoot_tokens == 0
+
+
+def test_questions_over_a_cached_document_under_the_fused_order(
+        fused_step_offered):
+    """A lane decodes throughout, so every chunk rides a fused step: the
+    document's first request ends its prompt inside one, and its blocks
+    are published when that first token is read a call later. The
+    questions after it start past the document's shared blocks, and a
+    prompt that IS the document's whole blocks copies the shared block
+    its first decode writes into. The same tokens as each request served
+    cold, the hits of the two-program order, and the document's blocks
+    cached once."""
+    from paddle_tpu.observability.metrics import series_total
+
+    model, cfg = seeded()
+    doc = prompts(cfg, [43], seed=9)[0]
+    busy = prompts(cfg, [5], seed=11)[0]
+    asked = [np.concatenate([doc, q])
+             for q in prompts(cfg, [6, 11, 3], seed=10)] + [doc[:40]]
+
+    def serve(engine):
+        engine.add_request(busy, max_new_tokens=80)
+        rids = [engine.add_request(asked[0], max_new_tokens=8)]
+        while engine.cache.num_cached_blocks < 5:
+            engine.step()
+        rids += [engine.add_request(p, max_new_tokens=8)
+                 for p in asked[1:]]
+        out = engine.drain()
+        return [out[r] for r in rids]
+
+    alone = engine_for(model, enable_prefix_cache=False)
+    cold = []
+    for p in asked:
+        rid = alone.add_request(p, max_new_tokens=8)
+        cold.append(alone.run()[rid])
+    two = engine_for(model, num_slots=5, async_core=False)
+    fused = engine_for(model, num_slots=5)
+    assert serve(two) == cold
+    assert serve(fused) == cold
+    assert fused._fused is not None and fused.decode_steps_with_chunk > 0
+    # the two later questions hit the document's 5 full blocks, and the
+    # prompt of those blocks alone hits all of itself
+    assert fused.prefix_hit_tokens == two.prefix_hit_tokens == 3 * 40
+    assert series_total(fused.metrics_snapshot(),
+                        "engine_cow_copies_total") == 1
+    # the document's 5 blocks, then each question's own full blocks
+    own = sum(len(p) // 8 - 5 for p in asked)
+    assert fused.cache.num_cached_blocks == 5 + own \
+        + len(busy) // 8 == two.cache.num_cached_blocks
+
+
+def test_the_fused_steps_counters(fused_step_offered, expert_loads):
+    """The lanes and the rows walked are the two-program order's; the
+    experts' counts are those of ONE product over both row sets (each
+    call's loads as the product runs, in tiles of 4 rows); and
+    `decode_steps_with_chunk` counts the fused steps."""
+    from paddle_tpu.observability.metrics import series_total
+
+    model, cfg = seeded()
+    kw = dict(num_slots=3, prefill_chunk=16)
+    _, eng_s = serve_late(model, cfg, async_core=False, **kw)
+    del expert_loads[:]
+    _, eng = serve_late(model, cfg, **kw)
+    jax.effects_barrier()
+    t, t_s = eng.step_counter_totals, eng_s.step_counter_totals
+    for name in ("decode_live_lanes", "mla_context_rows"):
+        assert t[name] == t_s[name] > 0
+    experts = cfg.num_hidden_layers - cfg.first_k_dense_replace
+    fused = [s for rows, s in expert_loads if rows == 16 + 3]
+    stepped = [s for rows, s in expert_loads if rows == 3]
+    assert len(fused) == experts * eng.decode_steps_with_chunk > 0
+    assert len(fused) + len(stepped) == experts * eng.decode_steps
+    counted = fused + stepped
+    assert t["moe_assignments_held"] == sum(int(s.sum()) for s in counted)
+    assert t["moe_experts_touched"] == sum(int((s > 0).sum())
+                                           for s in counted)
+    assert t["moe_row_tiles"] == sum(int((-(-s // 4)).sum())
+                                     for s in counted)
+    assert series_total(eng.metrics_snapshot(),
+                        "engine_decode_steps_with_chunk_total") == \
+        eng.decode_steps_with_chunk
+    assert t_s["decode_steps_with_chunk"] == 0
+
+
+@pytest.mark.parametrize("offered", [True, False],
+                         ids=["offered", "not_offered"])
+def test_the_fused_step_traces_once_an_iteration_holds_both(request,
+                                                            offered):
+    """`decode_traces` is 1 until an iteration holds a chunk AND lanes
+    that decode, then 2 where the spec offers the fused step, and stays 1
+    where it does not."""
+    if offered:
+        request.getfixturevalue("fused_step_offered")
+    model, cfg = seeded()
+    a, b = prompts(cfg, [20, 30], seed=5)
+    eng = engine_for(model)
+    eng.add_request(a, max_new_tokens=4)
+    eng.run()
+    # a chunk with no lane beside it runs alone
+    assert (eng.decode_traces, eng.decode_steps_with_chunk) == (1, 0)
+    eng.add_request(a, max_new_tokens=12)
+    eng.step()
+    eng.step()
+    eng.add_request(b, max_new_tokens=4)
+    eng.run()
+    assert (eng._fused is not None) is offered
+    assert eng.decode_traces == (2 if offered else 1)
+    assert eng.prefill_traces == 1
+    assert (eng.decode_steps_with_chunk > 0) is offered
+
+
+@pytest.mark.parametrize("on_chip,widths,dense,offered", [
+    (False, (256, 128), 1, False), (True, (256, 128), 1, True),
+    (True, (64, 32), 1, False), (True, (256, 128), 3, False)],
+    ids=["off_the_chip", "the_kernels_widths", "a_narrow_width",
+         "no_expert_layer"])
+def test_the_fused_step_is_offered_where_the_experts_run_the_kernel(
+        monkeypatch, on_chip, widths, dense, offered):
+    """The hybrid's rule (`distributed/moe.one_product_reads_less`) at
+    the width `expert_share` resolves its product from: the narrower of
+    the hidden size and the gate-and-up product's."""
+    monkeypatch.setattr("paddle_tpu.core.device.on_tpu", lambda: on_chip)
+    model, cfg = seeded(dense=dense)
+    cfg.hidden_size, cfg.moe_intermediate_size = widths
+    assert model.serving_spec().offers_decode_with_chunk is offered
